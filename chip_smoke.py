@@ -1,0 +1,514 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on
+the chip.
+
+One process drives the parameter server's main path through the entry
+point a user calls (`kafka_ps_tpu.cli.run.main`) at the full width of
+the models the repo supports, on data made from a seed:
+
+  * the per-node message path (producer → buffers → k-step solver →
+    consistency gate → apply → async eval), logreg F=1024 C=5, 4
+    workers, default flags, once per consistency model (-c 0, 2, -1);
+  * the same path with `--pallas` (compiled Mosaic kernels, no
+    fallback);
+  * fused BSP at the widest model, `--task mlp --hidden_dim 4096`
+    (≈4.2 M parameters), at `--eval_every 1` (per-round program) and
+    `--eval_every 8` (the 8-round scan chunk);
+  * every Pallas kernel variant, compiled, against the XLA solver;
+  * with more than one chip: `--fused -r` and `--fused --param_shards`
+    over all of them.
+
+Each phase is checked by the repo's own means (the eval CSVs, the
+staleness auditor, the eval engine's lag, where theta lives) and the
+first failed check ends the run with a traceback and a non-zero exit.
+The last line of stdout is one JSON object; nothing is printed there
+unless every phase passed.
+
+Exit codes: 0 all phases passed on a TPU; 2 JAX found no TPU (says what
+it found); anything else a failed phase.  `python chip_smoke.py`, no
+arguments; under 1200 s cold.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import json
+import os
+import sys
+import tempfile
+import time
+
+NUM_CLASSES = 5
+CHANCE_F1 = 1.0 / NUM_CLASSES     # hard regime: offline ceiling ≈ 0.54
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """What the phases run at.  The defaults are the contract (full
+    width); tests/test_chip_smoke.py shrinks them for the CPU."""
+
+    num_features: int = 1024
+    fused_hidden: int = 4096      # widest supported model (fused BSP)
+    kernel_hidden: int = 128      # resident MLP kernel's width
+    buffer_min: int = 128
+    buffer_max: int = 1024        # slab rows per worker
+    train_rows: int = 4096        # 4 workers x 1024 rows
+    test_rows: int = 2000
+    per_node_clocks: int = 32
+    pallas_clocks: int = 8
+    fused_rounds: int = 24        # 3 scan chunks at --eval_every 8
+    multichip_rounds: int = 8
+    center_scale: float = 0.2     # synth.HARD_CENTER_SCALE: class overlap
+    interpret: bool = False       # kernels phase: Pallas interpreter
+
+
+class SmokeFailure(RuntimeError):
+    """A phase check did not hold."""
+
+
+def require(cond, phase: str, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(f"[{phase}] {what}")
+
+
+# -- shared plumbing ---------------------------------------------------------
+
+
+def make_data(workdir: str, sizes: Sizes) -> tuple[str, str]:
+    """Seeded train/test CSVs (kafka_ps_tpu.data.synth; the hard,
+    non-separable regime at full width) — nothing outside the checkout,
+    no network."""
+    from kafka_ps_tpu.data import synth
+    x, y = synth.generate(sizes.train_rows + sizes.test_rows,
+                          sizes.num_features, NUM_CLASSES, seed=0,
+                          center_scale=sizes.center_scale)
+    train = os.path.join(workdir, "train.csv")
+    test = os.path.join(workdir, "test.csv")
+    synth.write_csv(train, x[:sizes.train_rows], y[:sizes.train_rows])
+    synth.write_csv(test, x[sizes.train_rows:], y[sizes.train_rows:])
+    return train, test
+
+
+@contextlib.contextmanager
+def _in_dir(path: str):
+    os.makedirs(path)
+    cwd = os.getcwd()
+    os.chdir(path)
+    try:
+        yield
+    finally:
+        os.chdir(cwd)
+
+
+def run_cli(phase: str, phase_dir: str, argv: list[str]):
+    """One run through the normal entry point, in this process, from a
+    directory of its own (the CLI writes ./logs-*.csv).  Returns the
+    StreamingPSApp the run built (observed, not altered) and the wall
+    times around it."""
+    from kafka_ps_tpu.cli import run as run_mod
+
+    seen = {}
+    real = run_mod.make_app_from_args
+
+    def observe(*args, **kwargs):
+        app, logs = real(*args, **kwargs)
+        seen["app"], seen["built_at"] = app, time.time()
+        return app, logs
+
+    run_mod.make_app_from_args = observe
+    started = time.time()
+    try:
+        with _in_dir(phase_dir):
+            rc = run_mod.main(argv)
+    finally:
+        run_mod.make_app_from_args = real
+    require(rc == 0, phase, f"cli.run.main returned {rc}")
+    return seen["app"], {"started": started, "built_at": seen["built_at"],
+                         "ended": time.time()}
+
+
+def _platform_of(array) -> str:
+    return next(iter(array.devices())).platform
+
+
+def check_run(phase: str, phase_dir: str, app, times: dict, *,
+              consistency: int, owed_clocks: list[int],
+              platform: str, theta_on_device: bool = True,
+              learns: bool = True) -> dict:
+    """The checks every CLI phase owes, by the repo's own means.
+    `learns=False` (the short multi-chip runs) keeps every check but
+    the two on learning quality."""
+    import numpy as np
+
+    from kafka_ps_tpu.evaluation import logs, validate
+
+    server_csv = os.path.join(phase_dir, "logs-server.csv")
+    worker_csv = os.path.join(phase_dir, "logs-worker.csv")
+    for path in (server_csv, worker_csv):
+        with open(path) as f:
+            require("nan" not in f.read().lower(), phase,
+                    f"{os.path.basename(path)} holds a nan")
+    server = logs.load_server_log(server_csv)
+    worker = logs.load_worker_log(worker_csv)
+
+    got = [int(c) for c in server["vectorClock"]]
+    require(got == owed_clocks, phase,
+            f"logs-server.csv clocks {got} != the rows the eval cadence "
+            f"owes {owed_clocks}")
+    for col in ("loss", "fMeasure", "accuracy"):
+        require(bool(np.isfinite(server[col]).all()), phase,
+                f"server {col} not finite")
+    require(bool(np.isfinite(worker["loss"]).all()), phase,
+            "worker loss not finite")
+    first, last = server.iloc[0], server.iloc[-1]
+    if learns:
+        require(last["loss"] < first["loss"], phase,
+                f"test loss did not fall: clock "
+                f"{int(first['vectorClock'])} {first['loss']:.4f} -> clock "
+                f"{int(last['vectorClock'])} {last['loss']:.4f}")
+        require(last["fMeasure"] > CHANCE_F1 + 0.05, phase,
+                f"final F1 {last['fMeasure']:.3f} not above chance "
+                f"({CHANCE_F1:.2f})")
+
+    violations = validate.validate_run(worker, server, consistency)
+    require(violations == [], phase, f"validate_run: {violations}")
+    if app.eval_engine is not None:
+        require(app.eval_engine.lag_clocks == 0, phase,
+                f"eval_lag_clocks {app.eval_engine.lag_clocks} != 0")
+
+    import jax
+    theta = app.server.theta
+    if theta_on_device:
+        require(isinstance(theta, jax.Array), phase,
+                f"final theta is a {type(theta).__name__}, not a device "
+                "array")
+        require(_platform_of(theta) == platform, phase,
+                f"final theta lives on {_platform_of(theta)}, not "
+                f"{platform}")
+    require(bool(np.isfinite(np.asarray(theta)).all()), phase,
+            "final theta not finite")
+
+    first_row = float(worker["timestamp"].iloc[0]) / 1e3
+    return {
+        "solver": app.solver_program,
+        "wall_s": round(times["ended"] - times["started"], 2),
+        # test CSV load + app construction
+        "setup_s": round(times["built_at"] - times["started"], 2),
+        # first call: app built -> first worker row (stream prefill,
+        # compile, first dispatch returned)
+        "first_row_s": round(first_row - times["built_at"], 2),
+        # steady: first worker row -> run end (the remaining clocks,
+        # the device queue drained, logs flushed, threads joined) —
+        # row stamps are enqueue times, so only the END is a sync point
+        "rest_s": round(times["ended"] - first_row, 2),
+        "clocks": int(worker["vectorClock"].nunique()),
+        "eval_rows": len(got),
+        "loss": [round(float(first["loss"]), 4),
+                 round(float(last["loss"]), 4)],
+        "f1": round(float(last["fMeasure"]), 3),
+    }
+
+
+def _common_flags(train: str, test: str, sizes: Sizes) -> list[str]:
+    return ["-training", train, "-test", test, "-l", "-p", "0",
+            "--num_features", str(sizes.num_features),
+            "--num_classes", str(NUM_CLASSES),
+            "-min", str(sizes.buffer_min), "-max", str(sizes.buffer_max)]
+
+
+# -- phases ------------------------------------------------------------------
+
+
+def phase_per_node(workdir: str, train: str, test: str, sizes: Sizes,
+                   platform: str, consistency: int,
+                   pallas: bool = False) -> dict:
+    """The per-node message path — the one that carries every
+    subsystem — with default flags: threaded, gang on, async eval on,
+    k=2, eval every clock."""
+    workers = 4
+    clocks = sizes.pallas_clocks if pallas else sizes.per_node_clocks
+    name = f"per_node_c{consistency}" + ("_pallas" if pallas else "")
+    argv = _common_flags(train, test, sizes) + [
+        "-c", str(consistency), "--num_workers", str(workers),
+        "--max_iterations", str(workers * clocks)]
+    if pallas:
+        argv.append("--pallas")
+
+    from kafka_ps_tpu.ops import fused_update
+    traced = dict(fused_update.TRACE_COUNTS)
+    phase_dir = os.path.join(workdir, name)
+    app, times = run_cli(name, phase_dir, argv)
+
+    # one eval row per worker-0 gradient the server applied
+    applied = app.server.tracker.tracker[0].vector_clock
+    if consistency == 0:
+        require(applied == clocks, name,
+                f"BSP applied {applied} clocks of worker 0, not {clocks}")
+    rec = check_run(name, phase_dir, app, times, consistency=consistency,
+                    owed_clocks=list(range(applied)), platform=platform)
+    for w in app.workers:
+        require(_platform_of(w.theta) == platform, name,
+                f"worker {w.worker_id} replica lives on "
+                f"{_platform_of(w.theta)}")
+    kernels = {k: fused_update.TRACE_COUNTS[k] - traced[k] for k in traced}
+    if pallas:
+        require(rec["solver"].startswith("pallas-"), name,
+                f"--pallas ran solver {rec['solver']!r}")
+        require(kernels["resident"] + kernels["batched"] > 0, name,
+                f"--pallas traced no kernel program: {kernels}")
+        rec["kernels_traced"] = kernels
+    else:
+        require(not any(kernels.values()), name,
+                f"XLA phase traced kernel programs: {kernels}")
+    return rec
+
+
+def phase_fused(workdir: str, train: str, test: str, sizes: Sizes,
+                platform: str, eval_every: int) -> dict:
+    """Fused BSP at the widest supported model: eval_every 1 is the
+    per-round program, eval_every 8 the 8-round scan chunk
+    (StreamingPSApp.FUSED_CHUNK_ROUNDS)."""
+    workers = 4
+    name = f"fused_mlp{sizes.fused_hidden}_eval{eval_every}"
+    argv = _common_flags(train, test, sizes) + [
+        "--fused", "--task", "mlp", "--hidden_dim", str(sizes.fused_hidden),
+        "--num_workers", str(workers), "--eval_every", str(eval_every),
+        "--max_iterations", str(workers * sizes.fused_rounds)]
+    phase_dir = os.path.join(workdir, name)
+    app, times = run_cli(name, phase_dir, argv)
+    owed = [c for c in range(1, sizes.fused_rounds + 1)
+            if c % eval_every == 0]
+    rec = check_run(name, phase_dir, app, times, consistency=0,
+                    owed_clocks=owed, platform=platform)
+    chunked = "multi_step" in next(iter(app._fused_programs.values()))
+    require(chunked == (eval_every >= app.FUSED_CHUNK_ROUNDS), name,
+            f"scan-chunk program built={chunked} at eval_every={eval_every}")
+    rec["program"] = (f"bsp-scan-{app.FUSED_CHUNK_ROUNDS}" if chunked
+                      else "bsp-step")
+    rec["params"] = int(app.server.task.num_params)
+    return rec
+
+
+def phase_kernels(sizes: Sizes, platform: str) -> dict:
+    """Every Pallas variant at a shape its own selector admits,
+    compiled (interpret only where `sizes` asks by name), against the
+    XLA solver on the same inputs.  Compared by tolerance: both sides
+    run default-precision MXU passes in different orders."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.compress.slab import decode_x, encode_x
+    from kafka_ps_tpu.data import synth
+    from kafka_ps_tpu.models.task import get_task
+    from kafka_ps_tpu.ops import fused_update as fu
+    from kafka_ps_tpu.utils.config import ModelConfig
+
+    f, b, members = sizes.num_features, sizes.buffer_max, 4
+    cfg = ModelConfig(num_features=f, num_classes=NUM_CLASSES,
+                      hidden_dim=sizes.kernel_hidden)
+    tasks = {"logreg": get_task("logreg", cfg), "mlp": get_task("mlp", cfg)}
+    single = {"logreg": fu.local_update, "mlp": fu.mlp_local_update}
+    batched = {"logreg": fu.local_update_batched,
+               "mlp": fu.mlp_local_update_batched}
+    rng = np.random.default_rng(1)
+
+    def theta_for(task_name):
+        if task_name == "mlp":
+            return tasks["mlp"].init_params()
+        return jnp.asarray(rng.normal(scale=0.05, size=cfg.num_params),
+                           jnp.float32)
+
+    def slab(rows, seed):
+        x, y = synth.generate_hard(rows, f, NUM_CLASSES, seed=seed)
+        mask = (np.arange(rows) < rows - 7).astype(np.float32)
+        return jnp.asarray(x), jnp.asarray(y), jnp.asarray(mask)
+
+    # every variant must be traced here for the trace counters to name
+    # its program: drop what the --pallas phase already built at these
+    # shapes (the persistent cache still serves the compiles)
+    jax.clear_caches()
+
+    # the smallest doubling of the slab the resident selector refuses
+    big = b
+    while fu.select_program("logreg", cfg, big, "f32")[0] == "resident":
+        big *= 2
+
+    out = {}
+
+    def check(name, want_program, run, reference):
+        traced = dict(fu.TRACE_COUNTS)
+        t0 = time.perf_counter()
+        delta, loss = jax.block_until_ready(run())
+        first_s = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        jax.block_until_ready(run())
+        again_s = time.perf_counter() - t0
+        built = [k for k in traced if fu.TRACE_COUNTS[k] > traced[k]]
+        require(built == [want_program], name,
+                f"traced {built}, selector should pick {want_program}")
+        d_ref, l_ref = jax.block_until_ready(reference())
+        require(_platform_of(delta) == platform, name,
+                f"kernel output lives on {_platform_of(delta)}")
+        delta, d_ref = np.asarray(delta), np.asarray(d_ref)
+        require(bool(np.isfinite(delta).all()), name, "delta not finite")
+        err = float(np.max(np.abs(delta - d_ref)))
+        scale = float(np.max(np.abs(d_ref)))
+        require(err <= 0.03 * scale, name,
+                f"delta off the XLA solver: max|err| {err:.3g} vs "
+                f"max|ref| {scale:.3g}")
+        loss, l_ref = np.asarray(loss), np.asarray(l_ref)
+        require(bool(np.allclose(loss, l_ref, rtol=5e-3)), name,
+                f"loss {loss} vs XLA {l_ref}")
+        out[name] = {"program": want_program,
+                     "first_call_s": round(first_s, 3),
+                     "second_call_ms": round(again_s * 1e3, 3),
+                     "max_abs_err": err, "ref_max_abs": scale}
+
+    for task_name, task in tasks.items():
+        theta = theta_for(task_name)
+        xla = jax.jit(task.local_update)
+        for kind, rows, want in (("f32", b, "resident"),
+                                 ("f32", big, "streaming"),
+                                 ("bf16", b, "streaming"),
+                                 ("int8", b, "streaming")):
+            x, y, mask = slab(rows, seed=rows % 97)
+            stored = encode_x(kind, x)
+            check(f"{task_name}_{want}_{kind}_B{rows}", want,
+                  lambda: single[task_name](theta, stored, y, mask, cfg=cfg,
+                                            interpret=sizes.interpret),
+                  lambda: xla(theta, decode_x(stored), y, mask))
+        parts = [slab(b, seed=10 + i) for i in range(members)]
+        xs, ys, ms = (jnp.stack([p[i] for p in parts]) for i in range(3))
+        thetas = jnp.stack([theta * (1 + 0.1 * i) for i in range(members)])
+        xla_b = jax.jit(jax.vmap(task.local_update))
+        check(f"{task_name}_batched_k{members}_B{b}", "batched",
+              lambda: batched[task_name](thetas, xs, ys, ms, cfg=cfg,
+                                         interpret=sizes.interpret),
+              lambda: xla_b(thetas, xs, ys, ms))
+    return out
+
+
+def phase_multichip(workdir: str, train: str, test: str, sizes: Sizes,
+                    platform: str, device_count: int) -> dict:
+    """The fused path over every chip of the host: `--fused -r` (1-D
+    worker mesh) with one and two workers per chip, logreg and the wide
+    MLP, and `--fused --param_shards 2` (workers x params mesh).  The
+    worker slabs must actually be sharded over all the devices and the
+    loss stay finite; eight rounds are too few to ask the wide MLP for
+    quality."""
+    from kafka_ps_tpu.parallel import bsp, range_sharded
+
+    out = {}
+    runs = [(task, w, "-r") for task in ("logreg", "mlp")
+            for w in (device_count, 2 * device_count)]
+    runs += [(task, device_count, "--param_shards")
+             for task in ("logreg", "mlp")]
+    for task, workers, mode in runs:
+        name = f"multichip_{task}_w{workers}_{mode.strip('-')}"
+        argv = _common_flags(train, test, sizes) + [
+            "--fused", "--task", task,
+            "--hidden_dim", str(sizes.fused_hidden),
+            "--num_workers", str(workers), "--eval_every", "4",
+            "--max_iterations", str(workers * sizes.multichip_rounds)]
+        argv += [mode, "2"] if mode == "--param_shards" else [mode]
+
+        placed = []                   # device sets of the sharded slabs
+        module = range_sharded if mode == "--param_shards" else bsp
+        real = module.shard_worker_batches
+
+        def observe(mesh, x, y, mask, real=real):
+            arrays = real(mesh, x, y, mask)
+            placed.append({len(a.sharding.device_set) for a in arrays})
+            return arrays
+
+        module.shard_worker_batches = observe
+        phase_dir = os.path.join(workdir, name)
+        try:
+            app, times = run_cli(name, phase_dir, argv)
+        finally:
+            module.shard_worker_batches = real
+        owed = [c for c in range(1, sizes.multichip_rounds + 1)
+                if c % 4 == 0]
+        # --param_shards hands the server a host copy of theta after
+        # every step (range_sharded.unshard_theta) — recorded, not
+        # changed here
+        rec = check_run(name, phase_dir, app, times, consistency=0,
+                        owed_clocks=owed, platform=platform,
+                        theta_on_device=mode == "-r", learns=False)
+        require(placed and all(s == {device_count} for s in placed), name,
+                f"worker slabs span {placed}, not {device_count} devices")
+        rec["slab_devices"] = device_count
+        if mode == "-r":
+            require(len(app.server.theta.devices()) == device_count, name,
+                    "replicated theta does not span every device")
+        out[name] = rec
+    return out
+
+
+# -- entry point -------------------------------------------------------------
+
+
+def run_phases(sizes: Sizes, platform: str, device_count: int,
+               workdir: str) -> dict:
+    """Every phase in order; the first failed check raises."""
+    train, test = make_data(workdir, sizes)
+    phases = {}
+    for c in (0, 2, -1):
+        phases[f"per_node_c{c}"] = phase_per_node(
+            workdir, train, test, sizes, platform, c)
+    phases["per_node_c0_pallas"] = phase_per_node(
+        workdir, train, test, sizes, platform, 0, pallas=True)
+    for eval_every in (1, 8):
+        phases[f"fused_mlp{sizes.fused_hidden}_eval{eval_every}"] = \
+            phase_fused(workdir, train, test, sizes, platform, eval_every)
+    phases["kernels"] = phase_kernels(sizes, platform)
+    if device_count > 1:
+        phases.update(phase_multichip(workdir, train, test, sizes,
+                                      platform, device_count))
+    return phases
+
+
+def _cache_entries(cache_dir: str) -> int:
+    return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+
+def main() -> int:
+    from kafka_ps_tpu import native
+    from kafka_ps_tpu.utils import device
+
+    device.configure_compile_cache()
+    found = device.device_summary()       # first backend use
+    if found["platform"] != "tpu":
+        print(f"chip_smoke: JAX found platform={found['platform']!r} "
+              f"({found['kind']} x{found['count']}), not a TPU — nothing "
+              "was run", file=sys.stderr)
+        return 2
+    print(device.startup_line(), flush=True)
+    cache_dir = device.compile_cache_dir()
+    cache_before = _cache_entries(cache_dir)
+    print(f"[ingest] csv parser = {native.status()}", flush=True)
+
+    t0 = time.time()
+    with tempfile.TemporaryDirectory(prefix="chip_smoke-") as workdir:
+        phases = run_phases(Sizes(), found["platform"], found["count"],
+                            workdir)
+    print(json.dumps({
+        "ok": True,
+        "device": {"platform": found["platform"], "kind": found["kind"],
+                   "count": found["count"]},
+        "versions": {k: found[k] for k in ("jax", "jaxlib", "libtpu")},
+        "compile_cache": {"dir": cache_dir,
+                          "entries_at_start": cache_before,
+                          "entries_at_end": _cache_entries(cache_dir)},
+        "csv_parser": native.status(),
+        "wall_s": round(time.time() - t0, 1),
+        "phases": phases,
+        "claim": None,
+    }), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -21,6 +21,14 @@
 #   deploy/launch_local_multihost.sh --agg [N_RELAYS] [server args...]
 #
 # Writes logs-server.csv (+ logs-worker*.csv) into $PWD.
+#
+# CPU BY DEFAULT, on purpose: every mode here starts several processes
+# on one machine, and an accelerator belongs to ONE process — a second
+# process that reaches for a TPU dies at start-up ("Unable to
+# initialize backend 'tpu': ... libtpu multi-process lockfile").  On a
+# machine with a chip, at most one process of a fleet may hold it (for
+# the server/worker split: the worker; the server stays on the CPU).
+# The chip check is `python chip_smoke.py`, one process.
 set -euo pipefail
 
 NPROCS="${1:-2}"

@@ -81,8 +81,8 @@ def build_parser(include_server_flags: bool = True,
                         "dedicated thread coalesces pending (theta, "
                         "clock) snapshots into batched vmap dispatches "
                         "and emits the SAME CSV rows in clock order "
-                        "(bitwise-identical to the fused path, "
-                        "docs/EVALUATION.md)")
+                        "(equal to the fused path's to float32 "
+                        "tolerance, docs/EVALUATION.md)")
     p.add_argument("--no-eval-async", dest="eval_async",
                    action="store_false",
                    help="fuse evaluation back into the apply dispatch "
@@ -197,8 +197,10 @@ def build_parser(include_server_flags: bool = True,
     p.add_argument("--pallas", action="store_true",
                    help="use the Pallas fused local-update kernel for "
                         "worker iterations — logreg and mlp families "
-                        "(ops/fused_update.py; auto-falls-back off-TPU "
-                        "or past the VMEM budget)")
+                        "(ops/fused_update.py).  TPU only, and no "
+                        "fallback: a backend or shape no kernel admits "
+                        "stops the run with the reason; the start-up "
+                        "line and [status] name the program in use")
     p.add_argument("--compress", default="none", metavar="CODEC",
                    help="compressed delta transport "
                         "(kafka_ps_tpu/compress/, docs/COMPRESSION.md): "
@@ -359,19 +361,11 @@ def load_test_csv(path: str, num_features: int):
     return x, y
 
 
-def make_app_from_args(args, resuming: bool = False,
-                       process_index: int = 0):
-    """`process_index` > 0 (a non-coordinator host of a multi-process
-    job) writes no server log and a process-suffixed worker log — one
-    writer per file on a shared filesystem (deploy/README.md)."""
-    from kafka_ps_tpu.runtime.app import StreamingPSApp
+def cfg_from_args(args):
     from kafka_ps_tpu.utils.config import (BufferConfig, ModelConfig,
                                            PSConfig, ServingConfig,
                                            StreamConfig, TierConfig)
-    from kafka_ps_tpu.utils.csvlog import (CsvLogSink, NullLogSink,
-                                           SERVER_HEADER, WORKER_HEADER)
-
-    cfg = PSConfig(
+    return PSConfig(
         num_workers=args.num_workers,
         consistency_model=args.consistency_model,
         task=args.task,
@@ -406,6 +400,18 @@ def make_app_from_args(args, resuming: bool = False,
             warm_bytes=getattr(args, "tier_warm_bytes", 0),
             page_params=getattr(args, "tier_page_params", 1024)),
     )
+
+
+def make_app_from_args(args, resuming: bool = False,
+                       process_index: int = 0):
+    """`process_index` > 0 (a non-coordinator host of a multi-process
+    job) writes no server log and a process-suffixed worker log — one
+    writer per file on a shared filesystem (deploy/README.md)."""
+    from kafka_ps_tpu.runtime.app import StreamingPSApp
+    from kafka_ps_tpu.utils.csvlog import (CsvLogSink, NullLogSink,
+                                           SERVER_HEADER, WORKER_HEADER)
+
+    cfg = cfg_from_args(args)
     test_x, test_y = load_test_csv(args.test_data_file_path,
                                    args.num_features)
     suffix = f".p{process_index}" if process_index else ""
@@ -455,16 +461,36 @@ def main(argv=None) -> int:
 
 
 def apply_platform_env() -> None:
-    """Deployment hook shared by every CLI entry (this runner and the
-    socket roles, cli/socket_mode.py): KPS_PLATFORM pins the JAX
-    platform (e.g. =cpu for a broker-less smoke run or a CPU-mesh CI
-    job).  Must happen before first backend use; a plain JAX_PLATFORMS
-    env var can be overridden by accelerator plugins at interpreter
-    start."""
+    """Start-up hook shared by every CLI entry (this runner and the
+    socket roles, cli/socket_mode.py), run before first backend use:
+    KPS_PLATFORM pins the JAX platform for deployments that set the
+    program's own variable (deploy/: =cpu for a broker-less smoke run
+    or a CPU-mesh CI job — plain JAX_PLATFORMS works the same), and the
+    persistent compile cache is placed (utils/device.py)."""
+    from kafka_ps_tpu.utils import device
     platform = os.environ.get("KPS_PLATFORM")
     if platform:
         import jax
         jax.config.update("jax_platforms", platform)
+    device.configure_compile_cache()
+
+
+def announce_device(cfg, fused: bool = False) -> None:
+    """The ONE start-up line every entry point prints to stderr: where
+    the process runs (platform, device_kind, count — first backend use
+    happens here), the stack versions, the compile cache, and which
+    solver program `cfg` selects.  A `--pallas` request no kernel can
+    serve stops here, with the shape and the reason."""
+    from kafka_ps_tpu.ops.fused_update import PallasUnavailable
+    from kafka_ps_tpu.runtime.worker import solver_program
+    from kafka_ps_tpu.utils import device
+    try:
+        solver = "fused-bsp" if fused else solver_program(cfg)
+    except PallasUnavailable as e:
+        print(device.startup_line(solver="refused"), file=sys.stderr,
+              flush=True)
+        raise SystemExit(f"--pallas: {e}") from None
+    print(device.startup_line(solver=solver), file=sys.stderr, flush=True)
 
 
 def run_with_args(args) -> int:
@@ -556,6 +582,7 @@ def run_with_args(args) -> int:
         print("\nUsed parameter:")
         for k, v in sorted(vars(args).items()):
             print(f"    {k}: {v}")
+    announce_device(cfg_from_args(args), fused=args.fused)
 
     process_index = 0
     if distributed:

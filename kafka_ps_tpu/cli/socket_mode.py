@@ -32,7 +32,7 @@ from kafka_ps_tpu.runtime import fabric as fabric_mod
 from kafka_ps_tpu.runtime import net
 
 def _make_cfg(args):
-    from kafka_ps_tpu.cli.run import apply_platform_env
+    from kafka_ps_tpu.cli.run import announce_device, apply_platform_env
     from kafka_ps_tpu.utils.config import (BufferConfig, ModelConfig,
                                            PSConfig, StreamConfig,
                                            TierConfig)
@@ -45,7 +45,7 @@ def _make_cfg(args):
             "--tier-warm-bytes demotes pages to commit-log records; "
             "run with --durable-log DIR so the cold partition has a "
             "home (docs/TIERING.md)")
-    return PSConfig(
+    cfg = PSConfig(
         num_workers=args.num_workers,
         consistency_model=getattr(args, "consistency_model", 0),
         task=args.task,
@@ -74,6 +74,8 @@ def _make_cfg(args):
             warm_bytes=getattr(args, "tier_warm_bytes", 0),
             page_params=getattr(args, "tier_page_params", 1024)),
     )
+    announce_device(cfg)
+    return cfg
 
 
 def _codec_spec(args):

@@ -16,6 +16,7 @@ host→device slab transfer rather than a JSON round-trip through a broker.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 from typing import Callable, Iterator
@@ -23,28 +24,33 @@ from typing import Callable, Iterator
 Sink = Callable[[int, dict[int, float], int], None]  # (worker, features, label)
 
 
-def iter_csv_rows(csv_path: str, has_header: bool = True,
+def open_csv_rows(csv_path: str, has_header: bool = True,
                   num_features: int | None = None,
                   use_native: bool | None = None
-                  ) -> Iterator[tuple[dict[int, float], int]]:
-    """Yield (sparse_features, label) per CSV row, dropping zero features
+                  ) -> tuple[str, Iterator[tuple[dict[int, float], int]]]:
+    """(parser, rows): which parser serves this file — the native one's
+    `status()` or "python" with the reason — and the iterator of
+    (sparse_features, label) per CSV row, zero features dropped
     (CsvProducer.java:52-58).
 
     `use_native`: True forces the C++ parser (kafka_ps_tpu.native),
     False forces pure Python, None (default) auto-selects — the native
     path parses the whole file in one pass and replays rows; the Python
     path streams line by line."""
+    parser = "python (forced)"
     if use_native is not False:
         from kafka_ps_tpu import native
+        parser = native.status()
         parsed = None
         if native.is_available():
             try:
                 parsed = native.parse_csv(csv_path, has_header=has_header)
-            except RuntimeError:
+            except RuntimeError as e:
                 # the C parser is stricter (uniform width, no stray
                 # whitespace); on auto-select fall through to Python
                 if use_native:
                     raise
+                parser = f"python (native parser refused the file: {e})"
         elif use_native:
             raise RuntimeError("native CSV parser requested but unavailable")
         if parsed is not None:
@@ -53,9 +59,13 @@ def iter_csv_rows(csv_path: str, has_header: bool = True,
                 raise ValueError(
                     f"rows have {parsed.num_features + 1} columns, "
                     f"expected {num_features + 1}")
-            for i in range(parsed.num_rows):
-                yield parsed.row(i)
-            return
+            return parser, (parsed.row(i) for i in range(parsed.num_rows))
+    return parser, _python_rows(csv_path, has_header, num_features)
+
+
+def _python_rows(csv_path: str, has_header: bool,
+                 num_features: int | None
+                 ) -> Iterator[tuple[dict[int, float], int]]:
     with open(csv_path) as f:
         if has_header:
             f.readline()
@@ -70,6 +80,17 @@ def iter_csv_rows(csv_path: str, has_header: bool = True,
             feats = {i: float(v) for i, v in enumerate(cols[:-1])
                      if float(v) != 0.0}
             yield feats, int(float(cols[-1]))
+
+
+def iter_csv_rows(csv_path: str, has_header: bool = True,
+                  num_features: int | None = None,
+                  use_native: bool | None = None
+                  ) -> Iterator[tuple[dict[int, float], int]]:
+    """The rows of `open_csv_rows`, for callers that do not report the
+    parser.  Lazy like a generator: nothing is opened or parsed before
+    the first row is asked for."""
+    yield from open_csv_rows(csv_path, has_header, num_features,
+                             use_native)[1]
 
 
 class CsvStreamProducer:
@@ -109,9 +130,14 @@ class CsvStreamProducer:
         # <= 0 means unthrottled (no pacing at all).
         rows_per_sleep = (max(1, int(1000 / self.time_per_event_ms))
                           if self.time_per_event_ms > 0 else 0)
-        for feats, label in iter_csv_rows(self.csv_path, self.has_header,
-                                          self.num_features,
-                                          use_native=self.use_native):
+        # the parser choice used to be silent; a run now says which one
+        # fed it (stderr, beside the [device] start-up line)
+        parser, rows = open_csv_rows(self.csv_path, self.has_header,
+                                     self.num_features,
+                                     use_native=self.use_native)
+        print(f"[ingest] {self.csv_path}: csv parser = {parser}",
+              file=sys.stderr, flush=True)
+        for feats, label in rows:
             if self.stopped.is_set():
                 break
             worker = self.rows_sent % self.num_workers

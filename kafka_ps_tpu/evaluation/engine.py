@@ -3,10 +3,9 @@ the apply critical path (docs/EVALUATION.md "Async evaluation").
 
 The reference evaluates the full test set inside every server iteration
 (ServerProcessor.java:153-165); our fused port kept that shape — eval
-rides the apply dispatch (`ServerNode._apply_full_eval`) and costs ~2x
-per-node throughput at `eval_every=1` (BENCH r5: 148 vs 295 iters/s),
-because each eval re-reads the whole test set for a single theta — a
-memory-bound pass (docs/ROOFLINE.md).
+rides the apply dispatch (`ServerNode._apply_full_eval`), so each eval
+re-reads the whole test set for a single theta.  What that costs on
+the chip is not measured yet (PERF.md).
 
 This engine is the serving plane's batching economics (Clipper-style,
 serving/engine.py) applied to evaluation:
@@ -17,11 +16,12 @@ serving/engine.py) applied to evaluation:
     ServerNode only ever REPLACES theta, never mutates it), so enqueue
     costs no copy and no host sync;
   * a dedicated `kps-eval` thread pops the whole backlog and evaluates
-    k pending thetas as ONE batched dispatch — the vmap-of-kernel
-    construction PR 2 proved bitwise for gang solvers (runtime/gang.py
-    stacks thetas the same way): vmap runs the identical per-element
-    program, so each row's metrics are bit-identical to a standalone
-    eval of that theta;
+    k pending thetas as ONE batched dispatch — the vmap construction
+    the gang solvers use (runtime/gang.py stacks thetas the same way).
+    Each row's F1/accuracy equal a standalone eval of that theta; the
+    loss agrees to float32 tolerance — XLA may reduce the loss mean of
+    a wider vmap program in another order (1 ulp at width 8 under
+    jaxlib 0.9.0 on the CPU; tests/test_eval_engine.py states it);
   * results are emitted in strict clock order whatever the coalescing
     did, through the SAME emission point the fused path uses
     (`ServerNode._emit_eval`): CSV rows, `last_metrics`, and
@@ -29,10 +29,15 @@ serving/engine.py) applied to evaluation:
 
 Coalescing widths bucket to powers of two (pad by REPEATING the last
 theta and discard the extra rows — vmap rows are independent, so
-padding is bitwise-neutral) and are capped by the fused-update tile
-budget (`coalesce_width_cap`): chunking happens over pending thetas,
-NEVER over the test set — splitting X_test would reorder the loss-mean
-reduction and break the bitwise contract.
+padding never changes the kept rows) and are capped by the
+fused-update tile budget (`coalesce_width_cap`): chunking happens over
+pending thetas, NEVER over the test set — splitting X_test would
+change what the loss mean averages over, not just its rounding.
+
+A dispatch that raises (a compile failure, a device error) is KEPT:
+the engine stops, and the error re-raises to the next submitter, from
+`drain()` and from `close()` — a run whose eval rows stopped coming
+must not exit 0.
 
 Crash story: the engine holds no durable state.  Pending-eval clocks
 are exactly the eval-cadence clocks of gradients the durable log will
@@ -47,7 +52,6 @@ runtime/server.py), PS106 (telemetry calls carry host ints only).
 
 from __future__ import annotations
 
-import sys
 import threading
 from collections import deque
 
@@ -141,6 +145,9 @@ class EvalEngine:
         self._pending: deque = deque()
         self._cv = OrderedCondition("EvalEngine.pending")
         self._inflight = 0           # popped but not yet emitted
+        # guarded-by: _cv — first dispatch failure; once set the engine
+        # is dead and every entry point re-raises it
+        self._error: BaseException | None = None
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         # host-side counters for /evalz (telemetry/health.py)
@@ -166,7 +173,8 @@ class EvalEngine:
         past `max_pending` makes the SUBMITTER wait for the engine to
         catch up (each queued theta pins a device array; the bound is
         the memory cap, and dropping is not an option — every clock
-        owes a CSV row under the bitwise contract)."""
+        owes a CSV row)."""
+        self._raise_if_failed()
         clock = int(clock)
         with self._cv:
             self._pending.append((theta, clock))
@@ -198,6 +206,12 @@ class EvalEngine:
                     target=self._loop, daemon=True, name="kps-eval")
                 self._thread.start()
 
+    def _raise_if_failed(self) -> None:
+        with self._cv:
+            err = self._error
+        if err is not None:
+            raise RuntimeError("eval engine dispatch failed") from err
+
     def _loop(self) -> None:
         idle = 0.0
         tick = 0.25
@@ -205,7 +219,11 @@ class EvalEngine:
             with self._cv:
                 if not self._pending:
                     self._cv.wait(timeout=tick)
-            if not self.poll():
+            try:
+                progressed = self.poll()
+            except Exception:
+                return      # kept in _error: submit/drain/close re-raise
+            if not progressed:
                 idle += tick
                 if idle >= self._idle_exit:
                     with self._cv:
@@ -229,8 +247,11 @@ class EvalEngine:
             self._inflight = len(batch)
         try:
             self._dispatch(batch)
-        except Exception as e:       # pragma: no cover - diagnostics
-            print(f"eval engine dispatch error: {e!r}", file=sys.stderr)
+        except BaseException as e:
+            with self._cv:
+                if self._error is None:
+                    self._error = e
+            raise
         finally:
             with self._cv:
                 self._inflight = 0
@@ -305,13 +326,15 @@ class EvalEngine:
         be in flight — DeferredSink.flush owns those).  Drive loops
         call this at exit so `eval_lag_clocks` returns to 0 and the
         CSV is complete before sinks flush."""
+        self._raise_if_failed()
         if self._start_thread:
             self._ensure_thread()
             with self._cv:
                 ok = self._cv.wait_for(
                     lambda: (not self._pending and self._inflight == 0)
-                    or self._stop.is_set(),
+                    or self._stop.is_set() or self._error is not None,
                     timeout=timeout)
+            self._raise_if_failed()
             if not ok:               # pragma: no cover - watchdog
                 raise TimeoutError("eval engine drain timed out")
         else:
@@ -321,18 +344,22 @@ class EvalEngine:
     def close(self) -> None:
         """Drain, stop and join the kps-eval thread (it dispatches jit
         programs — must be joined before interpreter exit,
-        docs/TESTING.md), then evaluate anything still pending inline."""
-        if self._start_thread and not self._stop.is_set():
-            try:
-                self.drain()
-            except TimeoutError:     # pragma: no cover - watchdog
-                pass
-        self._stop.set()
-        with self._cv:
-            self._cv.notify_all()
-            t = self._thread
-        if t is not None and t is not threading.current_thread():
-            t.join(timeout=60.0)
+        docs/TESTING.md), then evaluate anything still pending inline.
+        Re-raises a kept dispatch failure AFTER the join."""
+        try:
+            if self._start_thread and not self._stop.is_set():
+                try:
+                    self.drain()
+                except TimeoutError:     # pragma: no cover - watchdog
+                    pass
+        finally:
+            self._stop.set()
+            with self._cv:
+                self._cv.notify_all()
+                t = self._thread
+            if t is not None and t is not threading.current_thread():
+                t.join(timeout=60.0)
+        self._raise_if_failed()
         while self.poll():           # leftovers after a timed-out drain
             pass
 

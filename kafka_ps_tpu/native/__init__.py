@@ -12,4 +12,5 @@ from kafka_ps_tpu.native.binding import (  # noqa: F401
     NativeCsv,
     is_available,
     parse_csv,
+    status,
 )

@@ -1,15 +1,21 @@
 """ctypes binding for the native CSV parser (csvparse.cpp).
 
-Loads `libkpscsv.so` from the package directory, building it with make
-on first use if a toolchain is present.  `is_available()` gates callers;
-data/stream.py falls back to the pure-Python parser when it is False,
-so the framework has no hard native dependency.
+Loads `libkpscsv.so` from the package directory.  The library is
+git-ignored, so whatever sits on disk may have been built from another
+revision of the source (a tool that copies the working tree copies the
+binary too): the build stamps the binary with the SHA-256 of
+csvparse.cpp, and `_load` rebuilds with make unless the file on disk
+carries the stamp of the source beside it.  `is_available()` gates
+callers; data/stream.py uses the pure-Python parser when it is False
+and says so (`status()`), so the framework has no hard native
+dependency.
 """
 
 from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,9 +24,27 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SO = os.path.join(_DIR, "libkpscsv.so")
+_SRC = os.path.join(_DIR, "csvparse.cpp")
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
+_why_unavailable = ""
+
+
+def _source_sha256() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _built_from(sha: str) -> bool:
+    """Does the library on disk carry the stamp of this source?  Read
+    from the file, not through dlopen — a stale library must be
+    replaced BEFORE the one load this process gets."""
+    try:
+        with open(_SO, "rb") as f:
+            return f"KPS_SRC_SHA256={sha}".encode() in f.read()
+    except OSError:
+        return False
 
 
 class _ParsedCsv(ctypes.Structure):
@@ -36,21 +60,25 @@ class _ParsedCsv(ctypes.Structure):
 
 
 def _load():
-    global _lib, _build_failed
+    global _lib, _build_failed, _why_unavailable
     with _lock:
         if _lib is not None or _build_failed:
             return _lib
-        if not os.path.exists(_SO):
+        sha = _source_sha256()
+        if not _built_from(sha):
             try:
-                subprocess.run(["make", "-C", _DIR, "libkpscsv.so"],
+                subprocess.run(["make", "-B", "-C", _DIR, "libkpscsv.so",
+                                f"SRC_SHA256={sha}"],
                                check=True, capture_output=True, timeout=120)
-            except (OSError, subprocess.SubprocessError):
+            except (OSError, subprocess.SubprocessError) as e:
                 _build_failed = True
+                _why_unavailable = f"build failed: {e!r}"
                 return None
         try:
             lib = ctypes.CDLL(_SO)
-        except OSError:
+        except OSError as e:
             _build_failed = True
+            _why_unavailable = f"load failed: {e!r}"
             return None
         lib.kps_parse_csv.restype = ctypes.POINTER(_ParsedCsv)
         lib.kps_parse_csv.argtypes = [ctypes.c_char_p, ctypes.c_int]
@@ -62,6 +90,13 @@ def _load():
 
 def is_available() -> bool:
     return _load() is not None
+
+
+def status() -> str:
+    """Which parser serves ingestion in this process, and why."""
+    if is_available():
+        return f"native (csvparse.cpp sha256 {_source_sha256()[:12]})"
+    return f"python (native parser unavailable: {_why_unavailable})"
 
 
 @dataclasses.dataclass(frozen=True)

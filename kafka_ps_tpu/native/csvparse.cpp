@@ -10,13 +10,24 @@
 // without re-parsing.
 //
 // Build: make -C kafka_ps_tpu/native   (g++ -O3 -shared -fPIC)
+//
+// The build stamps the binary with the SHA-256 of this file
+// (-DKPS_SRC_SHA256, Makefile); binding.py refuses a library whose
+// stamp is not the hash of the csvparse.cpp beside it, so a stale
+// libkpscsv.so copied from another tree is rebuilt, never loaded.
 
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
 #include <vector>
 
+#ifndef KPS_SRC_SHA256
+#define KPS_SRC_SHA256 "unstamped"
+#endif
+
 extern "C" {
+
+const char *kps_source_stamp() { return "KPS_SRC_SHA256=" KPS_SRC_SHA256; }
 
 struct ParsedCsv {
     long num_rows;

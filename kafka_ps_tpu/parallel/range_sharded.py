@@ -35,12 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kafka_ps_tpu.parallel.mesh import PARAM_AXIS, WORKER_AXIS
 from kafka_ps_tpu.utils.config import ModelConfig
 
-# jax.shard_map graduated from jax.experimental in 0.5; support both
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def padded_num_params(layout, num_param_shards: int) -> int:
     """theta length padded so every param shard is equal-size (static
@@ -109,9 +103,7 @@ def make_range_sharded_step(cfg: ModelConfig, num_workers: int,
         # weights pull: reassemble the full replica from the server shards
         theta_full = jax.lax.all_gather(theta_shard, PARAM_AXIS, axis=0,
                                         tiled=True)
-        if hasattr(jax.lax, "pcast"):      # varying-axis annotation is
-            theta_full = jax.lax.pcast(    # jax >= 0.7; a no-op before
-                theta_full, WORKER_AXIS, to="varying")
+        theta_full = jax.lax.pcast(theta_full, WORKER_AXIS, to="varying")
         deltas, losses = jax.vmap(
             lambda xx, yy, mm: local_update_padded(theta_full, xx, yy, mm)
         )(x, y, mask)
@@ -134,7 +126,7 @@ def make_range_sharded_step(cfg: ModelConfig, num_workers: int,
         return theta, (losses[0] if rounds == 1 else losses)
 
     data_spec = P((WORKER_AXIS, PARAM_AXIS))
-    sharded = _shard_map(
+    sharded = jax.shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(PARAM_AXIS), data_spec, data_spec, data_spec),
         out_specs=(P(PARAM_AXIS), P()))

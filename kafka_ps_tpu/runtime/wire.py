@@ -31,7 +31,7 @@ The byte CONTENT of the stream is identical to the sequential
 `send_frame` path — same frames, same order per connection — so a
 coalescing fleet interoperates bit-for-bit with a `--no-wire-coalesce`
 one, and the bench's `wire_ab` block pins theta + eval CSV bitwise
-across the lever (scripts/bench_gate.py).
+across the lever.
 
 Telemetry: `wire_frames_per_syscall` (histogram, per flush),
 `wire_send_queue_depth` (gauge, bytes queued), `wire_advisory_dropped`

@@ -1,0 +1,136 @@
+"""chip_smoke.py on the CPU: the script refuses to run off the chip,
+and its phase functions — the same code the chip run executes — hold at
+a tiny width with interpreted kernels.  Plus the start-up contract the
+script shares with every CLI entry: the compile-cache hook."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+sys.path.insert(0, str(REPO))
+import chip_smoke  # noqa: E402
+
+TINY = chip_smoke.Sizes(
+    num_features=128, fused_hidden=32, kernel_hidden=32, buffer_min=8,
+    buffer_max=64, train_rows=512, test_rows=128, per_node_clocks=12,
+    pallas_clocks=4, fused_rounds=16, multichip_rounds=8,
+    center_scale=1.0,           # too few rows to learn the hard regime
+    interpret=True)
+
+
+def _run(script_dir, env_extra, *args):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **env_extra)
+    return subprocess.run([sys.executable, *args], cwd=script_dir, env=env,
+                          capture_output=True, text=True, timeout=120)
+
+
+def test_refuses_a_cpu_backend_naming_it():
+    r = _run(REPO, {}, "chip_smoke.py")
+    assert r.returncode == 2
+    assert "platform='cpu'" in r.stderr and "not a TPU" in r.stderr
+    assert r.stdout == ""            # no result of any kind
+
+
+def test_fails_without_the_program(tmp_path):
+    shutil.copy(REPO / "chip_smoke.py", tmp_path)
+    r = _run(tmp_path, {"PYTHONPATH": ""}, "chip_smoke.py")
+    assert r.returncode not in (0, 2)
+    assert "kafka_ps_tpu" in r.stderr
+    assert r.stdout == ""
+
+
+@pytest.fixture(scope="module")
+def data(tmp_path_factory):
+    workdir = tmp_path_factory.mktemp("chip_smoke")
+    train, test = chip_smoke.make_data(str(workdir), TINY)
+    return str(workdir), train, test
+
+
+@pytest.mark.parametrize("consistency", [0, 2, -1])
+def test_per_node_phase(data, consistency):
+    workdir, train, test = data
+    rec = chip_smoke.phase_per_node(workdir, train, test, TINY, "cpu",
+                                    consistency)
+    assert rec["solver"] == "xla"
+    assert rec["eval_rows"] >= 1 and rec["loss"][1] < rec["loss"][0]
+
+
+def test_pallas_phase_refuses_off_the_chip(data, capsys):
+    """`--pallas` through the CLI means compiled kernels; on the CPU the
+    run stops with the reason instead of training on XLA."""
+    workdir, train, test = data
+    with pytest.raises(SystemExit, match="--pallas: compiled Mosaic "
+                                         "kernels need a TPU backend"):
+        chip_smoke.phase_per_node(workdir, train, test, TINY, "cpu", 0,
+                                  pallas=True)
+    assert "solver=refused" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("eval_every", [1, 8])
+def test_fused_phase(data, eval_every):
+    workdir, train, test = data
+    rec = chip_smoke.phase_fused(workdir, train, test, TINY, "cpu",
+                                 eval_every)
+    assert rec["program"] == ("bsp-step" if eval_every == 1
+                              else "bsp-scan-8")
+    assert rec["eval_rows"] == TINY.fused_rounds // eval_every
+
+
+def test_kernels_phase_interpreted():
+    out = chip_smoke.phase_kernels(TINY, "cpu")
+    programs = {rec["program"] for rec in out.values()}
+    assert programs == {"resident", "streaming", "batched"}
+    assert len(out) == 10            # 2 families x (4 single + 1 batched)
+
+
+def test_multichip_phase_on_the_virtual_mesh(data):
+    import jax
+    n = len(jax.devices())           # 8 virtual CPU devices (conftest)
+    assert n > 1
+    workdir, train, test = data
+    out = chip_smoke.phase_multichip(workdir, train, test, TINY, "cpu", n)
+    assert len(out) == 6
+    assert all(rec["slab_devices"] == n for rec in out.values())
+    assert {rec["solver"] for rec in out.values()} == {"fused-bsp"}
+
+
+def test_a_failed_check_raises(data):
+    workdir, train, test = data
+    with pytest.raises(chip_smoke.SmokeFailure, match="on cpu, not tpu"):
+        # the platform the caller expects is part of every phase's checks
+        chip_smoke.phase_per_node(workdir + "/wrong", train, test, TINY,
+                                  "tpu", 0)
+
+
+# -- the compile-cache hook (utils/device.py) --------------------------------
+
+_REPORT = ("import json, jax; from kafka_ps_tpu.cli.run import "
+           "apply_platform_env; apply_platform_env(); "
+           "print(json.dumps([jax.config.jax_compilation_cache_dir, "
+           "jax.config.jax_persistent_cache_min_compile_time_secs]))")
+
+
+def test_cache_path_is_fixed_inside_the_checkout():
+    env = {"JAX_COMPILATION_CACHE_DIR": ""}
+    a, b = (json.loads(_run(REPO, env, "-c", _REPORT).stdout)
+            for _ in range(2))
+    assert a == b == [str(REPO / ".jax_cache"), 0.0]
+
+
+def test_cache_placed_from_outside_is_left_alone(tmp_path):
+    placed = str(tmp_path / "elsewhere")
+    r = _run(REPO, {"JAX_COMPILATION_CACHE_DIR": placed}, "-c", _REPORT)
+    # JAX read its own variable; the hook set no directory of its own
+    assert json.loads(r.stdout) == [placed, 0.0]
+
+
+def test_suite_does_not_fill_the_checkout_cache():
+    """conftest disables the persistent cache for the suite and its
+    subprocesses: the chip tool copies the tree as it stands."""
+    assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"

@@ -216,16 +216,6 @@ def test_multi_step_mesh_matches_vmap():
                                atol=2e-5)
 
 
-def test_graft_entry_dryrun():
-    sys.path.insert(0, "/root/repo")
-    import __graft_entry__ as g
-    fn, args = g.entry()
-    import jax
-    loss = jax.jit(fn)(*args)
-    assert np.isfinite(float(loss))
-    g.dryrun_multichip(4)
-
-
 def test_eval_every_skips_offcadence_evals(tmp_path, monkeypatch):
     """--eval_every N: workers/server compute test metrics only on every
     Nth clock; off-cadence worker rows carry the reference's -1
@@ -455,3 +445,40 @@ def test_fused_chunking_range_sharded_mesh(tmp_path, monkeypatch):
         assert g["vectorClock"].tolist() == list(range(1, 25))
     assert validate.validate_run(w, s, consistency_model=0) == []
     assert s["loss"].iloc[-1] < s["loss"].iloc[0]
+
+
+def test_deferred_sink_keeps_a_fetch_failure_and_reraises(monkeypatch):
+    """A device scalar that cannot be fetched fails the run: the drain
+    thread keeps the error and submit/flush/close re-raise it — no row
+    is written with a nan standing in for the value."""
+    import time
+
+    import jax.numpy as jnp
+    import pytest
+
+    from kafka_ps_tpu.utils import asynclog
+
+    def device_lost(values):
+        raise FloatingPointError("injected fetch failure")
+    monkeypatch.setattr(asynclog, "_fetch_batched", device_lost)
+
+    lines = []
+    sink = asynclog.DeferredSink(lines.append, drain_interval=0.01)
+    sink.submit("row;{}", jnp.float32(1.5))
+    deadline = time.monotonic() + 10.0
+    while sink._error is None and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert isinstance(sink._error, FloatingPointError)
+    for entry in (lambda: sink.submit("row;{}", 2.0), sink.flush,
+                  sink.close):
+        with pytest.raises(RuntimeError, match="log drain failed"):
+            entry()
+    assert lines == []
+
+    # flush() on the caller's thread raises the fetch error directly
+    direct = asynclog.DeferredSink(lines.append, drain_interval=3600.0)
+    direct.submit("row;{}", jnp.float32(2.5))
+    with pytest.raises(FloatingPointError):
+        direct.flush()
+    direct.close()
+    assert lines == []

@@ -225,12 +225,15 @@ def test_backlog_coalesces_into_one_dispatch_in_clock_order():
     assert eng.stats()["widths"] == {"5": 1}
     assert [c for c, *_ in emitted] == [0, 1, 2, 3, 4]
     assert eng.lag_clocks == 0
-    # each coalesced row is bitwise-identical to a standalone eval
+    # each coalesced row equals a standalone eval to float32 tolerance:
+    # the width-8 vmap program reduces the loss mean in another order
+    # than the standalone one (1 ulp under jaxlib 0.9.0; widths 2 and 4
+    # happen to match bitwise), F1/accuracy come from integer counts
     import jax.numpy as jnp
     for (c, loss, f1, acc), t in zip(emitted, thetas):
         m = task.evaluate(jnp.asarray(t), jnp.asarray(x), jnp.asarray(y))
-        assert (loss, f1, acc) == (float(m.loss), float(m.f1),
-                                   float(m.accuracy))
+        assert loss == pytest.approx(float(m.loss), rel=1e-6)
+        assert (f1, acc) == (float(m.f1), float(m.accuracy))
 
 
 def test_width_cap_bounds_single_dispatch():
@@ -274,3 +277,52 @@ def test_coalesce_width_cap_properties():
     for np_, nt in [(6150, 64), (530_000, 2048), (10, 10)]:
         w = coalesce_width_cap(np_, nt)
         assert w >= 1 and (w & (w - 1)) == 0 and w <= _MAX_COALESCE
+
+
+# -- a failed dispatch fails the run (no exit 0 with rows missing) -----------
+
+def test_dispatch_failure_is_kept_and_reraised():
+    task, x, y, eng, emitted = _engine_fixture()
+    theta = np.zeros(task.num_params, np.float32)
+
+    def boom(batch):
+        raise FloatingPointError("injected device failure")
+    eng._dispatch = boom
+    eng.submit(theta, 0)
+    with pytest.raises(FloatingPointError):
+        eng.poll()                       # the dispatching caller sees it
+    for entry in (lambda: eng.submit(theta, 1), eng.drain, eng.close):
+        with pytest.raises(RuntimeError,
+                           match="eval engine dispatch failed") as info:
+            entry()
+        assert isinstance(info.value.__cause__, FloatingPointError)
+    assert emitted == []
+
+
+def test_cli_run_fails_on_an_eval_dispatch_error(tmp_path, monkeypatch):
+    """The default path (threaded, --eval-async on) through the CLI: an
+    eval dispatch that raises on the kps-eval thread must surface as a
+    failed run, not a return code 0 with the popped clocks' rows
+    silently missing."""
+    from kafka_ps_tpu.cli import run as run_mod
+    from kafka_ps_tpu.data.synth import generate, write_csv
+
+    monkeypatch.chdir(tmp_path)
+    x, y = generate(260, 16, 3, noise=1.0, sparsity=0.5, seed=0)
+    write_csv("train.csv", x[:200], y[:200])
+    write_csv("test.csv", x[200:], y[200:])
+    real = EvalEngine._dispatch
+    calls = []
+
+    def flaky(self, batch):
+        calls.append(len(batch))
+        if len(calls) > 2:
+            raise FloatingPointError("injected device failure")
+        return real(self, batch)
+    monkeypatch.setattr(EvalEngine, "_dispatch", flaky)
+    with pytest.raises(RuntimeError, match="eval engine dispatch failed"):
+        run_mod.main(["-training", "train.csv", "-test", "test.csv",
+                      "--num_features", "16", "--num_classes", "3",
+                      "--num_workers", "2", "-p", "1", "-l",
+                      "--max_iterations", "400"])
+    assert len(calls) == 3               # the engine stopped at the error

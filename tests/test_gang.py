@@ -97,12 +97,18 @@ def test_serial_gang_reduces_dispatches(consistency):
 
 
 @pytest.mark.parametrize("task,use_pallas", [("mlp", False),
-                                             ("logreg", True),
-                                             ("mlp", True)])
+                                             ("logreg", "interpret"),
+                                             ("mlp", "interpret")])
 def test_serial_gang_bitwise_other_families(task, use_pallas):
-    # use_pallas on CPU exercises the gang's pallas dispatch route with
-    # both arms on their XLA fallbacks — same-path-vs-same-path bitwise
+    # "interpret" asks for the kernels by name on the CPU: the gang arm
+    # runs the batched grid kernel, the per-message arm the resident
+    # one — the grid instance is the same kernel body on the same block
+    from kafka_ps_tpu.ops import fused_update
     res = run_serial_pair(0, task=task, use_pallas=use_pallas)
+    if use_pallas:
+        # no silent XLA: both kernel programs were actually traced
+        assert fused_update.TRACE_COUNTS["batched"] >= 1
+        assert fused_update.TRACE_COUNTS["resident"] >= 1
     assert res[True][0].tobytes() == res[False][0].tobytes()
     assert strip_ts(res[True][1]["worker"]) == \
         strip_ts(res[False][1]["worker"])

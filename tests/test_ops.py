@@ -58,38 +58,46 @@ def test_kernel_all_masked_rows_no_nan():
 
 
 def test_oversize_batch_streams_through_vmem():
-    """A batch too big for whole-slab VMEM residency now STREAMS through
-    the tiled double-buffered kernel (docs/PERFORMANCE.md) instead of
-    falling back to XLA — allow_fallback=False proves a kernel ran."""
+    """A batch too big for whole-slab VMEM residency STREAMS through
+    the tiled double-buffered kernel (docs/PERFORMANCE.md) — the trace
+    counter proves which program was built."""
     cfg = ModelConfig(num_features=512, num_classes=5)
     big = fused_update._VMEM_BYTE_BUDGET // (4 * cfg.num_features) + 8
     big += (-big) % 8
     x, y, mask = _batch(n=big, cfg=cfg)
     assert not fused_update.fits_in_vmem(big, cfg.num_features)
-    assert fused_update.stream_tile(big, cfg.num_features, "f32")
+    assert fused_update.select_program("logreg", cfg, big, "f32")[0] \
+        == "streaming"
+    streamed = fused_update.TRACE_COUNTS["streaming"]
     d, loss = fused_update.local_update(_theta(cfg), x, y, mask, cfg=cfg,
-                                        interpret=True,
-                                        allow_fallback=False)
+                                        interpret=True)
+    assert fused_update.TRACE_COUNTS["streaming"] == streamed + 1
     d_ref, loss_ref = logreg.local_update(_theta(cfg), x, y, mask, cfg=cfg)
     np.testing.assert_allclose(np.asarray(d), np.asarray(d_ref),
                                rtol=2e-4, atol=2e-5)
     assert float(loss) == pytest.approx(float(loss_ref), rel=2e-4)
 
 
-def test_unstreamable_problem_still_refuses():
+def test_unstreamable_problem_refuses_with_shape_and_reason():
     # features so wide the weight set alone blows the VMEM budget —
-    # neither the resident kernel nor a streaming tile can fit, so the
-    # XLA fallback (or the refusal under allow_fallback=False) remains
+    # neither the resident kernel nor a streaming tile can fit, and
+    # there is no XLA fallback to hide behind
     assert not fused_update.fits_in_vmem(16, 150_000)
     assert fused_update.stream_tile(16, 150_000, "f32") is None
     cfg = ModelConfig(num_features=1024 * 256, num_classes=5)
     x = jnp.zeros((8, cfg.num_features), jnp.float32)
     y = jnp.ones((8,), jnp.int32)
     mask = jnp.ones((8,), jnp.float32)
-    with pytest.raises(ValueError, match="pallas local_update unavailable"):
+    with pytest.raises(fused_update.PallasUnavailable,
+                       match=r"features=262144.*VMEM budget"):
         fused_update.local_update(jnp.zeros((cfg.num_params,)), x, y, mask,
-                                  cfg=cfg, interpret=True,
-                                  allow_fallback=False)
+                                  cfg=cfg, interpret=True)
+    # a feature axis the streaming tiles cannot lay out says so
+    with pytest.raises(fused_update.PallasUnavailable,
+                       match="multiple of 128"):
+        fused_update.select_program(
+            "logreg", ModelConfig(num_features=1000, num_classes=5),
+            1 << 20, "f32")
 
 
 # streaming needs a lane-multiple feature axis (stream_tile returns
@@ -127,8 +135,7 @@ def test_streaming_kernel_decodes_slab_storage():
                                               y, mask, cfg=STREAM_CFG)
         d_st, loss_st = fused_update.local_update(theta, stored, y, mask,
                                                   cfg=STREAM_CFG,
-                                                  interpret=True,
-                                                  allow_fallback=False)
+                                                  interpret=True)
         np.testing.assert_allclose(np.asarray(d_st), np.asarray(d_ref),
                                    rtol=2e-4, atol=2e-5, err_msg=kind)
         assert float(loss_st) == pytest.approx(float(loss_ref), rel=2e-4)
@@ -152,13 +159,25 @@ def test_mlp_streaming_kernel_matches_xla():
         assert float(loss_st) == pytest.approx(float(loss_ref), rel=2e-4)
 
 
-def test_fallback_refusal_when_disallowed():
-    if jax.default_backend() == "tpu":
-        pytest.skip("fallback only triggers off-TPU")
+def test_compiled_kernel_refuses_a_non_tpu_backend():
+    """Without interpret=True the kernels mean "compiled Mosaic"; off
+    the chip that is a refusal naming the backend, never the XLA
+    solver under a pallas label."""
+    assert jax.default_backend() == "cpu"      # tests/conftest.py
     x, y, mask = _batch(n=24)
-    with pytest.raises(ValueError, match="pallas local_update unavailable"):
-        fused_update.local_update(_theta(), x, y, mask, cfg=CFG,
-                                  allow_fallback=False)
+    xs, ys, ms = x[None], y[None], mask[None]
+    for call in (
+            lambda: fused_update.local_update(_theta(), x, y, mask, cfg=CFG),
+            lambda: fused_update.local_update_batched(
+                _theta()[None], xs, ys, ms, cfg=CFG),
+            lambda: fused_update.mlp_local_update(
+                _mlp_task().init_params(), x, y, mask, cfg=MLP_CFG),
+            lambda: fused_update.mlp_local_update_batched(
+                _mlp_task().init_params()[None], xs, ys, ms, cfg=MLP_CFG),
+            lambda: fused_update.program_name("logreg", CFG, 24, "f32")):
+        with pytest.raises(fused_update.PallasUnavailable,
+                           match="need a TPU backend, found 'cpu'"):
+            call()
 
 
 def test_out_of_range_label_loss_matches_xla_path():
@@ -224,27 +243,73 @@ def test_mlp_kernel_all_masked_rows_no_nan():
     assert np.isfinite(float(loss))
 
 
-def test_mlp_oversize_hidden_falls_back():
+def test_mlp_oversize_hidden_refuses():
+    """`--pallas --task mlp --hidden_dim 4096` cannot fit and says so
+    instead of training on XLA — at every layer that can be asked."""
+    from kafka_ps_tpu.runtime.worker import solver_program
+    from kafka_ps_tpu.utils.config import PSConfig
+
     assert not fused_update.mlp_fits_in_vmem(1024, 1024, 4096)
     cfg = ModelConfig(num_features=1024, num_classes=5, hidden_dim=4096)
     task = _mlp_task(cfg)
     x, y, mask = _batch(n=16, cfg=cfg)
-    with pytest.raises(ValueError, match="mlp_local_update unavailable"):
+    with pytest.raises(fused_update.PallasUnavailable,
+                       match=r"no pallas mlp kernel fits \(batch=16, "
+                             r"features=1024, hidden=4096, slab=f32\)"):
         fused_update.mlp_local_update(task.init_params(), x, y, mask,
-                                      cfg=cfg, interpret=True,
-                                      allow_fallback=False)
-    d, loss = fused_update.mlp_local_update(task.init_params(), x, y,
-                                            mask, cfg=cfg, interpret=True)
-    d_ref, _ = task.local_update(task.init_params(), x, y, mask)
-    np.testing.assert_allclose(np.asarray(d), np.asarray(d_ref),
-                               rtol=1e-6, atol=1e-7)
-    assert np.isfinite(float(loss))
+                                      cfg=cfg, interpret=True)
+    with pytest.raises(fused_update.PallasUnavailable, match="hidden=4096"):
+        solver_program(PSConfig(task="mlp", model=cfg,
+                                use_pallas="interpret"))
+
+
+def test_solver_program_names():
+    from kafka_ps_tpu.runtime.worker import solver_program
+    from kafka_ps_tpu.utils.config import PSConfig
+
+    assert solver_program(PSConfig()) == "xla"
+    assert solver_program(PSConfig(use_pallas="interpret")) \
+        == "pallas-resident+batched"
+    assert solver_program(PSConfig(use_pallas="interpret",
+                                   use_gang=False)) == "pallas-resident"
+    assert solver_program(PSConfig(use_pallas="interpret",
+                                   slab_dtype="int8")) == "pallas-streaming"
+    with pytest.raises(fused_update.PallasUnavailable, match="found 'cpu'"):
+        solver_program(PSConfig(use_pallas=True))
+
+
+def test_batched_streaming_members_run_the_kernel_per_member():
+    """A gang release set over bf16/int8 member slabs has no grid
+    kernel; the batched entry runs the streaming kernel once per member
+    inside one jit (it used to drop to the vmapped XLA solver)."""
+    from kafka_ps_tpu.compress.slab import encode_x
+
+    k = 3
+    theta = _theta(STREAM_CFG)
+    thetas = jnp.stack([theta * (1 + 0.1 * i) for i in range(k)])
+    parts = [_batch(n=96, seed=i, cfg=STREAM_CFG) for i in range(k)]
+    ys = jnp.stack([p[1] for p in parts])
+    masks = jnp.stack([p[2] for p in parts])
+    for kind in ("bf16", "int8"):
+        stored = [encode_x(kind, p[0]) for p in parts]
+        xs = jax.tree.map(lambda *leaves: jnp.stack(leaves), *stored)
+        streamed = fused_update.TRACE_COUNTS["streaming"]
+        ds, ls = fused_update.local_update_batched(
+            thetas, xs, ys, masks, cfg=STREAM_CFG, interpret=True)
+        assert fused_update.TRACE_COUNTS["streaming"] == streamed + k
+        for i in range(k):
+            d1, l1 = fused_update.local_update(
+                thetas[i], stored[i], ys[i], masks[i], cfg=STREAM_CFG,
+                interpret=True)
+            np.testing.assert_allclose(np.asarray(ds[i]), np.asarray(d1),
+                                       rtol=1e-6, atol=1e-7, err_msg=kind)
+            assert float(ls[i]) == pytest.approx(float(l1), rel=1e-6)
 
 
 def test_worker_pallas_dispatch_accepts_both_families():
-    """--pallas dispatches by task family in the per-node worker path
-    (runtime/worker._solver_fns); off-TPU both kernels fall back to
-    their XLA paths, so the worker trains normally."""
+    """use_pallas dispatches by task family in the per-node worker path
+    (runtime/worker._solver_fns); on the CPU the kernels run where the
+    config asks for the interpreter by name."""
     from kafka_ps_tpu.data.buffer import SlidingBuffer
     from kafka_ps_tpu.runtime import fabric as fabric_mod
     from kafka_ps_tpu.runtime.messages import KeyRange, WeightsMessage
@@ -253,7 +318,7 @@ def test_worker_pallas_dispatch_accepts_both_families():
 
     for task_name in ("logreg", "mlp"):
         cfg = PSConfig(
-            num_workers=1, task=task_name, use_pallas=True,
+            num_workers=1, task=task_name, use_pallas="interpret",
             model=ModelConfig(num_features=16, num_classes=3,
                               hidden_dim=8),
             buffer=BufferConfig(min_size=4, max_size=32))

@@ -10,6 +10,8 @@ offline ceiling is well below 1.0, like the reference's fine-food task
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import pytest
 
@@ -30,6 +32,8 @@ def _sklearn_f1(train_x, train_y, test_x, test_y) -> float:
     return float(f1_score(test_y, m.predict(test_x), average="weighted"))
 
 
+@pytest.mark.skipif(not os.path.isfile(MOCKDATA),
+                    reason="reference checkout not present")
 def test_logreg_agrees_with_sklearn_on_reference_mockdata():
     """SURVEY §7 build step 1: validate the LR against sklearn on the
     reference's own committed dataset (mockData/lr_dataset_stripped.csv,
