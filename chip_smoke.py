@@ -21,8 +21,11 @@ the models the repo supports, on data made from a seed:
 Each phase is checked by the repo's own means (the eval CSVs, the
 staleness auditor, the eval engine's lag, where theta lives) and the
 first failed check ends the run with a traceback and a non-zero exit.
-The last line of stdout is one JSON object; nothing is printed there
-unless every phase passed.
+Stdout ends with two JSON lines, printed only when every phase passed:
+the report (per-phase times, solver programs, cache, `"claim": null`)
+and then, last, the verdict — exactly
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`
+with the device as JAX reports it.
 
 Exit codes: 0 all phases passed on a TPU; 2 JAX found no TPU (says what
 it found); anything else a failed phase.  `python chip_smoke.py`, no
@@ -474,6 +477,16 @@ def _cache_entries(cache_dir: str) -> int:
     return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
 
 
+def verdict_line(found: dict) -> str:
+    """The last line of stdout: `ok` and the device as JAX reports it,
+    and no other key — the report goes on the line before."""
+    return json.dumps({
+        "ok": True,
+        "device": {"platform": str(found["platform"]),
+                   "kind": str(found["kind"]),
+                   "count": int(found["count"])}})
+
+
 def main() -> int:
     from kafka_ps_tpu import native
     from kafka_ps_tpu.utils import device
@@ -495,9 +508,6 @@ def main() -> int:
         phases = run_phases(Sizes(), found["platform"], found["count"],
                             workdir)
     print(json.dumps({
-        "ok": True,
-        "device": {"platform": found["platform"], "kind": found["kind"],
-                   "count": found["count"]},
         "versions": {k: found[k] for k in ("jax", "jaxlib", "libtpu")},
         "compile_cache": {"dir": cache_dir,
                           "entries_at_start": cache_before,
@@ -507,6 +517,7 @@ def main() -> int:
         "phases": phases,
         "claim": None,
     }), flush=True)
+    print(verdict_line(found), flush=True)
     return 0
 
 

@@ -108,6 +108,27 @@ def test_a_failed_check_raises(data):
                                   "tpu", 0)
 
 
+def test_stdout_ends_with_the_verdict_and_nothing_else(monkeypatch, capsys):
+    """The driver reads the LAST stdout line and takes exactly
+    {"ok", "device": {"platform", "kind", "count"}}; the report (with
+    `"claim": null`) is the line before."""
+    from kafka_ps_tpu.utils import device
+    found = dict(device.device_summary(), platform="tpu",
+                 kind="TPU v5 lite", count=1)
+    monkeypatch.setattr(device, "device_summary", lambda: found)
+    monkeypatch.setattr(device, "configure_compile_cache", lambda: None)
+    monkeypatch.setattr(chip_smoke, "run_phases",
+                        lambda *a: {"per_node_c0": {"solver": "xla"}})
+    assert chip_smoke.main() == 0
+    lines = capsys.readouterr().out.splitlines()
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    report = json.loads(lines[-2])
+    assert list(report)[-1] == "claim" and report["claim"] is None
+    assert report["phases"] == {"per_node_c0": {"solver": "xla"}}
+
+
 # -- the compile-cache hook (utils/device.py) --------------------------------
 
 _REPORT = ("import json, jax; from kafka_ps_tpu.cli.run import "
