@@ -176,17 +176,19 @@ class EvalEngine:
         owes a CSV row)."""
         self._raise_if_failed()
         clock = int(clock)
-        with self._cv:
-            self._pending.append((theta, clock))
-            self._submitted_clock = clock
-            backlog = len(self._pending)
-            self._cv.notify_all()
-        if self.telemetry.enabled:
-            self._m_lag.set(self._submitted_clock - self._evaluated_clock)
-        if self._start_thread:
-            self._ensure_thread()
-        if backlog > self._max_pending:
-            self.drain()
+        with self.tracer.span("eval.submit", clock=clock):
+            with self._cv:
+                self._pending.append((theta, clock))
+                self._submitted_clock = clock
+                backlog = len(self._pending)
+                self._cv.notify_all()
+            if self.telemetry.enabled:
+                self._m_lag.set(self._submitted_clock
+                                - self._evaluated_clock)
+            if self._start_thread:
+                self._ensure_thread()
+            if backlog > self._max_pending:
+                self.drain()
 
     @property
     def lag_clocks(self) -> int:
@@ -275,7 +277,6 @@ class EvalEngine:
             thetas = [jnp.asarray(t) for t, _ in batch]
             thetas.extend([thetas[-1]] * (width - k))
             mets = self._program(width)(self._tx, self._ty, *thetas)
-            self.tracer.count("eval.dispatch_async")
         self._dispatches += 1
         self._evals += k
         self._width_counts[k] = self._width_counts.get(k, 0) + 1
@@ -327,19 +328,20 @@ class EvalEngine:
         call this at exit so `eval_lag_clocks` returns to 0 and the
         CSV is complete before sinks flush."""
         self._raise_if_failed()
-        if self._start_thread:
-            self._ensure_thread()
-            with self._cv:
-                ok = self._cv.wait_for(
-                    lambda: (not self._pending and self._inflight == 0)
-                    or self._stop.is_set() or self._error is not None,
-                    timeout=timeout)
-            self._raise_if_failed()
-            if not ok:               # pragma: no cover - watchdog
-                raise TimeoutError("eval engine drain timed out")
-        else:
-            while self.poll():
-                pass
+        with self.tracer.span("eval.drain"):
+            if self._start_thread:
+                self._ensure_thread()
+                with self._cv:
+                    ok = self._cv.wait_for(
+                        lambda: (not self._pending and self._inflight == 0)
+                        or self._stop.is_set() or self._error is not None,
+                        timeout=timeout)
+                self._raise_if_failed()
+                if not ok:               # pragma: no cover - watchdog
+                    raise TimeoutError("eval engine drain timed out")
+            else:
+                while self.poll():
+                    pass
 
     def close(self) -> None:
         """Drain, stop and join the kps-eval thread (it dispatches jit

@@ -108,7 +108,6 @@ class CommitLog:
         if self.active.size >= self.config.segment_bytes:
             self._roll()
         offset = self.active.append(payload)
-        self.tracer.count("log.appends")
         if self.telemetry.enabled:
             self._m_appends.inc()
         if FLIGHT.enabled:

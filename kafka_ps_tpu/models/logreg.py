@@ -138,16 +138,21 @@ def local_update_onehot(theta: jax.Array, x: jax.Array, onehot: jax.Array,
                         ) -> tuple[jax.Array, jax.Array]:
     """local_update with the one-hot precomputed by the caller — the
     fused multi-round BSP step hoists it above its rounds-scan (the
-    labels never change between rounds)."""
+    labels never change between rounds).  The `kps.fit.*` scopes are
+    models/mlp.py's: metadata a device trace splits the time by."""
     lr = cfg.local_learning_rate
 
     def step(t, _):
-        g, _ = grad_loss_onehot(t, x, onehot, mask, cfg)
-        return t - lr * g, None
+        with jax.named_scope("kps.fit.grad"):
+            g, _ = grad_loss_onehot(t, x, onehot, mask, cfg)
+        with jax.named_scope("kps.fit.param_step"):
+            return t - lr * g, None
 
     theta_new, _ = jax.lax.scan(step, theta, None, length=cfg.num_max_iter)
-    _, final_loss = grad_loss_onehot(theta_new, x, onehot, mask, cfg)
-    return theta_new - theta, final_loss
+    with jax.named_scope("kps.fit.loss"):
+        _, final_loss = grad_loss_onehot(theta_new, x, onehot, mask, cfg)
+    with jax.named_scope("kps.fit.delta"):
+        return theta_new - theta, final_loss
 
 
 def sparse_to_dense(rows: list[dict[int, float]], num_features: int) -> np.ndarray:

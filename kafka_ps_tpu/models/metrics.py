@@ -52,8 +52,9 @@ def evaluate(theta: jax.Array, x_test: jax.Array, y_test: jax.Array,
              *, cfg: ModelConfig) -> Metrics:
     """Full-test-set metrics, same cadence as the reference (every server
     iteration on worker 0's update, ServerProcessor.java:153-165)."""
-    params = unflatten(theta, cfg)
-    preds = jnp.argmax(logits(params, x_test), axis=-1)
-    loss = loss_fn(params, x_test, y_test, jnp.ones(x_test.shape[0]))
-    f1, acc = weighted_f1_accuracy(preds, y_test, cfg.num_rows)
-    return Metrics(f1=f1, accuracy=acc, loss=loss)
+    with jax.named_scope("kps.eval"):
+        params = unflatten(theta, cfg)
+        preds = jnp.argmax(logits(params, x_test), axis=-1)
+        loss = loss_fn(params, x_test, y_test, jnp.ones(x_test.shape[0]))
+        f1, acc = weighted_f1_accuracy(preds, y_test, cfg.num_rows)
+        return Metrics(f1=f1, accuracy=acc, loss=loss)

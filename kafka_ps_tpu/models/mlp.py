@@ -125,25 +125,34 @@ class MLPTask:
 def _local_update_onehot(theta, x, onehot, mask, *, cfg: ModelConfig):
     """Jitted like logreg.local_update so the per-node worker hot path
     runs one cached XLA program per iteration (re-jitting inside an
-    enclosing jit — the fused BSP steps — is free: it inlines)."""
+    enclosing jit — the fused BSP steps — is free: it inlines).  The
+    `kps.fit.*` scopes name each part in the operations' metadata, so a
+    device trace splits the solver's time by them (metadata only)."""
     lr = cfg.local_learning_rate
     grad = jax.grad(_loss_onehot)
 
     def step(t, _):
-        return t - lr * grad(t, x, onehot, mask, cfg), None
+        with jax.named_scope("kps.fit.grad"):
+            g = grad(t, x, onehot, mask, cfg)
+        with jax.named_scope("kps.fit.param_step"):
+            return t - lr * g, None
 
     theta_new, _ = jax.lax.scan(step, theta, None, length=cfg.num_max_iter)
-    final_loss = _loss_onehot(theta_new, x, onehot, mask, cfg)
-    return theta_new - theta, final_loss
+    with jax.named_scope("kps.fit.loss"):
+        final_loss = _loss_onehot(theta_new, x, onehot, mask, cfg)
+    with jax.named_scope("kps.fit.delta"):
+        return theta_new - theta, final_loss
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _evaluate(theta, x_test, y_test, *, cfg: ModelConfig):
-    params = unflatten(theta, cfg)
-    lg = logits(params, x_test)
-    preds = jnp.argmax(lg, axis=-1)
-    onehot = jax.nn.one_hot(y_test, cfg.num_rows, dtype=jnp.float32)
-    loss = _loss_onehot(theta, x_test, onehot,
-                        jnp.ones(x_test.shape[0]), cfg)
-    f1, acc = metrics_mod.weighted_f1_accuracy(preds, y_test, cfg.num_rows)
-    return metrics_mod.Metrics(f1=f1, accuracy=acc, loss=loss)
+    with jax.named_scope("kps.eval"):
+        params = unflatten(theta, cfg)
+        lg = logits(params, x_test)
+        preds = jnp.argmax(lg, axis=-1)
+        onehot = jax.nn.one_hot(y_test, cfg.num_rows, dtype=jnp.float32)
+        loss = _loss_onehot(theta, x_test, onehot,
+                            jnp.ones(x_test.shape[0]), cfg)
+        f1, acc = metrics_mod.weighted_f1_accuracy(preds, y_test,
+                                                   cfg.num_rows)
+        return metrics_mod.Metrics(f1=f1, accuracy=acc, loss=loss)

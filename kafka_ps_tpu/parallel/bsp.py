@@ -66,13 +66,16 @@ def make_bsp_step(cfg: ModelConfig, num_workers: int, server_lr: float,
     task = task or _default_task(cfg)
 
     def apply(theta, delta_sum, loss_sum):
-        return theta + server_lr * delta_sum, loss_sum / num_workers
+        with jax.named_scope("kps.bsp.apply"):
+            return theta + server_lr * delta_sum, loss_sum / num_workers
 
     if mesh is None:
         @jax.jit
         def step(theta, x, y, mask):
             deltas, losses = _vmapped_local_updates(theta, x, y, mask, task)
-            return apply(theta, deltas.sum(0), losses.sum())
+            with jax.named_scope("kps.bsp.reduce"):
+                delta_sum, loss_sum = deltas.sum(0), losses.sum()
+            return apply(theta, delta_sum, loss_sum)
 
         return step
 
@@ -87,8 +90,9 @@ def make_bsp_step(cfg: ModelConfig, num_workers: int, server_lr: float,
         # stable varying-axes type (psum below restores invariance).
         theta_v = jax.lax.pcast(theta, WORKER_AXIS, to="varying")
         deltas, losses = _vmapped_local_updates(theta_v, x, y, mask, task)
-        delta_sum = jax.lax.psum(deltas.sum(0), WORKER_AXIS)
-        loss_sum = jax.lax.psum(losses.sum(), WORKER_AXIS)
+        with jax.named_scope("kps.bsp.reduce"):
+            delta_sum = jax.lax.psum(deltas.sum(0), WORKER_AXIS)
+            loss_sum = jax.lax.psum(losses.sum(), WORKER_AXIS)
         return apply(theta, delta_sum, loss_sum)
 
     sharded = jax.shard_map(
@@ -118,11 +122,13 @@ def make_bsp_multi_step(cfg: ModelConfig, num_workers: int, server_lr: float,
                        if psum_axis else theta)
         deltas, losses = _vmapped_local_updates_onehot(
             theta_local, x, onehot, mask, task)
-        delta_sum, loss_sum = deltas.sum(0), losses.sum()
-        if psum_axis:
-            delta_sum = jax.lax.psum(delta_sum, WORKER_AXIS)
-            loss_sum = jax.lax.psum(loss_sum, WORKER_AXIS)
-        return theta + server_lr * delta_sum, loss_sum / num_workers
+        with jax.named_scope("kps.bsp.reduce"):
+            delta_sum, loss_sum = deltas.sum(0), losses.sum()
+            if psum_axis:
+                delta_sum = jax.lax.psum(delta_sum, WORKER_AXIS)
+                loss_sum = jax.lax.psum(loss_sum, WORKER_AXIS)
+        with jax.named_scope("kps.bsp.apply"):
+            return theta + server_lr * delta_sum, loss_sum / num_workers
 
     def scanned(theta, x, y, mask, psum_axis):
         # labels are fixed across rounds: one-hot once, above the scan

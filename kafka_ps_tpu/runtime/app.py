@@ -38,6 +38,11 @@ from kafka_ps_tpu.utils.config import PSConfig, SEQUENTIAL
 from kafka_ps_tpu.utils.trace import NULL_TRACER
 
 
+# the slab-refresh part of `StreamingPSApp.last_run` before any refresh
+NO_SLAB_REFRESH = {"slab_refreshes": 0, "slab_refresh_s": 0.0,
+                   "slab_refresh_bytes": 0}
+
+
 class StreamingPSApp:
     """One process hosting the server + N logical workers, like the
     reference's single-JVM local deployment (SURVEY §4)."""
@@ -98,6 +103,11 @@ class StreamingPSApp:
         # the whole multi-round program every call (hundreds of ms at
         # MLP-4096) even when the XLA compile cache hits
         self._fused_programs: dict = {}
+        # the last drive call, written as run_fused_bsp / run_serial
+        # return: path, seconds, and the slab refreshes at the head of a
+        # fused call (count, seconds, bytes), which a trace of the
+        # steady state never holds
+        self.last_run: dict = {}
         self._reroute_counter = 0
         # durable resume: leading stream rows to drop because the log
         # already holds them (the CSV producer deterministically
@@ -394,13 +404,14 @@ class StreamingPSApp:
         drive loops call this on exit so callers see complete logs.
         Pending async evals drain FIRST: their rows enter the server
         sink's queue before the sink itself is flushed."""
-        if self.eval_engine is not None:
-            self.eval_engine.drain()
-        for sink in (self.server.log, *{id(w.log): w.log
-                                        for w in self.workers}.values()):
-            flush = getattr(sink, "flush", None)
-            if flush is not None:
-                flush()
+        with self.tracer.span("app.flush_logs"):
+            if self.eval_engine is not None:
+                self.eval_engine.drain()
+            for sink in (self.server.log, *{id(w.log): w.log
+                                            for w in self.workers}.values()):
+                flush = getattr(sink, "flush", None)
+                if flush is not None:
+                    flush()
 
     def close_logs(self) -> None:
         """Close the deferred sinks: joins their drain threads (which
@@ -440,45 +451,15 @@ class StreamingPSApp:
         batched apply (runtime/server.process_batch).  `--no-gang` keeps
         the original strictly per-message alternation."""
         reporter = self._start_status(status_every)
+        t_call = time.perf_counter()
         stalled_rounds = 0
         gang = self._make_gang()
         try:
             self.server.start_training_loop()
             while self.server.iterations < max_server_iterations:
-                progressed = False
-                if gang is not None and gang.drain_serial():
-                    progressed = True
-                for worker in self.workers:
-                    msg = self.fabric.poll(fabric_mod.WEIGHTS_TOPIC,
-                                           worker.worker_id)
-                    if msg is not None:
-                        worker.on_weights(msg)
-                        progressed = True
-                if gang is None:
-                    while self.server.iterations < max_server_iterations:
-                        g = self.fabric.poll(fabric_mod.GRADIENTS_TOPIC, 0)
-                        if g is None:
-                            break
-                        self.server.process(g)
-                        progressed = True
-                else:
-                    # drain the whole backlog, capped so a full batch
-                    # cannot overshoot the iteration budget (bench runs
-                    # rely on exact counts); drops (zombies/duplicates)
-                    # under-fill a round and the outer loop tops it up
-                    batch = []
-                    while (self.server.iterations + len(batch)
-                           < max_server_iterations):
-                        g = self.fabric.poll(fabric_mod.GRADIENTS_TOPIC, 0)
-                        if g is None:
-                            break
-                        batch.append(g)
-                    if len(batch) > 1:
-                        self.server.process_batch(batch)
-                        progressed = True
-                    elif batch:
-                        self.server.process(batch[0])
-                        progressed = True
+                with self.tracer.span("serial.round"):
+                    progressed = self._serial_round(
+                        gang, max_server_iterations)
                 if pump is not None:
                     pump()
                 # pump() can only add buffer rows, never fabric messages,
@@ -490,6 +471,49 @@ class StreamingPSApp:
         finally:
             reporter.stop()
             self.flush_logs()
+        self.last_run = {"path": "serial",
+                         "seconds": time.perf_counter() - t_call,
+                         **NO_SLAB_REFRESH}
+
+    def _serial_round(self, gang, max_server_iterations: int) -> bool:
+        """One turn of the serial scheduler: weights out, gradients in.
+        Whether any message moved."""
+        progressed = False
+        if gang is not None:
+            with self.tracer.span("gang.drain"):
+                progressed = gang.drain_serial()
+        with self.tracer.span("serial.deliver"):
+            for worker in self.workers:
+                msg = self.fabric.poll(fabric_mod.WEIGHTS_TOPIC,
+                                       worker.worker_id)
+                if msg is not None:
+                    worker.on_weights(msg)
+                    progressed = True
+        if gang is None:
+            while self.server.iterations < max_server_iterations:
+                g = self.fabric.poll(fabric_mod.GRADIENTS_TOPIC, 0)
+                if g is None:
+                    break
+                self.server.process(g)
+                progressed = True
+            return progressed
+        # drain the whole backlog, capped so a full batch cannot
+        # overshoot the iteration budget (bench runs rely on exact
+        # counts); drops (zombies/duplicates) under-fill a round and
+        # the outer loop tops it up
+        batch = []
+        with self.tracer.span("serial.collect"):
+            while (self.server.iterations + len(batch)
+                   < max_server_iterations):
+                g = self.fabric.poll(fabric_mod.GRADIENTS_TOPIC, 0)
+                if g is None:
+                    break
+                batch.append(g)
+        if len(batch) > 1:
+            self.server.process_batch(batch)
+        elif batch:
+            self.server.process(batch[0])
+        return progressed or bool(batch)
 
     def run_threaded(self, max_server_iterations: int,
                      poll_timeout: float = 0.1,
@@ -724,12 +748,15 @@ class StreamingPSApp:
         # the bottleneck.  num_tuples_seen strictly increases on every
         # insert, so it is the buffer content version.
         reporter = self._start_status(status_every)
+        t_call = time.perf_counter()
         try:
-            self._run_fused_loop(max_server_iterations, mesh, log_metrics,
-                                 range_mode, multiproc, step, theta, clock,
-                                 active, feed, task, progs)
+            refresh = self._run_fused_loop(
+                max_server_iterations, mesh, log_metrics, range_mode,
+                multiproc, step, theta, clock, active, feed, task, progs)
         finally:
             reporter.stop()
+        self.last_run = {"path": "fused",
+                         "seconds": time.perf_counter() - t_call, **refresh}
 
     # rounds per fused chunk dispatch: several rounds share one
     # dispatch, few enough that stream arrivals are picked up promptly
@@ -737,8 +764,9 @@ class StreamingPSApp:
 
     def _run_fused_loop(self, max_server_iterations, mesh, log_metrics,
                         range_mode, multiproc, step, theta, clock, active,
-                        feed, task, progs) -> None:
-        import jax
+                        feed, task, progs) -> dict:
+        """The fused drive loop; returns the call's slab-refresh counts
+        (`StreamingPSApp.last_run`)."""
         import jax.numpy as jnp
 
         from kafka_ps_tpu.parallel import range_sharded
@@ -767,140 +795,184 @@ class StreamingPSApp:
 
         x = y = mask = None
         slab_versions: list[int] | None = None
+        refresh = dict(NO_SLAB_REFRESH)
         while self.server.iterations < max_server_iterations:
-            versions = [self.buffers[w].num_tuples_seen for w in feed]
-            # The version cache stays valid multi-process: the global
-            # array build below (make_array_from_process_local_data) is
-            # process-local — device_put of this host's shards only, no
-            # cross-process rendezvous — so hosts may disagree about
-            # re-uploading without hanging, and a host whose buffers are
-            # unchanged reuses device slabs with identical content.
-            if versions != slab_versions:
-                slabs = []
-                for w in feed:
-                    sx, sy, sm = self.buffers[w].snapshot()
-                    if sm.sum() == 0:
-                        raise RuntimeError(
-                            f"There is no data in the buffer of worker {w}")
-                    slabs.append((sx, sy, sm))
-                x = np.stack([s[0] for s in slabs])
-                y = np.stack([s[1] for s in slabs])
-                mask = np.stack([s[2] for s in slabs])
-                if multiproc:
-                    from kafka_ps_tpu.parallel import multihost
-                    x, y, mask = multihost.shard_worker_batches_global(
-                        mesh, x, y, mask)
-                elif range_mode:
-                    x, y, mask = range_sharded.shard_worker_batches(
-                        mesh, x, y, mask)
-                elif mesh is not None:
-                    x, y, mask = bsp.shard_worker_batches(mesh, x, y, mask)
-                else:
-                    x, y, mask = (jnp.asarray(x), jnp.asarray(y),
-                                  jnp.asarray(mask))
-                slab_versions = versions
-            # rounds until the run cap / the next eval clock
-            rounds_left = -((self.server.iterations - max_server_iterations)
-                            // len(active))
-            r = min(CHUNK, rounds_left)
-            if log_metrics and self.server.test_x is not None:
-                r = min(r, self.cfg.eval_every
-                        - (clock % self.cfg.eval_every))
-            use_chunk = r == CHUNK
-            if not use_chunk:
-                r = 1
-            losses = None
-            with self.tracer.span("bsp.step", clock=clock + 1, rounds=r):
-                if use_chunk:
-                    theta, losses = get_multi_step()(theta, x, y, mask)
-                    mean_loss = losses[-1]
-                else:
-                    theta, mean_loss = step(theta, x, y, mask)
-                if self.tracer.enabled or (multiproc and log_metrics):
-                    # sync so the span measures the real step, not the
-                    # async dispatch.  Multi-process runs with logging
-                    # ALSO sync here: the psum makes every process's
-                    # step k finish together on device, and blocking
-                    # the hosts on it keeps their row timestamps
-                    # aligned per clock — fully async hosts submit all
-                    # their rows (and stamp them) way ahead of the
-                    # device, and the auditor's cross-file
-                    # timestamp-sorted spread becomes fiction.
-                    # Untraced single-process runs keep pipelining.
+            # one span a turn: the loop's own Python between its
+            # children is this span's self time
+            with self.tracer.span("fused.chunk"):
+                versions = [self.buffers[w].num_tuples_seen for w in feed]
+                # The version cache stays valid multi-process: the global
+                # array build below (make_array_from_process_local_data)
+                # is process-local — device_put of this host's shards
+                # only, no cross-process rendezvous — so hosts may
+                # disagree about re-uploading without hanging, and a host
+                # whose buffers are unchanged reuses device slabs with
+                # identical content.
+                if versions != slab_versions:
+                    t_refresh = time.perf_counter()
+                    # x, y and the float32 validity mask of each slab
+                    nbytes = sum(b.x.nbytes + b.y.nbytes + 4 * len(b.y)
+                                 for b in (self.buffers[w] for w in feed))
+                    with self.tracer.span("fused.slab_refresh",
+                                          bytes=nbytes, workers=len(feed)):
+                        # `slabs` stays referenced until the loop
+                        # returns: dropped here, the host copies are
+                        # freed at the top of the heap, glibc hands
+                        # their pages back, and every call faults them
+                        # in again (+1 s a call at 256 workers, PERF.md
+                        # PR 24)
+                        slabs = []
+                        for w in feed:
+                            sx, sy, sm = self.buffers[w].snapshot()
+                            if sm.sum() == 0:
+                                raise RuntimeError(
+                                    "There is no data in the buffer of "
+                                    f"worker {w}")
+                            slabs.append((sx, sy, sm))
+                        x = np.stack([s[0] for s in slabs])
+                        y = np.stack([s[1] for s in slabs])
+                        mask = np.stack([s[2] for s in slabs])
+                        if multiproc:
+                            from kafka_ps_tpu.parallel import multihost
+                            x, y, mask = \
+                                multihost.shard_worker_batches_global(
+                                    mesh, x, y, mask)
+                        elif range_mode:
+                            x, y, mask = range_sharded.shard_worker_batches(
+                                mesh, x, y, mask)
+                        elif mesh is not None:
+                            x, y, mask = bsp.shard_worker_batches(
+                                mesh, x, y, mask)
+                        else:
+                            x, y, mask = (jnp.asarray(x), jnp.asarray(y),
+                                          jnp.asarray(mask))
+                    slab_versions = versions
+                    refresh["slab_refreshes"] += 1
+                    refresh["slab_refresh_bytes"] += nbytes
+                    refresh["slab_refresh_s"] += (time.perf_counter()
+                                                  - t_refresh)
+                # rounds until the run cap / the next eval clock
+                rounds_left = -(
+                    (self.server.iterations - max_server_iterations)
+                    // len(active))
+                r = min(CHUNK, rounds_left)
+                if log_metrics and self.server.test_x is not None:
+                    r = min(r, self.cfg.eval_every
+                            - (clock % self.cfg.eval_every))
+                use_chunk = r == CHUNK
+                if not use_chunk:
+                    r = 1
+                losses = None
+                # the dispatch call: enqueue, plus any wait for a full
+                # queue — no span reads a device value
+                with self.tracer.span("bsp.step", clock=clock + 1,
+                                      rounds=r):
+                    if use_chunk:
+                        theta, losses = get_multi_step()(theta, x, y, mask)
+                        mean_loss = losses[-1]
+                    else:
+                        theta, mean_loss = step(theta, x, y, mask)
+                if multiproc and log_metrics:
+                    # Multi-process runs with logging sync here: the
+                    # psum makes every process's step k finish together
+                    # on device, and blocking the hosts on it keeps
+                    # their row timestamps aligned per clock — fully
+                    # async hosts submit all their rows (and stamp them)
+                    # way ahead of the device, and the auditor's
+                    # cross-file timestamp-sorted spread becomes
+                    # fiction.  Single-process runs keep pipelining.
                     mean_loss = float(mean_loss)
-            self.tracer.count("bsp.steps")
-            clock += r
-            self.server.iterations += r * len(active)
-            # theta is updated by replacement everywhere (runtime/server
-            # module doc), so the device array is stored directly — no
-            # per-step device->host copy
-            if range_mode:
-                self.server.theta = range_sharded.unshard_theta(theta, task)
-            else:
-                self.server.theta = theta
-            for w in active:
-                self.workers[w].iterations += r
-                self.server.tracker.tracker[w].vector_clock = clock
-                self.server.tracker.tracker[w].weights_message_sent = True
-            # fused-path publication point: the chunk boundary is the
-            # gate release (all active workers advanced to `clock`)
-            self.server.publish_snapshot()
-            self.server.maybe_checkpoint()
-            if log_metrics and self.server.test_x is not None:
-                is_eval = clock % self.cfg.eval_every == 0
-                m = None
-                if is_eval:
-                    # range mode: theta is the padded sharded vector;
-                    # eval on the reassembled flat layout (just stored)
-                    eval_theta = (jnp.asarray(self.server.theta)
-                                  if range_mode else theta)
-                    m = self.server.task.evaluate(
-                        eval_theta, self.server.test_x, self.server.test_y)
-                    self.server.last_metrics = m
-                now = int(time.time() * 1000)
-                # multi-process: the server line is process 0's alone
-                # (identical replicated metrics; one writer per file).
-                # Metric fields stay device futures (asynclog) so the
-                # next chunk dispatches while the eval completes.
-                if is_eval and (not multiproc or jax.process_index() == 0):
-                    asynclog.submit_or_write(
-                        self.server.log, f"{now};-1;{clock};{{}};{{}};{{}}",
-                        m.loss, m.f1, m.accuracy)
-                # Worker log lines, same schema AND CADENCE as the
-                # per-node path (WorkerTrainingProcessor.java:85-92):
-                # one row per worker per CLOCK — off-cadence clocks log
-                # the reference's -1 placeholders, eval clocks the
-                # shared test metrics (identical across workers under
-                # BSP — replicated weights).  Rows go out CLOCK-major
-                # so a same-millisecond batch keeps the logged spread
-                # within the BSP bound (the staleness auditor orders
-                # ties by file order).  A chunk logs each of its r
-                # rounds with that round's mean local loss.  Each
-                # process logs only the workers it hosts (its sink path
-                # is process-suffixed in multi-host mode, cli/run.py).
-                # Log-schema caveat: numTuplesSeen is CHUNK-granular
-                # here, not round-granular — all r rows of a chunk stamp
-                # the buffer version sampled after the chunk dispatch,
-                # because the per-round values no longer exist (the
-                # rounds ran fused on device against one slab snapshot).
-                # The per-node path stamps it per iteration; consumers
-                # correlating loss against data volume should treat the
-                # fused path's column as a step function with CHUNK-wide
-                # treads.
-                for i in range(r):
-                    ci = clock - r + 1 + i
-                    round_loss = (losses[i] if losses is not None
-                                  else mean_loss)
-                    ci_eval = is_eval and ci == clock
-                    f1 = m.f1 if ci_eval else -1.0
-                    acc = m.accuracy if ci_eval else -1.0
-                    for w in feed:
-                        asynclog.submit_or_write(
-                            self.workers[w].log,
-                            f"{now};{w};{ci};{{}};{{}};{{}};"
-                            f"{self.buffers[w].num_tuples_seen}",
-                            round_loss, f1, acc)
+                self.tracer.count("bsp.steps")
+                clock += r
+                self.server.iterations += r * len(active)
+                with self.tracer.span("fused.publish"):
+                    # theta is updated by replacement everywhere
+                    # (runtime/server module doc), so the device array
+                    # is stored directly — no per-step device->host copy
+                    if range_mode:
+                        self.server.theta = range_sharded.unshard_theta(
+                            theta, task)
+                    else:
+                        self.server.theta = theta
+                    for w in active:
+                        self.workers[w].iterations += r
+                        self.server.tracker.tracker[w].vector_clock = clock
+                        self.server.tracker.tracker[w] \
+                            .weights_message_sent = True
+                    # fused-path publication point: the chunk boundary
+                    # is the gate release (all active workers advanced
+                    # to `clock`)
+                    self.server.publish_snapshot()
+                    self.server.maybe_checkpoint()
+                if log_metrics and self.server.test_x is not None:
+                    self._log_fused_rounds(
+                        theta, clock, r, losses, mean_loss, feed,
+                        range_mode, multiproc)
         self.flush_logs()    # deferred rows out before the loop returns
+        return refresh
+
+    def _log_fused_rounds(self, theta, clock, r, losses, mean_loss, feed,
+                          range_mode, multiproc) -> None:
+        """The server's eval row (on cadence) and every fed worker's
+        row for each of the `r` rounds that ended on `clock`."""
+        import jax
+        import jax.numpy as jnp
+
+        is_eval = clock % self.cfg.eval_every == 0
+        m = None
+        if is_eval:
+            with self.tracer.span("fused.eval", clock=clock):
+                # range mode: theta is the padded sharded vector;
+                # eval on the reassembled flat layout (just stored)
+                eval_theta = (jnp.asarray(self.server.theta)
+                              if range_mode else theta)
+                m = self.server.task.evaluate(
+                    eval_theta, self.server.test_x, self.server.test_y)
+                self.server.last_metrics = m
+        now = int(time.time() * 1000)
+        with self.tracer.span("fused.log_rows", rows=r * len(feed)):
+            # multi-process: the server line is process 0's alone
+            # (identical replicated metrics; one writer per file).
+            # Metric fields stay device futures (asynclog) so the
+            # next chunk dispatches while the eval completes.
+            if is_eval and (not multiproc or jax.process_index() == 0):
+                asynclog.submit_or_write(
+                    self.server.log, f"{now};-1;{clock};{{}};{{}};{{}}",
+                    m.loss, m.f1, m.accuracy)
+            # Worker log lines, same schema AND CADENCE as the
+            # per-node path (WorkerTrainingProcessor.java:85-92):
+            # one row per worker per CLOCK — off-cadence clocks log
+            # the reference's -1 placeholders, eval clocks the
+            # shared test metrics (identical across workers under
+            # BSP — replicated weights).  Rows go out CLOCK-major
+            # so a same-millisecond batch keeps the logged spread
+            # within the BSP bound (the staleness auditor orders
+            # ties by file order).  A chunk logs each of its r
+            # rounds with that round's mean local loss.  Each
+            # process logs only the workers it hosts (its sink path
+            # is process-suffixed in multi-host mode, cli/run.py).
+            # Log-schema caveat: numTuplesSeen is CHUNK-granular
+            # here, not round-granular — all r rows of a chunk stamp
+            # the buffer version sampled after the chunk dispatch,
+            # because the per-round values no longer exist (the
+            # rounds ran fused on device against one slab snapshot).
+            # The per-node path stamps it per iteration; consumers
+            # correlating loss against data volume should treat the
+            # fused path's column as a step function with CHUNK-wide
+            # treads.
+            for i in range(r):
+                ci = clock - r + 1 + i
+                round_loss = (losses[i] if losses is not None
+                              else mean_loss)
+                ci_eval = is_eval and ci == clock
+                f1 = m.f1 if ci_eval else -1.0
+                acc = m.accuracy if ci_eval else -1.0
+                for w in feed:
+                    asynclog.submit_or_write(
+                        self.workers[w].log,
+                        f"{now};{w};{ci};{{}};{{}};{{}};"
+                        f"{self.buffers[w].num_tuples_seen}",
+                        round_loss, f1, acc)
 
     def stop(self) -> None:
         self._stop.set()

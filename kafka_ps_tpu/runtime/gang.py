@@ -46,7 +46,7 @@ release counts as its MEMBER SET, not as one event — when the gate
 applies a CompositeDelta (or flushes a BSP round buffer) the released
 workers it unblocks form a single release set and emit ONE GangNotice
 covering every member, exactly as if the per-member deltas had arrived
-back to back; `gang.batched_members` therefore accounts fan-in
+back to back; `gang_members_total` therefore accounts fan-in
 correctly under aggregation with no special casing here.  The relay's
 grouped weights fan-out (T_WEIGHTS_AGG) is invisible to this module:
 by the time a member worker polls its weights message the relay has
@@ -119,10 +119,14 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
         solver_b = jax.vmap(solver_1)
 
     # the exact composite the single path jits (worker._solver_fns):
-    # k-step solver + full-test-set eval of theta+delta, one program
+    # k-step solver + full-test-set eval of theta+delta, one program.
+    # The two scopes split that program's device time into training
+    # and the members' test-set evaluations (metadata only).
     def composite(theta, x, y, mask, test_x, test_y):
-        delta, loss = solver_1(theta, x, y, mask)
-        m = task.evaluate(theta + delta, test_x, test_y)
+        with jax.named_scope("kps.gang.fit"):
+            delta, loss = solver_1(theta, x, y, mask)
+        with jax.named_scope("kps.gang.eval"):
+            m = task.evaluate(theta + delta, test_x, test_y)
         return delta, loss, m.f1, m.accuracy
 
     def unstack(a, k):
@@ -160,9 +164,11 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
         T = jnp.stack(thetas)
         X, Y, M = tstack(xs), jnp.stack(ys), jnp.stack(masks)
         if use_pallas:
-            deltas, losses = solver_b(T, X, Y, M)
-            met = jax.vmap(lambda t, d: task.evaluate(t + d, test_x,
-                                                      test_y))(T, deltas)
+            with jax.named_scope("kps.gang.fit"):
+                deltas, losses = solver_b(T, X, Y, M)
+            with jax.named_scope("kps.gang.eval"):
+                met = jax.vmap(lambda t, d: task.evaluate(
+                    t + d, test_x, test_y))(T, deltas)
             f1s, accs = met.f1, met.accuracy
         else:
             deltas, losses, f1s, accs = jax.vmap(
@@ -177,10 +183,11 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
         X, Y, M = tstack(xs), jnp.stack(ys), jnp.stack(masks)
         if use_pallas:
             thetas = jnp.broadcast_to(theta[None], (k,) + theta.shape)
-            deltas, losses = solver_b(thetas, X, Y, M)
-            met = jax.vmap(lambda t, d: task.evaluate(t + d, test_x,
-                                                      test_y)
-                           )(thetas, deltas)
+            with jax.named_scope("kps.gang.fit"):
+                deltas, losses = solver_b(thetas, X, Y, M)
+            with jax.named_scope("kps.gang.eval"):
+                met = jax.vmap(lambda t, d: task.evaluate(
+                    t + d, test_x, test_y))(thetas, deltas)
             f1s, accs = met.f1, met.accuracy
         else:
             deltas, losses, f1s, accs = jax.vmap(
@@ -415,7 +422,6 @@ class GangDispatcher:
                 out = fns["update_stacked"](tuple(thetas), xs, ys, masks)
         self.tracer.count("dispatch.device")
         self.tracer.count("gang.batched_dispatches")
-        self.tracer.count("gang.batched_members", k)
         if self.telemetry.enabled:
             self._m_dispatches.inc()
             self._m_members.inc(k)

@@ -39,6 +39,7 @@ import threading
 from collections import deque
 
 from kafka_ps_tpu.analysis.lockgraph import OrderedCondition, OrderedLock
+from kafka_ps_tpu.utils import trace
 
 
 @functools.lru_cache(maxsize=None)
@@ -49,8 +50,10 @@ def _stacker(n: int):
     keeps it to a handful of cached programs."""
     import jax
     import jax.numpy as jnp
-    return jax.jit(
-        lambda vs: jnp.stack([jnp.asarray(v, jnp.float32) for v in vs]))
+
+    def log_stack(vs):
+        return jnp.stack([jnp.asarray(v, jnp.float32) for v in vs])
+    return jax.jit(log_stack)
 
 
 # Stacker programs take one argument PER scalar, and XLA compile time
@@ -68,14 +71,18 @@ def _fetch_batched(jax_vals: list) -> list[float]:
     scalars."""
     import numpy as np
     out: list[float] = []
-    for start in range(0, len(jax_vals), _MAX_STACK):
-        chunk = jax_vals[start:start + _MAX_STACK]
-        n = 1
-        while n < len(chunk):
-            n *= 2
-        padded = tuple(chunk) + (0.0,) * (n - len(chunk))
-        flat = np.asarray(_stacker(n)(padded))
-        out.extend(float(flat[i]) for i in range(len(chunk)))
+    if not jax_vals:
+        return out
+    # the transfer waits for scalars the device has not produced yet
+    with trace.span("log.fetch", scalars=len(jax_vals)):
+        for start in range(0, len(jax_vals), _MAX_STACK):
+            chunk = jax_vals[start:start + _MAX_STACK]
+            n = 1
+            while n < len(chunk):
+                n *= 2
+            padded = tuple(chunk) + (0.0,) * (n - len(chunk))
+            flat = np.asarray(_stacker(n)(padded))
+            out.extend(float(flat[i]) for i in range(len(chunk)))
     return out
 
 
@@ -140,7 +147,7 @@ class DeferredSink:
             n = len(self._pending)
         self._ensure_thread()
         if n > self._max_pending:
-            self.flush()             # backlogged: pay one batched fetch
+            self.flush("backlog")    # pay one batched fetch
 
     def __call__(self, line: str) -> None:
         with self._lock:
@@ -226,10 +233,11 @@ class DeferredSink:
                 return
             ticket = self._take_ticket_locked()
         lines: list[str] = []
-        try:
-            lines = self._format_entries(ready)
-        finally:
-            self._emit_in_turn(ticket, lines)
+        with trace.span("log.drain", entries=len(ready)):
+            try:
+                lines = self._format_entries(ready)
+            finally:
+                self._emit_in_turn(ticket, lines)
 
     def _format_entries(self, entries) -> list[str]:
         """Format entries in order, fetching every device scalar they
@@ -251,7 +259,9 @@ class DeferredSink:
     def flush_ready(self) -> None:
         self._drain_ready()
 
-    def flush(self) -> None:
+    def flush(self, reason: str = "explicit") -> None:
+        """`reason` names the caller in the span: `backlog` (submit met
+        max_pending), `explicit` (a drive loop's exit), `close`."""
         self._raise_if_failed()
         with self._lock:
             entries = list(self._pending)
@@ -261,11 +271,12 @@ class DeferredSink:
             # batch popped before this point has been written
             ticket = self._take_ticket_locked()
         lines: list[str] = []
-        try:
-            if entries:
-                lines = self._format_entries(entries)
-        finally:
-            self._emit_in_turn(ticket, lines)
+        with trace.span("log.flush", entries=len(entries), reason=reason):
+            try:
+                if entries:
+                    lines = self._format_entries(entries)
+            finally:
+                self._emit_in_turn(ticket, lines)
 
     def close(self) -> None:
         self._stop.set()
@@ -278,7 +289,7 @@ class DeferredSink:
             # out (its work is bounded: one batched fetch)
             t.join(timeout=60.0)
         try:
-            self.flush()
+            self.flush("close")
         finally:
             close = getattr(self._sink, "close", None)
             if close is not None:

@@ -9,7 +9,13 @@ JAX reads it itself and this module sets no directory in code.  Unset →
 one fixed path inside the checkout (the path is part of JAX's cache key,
 so a directory that moves never hits).  The minimum-compile-time
 threshold drops to 0 so the many sub-second programs (apply, log
-stacker, scatter buckets) are kept too.
+stacker, scatter buckets) are kept too.  Operation metadata (named
+scopes, source lines) is part of the key: left out, as JAX's default
+has it, a cache warmed by an older build hands back executables that
+carry that build's metadata, and a device trace then shows none of the
+`kps.*` scopes (measured on the chip, PERF.md PR 24).  The price is one
+compile of a program after an edit that moves the lines it was traced
+from.
 """
 
 from __future__ import annotations
@@ -29,6 +35,7 @@ def configure_compile_cache() -> None:
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def compile_cache_dir() -> str:

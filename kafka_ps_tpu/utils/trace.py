@@ -13,11 +13,19 @@ Three layers:
     runtime/net.py + docs/OBSERVABILITY.md).
   * `Tracer.span(...)` context manager — wrap any section; thread-safe,
     so the threaded runtime's per-worker threads can share one tracer.
+    Every span, on NULL_TRACER too, is also a
+    `jax.profiler.TraceAnnotation` named `kps.<name>`: while a profiler
+    session runs, the program's spans lie on the host plane of the
+    same `.xplane.pb` as the device operations, on the profiler's
+    clock (docs/OBSERVABILITY.md "One clock").  Code with no tracer
+    object calls the module-level `span(...)`, the same function.
   * `device_trace(...)` — jax.profiler wrapper capturing XLA/TPU traces
     (HLO timelines, per-op device time) to a TensorBoard logdir.
 
-Zero overhead when disabled: the module-level NULL_TRACER no-ops every
-call, and runtime code takes `tracer or NULL_TRACER`.
+Disabled (the module-level NULL_TRACER, which runtime code takes as
+`tracer or NULL_TRACER`) a counter or flow call returns at once, and a
+span costs the annotation's inactive check: no lock, no list append,
+no clock read.
 """
 
 from __future__ import annotations
@@ -58,40 +66,30 @@ class Tracer:
         self.enabled = True
 
     # -- spans -------------------------------------------------------------
-    @contextlib.contextmanager
-    def span(self, name: str, **args):
-        if not self.enabled:
-            yield
-            return
-        start = self._clock()
-        try:
-            yield
-        finally:
-            end = self._clock()
-            with self._lock:
-                self._events.append({
-                    "name": name,
-                    "ph": "X",                      # complete event
-                    "ts": (start - self._t0) * 1e6,  # µs, trace convention
-                    "dur": (end - start) * 1e6,
-                    "pid": self.pid,
-                    "tid": threading.get_ident() % 2 ** 31,
-                    "args": args,
-                })
+    def span(self, name: str, **args) -> "_Span":
+        """Context manager round a section: a profiler annotation
+        `kps.<name>` always, a Chrome event as well when this tracer is
+        enabled.  `args` are host ints and literal strings."""
+        return _Span(self, name, args)
 
     def span_at(self, name: str, start: float, end: float, **args) -> None:
         """Record a complete span from two clock values already taken
         (same clock as this tracer, time.perf_counter by default).
         For retroactive sections whose start predates the decision to
         record them — e.g. the consistency gate's hold time, known only
-        at release (runtime/server.py:_observe_gate_release)."""
-        if not self.enabled:
-            return
+        at release (runtime/server.py:_observe_gate_release).  A Chrome
+        event alone: a profiler annotation is opened and closed, it
+        cannot be stamped afterwards."""
+        if self.enabled:
+            self._record(name, start, end, args)
+
+    def _record(self, name: str, start: float, end: float,
+                args: dict) -> None:
         with self._lock:
             self._events.append({
                 "name": name,
-                "ph": "X",
-                "ts": (start - self._t0) * 1e6,
+                "ph": "X",                      # complete event
+                "ts": (start - self._t0) * 1e6,  # µs, trace convention
                 "dur": max(0.0, end - start) * 1e6,
                 "pid": self.pid,
                 "tid": threading.get_ident() % 2 ** 31,
@@ -200,6 +198,37 @@ class Tracer:
         return path
 
 
+# jax.profiler.TraceAnnotation, imported by the first span: modules that
+# only count or time (log/, serving/loadgen.py) load without JAX
+_TraceAnnotation = None
+
+
+class _Span:
+    """One `Tracer.span(...)`: the profiler annotation, and the Chrome
+    event where the tracer records."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_note", "_start")
+
+    def __init__(self, tracer: Tracer, name: str, args: dict):
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._tracer, self._name, self._args = tracer, name, args
+        self._note = _TraceAnnotation("kps." + name, **args)
+
+    def __enter__(self) -> None:
+        self._note.__enter__()
+        tracer = self._tracer
+        self._start = tracer._clock() if tracer.enabled else None
+
+    def __exit__(self, *exc) -> None:
+        if self._start is not None:
+            tracer = self._tracer
+            tracer._record(self._name, self._start, tracer._clock(),
+                           self._args)
+        self._note.__exit__(*exc)
+
+
 class LatencyRecorder:
     """Sliding-window latency samples with percentile export — the
     serving plane's p50/p99 (seconds in, milliseconds out). Bounded so
@@ -242,16 +271,25 @@ class _NullTracer(Tracer):
 
 NULL_TRACER = _NullTracer()
 
+# for code that holds no tracer object (utils/asynclog.py): the
+# annotation alone
+span = NULL_TRACER.span
+
 
 @contextlib.contextmanager
 def device_trace(logdir: str | None):
     """XLA/TPU device profiling via jax.profiler (per-op device time,
-    HLO timeline — view with TensorBoard).  None → no-op."""
+    HLO timeline, and the program's own `kps.*` spans on the host plane
+    — view with TensorBoard).  None → no-op.  The Python call tracer is
+    off: it hooks every call of the per-node loop and slows the host it
+    is measuring; the spans are TraceMe events, which stay."""
     if logdir is None:
         yield
         return
     import jax
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
     try:
         yield
     finally:
