@@ -80,7 +80,19 @@ def loss_fn(params: LogRegParams, x: jax.Array, y: jax.Array,
 
 def grad_loss(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
               cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """Closed-form (gradient, loss) of the masked softmax-CE objective.
+    """Closed-form (gradient, loss) of the masked softmax-CE objective at
+    a flat theta, the gradient flat too — see `grad_loss_onehot`."""
+    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
+    grad, loss = grad_loss_onehot(unflatten(theta, cfg), x, onehot, mask)
+    return grad.flat, loss
+
+
+def grad_loss_onehot(params: LogRegParams, x: jax.Array, onehot: jax.Array,
+                     mask: jax.Array) -> tuple[LogRegParams, jax.Array]:
+    """Closed-form (gradient leaves, loss) with the label one-hot
+    precomputed — callers running many solver steps on a fixed batch
+    (lax.scan in `fit` and the fused multi-round BSP step) hoist the
+    one-hot out of the loop.
 
     Written explicitly (G = (softmax − onehot)·mask/n; ∇W = Gᵀ·x — two
     MXU matmuls) rather than via `jax.grad` so the same code is safe
@@ -89,32 +101,20 @@ def grad_loss(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
     which would silently turn a per-worker gradient into the global sum
     (see tests/test_parallel.py::test_explicit_grad_matches_autodiff).
     """
-    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
-    return grad_loss_onehot(theta, x, onehot, mask, cfg)
-
-
-def grad_loss_onehot(theta: jax.Array, x: jax.Array, onehot: jax.Array,
-                     mask: jax.Array, cfg: ModelConfig
-                     ) -> tuple[jax.Array, jax.Array]:
-    """grad_loss with the label one-hot precomputed — callers running
-    many solver steps on a fixed batch (lax.scan in local_update and the
-    fused multi-round BSP step) hoist the one-hot out of the loop."""
-    params = unflatten(theta, cfg)
     lg = logits(params, x)
     logp = jax.nn.log_softmax(lg, axis=-1)
     denom = jnp.maximum(mask.sum(), 1.0)
     nll = -(logp * onehot).sum(axis=-1)
     loss = (nll * mask).sum() / denom
     g = (jnp.exp(logp) - onehot) * (mask / denom)[:, None]   # [B, C+1]
-    grad = LogRegParams(weights=g.T @ x, intercept=g.sum(axis=0)).flat
-    return grad, loss
+    return LogRegParams(weights=g.T @ x, intercept=g.sum(axis=0)), loss
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def local_update(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
-                 *, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """cfg.num_max_iter local optimizer iterations on the buffer →
-    (delta, loss at the updated parameters).
+def fit(params: LogRegParams, x: jax.Array, onehot: jax.Array,
+        mask: jax.Array, *, cfg: ModelConfig
+        ) -> tuple[LogRegParams, jax.Array]:
+    """cfg.num_max_iter local optimizer iterations on the buffer, on the
+    leaves → (new leaves, loss at them).
 
     The reference's "gradient" is a k-step local-solver delta
     (newWeights − oldWeights after maxIter=2 LBFGS steps,
@@ -123,6 +123,27 @@ def local_update(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
     so the whole thing is one fused XLA program; the capability
     ("k local solver steps, delta exchanged") is what is matched, not
     Spark's line-search trajectory (documented divergence, SURVEY §7).
+    The `kps.fit.*` scopes are models/mlp.py's: metadata a device trace
+    splits the time by."""
+    lr = cfg.local_learning_rate
+
+    def step(p, _):
+        with jax.named_scope("kps.fit.grad"):
+            g, _ = grad_loss_onehot(p, x, onehot, mask)
+        with jax.named_scope("kps.fit.param_step"):
+            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+
+    new, _ = jax.lax.scan(step, params, None, length=cfg.num_max_iter)
+    with jax.named_scope("kps.fit.loss"):
+        _, final_loss = grad_loss_onehot(new, x, onehot, mask)
+    return new, final_loss
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def local_update(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
+                 *, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """`fit` from a flat theta → (flat delta, loss at the updated
+    parameters).
 
     `x` may arrive in any device-slab storage form (f32/bf16 array or
     QuantizedSlab) — decode fuses into this program, and for f32 it is
@@ -136,23 +157,11 @@ def local_update(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
 def local_update_onehot(theta: jax.Array, x: jax.Array, onehot: jax.Array,
                         mask: jax.Array, *, cfg: ModelConfig
                         ) -> tuple[jax.Array, jax.Array]:
-    """local_update with the one-hot precomputed by the caller — the
-    fused multi-round BSP step hoists it above its rounds-scan (the
-    labels never change between rounds).  The `kps.fit.*` scopes are
-    models/mlp.py's: metadata a device trace splits the time by."""
-    lr = cfg.local_learning_rate
-
-    def step(t, _):
-        with jax.named_scope("kps.fit.grad"):
-            g, _ = grad_loss_onehot(t, x, onehot, mask, cfg)
-        with jax.named_scope("kps.fit.param_step"):
-            return t - lr * g, None
-
-    theta_new, _ = jax.lax.scan(step, theta, None, length=cfg.num_max_iter)
-    with jax.named_scope("kps.fit.loss"):
-        _, final_loss = grad_loss_onehot(theta_new, x, onehot, mask, cfg)
+    """local_update with the one-hot precomputed by the caller."""
+    params = unflatten(theta, cfg)
+    new, loss = fit(params, x, onehot, mask, cfg=cfg)
     with jax.named_scope("kps.fit.delta"):
-        return theta_new - theta, final_loss
+        return jax.tree.map(jnp.subtract, new, params).flat, loss
 
 
 def sparse_to_dense(rows: list[dict[int, float]], num_features: int) -> np.ndarray:

@@ -47,14 +47,19 @@ def weighted_f1_accuracy(preds: jax.Array, labels: jax.Array, n: int):
     return weighted_f1, accuracy
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def evaluate(theta: jax.Array, x_test: jax.Array, y_test: jax.Array,
-             *, cfg: ModelConfig) -> Metrics:
+def evaluate_leaves(params, x_test: jax.Array, y_test: jax.Array,
+                    *, cfg: ModelConfig) -> Metrics:
     """Full-test-set metrics, same cadence as the reference (every server
     iteration on worker 0's update, ServerProcessor.java:153-165)."""
     with jax.named_scope("kps.eval"):
-        params = unflatten(theta, cfg)
         preds = jnp.argmax(logits(params, x_test), axis=-1)
         loss = loss_fn(params, x_test, y_test, jnp.ones(x_test.shape[0]))
         f1, acc = weighted_f1_accuracy(preds, y_test, cfg.num_rows)
         return Metrics(f1=f1, accuracy=acc, loss=loss)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",))
+def evaluate(theta: jax.Array, x_test: jax.Array, y_test: jax.Array,
+             *, cfg: ModelConfig) -> Metrics:
+    """`evaluate_leaves` of a flat theta."""
+    return evaluate_leaves(unflatten(theta, cfg), x_test, y_test, cfg=cfg)

@@ -3,17 +3,26 @@
 Proves the PS runtime is model-agnostic (the reference hardwires its
 single LR task, ml/LogisticRegressionTaskSpark.java — but its processor
 layer only touches the task surface, so a faithful framework must
-accept any task honoring the same contract): a flat parameter vector
-addressed by KeyRange keys, a k-step local solver returning a delta,
-and test metrics.
+accept any task honoring the same contract, models/task.py): the
+parameters' leaves `MLPParams`, a k-step local solver `fit` that
+carries them, test metrics from them, and `unflatten` / `flatten`
+between the leaves and the flat vector addressed by KeyRange keys.
 
 Layout (flat, contiguous — the PS key space):
     W1 [H, F] | b1 [H] | W2 [C+1, H] | b2 [C+1]
 
+The flat vector exists only where a program begins and ends.  Carried
+through the local solver under the worker `vmap` it is `[workers, P]`,
+which a TPU tiles over (worker, key): every step then cut W1 out of it
+and re-laid it out as a matrix, and re-laid the gradient out to be
+concatenated back — three 1 GB copies a step at 64 workers x H=4096,
+a third of the solver's device time (PERF.md §6, PR 25).
+
 Gradients come from `jax.grad`: safe here because every caller
-(parallel/bsp.py, parallel/range_sharded.py) marks theta device-varying
-with `pcast(..., to="varying")` before differentiating inside shard_map, so no replicated
-cotangent psums are inserted (the hazard logreg.grad_loss documents).
+(parallel/bsp.py, parallel/range_sharded.py) marks the parameters
+device-varying with `pcast(..., to="varying")` before differentiating
+inside shard_map, so no replicated cotangent psums are inserted (the
+hazard logreg.grad_loss_onehot documents).
 """
 
 from __future__ import annotations
@@ -26,6 +35,7 @@ import jax.numpy as jnp
 
 from kafka_ps_tpu.compress.slab import decode_x
 from kafka_ps_tpu.models import metrics as metrics_mod
+from kafka_ps_tpu.models import task as task_mod
 from kafka_ps_tpu.utils.config import ModelConfig
 
 
@@ -63,9 +73,8 @@ def logits(params: MLPParams, x: jax.Array) -> jax.Array:
     return hidden @ params.w2.T + params.b2
 
 
-def _loss_onehot(theta, x, onehot, mask, cfg: ModelConfig):
-    lg = logits(unflatten(theta, cfg), x)
-    logp = jax.nn.log_softmax(lg, axis=-1)
+def loss_onehot(params: MLPParams, x, onehot, mask):
+    logp = jax.nn.log_softmax(logits(params, x), axis=-1)
     nll = -(logp * onehot).sum(axis=-1)
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
@@ -97,14 +106,25 @@ class MLPTask:
             w1=w1, b1=jnp.zeros(cfg.hidden_dim),
             w2=w2, b2=jnp.zeros(cfg.num_rows)))
 
+    def unflatten(self, theta) -> MLPParams:
+        return unflatten(theta, self.cfg)
+
+    def flatten(self, leaves: MLPParams) -> jax.Array:
+        return flatten(leaves)
+
+    def fit(self, leaves, x, onehot, mask):
+        return fit(leaves, x, onehot, mask, cfg=self.cfg)
+
+    def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
+        return evaluate_leaves(leaves, x_test, y_test, cfg=self.cfg)
+
     def local_update_onehot(self, theta, x, onehot, mask):
         return _local_update_onehot(theta, x, onehot, mask, cfg=self.cfg)
 
     def local_update(self, theta, x, y, mask):
-        # slab-storage decode (f32 identity) fuses into the jit below
-        x = decode_x(x)
+        # slab-storage decode (f32 identity) fuses into the caller's jit
         onehot = jax.nn.one_hot(y, self.cfg.num_rows, dtype=jnp.float32)
-        return self.local_update_onehot(theta, x, onehot, mask)
+        return self.local_update_onehot(theta, decode_x(x), onehot, mask)
 
     def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
         return _evaluate(theta, x_test, y_test, cfg=self.cfg)
@@ -118,41 +138,50 @@ class MLPTask:
     def predict_logits(self, theta, x):
         """(B, F) → (B, C) class scores — the serving plane's forward
         pass (kafka_ps_tpu/serving/engine.py)."""
-        return logits(unflatten(theta, self.cfg), x)
+        return logits(self.unflatten(theta), x)
 
+
+def fit(params: MLPParams, x, onehot, mask, *, cfg: ModelConfig):
+    """cfg.num_max_iter full-batch steps on the leaves → (new leaves,
+    loss at them).  The `kps.fit.*` scopes name each part in the
+    operations' metadata, so a device trace splits the solver's time by
+    them (metadata only)."""
+    lr = cfg.local_learning_rate
+    grad = jax.grad(loss_onehot)
+
+    def step(p, _):
+        with jax.named_scope("kps.fit.grad"):
+            g = grad(p, x, onehot, mask)
+        with jax.named_scope("kps.fit.param_step"):
+            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+
+    new, _ = jax.lax.scan(step, params, None, length=cfg.num_max_iter)
+    with jax.named_scope("kps.fit.loss"):
+        return new, loss_onehot(new, x, onehot, mask)
+
+
+def evaluate_leaves(params: MLPParams, x_test, y_test, *, cfg: ModelConfig):
+    with jax.named_scope("kps.eval"):
+        lg = logits(params, x_test)
+        preds = jnp.argmax(lg, axis=-1)
+        onehot = jax.nn.one_hot(y_test, cfg.num_rows, dtype=jnp.float32)
+        loss = loss_onehot(params, x_test, onehot,
+                           jnp.ones(x_test.shape[0]))
+        f1, acc = metrics_mod.weighted_f1_accuracy(preds, y_test,
+                                                   cfg.num_rows)
+        return metrics_mod.Metrics(f1=f1, accuracy=acc, loss=loss)
+
+
+# The flat entry points, jitted like logreg.local_update so that a
+# caller holding one flat theta pays one cached XLA program (re-jitting
+# inside an enclosing jit — the per-node solver programs — is free: it
+# inlines).
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _local_update_onehot(theta, x, onehot, mask, *, cfg: ModelConfig):
-    """Jitted like logreg.local_update so the per-node worker hot path
-    runs one cached XLA program per iteration (re-jitting inside an
-    enclosing jit — the fused BSP steps — is free: it inlines).  The
-    `kps.fit.*` scopes name each part in the operations' metadata, so a
-    device trace splits the solver's time by them (metadata only)."""
-    lr = cfg.local_learning_rate
-    grad = jax.grad(_loss_onehot)
-
-    def step(t, _):
-        with jax.named_scope("kps.fit.grad"):
-            g = grad(t, x, onehot, mask, cfg)
-        with jax.named_scope("kps.fit.param_step"):
-            return t - lr * g, None
-
-    theta_new, _ = jax.lax.scan(step, theta, None, length=cfg.num_max_iter)
-    with jax.named_scope("kps.fit.loss"):
-        final_loss = _loss_onehot(theta_new, x, onehot, mask, cfg)
-    with jax.named_scope("kps.fit.delta"):
-        return theta_new - theta, final_loss
+    return task_mod.flat_local_update(MLPTask(cfg), theta, x, onehot, mask)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _evaluate(theta, x_test, y_test, *, cfg: ModelConfig):
-    with jax.named_scope("kps.eval"):
-        params = unflatten(theta, cfg)
-        lg = logits(params, x_test)
-        preds = jnp.argmax(lg, axis=-1)
-        onehot = jax.nn.one_hot(y_test, cfg.num_rows, dtype=jnp.float32)
-        loss = _loss_onehot(theta, x_test, onehot,
-                            jnp.ones(x_test.shape[0]), cfg)
-        f1, acc = metrics_mod.weighted_f1_accuracy(preds, y_test,
-                                                   cfg.num_rows)
-        return metrics_mod.Metrics(f1=f1, accuracy=acc, loss=loss)
+    return evaluate_leaves(unflatten(theta, cfg), x_test, y_test, cfg=cfg)

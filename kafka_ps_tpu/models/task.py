@@ -8,11 +8,21 @@ over a flat integer-keyed parameter vector.  The processors only ever
 touch that surface, so the PS runtime is model-agnostic in spirit —
 this module makes it so in fact.  A task owns:
 
-  * the flat parameter layout (`num_params` — the KeyRange key space),
-  * the k-step local solver (`local_update` → delta, the "gradient"
-    the reference exchanges, LogisticRegressionTaskSpark.java:179-220),
-  * test evaluation (`evaluate` → weighted F1 / accuracy / loss,
-    Metrics.java:15-24).
+  * the flat parameter layout (`num_params` — the KeyRange key space)
+    and the way between it and the parameters' leaves (`unflatten`,
+    `flatten`),
+  * the k-step local solver on the leaves (`fit` → new leaves; its
+    difference from the old ones is the delta, the "gradient" the
+    reference exchanges, LogisticRegressionTaskSpark.java:179-220),
+  * test evaluation from the leaves (`evaluate_leaves` → weighted F1 /
+    accuracy / loss, Metrics.java:15-24).
+
+Inside a solver program the parameters are their leaves; the flat
+vector is what a program takes and returns (the wire and server
+contract).  The flat entry points (`local_update`, `evaluate`, …) are
+that surface wrapped — unflatten, fit, flatten the delta — for callers
+that hold one flat theta and want one flat delta: the range-sharded
+step, the server's eval, serving, the Pallas kernels' callers.
 
 Every entry point (runtime worker, fused BSP step, range-sharded step,
 server eval) dispatches through a task; `logreg` stays the default —
@@ -22,9 +32,10 @@ runtime generalizes.
 
 from __future__ import annotations
 
-from typing import Protocol
+from typing import Any, Protocol
 
 import jax
+import jax.numpy as jnp
 
 from kafka_ps_tpu.models import logreg
 from kafka_ps_tpu.models import metrics as metrics_mod
@@ -35,7 +46,8 @@ class MLTask(Protocol):
     """What the PS runtime needs from a model family.  All functions are
     jit-safe and shard_map-safe (no data-dependent Python control flow;
     gradients must not rely on AD of replicated operands — see
-    logreg.grad_loss's note on shard_map cotangent psums)."""
+    logreg.grad_loss_onehot's note on shard_map cotangent psums).
+    `leaves` is the family's pytree of parameter arrays."""
 
     cfg: ModelConfig
 
@@ -43,6 +55,15 @@ class MLTask(Protocol):
     def num_params(self) -> int: ...
 
     def init_params(self) -> jax.Array: ...
+
+    def unflatten(self, theta) -> Any: ...
+
+    def flatten(self, leaves) -> jax.Array: ...
+
+    def fit(self, leaves, x, onehot, mask): ...
+
+    def evaluate_leaves(self, leaves, x_test, y_test) \
+            -> metrics_mod.Metrics: ...
 
     def local_update(self, theta, x, y, mask): ...
 
@@ -54,6 +75,21 @@ class MLTask(Protocol):
             -> metrics_mod.Metrics: ...
 
     def predict_logits(self, theta, x) -> jax.Array: ...
+
+
+def fit_delta(task: MLTask, leaves, x, onehot, mask):
+    """k local steps from `leaves` → (delta leaves, loss at the new
+    parameters)."""
+    new, loss = task.fit(leaves, x, onehot, mask)
+    with jax.named_scope("kps.fit.delta"):
+        return jax.tree.map(jnp.subtract, new, leaves), loss
+
+
+def flat_local_update(task: MLTask, theta, x, onehot, mask):
+    """The flat face of the solver: one flat theta in, one flat delta
+    out."""
+    delta, loss = fit_delta(task, task.unflatten(theta), x, onehot, mask)
+    return task.flatten(delta), loss
 
 
 class LogRegTask:
@@ -69,6 +105,19 @@ class LogRegTask:
 
     def init_params(self):
         return logreg.init_params(self.cfg).flat
+
+    def unflatten(self, theta) -> logreg.LogRegParams:
+        return logreg.unflatten(theta, self.cfg)
+
+    def flatten(self, leaves: logreg.LogRegParams) -> jax.Array:
+        return leaves.flat
+
+    def fit(self, leaves, x, onehot, mask):
+        return logreg.fit(leaves, x, onehot, mask, cfg=self.cfg)
+
+    def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
+        return metrics_mod.evaluate_leaves(leaves, x_test, y_test,
+                                           cfg=self.cfg)
 
     def local_update(self, theta, x, y, mask):
         return logreg.local_update(theta, x, y, mask, cfg=self.cfg)
@@ -93,7 +142,7 @@ class LogRegTask:
     def predict_logits(self, theta, x):
         """(B, F) → (B, C+1) class scores — the serving plane's forward
         pass (kafka_ps_tpu/serving/engine.py)."""
-        return logreg.logits(logreg.unflatten(theta, self.cfg), x)
+        return logreg.logits(self.unflatten(theta), x)
 
 
 _REGISTRY = {"logreg": LogRegTask}

@@ -316,7 +316,7 @@ def _mlp_kernel(x_ref, y_ref, mask_ref, w1_ref, b1_ref, w2_ref, b2_ref,
     neg_inf_pad = (1.0 - valid) * (-1e30)
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     # Out-of-range labels: jax.nn.one_hot yields an all-zero row, and
-    # jax.grad of the one-hot CE (models/mlp._loss_onehot — the XLA
+    # jax.grad of the one-hot CE (models/mlp.loss_onehot — the XLA
     # path this kernel must match) then gives that row ZERO gradient.
     # The closed-form (softmax - onehot) does NOT (it leaves softmax),
     # so the row-validity factor kills it explicitly.  NOTE this

@@ -15,12 +15,14 @@ A `GangDispatcher` claims a release set (advertised by the server's
 advisory `GangNotice` on GANG_TOPIC alongside the per-worker messages),
 runs `_prepare` on every member (each keeps its private buffer slab and
 `num_tuples_seen` version), stacks the member slabs, and runs ONE
-vmapped solver dispatch over the (k, …) batch — theta broadcasts when
-the set shares one weights array (sequential consistency: the server
-aliases the same device theta into every member's message), stacks
-otherwise (bounded/eventual sets with differing clocks).  The k deltas
-and metric futures are unstacked INSIDE the jit (one dispatch, k
-buffers out), then `_finish` runs per member in worker-id order — the
+vmapped solver dispatch over the (k, …) batch — the parameters' leaves
+broadcast when the set shares one weights array (sequential
+consistency: the server aliases the same device theta into every
+member's message), stack otherwise (bounded/eventual sets with
+differing clocks).  The k flat deltas and metric futures are fanned out
+INSIDE the jit (one dispatch, k buffers out; `_gang_solver_fns` says
+what that costs on the device), then `_finish` runs per member in
+worker-id order — the
 same per-worker CSV rows and the same per-worker GradientMessages, in
 the same order, as the per-message path.  Bitwise equivalence with the
 per-message path is a tested invariant (tests/test_gang.py), not an
@@ -78,7 +80,8 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
     """Batched counterparts of worker._solver_fns, one compile per
     (task, cfg, member-count) — four jit'd entry points over TUPLES of
     per-member arrays (stacked inside the jit, so stacking costs no
-    extra dispatch; unstacked inside the jit, so fan-out costs none
+    extra dispatch), each returning k flat deltas and k scalars of each
+    kind (fanned out inside the jit, so fan-out costs no dispatch
     either):
 
       update_stacked(thetas, xs, ys, masks)
@@ -86,48 +89,26 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
       update_eval_stacked(thetas, xs, ys, masks, test_x, test_y)
       update_eval_bcast(theta, xs, ys, masks, test_x, test_y)
 
-    The non-pallas variants vmap the SAME composite function the
-    single-dispatch path jits (vmap preserves per-element semantics —
-    the bitwise-equivalence test in tests/test_gang.py is the
-    contract).  With use_pallas the solver goes through the batched
-    kernels (ops/fused_update.*_batched: the grid over the worker axis
-    where the member slab is resident-sized, the streaming kernel per
-    member otherwise); a kernel that fails to compile fails the run."""
+    The non-pallas variants vmap the SAME leaf-level function the
+    single-dispatch path jits (worker.fit_and_eval; vmap preserves
+    per-element semantics — the bitwise-equivalence test in
+    tests/test_gang.py is the contract).  The flat vectors stop at the
+    program's edges: a shared theta is unflattened once, member thetas
+    one by one and their leaves stacked; the deltas leave as stacked
+    leaves [k, …], and member i's flat delta is flattened from row i of
+    every leaf.  What the fan-out costs on the device is that
+    concatenation, 16.9 MB written a member at H=4096; cut out of a
+    [k, P] array, whose TPU tiles interleave eight members, it cost 16%
+    of the program (PERF.md §6, PR 25).  With use_pallas the solver
+    goes through the batched kernels (ops/fused_update.*_batched: the
+    grid over the worker axis where the member slab is resident-sized,
+    the streaming kernel per member otherwise), which take and return
+    [k, P]; a kernel that fails to compile fails the run."""
     import jax
     import jax.numpy as jnp
 
     from kafka_ps_tpu.models.task import get_task
     task = get_task(task_name, cfg)
-
-    if use_pallas:
-        from kafka_ps_tpu.ops import fused_update
-        interpret = use_pallas == "interpret"
-        single = {"logreg": fused_update.local_update,
-                  "mlp": fused_update.mlp_local_update}[task_name]
-        batched = {"logreg": fused_update.local_update_batched,
-                   "mlp": fused_update.mlp_local_update_batched
-                   }[task_name]
-
-        def solver_b(thetas, xs, ys, masks):
-            return batched(thetas, xs, ys, masks, cfg=cfg,
-                           interpret=interpret)
-
-        def solver_1(theta, x, y, mask):
-            return single(theta, x, y, mask, cfg=cfg, interpret=interpret)
-    else:
-        solver_1 = task.local_update
-        solver_b = jax.vmap(solver_1)
-
-    # the exact composite the single path jits (worker._solver_fns):
-    # k-step solver + full-test-set eval of theta+delta, one program.
-    # The two scopes split that program's device time into training
-    # and the members' test-set evaluations (metadata only).
-    def composite(theta, x, y, mask, test_x, test_y):
-        with jax.named_scope("kps.gang.fit"):
-            delta, loss = solver_1(theta, x, y, mask)
-        with jax.named_scope("kps.gang.eval"):
-            m = task.evaluate(theta + delta, test_x, test_y)
-        return delta, loss, m.f1, m.accuracy
 
     def unstack(a, k):
         return tuple(a[i] for i in range(k))
@@ -135,66 +116,88 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
     def tstack(items):
         # componentwise stack: identical to jnp.stack for plain member
         # slabs, and stacks QuantizedSlab (int8 slab storage,
-        # compress/slab.py) field-by-field — vmap then maps over the
-        # leading axis of every leaf, preserving per-element semantics
+        # compress/slab.py) and parameter leaves field-by-field — vmap
+        # then maps over the leading axis of every leaf, preserving
+        # per-element semantics
         return jax.tree.map(lambda *leaves: jnp.stack(leaves), *items)
 
-    @jax.jit
-    def update_stacked(thetas, xs, ys, masks):
-        k = len(xs)
-        deltas, losses = solver_b(jnp.stack(thetas), tstack(xs),
-                                  jnp.stack(ys), jnp.stack(masks))
-        return unstack(deltas, k), unstack(losses, k)
+    if use_pallas:
+        from kafka_ps_tpu.ops import fused_update
+        interpret = use_pallas == "interpret"
+        batched = {"logreg": fused_update.local_update_batched,
+                   "mlp": fused_update.mlp_local_update_batched
+                   }[task_name]
 
-    @jax.jit
-    def update_bcast(theta, xs, ys, masks):
-        k = len(xs)
-        if use_pallas:
-            thetas = jnp.broadcast_to(theta[None], (k,) + theta.shape)
-            deltas, losses = solver_b(thetas, tstack(xs),
-                                      jnp.stack(ys), jnp.stack(masks))
-        else:
-            deltas, losses = jax.vmap(solver_1, in_axes=(None, 0, 0, 0))(
-                theta, tstack(xs), jnp.stack(ys), jnp.stack(masks))
-        return unstack(deltas, k), unstack(losses, k)
-
-    @jax.jit
-    def update_eval_stacked(thetas, xs, ys, masks, test_x, test_y):
-        k = len(xs)
-        T = jnp.stack(thetas)
-        X, Y, M = tstack(xs), jnp.stack(ys), jnp.stack(masks)
-        if use_pallas:
+        def solve(thetas, shared, xs, ys, masks):
+            k = len(xs)
+            T = (jnp.broadcast_to(thetas[None], (k,) + thetas.shape)
+                 if shared else jnp.stack(thetas))
             with jax.named_scope("kps.gang.fit"):
-                deltas, losses = solver_b(T, X, Y, M)
+                deltas, losses = batched(
+                    T, tstack(xs), jnp.stack(ys), jnp.stack(masks),
+                    cfg=cfg, interpret=interpret)
+            return T, deltas, losses
+
+        def update(thetas, shared, xs, ys, masks):
+            k = len(xs)
+            _, deltas, losses = solve(thetas, shared, xs, ys, masks)
+            return unstack(deltas, k), unstack(losses, k)
+
+        def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
+            k = len(xs)
+            T, deltas, losses = solve(thetas, shared, xs, ys, masks)
             with jax.named_scope("kps.gang.eval"):
                 met = jax.vmap(lambda t, d: task.evaluate(
                     t + d, test_x, test_y))(T, deltas)
-            f1s, accs = met.f1, met.accuracy
-        else:
+            return (unstack(deltas, k), unstack(losses, k),
+                    unstack(met.f1, k), unstack(met.accuracy, k))
+    else:
+        def member_leaves(thetas, shared):
+            """(leaves, their vmap axis): one set for a shared theta,
+            else the members' own, stacked leaf by leaf."""
+            if shared:
+                return task.unflatten(thetas), None
+            return tstack([task.unflatten(t) for t in thetas]), 0
+
+        def fan_out(deltas, k):
+            return tuple(task.flatten(jax.tree.map(lambda a: a[i], deltas))
+                         for i in range(k))
+
+        def update(thetas, shared, xs, ys, masks):
+            k = len(xs)
+            leaves, axis = member_leaves(thetas, shared)
+            deltas, losses = jax.vmap(
+                functools.partial(worker_mod.fit_slab, task),
+                in_axes=(axis, 0, 0, 0))(
+                    leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks))
+            return fan_out(deltas, k), unstack(losses, k)
+
+        def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
+            k = len(xs)
+            leaves, axis = member_leaves(thetas, shared)
             deltas, losses, f1s, accs = jax.vmap(
-                composite, in_axes=(0, 0, 0, 0, None, None))(
-                    T, X, Y, M, test_x, test_y)
-        return (unstack(deltas, k), unstack(losses, k),
-                unstack(f1s, k), unstack(accs, k))
+                functools.partial(worker_mod.fit_and_eval, task),
+                in_axes=(axis, 0, 0, 0, None, None))(
+                    leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks),
+                    test_x, test_y)
+            return (fan_out(deltas, k), unstack(losses, k),
+                    unstack(f1s, k), unstack(accs, k))
+
+    @jax.jit
+    def update_stacked(thetas, xs, ys, masks):
+        return update(thetas, False, xs, ys, masks)
+
+    @jax.jit
+    def update_bcast(theta, xs, ys, masks):
+        return update(theta, True, xs, ys, masks)
+
+    @jax.jit
+    def update_eval_stacked(thetas, xs, ys, masks, test_x, test_y):
+        return update_eval(thetas, False, xs, ys, masks, test_x, test_y)
 
     @jax.jit
     def update_eval_bcast(theta, xs, ys, masks, test_x, test_y):
-        k = len(xs)
-        X, Y, M = tstack(xs), jnp.stack(ys), jnp.stack(masks)
-        if use_pallas:
-            thetas = jnp.broadcast_to(theta[None], (k,) + theta.shape)
-            with jax.named_scope("kps.gang.fit"):
-                deltas, losses = solver_b(thetas, X, Y, M)
-            with jax.named_scope("kps.gang.eval"):
-                met = jax.vmap(lambda t, d: task.evaluate(
-                    t + d, test_x, test_y))(thetas, deltas)
-            f1s, accs = met.f1, met.accuracy
-        else:
-            deltas, losses, f1s, accs = jax.vmap(
-                composite, in_axes=(None, 0, 0, 0, None, None))(
-                    theta, X, Y, M, test_x, test_y)
-        return (unstack(deltas, k), unstack(losses, k),
-                unstack(f1s, k), unstack(accs, k))
+        return update_eval(theta, True, xs, ys, masks, test_x, test_y)
 
     return {"update_stacked": update_stacked,
             "update_bcast": update_bcast,

@@ -32,6 +32,7 @@ import numpy as np
 
 from kafka_ps_tpu.compress import slab as slab_mod
 from kafka_ps_tpu.data.buffer import SlidingBuffer
+from kafka_ps_tpu.models.task import fit_delta
 from kafka_ps_tpu.runtime import fabric as fabric_mod
 from kafka_ps_tpu.runtime.messages import GradientMessage, KeyRange, WeightsMessage
 from kafka_ps_tpu.telemetry import NULL_MODEL_HEALTH, NULL_TELEMETRY
@@ -59,6 +60,32 @@ def solver_program(cfg: PSConfig) -> str:
     return f"pallas-{name}"
 
 
+def fit_slab(task, leaves, x, y, mask):
+    """The k-step solver on one member's slab as the worker stores it
+    (labels, any slab storage form) → (delta leaves, loss)."""
+    onehot = jax.nn.one_hot(y, task.cfg.num_rows, dtype=jnp.float32)
+    return fit_delta(task, leaves, slab_mod.decode_x(x), onehot, mask)
+
+
+def fit_and_eval(task, leaves, x, y, mask, test_x, test_y):
+    """One member's iteration on the parameters' leaves: the k-step
+    solver, then the full-test-set evaluation of the post-fit model →
+    (delta leaves, loss, f1, accuracy).  The single program below and
+    the gang programs (runtime/gang.py, vmapped over the members) run
+    this one function.  The evaluated model is `leaves + delta`, what
+    the server holds after applying this delta alone — bitwise the
+    `theta + delta` the flat formulation evaluated (and the Pallas arm
+    still does); the fit's own result differs from it by one float32
+    rounding.  The two scopes split the program's device time into
+    training and the members' evaluations (metadata only)."""
+    with jax.named_scope("kps.gang.fit"):
+        delta, loss = fit_slab(task, leaves, x, y, mask)
+    with jax.named_scope("kps.gang.eval"):
+        m = task.evaluate_leaves(jax.tree.map(jnp.add, leaves, delta),
+                                 test_x, test_y)
+    return delta, loss, m.f1, m.accuracy
+
+
 @functools.lru_cache(maxsize=None)
 def _solver_fns(task_name: str, cfg, use_pallas: bool | str):
     """One compiled program per (task, cfg) — shared by every WorkerNode
@@ -69,7 +96,9 @@ def _solver_fns(task_name: str, cfg, use_pallas: bool | str):
     as ONE dispatch instead of three (update, theta+delta, evaluate).
     Metric semantics are unchanged — each worker still evaluates its
     own post-fit model, like the reference's in-iteration eval
-    (LogisticRegressionTaskSpark.java:186).
+    (LogisticRegressionTaskSpark.java:186).  Both take and return flat
+    vectors (the wire contract); the XLA arm works on the leaves
+    between.
 
     `use_pallas`: False = the XLA solver; True = the compiled Mosaic
     kernel (TPU only — no fallback, ops/fused_update.py);
@@ -84,13 +113,18 @@ def _solver_fns(task_name: str, cfg, use_pallas: bool | str):
 
         def update_fn(theta, x, y, mask):
             return kernel(theta, x, y, mask, cfg=cfg, interpret=interpret)
+
+        def update_and_eval(theta, x, y, mask, test_x, test_y):
+            delta, loss = update_fn(theta, x, y, mask)
+            m = task.evaluate(theta + delta, test_x, test_y)
+            return delta, loss, m.f1, m.accuracy
     else:
         update_fn = task.local_update
 
-    def update_and_eval(theta, x, y, mask, test_x, test_y):
-        delta, loss = update_fn(theta, x, y, mask)
-        m = task.evaluate(theta + delta, test_x, test_y)
-        return delta, loss, m.f1, m.accuracy
+        def update_and_eval(theta, x, y, mask, test_x, test_y):
+            delta, *scalars = fit_and_eval(task, task.unflatten(theta),
+                                           x, y, mask, test_x, test_y)
+            return task.flatten(delta), *scalars
 
     return jax.jit(update_fn), jax.jit(update_and_eval)
 

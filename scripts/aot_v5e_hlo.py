@@ -1,0 +1,180 @@
+"""Compile the solver programs for a TPU v5e that is described, not
+attached, and list what the compiler made of them (PERF.md §3, solver).
+
+libtpu on a host without a chip compiles for `v5e:2x2` from shapes
+alone (`jax.experimental.topologies`), a few seconds a program.  The
+programs compiled this way are the chip's: the same instruction names
+as a device trace shows and the same `temp_size_in_bytes` as the
+ledger's `memory_scratch_bytes`.  Nothing runs, so this gives no time —
+only the instructions, their shapes, XLA's own cycle estimates and the
+program's bytes.
+
+    JAX_PLATFORMS=cpu python scripts/aot_v5e_hlo.py            # cell sizes
+    JAX_PLATFORMS=cpu python scripts/aot_v5e_hlo.py --hidden 512 --workers 8
+
+What to look for: an instruction outside the fused computations whose
+result is as large as W1 times the workers on a chip — a `copy` or a
+`slice` there is a relayout of every worker's parameters, which is
+what carrying the flat key-space vector through the local solver cost
+(PERF.md §6, PR 25).
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+PROGRAMS = ("bsp_scan", "bsp_scan_mesh", "gang")
+
+_DTYPE_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1,
+                "f16": 2, "s8": 1, "u8": 1}
+_RESULT = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]")
+
+
+def describe_v5e():
+    """The `v5e:2x2` topology, or raises what libtpu raises where it
+    offers none."""
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+def compile_program(program: str, topo, *, hidden: int = 4096,
+                    workers: int = 64, rows: int = 1024, features: int = 1024,
+                    classes: int = 5, test_rows: int = 2000, rounds: int = 8):
+    """One solver program of the benchmark's cells (`workers` a chip),
+    compiled for the described chip(s) from shapes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                              SingleDeviceSharding)
+
+    from kafka_ps_tpu.models.task import get_task
+    from kafka_ps_tpu.parallel import bsp
+    from kafka_ps_tpu.parallel.mesh import WORKER_AXIS
+    from kafka_ps_tpu.runtime import gang
+    from kafka_ps_tpu.utils.config import ModelConfig
+
+    cfg = ModelConfig(num_features=features, num_classes=classes,
+                      hidden_dim=hidden, num_max_iter=2,
+                      local_learning_rate=0.005)
+    task = get_task("mlp", cfg)
+    if program == "bsp_scan_mesh":
+        import numpy as np
+        mesh = Mesh(np.array(topo.devices), (WORKER_AXIS,))
+        n = workers * mesh.devices.size
+        shared = NamedSharding(mesh, P())
+        split = NamedSharding(mesh, P(WORKER_AXIS))
+    else:
+        mesh, n = None, workers
+        shared = split = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype, sharding):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    theta = shape((task.num_params,), jnp.float32, shared)
+    if program == "gang":
+        fn = gang._gang_solver_fns("mlp", cfg, False)["update_eval_bcast"]
+        args = (theta,
+                (shape((rows, features), jnp.float32, shared),) * n,
+                (shape((rows,), jnp.int32, shared),) * n,
+                (shape((rows,), jnp.float32, shared),) * n,
+                shape((test_rows, features), jnp.float32, shared),
+                shape((test_rows,), jnp.int32, shared))
+    else:
+        fn = bsp.make_bsp_multi_step(cfg, n, 1.0 / n, rounds, mesh=mesh,
+                                     task=task)
+        args = (theta, shape((n, rows, features), jnp.float32, split),
+                shape((n, rows), jnp.int32, split),
+                shape((n, rows), jnp.float32, split))
+    return fn.lower(*args).compile()
+
+
+def top_level_instructions(hlo_text: str):
+    """(computation, name, line, result bytes, XLA's estimated cycles)
+    for every instruction that is not inside a fused computation: what
+    the device runs one after another."""
+    fused = set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", hlo_text))
+    out = []
+    comp = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _RESULT.match(line)
+        if comp is None or comp in fused or not m:
+            continue
+        name, dtype, dims = m.groups()
+        size = _DTYPE_BYTES.get(dtype, 4)
+        for d in filter(None, dims.split(",")):
+            size *= int(d)
+        cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+        out.append((comp, name, line.strip(), size,
+                    int(cycles.group(1)) if cycles else 0))
+    return out
+
+
+def big_relayouts(hlo_text: str, at_least_bytes: int):
+    """Top-level `copy` and `slice` instructions whose result holds at
+    least `at_least_bytes`."""
+    return [(comp, name, size)
+            for comp, name, _, size, _ in top_level_instructions(hlo_text)
+            if size >= at_least_bytes
+            and re.match(r"(copy|slice)[.\d]*$", name)]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--hidden", type=int, default=4096)
+    ap.add_argument("--workers", type=int, default=64,
+                    help="logical workers a chip")
+    ap.add_argument("--rows", type=int, default=1024)
+    ap.add_argument("--programs", nargs="*", default=list(PROGRAMS),
+                    choices=PROGRAMS)
+    ap.add_argument("--dump", help="directory for each program's HLO text")
+    ap.add_argument("--top", type=int, default=14,
+                    help="costliest top-level instructions to list a program")
+    args = ap.parse_args(argv)
+
+    topo = describe_v5e()
+    w1_bytes = args.hidden * 1024 * 4
+    for program in args.programs:
+        compiled = compile_program(program, topo, hidden=args.hidden,
+                                   workers=args.workers, rows=args.rows)
+        text = compiled.as_text()
+        mem = compiled.memory_analysis()
+        cost = compiled.cost_analysis() or {}
+        print(f"== {program}: scratch {mem.temp_size_in_bytes / 1e9:.4f} GB, "
+              f"bytes accessed {cost.get('bytes accessed', 0) / 1e9:.2f} GB")
+        if args.dump:
+            os.makedirs(args.dump, exist_ok=True)
+            with open(os.path.join(args.dump, program + ".hlo.txt"),
+                      "w") as f:
+                f.write(text)
+        instrs = top_level_instructions(text)
+        by_comp: dict[str, int] = {}
+        for comp, *_, cycles in instrs:
+            by_comp[comp] = by_comp.get(comp, 0) + cycles
+        print("   estimated cycles by computation (M): " + ", ".join(
+            f"{c[:24]} {n / 1e6:.1f}" for c, n in sorted(
+                by_comp.items(), key=lambda kv: -kv[1])[:4]))
+        for comp, name, line, size, cycles in sorted(
+                instrs, key=lambda r: -r[4])[:args.top]:
+            print(f"   {cycles / 1e6:7.2f} Mcyc {size / 1e6:8.1f} MB  "
+                  f"{comp[:20]:20s} {line[:100]}")
+        bad = big_relayouts(text, (args.workers * w1_bytes) // 2)
+        print(f"   copy/slice results of half of workers x W1 or more: "
+              f"{[(c[:24], n) for c, n, _ in bad] or 'none'}")
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -59,7 +59,7 @@ def test_mlp_grad_matches_autodiff_reference():
     mask = mask.at[-10:].set(0.0)
     theta = task.init_params()
     onehot = jax.nn.one_hot(y, CFG.num_rows, dtype=jnp.float32)
-    loss_before = mlp._loss_onehot(theta, x, onehot, mask, CFG)
+    loss_before = mlp.loss_onehot(mlp.unflatten(theta, CFG), x, onehot, mask)
     delta, loss_after = task.local_update(theta, x, y, mask)
     assert np.isfinite(np.asarray(delta)).all()
     assert float(loss_after) < float(loss_before)
@@ -149,3 +149,101 @@ def test_mlp_streaming_app_end_to_end():
     assert float(app.server.last_metrics.accuracy) > 0.5
     # theta is the MLP layout, not logreg's
     assert app.server.theta.shape == (mlp.num_params(CFG),)
+
+# -- the leaf-level surface against the flat-carry solver it replaced (PR 25) --
+
+def flat_carry_reference(family, cfg, theta, x, y, mask):
+    """The solver as it stood before PR 25, written out plainly: the
+    scan carries the FLAT vector, every step unflattens it, takes the
+    gradient flat and steps `t - lr * g` on the flat vector; the final
+    loss unflattens once more.  (A TPU re-laid the parameters out for
+    each of those; the numbers are what must not change.)"""
+    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
+    lr = cfg.local_learning_rate
+
+    if family == "mlp":
+        def loss_of(t):
+            p = mlp.unflatten(t, cfg)
+            hidden = jax.nn.relu(x @ p.w1.T + p.b1)
+            logp = jax.nn.log_softmax(hidden @ p.w2.T + p.b2, axis=-1)
+            nll = -(logp * onehot).sum(axis=-1)
+            return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+
+        def grad_loss(t):
+            return jax.grad(loss_of)(t), loss_of(t)
+    else:
+        def grad_loss(t):
+            n_coef = cfg.num_rows * cfg.num_features
+            w = t[:n_coef].reshape(cfg.num_rows, cfg.num_features)
+            logp = jax.nn.log_softmax(x @ w.T + t[n_coef:], axis=-1)
+            denom = jnp.maximum(mask.sum(), 1.0)
+            loss = (-(logp * onehot).sum(axis=-1) * mask).sum() / denom
+            g = (jnp.exp(logp) - onehot) * (mask / denom)[:, None]
+            return jnp.concatenate([(g.T @ x).reshape(-1),
+                                    g.sum(axis=0)]), loss
+
+    @jax.jit
+    def solve(t0):
+        t, _ = jax.lax.scan(lambda t, _: (t - lr * grad_loss(t)[0], None),
+                            t0, None, length=cfg.num_max_iter)
+        return t - t0, grad_loss(t)[1]
+
+    return solve(theta)
+
+
+def _solver_case(family, k):
+    cfg = ModelConfig(num_features=24, num_classes=3, hidden_dim=16,
+                      num_max_iter=k, local_learning_rate=0.05)
+    task = get_task(family, cfg)
+    x, y, mask = _data(cfg=cfg, seed=10 + k)
+    mask = mask.at[-10:].set(0.0)           # masked rows
+    y = y.at[3].set(cfg.num_rows + 2)       # an out-of-range label
+    # away from logreg's all-zero start, where rounding has no room
+    theta = task.init_params() + 0.01 * jax.random.normal(
+        jax.random.PRNGKey(k), (task.num_params,))
+    return cfg, task, theta, x, y, mask
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+@pytest.mark.parametrize("family", ["mlp", "logreg"])
+def test_local_update_matches_the_flat_carry_solver(family, k):
+    """Same steps on the same numbers in another memory layout: on the
+    CPU the new solver's delta and loss are BITWISE the flat-carry
+    solver's (elementwise steps and differences do not care how the
+    parameters are grouped, and the matrix products see the same
+    operands), so the comparison is exact, not 1e-6."""
+    cfg, task, theta, x, y, mask = _solver_case(family, k)
+    d_ref, l_ref = flat_carry_reference(family, cfg, theta, x, y, mask)
+    d_new, l_new = jax.jit(task.local_update)(theta, x, y, mask)
+    assert np.isfinite(np.asarray(d_ref)).all() and np.asarray(d_ref).any()
+    np.testing.assert_array_equal(np.asarray(d_new), np.asarray(d_ref))
+    assert float(l_new) == float(l_ref)
+
+
+@pytest.mark.parametrize("family", ["mlp", "logreg"])
+def test_flatten_inverts_unflatten_through_the_protocol(family):
+    task = get_task(family, CFG)
+    theta = jax.random.normal(jax.random.PRNGKey(3), (task.num_params,))
+    leaves = task.unflatten(theta)
+    assert sum(a.size for a in jax.tree.leaves(leaves)) == task.num_params
+    np.testing.assert_array_equal(np.asarray(task.flatten(leaves)),
+                                  np.asarray(theta))
+
+
+@pytest.mark.parametrize("family", ["mlp", "logreg"])
+def test_flat_wrapper_is_flatten_of_the_leaf_level_fit(family):
+    """One solver a family: `local_update` is unflatten, `fit`, the
+    difference, flatten — and `evaluate` is `evaluate_leaves`."""
+    cfg, task, theta, x, y, mask = _solver_case(family, 2)
+    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
+    leaves = task.unflatten(theta)
+    new, loss = jax.jit(task.fit)(leaves, x, onehot, mask)
+    delta, loss_flat = jax.jit(task.local_update)(theta, x, y, mask)
+    np.testing.assert_array_equal(
+        np.asarray(delta),
+        np.asarray(task.flatten(jax.tree.map(jnp.subtract, new, leaves))))
+    assert float(loss_flat) == float(loss)
+    y_ok = jnp.clip(y, 0, cfg.num_rows - 1)
+    m_flat = task.evaluate(theta, x, y_ok)
+    m_leaf = jax.jit(task.evaluate_leaves)(leaves, x, y_ok)
+    assert [float(v) for v in m_flat] == [float(v) for v in m_leaf]

@@ -7,6 +7,7 @@ fixture."""
 import functools
 import glob
 import os
+import re
 import time
 
 import jax
@@ -251,6 +252,7 @@ def test_last_run_counts_the_refresh_and_its_bytes():
 @functools.lru_cache(maxsize=None)
 def lowered_text(program: str) -> str:
     from kafka_ps_tpu.models import logreg, mlp
+    from kafka_ps_tpu.models.task import fit_delta
     from kafka_ps_tpu.parallel import bsp
     from kafka_ps_tpu.runtime import gang
     cfg = ModelConfig(num_features=16, num_classes=3, hidden_dim=8)
@@ -261,8 +263,12 @@ def lowered_text(program: str) -> str:
     mask = jnp.ones((2, 8))
     onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
     if program == "mlp_local_update":
-        low = mlp._local_update_onehot.lower(theta, x[0], onehot[0],
-                                             mask[0], cfg=cfg)
+        # the solver as the BSP round and the gang run it: from one
+        # shared flat theta, on the leaves, under a 2-worker vmap
+        low = jax.jit(lambda t, xs, os, ms: jax.vmap(
+            lambda xx, oo, mm: fit_delta(task, task.unflatten(t),
+                                         xx, oo, mm))(xs, os, ms)
+                      ).lower(theta, x, onehot, mask)
     elif program == "logreg_local_update":
         low = logreg.local_update.lower(
             logreg.init_params(cfg).flat, x[0], y[0], mask[0], cfg=cfg)
@@ -298,6 +304,48 @@ BSP = FIT + ["kps.bsp.reduce", "kps.bsp.apply"]
                                   "kps.eval"]]])
 def test_lowered_program_carries_the_scope(program, scope):
     assert scope in lowered_text(program)
+
+
+# -- the flat key-space vector only at the programs' edges (PR 25) ------------
+
+WORKERS, NUM_PARAMS = 2, 8 * 16 + 8 + 4 * 8 + 4     # lowered_text's sizes
+FLAT = f"tensor<{NUM_PARAMS}xf32>"
+
+
+def flat_concatenations(text: str) -> int:
+    return len(re.findall(
+        rf"stablehlo\.concatenate .*-> {FLAT}(?: loc\(.*\))?$", text, re.M))
+
+
+def all_reduce_results(text: str) -> list[str]:
+    """Result type of each all-reduce (its region's closing line)."""
+    return [re.search(r"^\s*\}\) : \([^)]*\) -> (\S+)", part, re.M).group(1)
+            for part in text.split('"stablehlo.all_reduce"')[1:]]
+
+
+@pytest.mark.parametrize("program", [
+    "mlp_local_update", "bsp_step", "bsp_scan", "bsp_scan_mesh", "gang"])
+def test_no_program_builds_a_workers_by_params_array(program):
+    """Inside a solver program the parameters are their leaves: under
+    the worker vmap a flat carry is [workers, P], which a TPU tiles over
+    (worker, key) and re-lays out every local step (PERF.md §6, PR 25)."""
+    text = lowered_text(program)
+    assert f"tensor<{WORKERS}x{NUM_PARAMS}x" not in text
+    if program == "mlp_local_update":
+        assert flat_concatenations(text) == 0        # leaves in, leaves out
+    elif program == "gang":
+        # the wire contract: still k flat deltas, each flattened after
+        # the fan-out from its own row of every leaf
+        signature = text[text.index("func.func public @main"):].split(
+            "\n", 1)[0]
+        assert signature.split(") -> (", 1)[1].count(FLAT) == WORKERS
+        assert flat_concatenations(text) == WORKERS
+    else:
+        # one flat sum a round (the round's body is in the text once),
+        # and under a mesh one all-reduce of it beside the scalar loss's
+        assert flat_concatenations(text) == 1
+        assert all_reduce_results(text) == (
+            [FLAT, "tensor<f32>"] if program == "bsp_scan_mesh" else [])
 
 
 def test_the_log_stacker_has_a_name():
