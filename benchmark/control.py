@@ -1,23 +1,15 @@
 """The control of `correct`: the reference put in the program's place,
-with what a lower-precision program would hold rounded to bfloat16.
+with what a lower-precision program would hold in the precision below.
 
     python benchmark/control.py --workload <cell> --seeds 1,2,3
 
-For each seed it makes the cell's rows, runs the float32 reference and
-each control through the cell's first clocks, and prints the numbers the
-benchmark compares (`delta_norm_gap`, `loss_gap`) for control against
-reference.  The benchmark's own runs never call this; its readings set
-the limits in the cell's file (PERF.md gives them).
-
-Controls:
-  * `theta_bf16` — the shared parameters held in bfloat16 between
-    clocks (what halving the 16.9 MB broadcast and delta would do): the
-    control that has to come out as not correct;
-  * `slab_bf16` — the worker slabs held in bfloat16 (`--slab-dtype
-    bf16`): recorded to show what the comparison can NOT see.  On the
-    chip the program's matrix products already round the slab to
-    bfloat16 (default precision), so against a `highest` reference this
-    control and the sound program read alike.
+For each seed it makes the cell's items, runs the family's reference and
+each of its controls (`reference.CONTROLS`: the reference's own keywords
+by name, and what each stands for) through the cell's first clocks, and
+prints the numbers the benchmark compares (`delta_norm_gap`, `loss_gap`)
+for control against reference.  The benchmark's own runs never call
+this; its readings set the limits in the cell's file (PERF.md gives
+them).
 """
 
 from __future__ import annotations
@@ -34,37 +26,33 @@ for _p in (os.path.dirname(HERE), HERE):
 
 
 def readings(cell: dict, seed: int, shrink=None, shrink_data=None) -> dict:
-    import jax.numpy as jnp
     import numpy as np
 
-    import reference
     import run as harness
     from kafka_ps_tpu.cli import run as cli
 
     cfg = cli.cfg_from_args(cli.build_parser().parse_args(
         harness.cli_flags(cell, shrink)))
-    shapes = harness.reference_shapes(cfg)
+    family = harness.Family(cell)
+    reference = family.reference
+    shapes = reference.shapes(cfg)
     data = dict(cell["config"]["data"], **(shrink_data or {}))
-    w, rows = cfg.num_workers, data["rows_per_worker"]
-    x, y, _, _ = harness.make_rows(cfg, data, seed)
-    # row i goes to worker i % w, as datagen.feed delivers them
-    slabs = [(x[i::w], y[i::w], np.ones((rows,), np.float32))
-             for i in range(w)]
+    train, _ = family.datagen.make(seed, cfg, data)
+    slabs = family.datagen.slabs(train, cfg.num_workers)
     chk = cell["traffic"]["check"]
     clocks, stride = chk["clocks"], chk["stride_clocks"]
     theta0 = np.asarray(reference.init_params(shapes))
-    want_t, want_l = reference.Reference(shapes).run(theta0, slabs, clocks)
+    want_t, want_l = reference.Reference(shapes).run(theta0, slabs, clocks,
+                                                     keep_every=stride)
     out = {}
-    for name, kwargs in (("theta_bf16", {"theta_dtype": jnp.bfloat16}),
-                         ("slab_bf16", {"slab_dtype": jnp.bfloat16})):
+    for name, kwargs in reference.CONTROLS.items():
         got_t, got_l = reference.Reference(shapes, **kwargs).run(
-            theta0, slabs, clocks)
+            theta0, slabs, clocks, keep_every=stride)
         out[name] = {
             "delta_norm_gap": max(
-                reference.leaf_norm_gap(got_t[c - 1], want_t[c - 1], theta0,
-                                        shapes)
-                for c in range(stride, clocks + 1, stride)),
-            "loss_gap": max(reference.relative_gap(g, r)
+                reference.param_gap(got, want, theta0, shapes)
+                for got, want in zip(got_t, want_t)),
+            "loss_gap": max(harness.relative_gap(g, r)
                             for g, r in zip(got_l, want_l))}
     return out
 

@@ -14,14 +14,13 @@ parameters.  Bytes are counted at the float32 the configuration states.
 The per-node path also evaluates the post-fit model on the test set
 inside the same dispatch: `eval_cost` is that forward pass, added by the
 roofline reader for the programs that carry it.
+
+`update(cfg)` and `evaluation(cfg, test)` are what a roofline reader
+calls (`run.family.costs`): these shapes' costs at the CLI's
+configuration.  The table of peaks is benchmark/peaks.py's.
 """
 
 from __future__ import annotations
-
-import json
-import os
-
-_HERE = os.path.dirname(os.path.abspath(__file__))
 
 
 def logreg_update_flops(b: int, f: int, c1: int, k: int) -> float:
@@ -82,24 +81,15 @@ def eval_cost(task: str, n: int, f: int, h: int,
     raise KeyError(f"no cost model for task {task!r}")
 
 
-def device_peaks(device_kind: str) -> tuple[float, float]:
-    """(bf16 FLOP/s, HBM bytes/s) of one chip from peaks.json; a device
-    that is not in the table is an error, never a default."""
-    with open(os.path.join(_HERE, "peaks.json")) as fh:
-        table = json.load(fh)
-    row = table.get(device_kind)
-    if not isinstance(row, dict):
-        known = sorted(k for k in table if not k.startswith("_"))
-        raise KeyError(f"no published peaks for device_kind "
-                       f"{device_kind!r}; known: {known} — add the row "
-                       "with its source to benchmark/peaks.json")
-    return float(row["bf16_flops_per_s"]), float(row["hbm_bytes_per_s"])
+def update(cfg) -> tuple[float, float]:
+    """(flops, bytes) of one worker update at the CLI's configuration."""
+    m = cfg.model
+    return update_cost(cfg.task, cfg.buffer.max_size, m.num_features,
+                       m.hidden_dim, m.num_rows, m.num_max_iter)
 
 
-def least_seconds(flops: float, bytes_: float,
-                  device_kind: str) -> tuple[float, str]:
-    """The least time one chip could take for this work, and which
-    bound sets it."""
-    peak_f, peak_b = device_peaks(device_kind)
-    t_f, t_b = flops / peak_f, bytes_ / peak_b
-    return (t_f, "compute") if t_f >= t_b else (t_b, "memory")
+def evaluation(cfg, test) -> tuple[float, float]:
+    """(flops, bytes) of one evaluation of the test set (rows, labels)."""
+    m = cfg.model
+    return eval_cost(cfg.task, len(test[1]), m.num_features, m.hidden_dim,
+                     m.num_rows)

@@ -3,7 +3,7 @@ the least the chip could take for the work they did."""
 
 import re
 
-import costs
+import peaks
 
 
 def read(run, spec):
@@ -32,21 +32,17 @@ def read(run, spec):
     solver_s = sum(times.values())
     if solver_s <= 0 or not updates:
         return None
-    m = run.cfg.model
-    flops, bytes_ = costs.update_cost(
-        run.cfg.task, run.cfg.buffer.max_size, m.num_features, m.hidden_dim,
-        m.num_rows, m.num_max_iter)
+    costs = run.family.costs      # the family's operations and bytes
+    flops, bytes_ = costs.update(run.cfg)
     # counted only where every solver program in the trace carries it:
     # a share may read low, never high
     with_eval = (matching(times, spec["eval_rides_along_patterns"])
                  == times)
     if with_eval:
-        e_flops, e_bytes = costs.eval_cost(
-            run.cfg.task, len(run.test_y), m.num_features, m.hidden_dim,
-            m.num_rows)
+        e_flops, e_bytes = costs.evaluation(run.cfg, run.test)
         flops, bytes_ = flops + e_flops, bytes_ + e_bytes
     kind = run.devices[0].device_kind
-    least, bound = costs.least_seconds(flops, bytes_, kind)
+    least, bound = peaks.least_seconds(flops, bytes_, kind)
     print(f"[bench] step_roofline_share: {flops:.4g} FLOP and {bytes_:.4g} "
           f"bytes per update{' and its test-set evaluation' if with_eval else ''}"
           f", least {least * 1e3:.4f} ms ({bound}-bound) on {kind}; "

@@ -23,6 +23,9 @@ are 1..num_classes and row 0 is never observed.
 The work runs one worker at a time, so the reference's own footprint
 stays far below the program's and `memory_peak_bytes` stays the
 program's.
+
+This is the family that a configuration without a `family` key gets;
+benchmark/run.py's docstring has the interface it calls.
 """
 
 from __future__ import annotations
@@ -35,6 +38,9 @@ import jax.numpy as jnp
 import numpy as np
 
 PRECISION = "highest"
+# the column of the program's server log that each evaluated number is
+# judged against (the header the program's CSV sink writes)
+LOG_COLUMN = {"loss": "loss", "f1": "fMeasure", "accuracy": "accuracy"}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,6 +69,15 @@ class Shapes:
     @property
     def num_params(self) -> int:
         return sum(int(np.prod(s)) for _, s in self.leaves())
+
+
+def shapes(cfg) -> Shapes:
+    """The reference's view of the CLI's configuration."""
+    return Shapes(
+        task=cfg.task, num_features=cfg.model.num_features,
+        num_classes=cfg.model.num_classes, hidden_dim=cfg.model.hidden_dim,
+        local_iterations=cfg.model.num_max_iter,
+        local_lr=cfg.model.local_learning_rate, num_workers=cfg.num_workers)
 
 
 def split(theta, shapes: Shapes) -> dict:
@@ -120,10 +135,10 @@ class Reference:
     """Jitted once per cell; every call under `highest` precision.
 
     `theta_dtype` / `slab_dtype` exist for the CONTROL only
-    (benchmark/control.py): the same reference with the shared
-    parameters, or the worker slabs, held in a lower precision between
-    uses — the step a later PR would be tempted by.  All arithmetic
-    stays float32 either way."""
+    (`CONTROLS`, benchmark/control.py): the same reference with the
+    shared parameters, or the worker slabs, held in a lower precision
+    between uses — the step a later PR would be tempted by.  All
+    arithmetic stays float32 either way."""
 
     def __init__(self, shapes: Shapes, theta_dtype=None, slab_dtype=None):
         self.shapes = shapes
@@ -150,22 +165,26 @@ class Reference:
             theta = self._store(theta + total / w)
         return theta, float(np.mean([float(v) for v in losses]))
 
-    def run(self, theta0, slabs, clocks: int):
-        """`clocks` BSP clocks from theta0: ([theta after each clock] as
-        host arrays, [mean loss of each clock])."""
+    def run(self, theta0, slabs, clocks: int, keep_every: int = 1):
+        """`clocks` BSP clocks from theta0: ([theta after every
+        `keep_every`-th clock] as host arrays, [mean loss of each
+        clock])."""
         theta = self._store(jnp.asarray(theta0, jnp.float32))
         thetas, losses = [], []
-        for _ in range(clocks):
+        for done in range(1, clocks + 1):
             theta, loss = self.clock(theta, slabs)
-            thetas.append(np.asarray(theta))
+            if done % keep_every == 0:
+                thetas.append(np.asarray(theta))
             losses.append(loss)
         return thetas, losses
 
-    def evaluate(self, theta, test_x, test_y) -> dict:
+    def evaluate(self, theta, test) -> dict:
+        """The test set (rows, labels) under `theta`, by LOG_COLUMN's
+        names."""
         with jax.default_matmul_precision(PRECISION):
             loss, f1, acc = self._evaluate(jnp.asarray(theta, jnp.float32),
-                                           jnp.asarray(test_x),
-                                           jnp.asarray(test_y))
+                                           jnp.asarray(test[0]),
+                                           jnp.asarray(test[1]))
         return {"loss": float(loss), "f1": float(f1), "accuracy": float(acc)}
 
 
@@ -187,7 +206,7 @@ def _evaluate(theta, x, y, shapes: Shapes):
 # -- the comparison ----------------------------------------------------------
 
 
-def leaf_norm_gap(theta_prog, theta_ref, theta0, shapes: Shapes) -> float:
+def param_gap(theta_prog, theta_ref, theta0, shapes: Shapes) -> float:
     """Worst leaf of | ||prog change|| - ||ref change|| | over the
     reference's norm of that leaf's change or of the median leaf's,
     whichever is larger (some leaves hardly move)."""
@@ -204,18 +223,15 @@ def leaf_norm_gap(theta_prog, theta_ref, theta0, shapes: Shapes) -> float:
     return worst
 
 
-def relative_gap(got: float, want: float) -> float:
-    return abs(got - want) / max(abs(want), 1e-30)
-
-
-def bsp_spread(rows: list[tuple[int, int]], num_workers: int) -> int:
-    """Largest max-min of the workers' newest logged clocks, walking the
-    worker log in file order.  Under BSP it may never pass 1."""
-    newest = [None] * num_workers
-    worst = 0
-    for worker, clock in rows:
-        newest[worker] = clock
-        seen = [c for c in newest if c is not None]
-        if len(seen) == num_workers:
-            worst = max(worst, max(seen) - min(seen))
-    return worst
+# the controls of benchmark/control.py: Reference keywords by name.
+#   theta_bf16  the shared parameters held in bfloat16 between clocks
+#               (what halving the 16.9 MB broadcast and delta would do):
+#               the control that has to come out as not correct;
+#   slab_bf16   the worker slabs held in bfloat16 (`--slab-dtype bf16`):
+#               recorded to show what the comparison can NOT see.  On
+#               the chip the program's matrix products already round the
+#               slab to bfloat16 (default precision), so against a
+#               `highest` reference this control and the sound program
+#               read alike.
+CONTROLS = {"theta_bf16": {"theta_dtype": jnp.bfloat16},
+            "slab_bf16": {"slab_dtype": jnp.bfloat16}}

@@ -6,21 +6,44 @@ line last.
 
 The cell's configuration (`benchmark/configs/<config>.json`) and traffic
 (`benchmark/workloads/<cell>.json`) are data; this file never names one.
+Whatever depends on the family of the model is files too, which the
+configuration names (`"family": "<name>"` stands for
+`benchmark/families/<name>/`; without the key the files are the ones
+beside this one):
+
+    reference.py  shapes(cfg), init_params(shapes), Reference(shapes) with
+                  .run(theta0, slabs, clocks, keep_every) -> (parameters
+                  after each kept clock, each clock's mean loss) and
+                  .evaluate(theta, test) -> {name: value}; LOG_COLUMN
+                  {name: column of the program's server log};
+                  param_gap(theta_prog, theta_ref, theta0, shapes);
+                  CONTROLS {name: Reference keywords} for control.py
+    datagen.py    make(seed, cfg, data) -> (train, test), each a tuple of
+                  arrays over the items, test as StreamingPSApp takes it;
+                  feed(sink, train, num_workers) through the program's
+                  own data_sink; slabs(train, num_workers), what the
+                  buffers then hold (control.py runs no program)
+    costs.py      update(cfg), evaluation(cfg, test) -> (operations,
+                  bytes), for the roofline readers
+    tiny.json     the flags and data the CPU tests shrink the cells to
+
 It builds the app the way `kafka_ps_tpu/cli/run.py` does (the CLI's own
 parser, `cfg_from_args`, `StreamingPSApp` with the CSV log sinks), feeds
 the buffers through `StreamingPSApp.data_sink`, and drives the app's own
 loop (`run_fused_bsp` or `run_serial`): one `run_*` call to an update
 count, then a device sync.  The cell's file says how the window is cut
 (`window.mode`): `slices` is the run of whole fixed-size calls that fits
-in `--seconds` (each a sample of `clock_ms_p95`); `one_call` is a single
+in `--seconds` (each a sample of the slice readers); `one_call` is a single
 call sized to last `--seconds`, from two timed warm-up calls.  Everything
 before the window is `setup_s`, except the reference's own time, which is
 reported apart.
 
-`correct` is decided by `benchmark/reference.py` (plain float32 at
-`highest` precision) against the limits in the cell's file; every number
-compared is printed beside its limit.  A run that finds no TPU, or fewer
-chips than the cell asks for, exits 2 and prints no result.
+`correct` is decided by the family's reference (the default one: plain
+float32 at `highest` precision) against the limits in the cell's file;
+every number compared is printed beside its limit, and once more as the
+last lines of standard error and under `compared`, last in the result.
+A run that finds no TPU, or fewer chips than the cell asks for, exits 2
+and prints no result.
 """
 
 from __future__ import annotations
@@ -44,6 +67,8 @@ import threading  # noqa: E402
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+PROBE_TRIES = 3       # Run.probe
 
 
 def say(*parts) -> None:
@@ -56,19 +81,29 @@ def load_json(*path):
         return json.load(fh)
 
 
-def load_cell(name: str) -> dict:
+def load_cell(name: str, manifest_path: str | None = None) -> dict:
     """The cell's entry in BENCHMARK.json with its configuration and
-    traffic files, found by name."""
-    manifest = load_json(ROOT, "BENCHMARK.json")
+    traffic files, found by name, and the directory of its family's
+    files.  `manifest_path` (tests only) is another manifest, whose
+    files lie beside it as the benchmark's lie beside BENCHMARK.json."""
+    manifest_path = os.path.abspath(manifest_path or os.path.join(
+        ROOT, "BENCHMARK.json"))
+    root = os.path.dirname(manifest_path)
+    manifest = load_json(manifest_path)
+    base = os.path.normpath(os.path.join(root, manifest["paths"][0]))
     entry = next((w for w in manifest["workloads"] if w["name"] == name),
                  None)
     if entry is None:
         raise SystemExit(f"unknown workload {name!r}")
     cfg_entry = next(c for c in manifest["configs"]
                      if c["name"] == entry["config"])
-    return {"entry": entry, "manifest": manifest,
-            "config": load_json(ROOT, cfg_entry["file"]),
-            "traffic": load_json(HERE, "workloads", name + ".json")}
+    config = load_json(root, cfg_entry["file"])
+    family = config.get("family")
+    return {"entry": entry, "manifest": manifest, "config": config,
+            "traffic": load_json(base, "workloads", name + ".json"),
+            "family": family,
+            "family_dir": (os.path.join(base, "families", family) if family
+                           else HERE)}
 
 
 def cell_metrics(cell: dict, group: str) -> list[dict]:
@@ -77,23 +112,60 @@ def cell_metrics(cell: dict, group: str) -> list[dict]:
             if "workloads" not in m or name in m["workloads"]]
 
 
-def reference_shapes(cfg):
-    """The reference's view of the CLI's configuration."""
-    import reference
-    return reference.Shapes(
-        task=cfg.task, num_features=cfg.model.num_features,
-        num_classes=cfg.model.num_classes, hidden_dim=cfg.model.hidden_dim,
-        local_iterations=cfg.model.num_max_iter,
-        local_lr=cfg.model.local_learning_rate, num_workers=cfg.num_workers)
+def load_module(path: str, name: str):
+    """A module by its path (a family's file, a metric's reader), once
+    a path: the tests and the harness then hold the same module."""
+    known = sys.modules.get(name)
+    if known is not None and getattr(known, "__file__", None) == path:
+        return known
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    sys.modules[name] = module        # dataclasses look their module up
+    try:
+        spec.loader.exec_module(module)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return module
 
 
-def make_rows(cfg, data: dict, seed: int):
-    """The cell's rows from the seed: (train_x, train_y, test_x, test_y)."""
-    import datagen
-    return datagen.make_rows(
-        seed, cfg.num_workers * data["rows_per_worker"], data["test_rows"],
-        cfg.model.num_features, cfg.model.num_classes, noise=data["noise"],
-        sparsity=data["sparsity"], center_scale=data["center_scale"])
+class Family:
+    """The cell's family files as modules: `.reference`, `.datagen`,
+    `.costs` (this file's docstring has what each holds)."""
+
+    PARTS = ("reference", "datagen", "costs")
+
+    def __init__(self, cell: dict):
+        prefix = ("family_" + re.sub(r"\W", "_", cell["family"]) + "_"
+                  if cell["family"] else "")
+        for part in self.PARTS:
+            setattr(self, part, load_module(
+                os.path.join(cell["family_dir"], part + ".py"),
+                prefix + part))
+
+
+def relative_gap(got: float, want: float) -> float:
+    return abs(got - want) / max(abs(want), 1e-30)
+
+
+def bsp_spread(rows: list[tuple[int, int]], num_workers: int) -> int:
+    """Largest max-min of the workers' newest logged clocks, walking the
+    worker log in file order.  Under BSP it may never pass 1."""
+    newest = [None] * num_workers
+    worst = 0
+    for worker, clock in rows:
+        newest[worker] = clock
+        seen = [c for c in newest if c is not None]
+        if len(seen) == num_workers:
+            worst = max(worst, max(seen) - min(seen))
+    return worst
+
+
+def read_log(path: str) -> tuple[list[str], list[list[str]]]:
+    """A CSV log of the program's: (its header's columns, its rows)."""
+    with open(path) as fh:
+        lines = [ln.rstrip("\n").split(";") for ln in fh]
+    return lines[0], lines[1:]
 
 
 def cli_flags(cell: dict, shrink: dict | None) -> list[str]:
@@ -107,24 +179,38 @@ def cli_flags(cell: dict, shrink: dict | None) -> list[str]:
 
 
 class Compiles:
-    """Programs built (compiled, or loaded from the persistent cache)
-    since the last `take()`: jax.monitoring's own event."""
+    """Programs built since the last `take()`: all of them (compiled, or
+    loaded from the persistent cache), and those compiled anew.
+    jax.monitoring's own events: the one that spans the compiler or the
+    cache's read, and before it, on the same thread, the cache's hit."""
 
     def __init__(self):
         import jax
-        self._n = 0
+        self._built = self._anew = 0
+        self._hit_on: set[int] = set()       # threads with a hit pending
         self._lock = threading.Lock()
-        jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
+        jax.monitoring.register_event_duration_secs_listener(self._on_built)
 
-    def _on(self, event, duration, **kwargs):
+    def _on_event(self, event, **kwargs):
+        if event == CACHE_HIT_EVENT:
+            with self._lock:
+                self._hit_on.add(threading.get_ident())
+
+    def _on_built(self, event, duration, **kwargs):
         if event == COMPILE_EVENT:
             with self._lock:
-                self._n += 1
+                self._built += 1
+                if threading.get_ident() in self._hit_on:
+                    self._hit_on.discard(threading.get_ident())
+                else:
+                    self._anew += 1
 
-    def take(self) -> int:
+    def take(self) -> tuple[int, int]:
         with self._lock:
-            n, self._n = self._n, 0
-        return n
+            taken = self._built, self._anew
+            self._built = self._anew = 0
+        return taken
 
 
 class Run:
@@ -150,8 +236,9 @@ class Run:
                          else "annotations"),
             **(trace_layout or {}))
         self.reference_s = 0.0
-        self.faults: list[str] = []
+        self.compared: dict[str, dict] = {}   # every number beside its limit
         self.call_times: list[float] = []     # one per call of the window
+        self.call_builds: list[int] = []      # programs built in each
         self.call_clocks = 0                  # clocks a window call asks for
         self.window_compiles = 0
         self.trace_dir = None
@@ -172,18 +259,16 @@ class Run:
         from kafka_ps_tpu.utils.csvlog import (CsvLogSink, SERVER_HEADER,
                                                WORKER_HEADER)
 
-        import datagen
-
         self.args = args = cli.build_parser().parse_args(self.flags)
         self.cfg = cfg = cli.cfg_from_args(args)
         self.workers = cfg.num_workers
-        self.shapes = reference_shapes(cfg)
+        self.family = family = Family(self.cell)
+        self.shapes = family.reference.shapes(cfg)
         cli.announce_device(cfg, fused=args.fused)
 
         t = time.time()
-        train_x, train_y, self.test_x, self.test_y = make_rows(
-            cfg, self.data, self.seed)
-        say(f"data: {len(train_y)} train + {len(self.test_y)} test rows "
+        train, self.test = family.datagen.make(self.seed, cfg, self.data)
+        say(f"data: {len(train[0])} train + {len(self.test[0])} test items "
             f"from seed {self.seed} in {time.time() - t:.2f}s")
 
         self.run_dir = tempfile.mkdtemp(prefix="kps-bench-")
@@ -191,10 +276,8 @@ class Run:
         self.worker_csv = os.path.join(self.run_dir, "logs-worker.csv")
         self.sinks = (CsvLogSink(self.server_csv, SERVER_HEADER),
                       CsvLogSink(self.worker_csv, WORKER_HEADER))
-        # no Tracer: the fused loop syncs per step when one is on
-        # (runtime/app.py _run_fused_loop), and no metric reads one
-        self.app = StreamingPSApp(cfg, test_x=self.test_x,
-                                  test_y=self.test_y,
+        self.app = StreamingPSApp(cfg, test_x=self.test[0],
+                                  test_y=self.test[1],
                                   server_log=self.sinks[0],
                                   worker_log=self.sinks[1])
         self.mesh = None
@@ -206,8 +289,8 @@ class Run:
                 raise SystemExit(f"{self.workers} workers do not divide "
                                  f"over {self.mesh.devices.size} devices")
         t = time.time()
-        datagen.feed(self.app.data_sink, train_x, train_y, self.workers)
-        say(f"fed {len(train_y)} rows through StreamingPSApp.data_sink in "
+        family.datagen.feed(self.app.data_sink, train, self.workers)
+        say(f"fed {len(train[0])} items through StreamingPSApp.data_sink in "
             f"{time.time() - t:.2f}s; buffers hold "
             f"{sorted({b.count for b in self.app.buffers})} rows")
         self.slabs = [b.snapshot() for b in self.app.buffers]
@@ -251,13 +334,16 @@ class Run:
         reference rounds on the same slabs."""
         import numpy as np
 
-        import reference
+        reference = self.family.reference
         chk = self.traffic["check"]
         clocks, stride = chk["clocks"], chk["stride_clocks"]
         t = time.time()
         ref = self.reference = reference.Reference(self.shapes)
         theta0 = np.asarray(reference.init_params(self.shapes))
-        ref_thetas, ref_losses = ref.run(theta0, self.slabs, clocks)
+        # the parameters after every `stride` clocks, the ones compared,
+        # and no others: a large family cannot keep a copy a clock
+        ref_thetas, ref_losses = ref.run(theta0, self.slabs, clocks,
+                                         keep_every=stride)
         self.reference_s += time.time() - t
         self.live_before_program = self.live_peak()
         say(f"reference: {clocks} clocks x {self.workers} workers in "
@@ -269,15 +355,15 @@ class Run:
                     float(np.max(np.abs(prog0 - theta0))),
                     chk["limits"]["init_theta_max_abs_gap"])
         worst = 0.0
-        for done in range(stride, clocks + 1, stride):
+        for kept in ref_thetas:
             self.drive(stride)
-            worst = max(worst, reference.leaf_norm_gap(
-                np.asarray(self.app.server.theta), ref_thetas[done - 1],
-                theta0, self.shapes))
+            worst = max(worst, reference.param_gap(
+                np.asarray(self.app.server.theta), kept, theta0,
+                self.shapes))
         self.number("delta_norm_gap", worst, chk["limits"]["delta_norm_gap"])
         losses = self.logged_losses()
-        gaps = [reference.relative_gap(losses.get(c, math.nan),
-                                       ref_losses[c - self.first_clock()])
+        gaps = [relative_gap(losses.get(c, math.nan),
+                             ref_losses[c - self.first_clock()])
                 for c in range(self.first_clock(),
                                self.first_clock() + clocks)]
         self.number("loss_gap", max(gaps), chk["limits"]["loss_gap"])
@@ -289,12 +375,7 @@ class Run:
         return 1 if self.drive_name == "fused" else 0
 
     def worker_rows(self) -> list[list[str]]:
-        with open(self.worker_csv) as fh:
-            return [ln.rstrip("\n").split(";") for ln in fh][1:]
-
-    def server_rows(self) -> list[list[str]]:
-        with open(self.server_csv) as fh:
-            return [ln.rstrip("\n").split(";") for ln in fh][1:]
+        return read_log(self.worker_csv)[1]
 
     def logged_losses(self) -> dict[int, float]:
         """Mean over the workers of each clock's logged loss."""
@@ -305,11 +386,18 @@ class Run:
                 if len(v) == self.workers}
 
     def number(self, name: str, value: float, limit: float) -> None:
-        ok = value <= limit          # nan fails
-        say(f"compare {name} = {value!r} limit {limit!r} "
-            f"{'ok' if ok else 'FAIL'}")
-        if not ok:
-            self.faults.append(name)
+        self.compared[name] = {"value": value, "limit": limit,
+                               "ok": value <= limit}      # nan fails
+        say(self.compare_line(name))
+
+    def compare_line(self, name: str) -> str:
+        c = self.compared[name]
+        return (f"compare {name} = {c['value']!r} limit {c['limit']!r} "
+                f"{'ok' if c['ok'] else 'FAIL'}")
+
+    @property
+    def faults(self) -> list[str]:
+        return [name for name, c in self.compared.items() if not c["ok"]]
 
     # -- warm-up and the window ----------------------------------------------
 
@@ -329,7 +417,7 @@ class Run:
         while clean < want:
             self.drive(self.call_clocks)
             n += 1
-            built = compiles.take()
+            built = compiles.take()[0]
             built_all += built
             clean = 0 if built else clean + 1
             if time.time() - t > cap:
@@ -340,15 +428,34 @@ class Run:
             f"{time.time() - t:.2f}s built {built_all} programs; the last "
             f"{clean} built none")
 
-    def size_one_call(self) -> None:
-        """Two timed calls of different length give a call's fixed
+    def probe(self, chunks: int, compiles: Compiles) -> float:
+        """The seconds a warm call of `chunks` scan chunks takes.  What
+        a process with a cold compile cache pays once is not a call's
+        cost: a call in which a program was compiled anew is made again,
+        at most PROBE_TRIES times, so the first run in a checkout sizes
+        its window as the later ones do, and a run that finds every
+        program in the cache makes each probe once.  Every call is
+        printed."""
+        for attempt in range(1, PROBE_TRIES + 1):
+            compiles.take()
+            took = self.drive(chunks * self.chunk_clocks)
+            built, anew = compiles.take()
+            say(f"warm-up: probe call {attempt} of {chunks} chunks took "
+                f"{took:.4f}s and built {built} programs, {anew} of them "
+                f"compiled anew: {'not a warm call' if anew else 'timed'}")
+            if not anew:
+                break
+        return took
+
+    def size_one_call(self, compiles: Compiles) -> None:
+        """Two timed warm calls of different length give a call's fixed
         cost (every run_fused_bsp call snapshots and uploads the slabs
         anew) and its cost per scan chunk; the window's one call is the
         whole number of chunks that then lasts `--seconds`."""
         w = self.traffic["window"]
         few, many = w["probe_chunks"]
-        t_few = self.drive(few * self.chunk_clocks)
-        t_many = self.drive(many * self.chunk_clocks)
+        t_few = self.probe(few, compiles)
+        t_many = self.probe(many, compiles)
         # at least a quarter of the longer call is taken to be chunks:
         # a difference lost in the clock's noise must not size the window
         per_chunk = max((t_many - t_few) / (many - few),
@@ -363,7 +470,7 @@ class Run:
 
     def window(self, compiles: Compiles) -> None:
         if self.mode == "one_call":
-            self.size_one_call()
+            self.size_one_call(compiles)
         else:
             self.warm_up_slices(compiles)
         self.updates_before = self.app.server.iterations
@@ -374,18 +481,20 @@ class Run:
         if self.mode == "one_call":
             tracer = self._trace_from_a_thread() if self.trace else None
             self.call_times.append(self.drive(self.call_clocks))
+            self.call_builds.append(compiles.take()[0])
             if tracer is not None:
                 tracer.join()
         else:
-            self._slices(start)
+            self._slices(start, compiles)
         self.window_s = time.time() - start
-        self.window_compiles = compiles.take()
+        # what the calls built, and what was built between them
+        self.window_compiles = sum(self.call_builds) + compiles.take()[0]
         self.updates_asked = (len(self.call_times) * self.call_clocks
                               * self.workers)
         self.updates_applied = (self.app.server.iterations
                                 - self.updates_before)
 
-    def _slices(self, start: float) -> None:
+    def _slices(self, start: float, compiles: Compiles) -> None:
         """Whole slices while the next one still fits.  A traced run
         profiles the slices from `start_after_s` until `seconds` have
         passed; the profiler's start and stop are not part of a slice."""
@@ -400,6 +509,7 @@ class Run:
                 tracing = True
             took = self.drive(self.call_clocks)
             self.call_times.append(took)
+            self.call_builds.append(compiles.take()[0])
             last = time.time()
             if tracing:
                 self.traced_updates += self.call_clocks * self.workers
@@ -455,7 +565,6 @@ class Run:
     def check_after(self) -> None:
         import numpy as np
 
-        import reference
         lim = self.traffic["check"]["limits"]
         self.number("updates_not_applied",
                     float(self.updates_asked - self.updates_applied), 0.0)
@@ -463,7 +572,7 @@ class Run:
         every = self.cfg.eval_every
         first = self.first_clock()
         owed = [c for c in range(first, clocks_now + first) if c % every == 0]
-        server = self.server_rows()
+        columns, server = read_log(self.server_csv)
         got = [int(r[2]) for r in server]
         self.number("eval_rows_missing_or_extra",
                     float(len(set(owed) ^ set(got)) + len(got)
@@ -475,8 +584,8 @@ class Run:
                     float(sum(not math.isfinite(v) for v in values)), 0.0)
         self.number("worker_rows_missing",
                     float(clocks_now * self.workers - len(wrows)), 0.0)
-        spread = reference.bsp_spread(
-            [(int(r[1]), int(r[2])) for r in wrows], self.workers)
+        spread = bsp_spread([(int(r[1]), int(r[2])) for r in wrows],
+                            self.workers)
         self.number("clock_spread_over_bsp_bound", float(max(0, spread - 1)),
                     0.0)
         tracker = self.app.server.tracker
@@ -488,17 +597,21 @@ class Run:
         moved = float(np.linalg.norm(theta - np.asarray(self.theta_before)))
         self.number("window_left_theta_unchanged", float(moved == 0.0), 0.0)
         t = time.time()
-        want = self.reference.evaluate(theta, self.test_x, self.test_y)
+        want = self.reference.evaluate(theta, self.test)
         self.reference_s += time.time() - t
-        last = server[-1]
-        self.number("final_eval_loss_gap",
-                    reference.relative_gap(float(last[3]), want["loss"]),
-                    lim["final_eval_loss_gap"])
-        self.number("final_eval_f1_gap", abs(float(last[4]) - want["f1"]),
-                    lim["final_eval_f1_gap"])
-        self.number("final_eval_accuracy_gap",
-                    abs(float(last[5]) - want["accuracy"]),
-                    lim["final_eval_accuracy_gap"])
+        # each evaluated number the cell's file gives a limit, against
+        # the last server row's column of it: the loss by its relative
+        # gap, the others (shares of the test set) by their absolute one
+        column_of = self.family.reference.LOG_COLUMN
+        for name, limit in lim.items():
+            found = re.fullmatch(r"final_eval_(.+)_gap", name)
+            if not found:
+                continue
+            key = found.group(1)
+            logged = float(server[-1][columns.index(column_of[key])])
+            self.number(name, (relative_gap(logged, want[key])
+                               if key == "loss"
+                               else abs(logged - want[key])), limit)
 
     def live_peak(self) -> int:
         """Peak of live buffers on the fullest chip, as the backend
@@ -562,8 +675,12 @@ class Run:
             f"{statistics.median(per_clock_ms):.4f} p95 {p95:.4f} max "
             f"{per_clock_ms[-1]:.4f} (n={len(per_clock_ms)}); compiles in "
             f"window {self.window_compiles}; reference {self.reference_s:.2f}s")
+        if len(self.call_times) > 1:
+            say("slices ms:", " ".join(
+                f"{1e3 * s:.1f}" + (f"[built {b}]" if b else "")
+                for s, b in zip(self.call_times, self.call_builds)))
         values = {"updates_per_s": self.updates_applied / self.window_s,
-                  "clock_ms_p95": p95, "setup_s": self.setup_s}
+                  "setup_s": self.setup_s}
         return {m["name"]: values[m["name"]]
                 for m in cell_metrics(self.cell, "end_to_end")}
 
@@ -574,12 +691,9 @@ class Run:
         for metric in cell_metrics(self.cell, "per_layer"):
             name = metric["name"]
             spec = load_json(HERE, "layer_metrics", name + ".json")
-            path = os.path.join(HERE, "layer_metrics", name + ".py")
-            module_spec = importlib.util.spec_from_file_location(
-                "layer_metric_" + name.replace("-", "_").replace(".", "_"),
-                path)
-            module = importlib.util.module_from_spec(module_spec)
-            module_spec.loader.exec_module(module)
+            module = load_module(
+                os.path.join(HERE, "layer_metrics", name + ".py"),
+                "layer_metric_" + re.sub(r"\W", "_", name))
             value = module.read(self, spec)
             if value is not None:
                 out[name] = value
@@ -601,9 +715,10 @@ def find_devices(chips: int, platform: str):
 
 def main(argv=None, *, platform: str = "tpu", shrink: dict | None = None,
          shrink_data: dict | None = None, break_step=None,
-         trace_layout: dict | None = None) -> int:
-    """`platform`, `shrink`, `shrink_data`, `break_step` and
-    `trace_layout` are for benchmark/tests only; the command line cannot
+         trace_layout: dict | None = None,
+         manifest: str | None = None) -> int:
+    """`platform`, `shrink`, `shrink_data`, `break_step`, `trace_layout`
+    and `manifest` are for benchmark/tests only; the command line cannot
     set them."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -615,7 +730,7 @@ def main(argv=None, *, platform: str = "tpu", shrink: dict | None = None,
     for path in (ROOT, HERE):
         if path not in sys.path:
             sys.path.insert(0, path)
-    cell = load_cell(ns.workload)
+    cell = load_cell(ns.workload, manifest)
     try:
         from kafka_ps_tpu.cli import run as cli
     except ImportError as e:
@@ -667,6 +782,13 @@ def main(argv=None, *, platform: str = "tpu", shrink: dict | None = None,
         result["breakdown"] = run.trace_summary["breakdown"]
     if run.faults:
         say("NOT CORRECT:", ", ".join(run.faults))
+    # each number compared beside its limit: last on standard error,
+    # and last in the result's line
+    result["compared"] = {k: {"value": v["value"], "limit": v["limit"]}
+                          for k, v in run.compared.items()}
+    for name in run.compared:
+        print(run.compare_line(name), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

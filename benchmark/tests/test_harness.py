@@ -11,7 +11,8 @@ import pytest
 from conftest import BENCH, ROOT
 from helpers import run_cell
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 CELLS = [(w["name"], "8" if w["chips"] == 4 else "4")
          for w in MANIFEST["workloads"]]
@@ -41,8 +42,13 @@ def test_untraced_run_reports_the_end_to_end_metrics(capsys, cell, workers):
         BENCH, "workloads", cell + ".json")))["window"]["mode"]
     calls = int(out.split("[bench] window: ")[1].split(" call(s)")[0])
     assert (calls == 1) if mode == "one_call" else (calls > 1)
-    # every number compared is printed beside its limit
+    # every number compared is printed beside its limit, and stands
+    # last in the result's line
     assert out.count("[bench] compare ") >= 12
+    assert list(result)[-1] == "compared"
+    assert len(result["compared"]) == out.count("[bench] compare ")
+    for number in result["compared"].values():
+        assert number["value"] <= number["limit"]
 
 
 @pytest.mark.parametrize("cell,workers", CELLS)
@@ -80,3 +86,86 @@ def test_a_run_without_the_accelerator_exits_nonzero_and_prints_no_result():
     assert p.returncode != 0
     assert not [ln for ln in p.stdout.splitlines() if ln.startswith("{")]
     assert "nothing was run" in p.stderr
+
+
+def test_the_numbers_compared_are_the_last_lines_of_standard_error(capsys):
+    import run as harness
+    from helpers import CPU_TRACE, tiny
+    cell = CELLS[0][0]
+    shrink, data = tiny(cell, "4")
+    assert harness.main(["--workload", cell, "--seed", "11", "--seconds",
+                         "1", "--trace", "0"], platform="cpu", shrink=shrink,
+                        shrink_data=data, trace_layout=CPU_TRACE) == 0
+    captured = capsys.readouterr()
+    result = json.loads(captured.out.splitlines()[-1])
+    last = captured.err.splitlines()[-len(result["compared"]):]
+    assert [ln.split()[1] for ln in last] == list(result["compared"])
+    assert all(ln.startswith("compare ") and ln.endswith(" ok")
+               for ln in last)
+
+
+class _Builds:
+    """Stands in for run.Compiles: what each call built, in turn."""
+
+    def __init__(self):
+        self.pending = (0, 0)
+
+    def take(self):
+        taken, self.pending = self.pending, (0, 0)
+        return taken
+
+
+def test_a_one_call_window_is_sized_from_warm_calls(capsys):
+    """A process with a cold compile cache compiles inside its probes:
+    seconds that a call does not cost.  Such a probe is made again, so
+    the window is the one a warm process gets; a warm process, which
+    only reads the cache, makes each probe once."""
+    import run as harness
+
+    def sized(calls):
+        """calls: (seconds, programs built, compiled anew) in turn."""
+        run = harness.Run.__new__(harness.Run)
+        run.traffic = {"window": {"probe_chunks": [1, 9]}}
+        run.chunk_clocks, run.seconds = 8, 20.0
+        builds = _Builds()
+        todo = list(calls)
+
+        def drive(clocks):
+            took, built, anew = todo.pop(0)
+            builds.pending = (built, anew)
+            return took
+        run.drive = drive
+        run.size_one_call(builds)
+        assert not todo
+        return run.call_clocks // 8
+
+    # 1.65 s a call + 0.354 s a chunk: 51 chunks last 20 s
+    warm = sized([(2.004, 0, 0), (4.836, 2, 0)])
+    assert warm == 51
+    # the cold process: 1.7 s of compiling inside the 1-chunk probe
+    # would read as a call's fixed cost and size the window at 100
+    cold = sized([(3.704, 1, 1), (2.004, 0, 0), (4.836, 2, 0)])
+    assert cold == warm
+    out = capsys.readouterr().out
+    assert out.count("not a warm call") == 1
+    # a process that never settles is sized from its last try
+    assert sized([(3.7, 1, 1)] * harness.PROBE_TRIES
+                 + [(4.836, 0, 0)]) > warm
+
+
+def test_a_program_read_from_the_cache_is_built_but_not_compiled_anew():
+    import threading
+
+    import run as harness
+    compiles = harness.Compiles()
+    compiles._on_event(harness.CACHE_HIT_EVENT)
+    compiles._on_built(harness.COMPILE_EVENT, 0.01)      # the read
+    compiles._on_built(harness.COMPILE_EVENT, 1.5)       # a compile
+    other = threading.Thread(
+        target=lambda: compiles._on_event(harness.CACHE_HIT_EVENT))
+    other.start()
+    other.join()          # another thread's hit is not this thread's
+    compiles._on_built(harness.COMPILE_EVENT, 0.5)
+    compiles._on_built("/jax/other", 1.0)
+    assert compiles.take() == (3, 2)
+    assert compiles.take() == (0, 0)

@@ -15,6 +15,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = {w["name"]: w for w in MANIFEST["workloads"]}
 END_TO_END = {m["name"]: m for m in MANIFEST["end_to_end"]}
+PER_LAYER = {m["name"]: m for m in MANIFEST["per_layer"]}
+SLICE_READERS = ("clock_ms_tail_p95", "clock_ms_median", "slow_slice_share")
 
 
 def cells_of(metric):
@@ -96,8 +98,9 @@ def test_every_cell_has_its_traffic_file(cell):
     if window["mode"] == "one_call":
         few, many = window["probe_chunks"]
         assert 1 <= few < many
-        # one call gives one sample: no tail is taken from it
-        assert cell not in cells_of(END_TO_END["clock_ms_p95"])
+        # one call gives one sample: no tail, body or share of it
+        for reader in SLICE_READERS:
+            assert cell not in cells_of(PER_LAYER[reader])
     else:
         assert window["slice_clocks"] >= 1 and window["clean_slices"] >= 1
     for limit in body["check"]["limits"].values():
@@ -156,9 +159,56 @@ def test_files_under_paths_are_named_from_a_names_characters():
             assert ok.match(rel), rel
 
 
+FAMILY_FILES = ("reference.py", "datagen.py", "costs.py", "tiny.json")
+FIXTURE_MANIFEST = os.path.join(BENCH, "tests", "fixtures", "BENCHMARK.json")
+
+
+def family_dirs(manifest_path):
+    """{configuration: the directory of its family's files}, as the
+    harness finds it."""
+    import run as harness
+    manifest = json.load(open(manifest_path))
+    return {w["config"]: harness.load_cell(w["name"],
+                                           manifest_path)["family_dir"]
+            for w in manifest["workloads"]}
+
+
+@pytest.mark.parametrize("manifest_path", [
+    os.path.join(ROOT, "BENCHMARK.json"), FIXTURE_MANIFEST])
+def test_a_family_has_its_four_files(manifest_path):
+    for config, directory in family_dirs(manifest_path).items():
+        for name in FAMILY_FILES:
+            assert os.path.isfile(os.path.join(directory, name)), (config,
+                                                                   name)
+        size = json.load(open(os.path.join(directory, "tiny.json")))
+        assert set(size) >= {"shrink", "data"}
+    # a family that is named lies under families/, the default beside
+    # the harness
+    named = family_dirs(FIXTURE_MANIFEST)
+    assert all(os.path.basename(os.path.dirname(d)) == "families"
+               for d in named.values())
+
+
+def test_the_harness_names_no_family():
+    """What depends on the family of the model comes from its files:
+    benchmark/run.py and control.py hold no task, no width and no
+    column of a row."""
+    words = re.compile("mlp|logreg|hidden_dim|num_features|num_classes")
+    for name in ("run.py", "control.py"):
+        body = open(os.path.join(BENCH, name)).read()
+        assert not words.search(body), (name, words.findall(body))
+
+
 def test_the_yardstick_imports_nothing_it_measures():
-    ref = open(os.path.join(BENCH, "reference.py")).read()
-    assert "kafka_ps_tpu" not in re.sub(r'""".*?"""', "", ref, flags=re.S)
+    references = {os.path.join(d, "reference.py")
+                  for m in (os.path.join(ROOT, "BENCHMARK.json"),
+                            FIXTURE_MANIFEST)
+                  for d in family_dirs(m).values()}
+    assert os.path.join(BENCH, "reference.py") in references
+    for path in references:
+        ref = open(path).read()
+        assert "kafka_ps_tpu" not in re.sub(r'""".*?"""', "", ref,
+                                            flags=re.S), path
     for name in os.listdir(BENCH):
         if name.endswith(".py"):
             body = open(os.path.join(BENCH, name)).read()
@@ -168,9 +218,10 @@ def test_the_yardstick_imports_nothing_it_measures():
 
 def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():
     import costs
-    assert costs.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    import peaks
+    assert peaks.device_peaks("TPU v5 lite") == (197e12, 819e9)
     with pytest.raises(KeyError):
-        costs.device_peaks("TPU v9 imaginary")
+        peaks.device_peaks("TPU v9 imaginary")
     flops, bytes_ = costs.update_cost("mlp", 1024, 1024, 4096, 6, 2)
     # forward 2*b*h*(f+c1); backward w.r.t. the parameters only
     # 2*b*h*(f+2*c1): two steps and the final loss are 43.3 GFLOP
@@ -179,10 +230,10 @@ def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():
     assert flops == pytest.approx(43.3e9, rel=2e-3)
     # 0.2198 ms at 197 TFLOP/s against 256.5 MB = 0.3132 ms at 819 GB/s:
     # the update is memory bound
-    least, bound = costs.least_seconds(flops, bytes_, "TPU v5 lite")
+    least, bound = peaks.least_seconds(flops, bytes_, "TPU v5 lite")
     assert bound == "memory"
     assert least == pytest.approx(bytes_ / 819e9)
-    assert costs.least_seconds(flops, 1.0, "TPU v5 lite") == (
+    assert peaks.least_seconds(flops, 1.0, "TPU v5 lite") == (
         pytest.approx(flops / 197e12), "compute")
     e_flops, e_bytes = costs.eval_cost("mlp", 2000, 1024, 4096, 6)
     assert e_flops == pytest.approx(2.0 * 2000 * 4096 * 1030)
@@ -193,9 +244,12 @@ def test_peaks_are_keyed_by_device_kind_and_unknown_is_an_error():
 def _fake_run(summary, traced_updates, test_rows=2000):
     """What the roofline reader sees of a run of mlp-4096 on one chip."""
     from types import SimpleNamespace as NS
+
+    import costs
     model = NS(num_features=1024, hidden_dim=4096, num_rows=6, num_max_iter=2)
     return NS(trace_summary=summary, traced_updates=traced_updates,
-              chunk_clocks=8, workers=64, test_y=[0] * test_rows,
+              chunk_clocks=8, workers=64, test=(None, [0] * test_rows),
+              family=NS(costs=costs),
               cfg=NS(task="mlp", model=model, buffer=NS(max_size=1024)),
               devices=[NS(device_kind="TPU v5 lite")])
 
@@ -211,6 +265,7 @@ def _roofline_reader():
 
 def test_roofline_share_of_whole_chunks_cut_out_of_one_call():
     import costs
+    import peaks
     reader, spec = _roofline_reader()
     # six whole runs of the scan program, 0.48 s each: 8 clocks x 64
     # workers a run, 0.9375 ms an update
@@ -218,7 +273,7 @@ def test_roofline_share_of_whole_chunks_cut_out_of_one_call():
                "module_whole_runs": {"jit__unknown": 6.0, "jit__lambda": 9.0},
                "module_whole_time_s": {"jit__unknown": 2.88,
                                        "jit__lambda": 0.001}}
-    least, _ = costs.least_seconds(
+    least, _ = peaks.least_seconds(
         *costs.update_cost("mlp", 1024, 1024, 4096, 6, 2), "TPU v5 lite")
     got = reader.read(_fake_run(summary, 0), spec)
     assert got == pytest.approx(100 * least * 6 * 512 / 2.88)
@@ -227,16 +282,17 @@ def test_roofline_share_of_whole_chunks_cut_out_of_one_call():
 
 def test_roofline_share_counts_the_evaluation_where_it_rides_along():
     import costs
+    import peaks
     reader, spec = _roofline_reader()
     summary = {"chips": 1, "module_time_s": {"jit_update_eval_bcast": 3.631,
                                              "jit_chain": 0.06}}
     u = costs.update_cost("mlp", 1024, 1024, 4096, 6, 2)
     e = costs.eval_cost("mlp", 2000, 1024, 4096, 6)
-    least, _ = costs.least_seconds(u[0] + e[0], u[1] + e[1], "TPU v5 lite")
+    least, _ = peaks.least_seconds(u[0] + e[0], u[1] + e[1], "TPU v5 lite")
     got = reader.read(_fake_run(summary, 2560), spec)
     assert got == pytest.approx(100 * least * 2560 / 3.631)
     # a solver program without the evaluation beside it: not counted
     summary["module_time_s"]["jit_step"] = 1.0
-    least, _ = costs.least_seconds(*u, "TPU v5 lite")
+    least, _ = peaks.least_seconds(*u, "TPU v5 lite")
     assert reader.read(_fake_run(summary, 2560), spec) == pytest.approx(
         100 * least * 2560 / 4.631)

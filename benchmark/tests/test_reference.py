@@ -3,10 +3,13 @@ arithmetic."""
 
 import math
 
+from types import SimpleNamespace as NS
+
 import numpy as np
 import pytest
 
 import reference
+import run as harness
 
 
 def test_two_local_steps_of_logistic_regression_by_hand():
@@ -55,7 +58,8 @@ def test_evaluation_by_hand():
     theta = np.array([0, 1, -1, 0, 0, 0], np.float32)
     x = np.array([[2.0], [3.0], [-1.0], [1.0]], np.float32)
     y = np.array([1, 1, 2, 2], np.int32)
-    got = ref.evaluate(theta, x, y)
+    got = ref.evaluate(theta, (x, y))
+    assert set(got) == set(reference.LOG_COLUMN)
     assert got["accuracy"] == pytest.approx(0.75)
     # class 1: precision 2/3, recall 1 -> F1 .8; class 2: precision 1,
     # recall 1/2 -> F1 2/3; equal support
@@ -80,12 +84,32 @@ def test_leaf_norm_gap_takes_the_worst_leaf_against_the_median_floor():
     prog = np.array([3.0, 4.0, 0.0, 0.0, 0.0, 0.5])
     # the intercept leaf hardly moves: its gap is held against the
     # median leaf's norm (2.5), not its own
-    assert reference.leaf_norm_gap(prog, ref, theta0, shapes) == \
+    assert reference.param_gap(prog, ref, theta0, shapes) == \
         pytest.approx(0.5 / 2.5, rel=1e-6)
-    assert reference.leaf_norm_gap(ref, ref, theta0, shapes) == 0.0
+    assert reference.param_gap(ref, ref, theta0, shapes) == 0.0
 
 
 def test_bsp_spread_reads_the_log_in_file_order():
     rows = [(0, 0), (1, 0), (0, 1), (1, 1), (0, 2), (1, 2)]
-    assert reference.bsp_spread(rows, 2) == 1
-    assert reference.bsp_spread(rows + [(0, 3), (0, 4)], 2) == 2
+    assert harness.bsp_spread(rows, 2) == 1
+    assert harness.bsp_spread(rows + [(0, 3), (0, 4)], 2) == 2
+
+
+def test_only_the_clocks_compared_are_kept():
+    shapes = reference.Shapes("logreg", 1, 1, 0, 1, 1.0, 1)
+    slab = (np.array([[1.0]], np.float32), np.array([1], np.int32),
+            np.array([1.0], np.float32))
+    theta0 = np.zeros(4, np.float32)
+    every, losses = reference.Reference(shapes).run(theta0, [slab], 6)
+    kept, same = reference.Reference(shapes).run(theta0, [slab], 6,
+                                                 keep_every=3)
+    assert len(every) == 6 and len(kept) == 2 and losses == same
+    assert kept[0].tolist() == every[2].tolist()
+    assert kept[1].tolist() == every[5].tolist()
+
+
+def test_shapes_are_read_off_the_clis_configuration():
+    model = NS(num_features=8, num_classes=3, hidden_dim=16, num_max_iter=2,
+               local_learning_rate=0.1)
+    got = reference.shapes(NS(task="mlp", model=model, num_workers=4))
+    assert got == reference.Shapes("mlp", 8, 3, 16, 2, 0.1, 4)

@@ -1,7 +1,7 @@
 """span_reduce.py: the attribution of device idle time to the program's
 own spans and of device operations to named scopes, on hand-made planes
 where every share is known by hand, on a trace recorded on the chip
-(benchmark/fixtures/pernode_one_chip.README.txt), and through the
+(benchmark/fixtures/README.txt), and through the
 harness on the CPU."""
 
 import importlib.util
@@ -268,7 +268,7 @@ def test_slab_refresh_share_reads_the_program_s_record_of_its_last_call():
 # -- the recorded trace --------------------------------------------------------
 
 def test_the_recorded_trace_gives_what_was_read_by_hand():
-    """fixtures/pernode_one_chip.README.txt: two slices of the per-node
+    """fixtures/README.txt: two slices of the per-node
     cell; the gaps and the spans over them were listed from the full
     trace, seconds to four places."""
     data = trace_reduce.load(FIXTURE)

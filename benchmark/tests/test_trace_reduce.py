@@ -158,3 +158,61 @@ def test_the_recorded_trace_reduces_to_what_was_read_by_hand():
     assert got["longest_gaps_s"][0][1] == pytest.approx(0.369, abs=0.002)
     assert got["breakdown"]["device_ops"][0][0] == "jit__unknown"
     assert got["collective_s"] == 0.0
+
+
+def test_the_programs_spans_name_idle_gaps_and_do_not_set_the_window():
+    """`idle_gap_prefixes`: a gap goes to the innermost of the harness's
+    annotations and the program's `kps.*` spans, whatever thread they
+    are on; the window still runs over the `bench.*` annotations alone,
+    and in a trace cut out of one call over the device operations."""
+    def data():
+        return _Data([
+            _Plane("/device:TPU:0", [_Line("XLA Ops", [
+                _Event("%fusion.1 = f32[]", 1.0, 4.0),
+                _Event("%fusion.1 = f32[]", 7.0, 9.0)])]),
+            _Plane("/host:CPU", [
+                _Line("python", [_Event("bench.run_slice", 1.0, 9.0),
+                                 _Event("kps.log.flush", 4.0, 6.0),
+                                 _Event("kps.bsp.step", 0.0, 12.0),
+                                 _Event("other.span", 4.0, 7.0)]),
+                _Line("kps-log-drain", [_Event("kps.log.drain", 6.5, 6.75)]),
+            ])])
+    got = trace_reduce.reduce(data(), CFG, chips=1)
+    assert got["window_s"] == pytest.approx(8.0)     # kps.bsp.step is wider
+    assert got["idle_by_host_activity_s"] == pytest.approx(
+        {"kps.log.flush": 2.0, "kps.log.drain": 0.25,
+         "bench.run_slice": 0.75})
+    assert got["breakdown"]["idle_gaps"][0] == ["kps.log.flush",
+                                                pytest.approx(2.0)]
+    cut = trace_reduce.reduce(data(), dict(CFG, window_from="device_ops"),
+                              chips=1)
+    assert cut["window_s"] == pytest.approx(8.0)
+    # no annotation of the harness's alone: the gaps go by kps.* too
+    only_program = dict(CFG, idle_gap_prefixes=["kps."],
+                        window_from="device_ops")
+    assert trace_reduce.reduce(data(), only_program, chips=1)[
+        "idle_by_host_activity_s"] == pytest.approx(
+        {"kps.log.flush": 2.0, "kps.log.drain": 0.25, "kps.bsp.step": 0.75})
+
+
+def test_the_recorded_per_node_trace_names_its_gaps_by_the_programs_spans():
+    """fixtures/README.txt: the slice boundary's dry spell is the sinks'
+    flush, the gang's drain and its dispatch call; with `kps.*` among
+    the prefixes the ledger's `idle_gaps` says so."""
+    path = os.path.join(BENCH, "fixtures", "pernode_one_chip.xplane.pb.gz")
+    got = trace_reduce.reduce(trace_reduce.load(path), CFG, chips=1)
+    assert got["window_s"] == pytest.approx(0.7989, abs=1e-3)
+    idle = got["idle_by_host_activity_s"]
+    named = sum(s for name, s in idle.items() if name.startswith("kps."))
+    assert named > 0.9 * sum(idle.values())
+    top = [name for name, _ in got["breakdown"]["idle_gaps"][:4]]
+    assert "kps.worker.local_update" in top and "kps.log.flush" in top
+    assert "unannotated" not in top
+    # the window and the busy time are what they were by bench.* alone
+    alone = trace_reduce.reduce(trace_reduce.load(path),
+                                dict(CFG, idle_gap_prefixes=["bench."]),
+                                chips=1)
+    assert (alone["window_s"], alone["busy_s"]) == (got["window_s"],
+                                                    got["busy_s"])
+    assert set(alone["idle_by_host_activity_s"]) <= {
+        "bench.run_slice", "bench.flush_logs", "bench.sync", "unannotated"}

@@ -13,7 +13,11 @@ the planes and lines are called on the chip is data
     run, named after the XLA module (`jit_<function>(<id>)`);
   * host annotations (`jax.profiler.TraceAnnotation`, written by
     benchmark/run.py around its own calls) are events on host-plane
-    lines whose names start with `host_annotation_prefix`.
+    lines whose names start with `host_annotation_prefix`: they set the
+    window;
+  * an idle gap is named after the host events whose names start with
+    one of `idle_gap_prefixes` (the harness's annotations and the
+    program's own spans, `kps.*`), of every thread.
 """
 
 from __future__ import annotations
@@ -77,8 +81,11 @@ def module_name(event_name: str) -> str:
 
 def read_planes(data, cfg: dict) -> dict:
     """{"devices": [{"name", "ops": [(name, s, e)], "modules": [...]}],
-    "host": [(name, s, e)]}, times in seconds."""
-    devices, host = [], []
+    "host": [(name, s, e)], "activity": [(name, s, e)]}, times in
+    seconds: the harness's annotations, and every host event that may
+    name an idle gap."""
+    devices, host, activity = [], [], []
+    gap_prefixes = tuple(cfg["idle_gap_prefixes"])
     op_line, module_line = (re.compile(cfg["op_line"]),
                             re.compile(cfg["module_line"]))
     for plane in data.planes:
@@ -99,11 +106,14 @@ def read_planes(data, cfg: dict) -> dict:
         if plane.name.startswith(cfg["host_plane_prefix"]):
             for line in plane.lines:
                 for ev in line.events:
+                    s = ev.start_ns * 1e-9
+                    event = (ev.name, s, s + ev.duration_ns * 1e-9)
                     if ev.name.startswith(cfg["host_annotation_prefix"]):
-                        s = ev.start_ns * 1e-9
-                        host.append((ev.name, s, s + ev.duration_ns * 1e-9))
+                        host.append(event)
+                    if ev.name.startswith(gap_prefixes):
+                        activity.append(event)
     devices.sort(key=lambda d: d["name"])
-    return {"devices": devices, "host": host}
+    return {"devices": devices, "host": host, "activity": activity}
 
 
 def split_by_host_activity(at: float, end: float,
@@ -206,10 +216,10 @@ def reduce(data, cfg: dict, chips: int) -> dict:
 
     by_activity: dict[str, float] = {}
     for s, e in gaps:
-        for what, secs in split_by_host_activity(s, e,
-                                                 planes["host"]).items():
+        for what, secs in split_by_host_activity(
+                s, e, planes["activity"]).items():
             by_activity[what] = by_activity.get(what, 0.0) + secs
-    longest = [[host_activity(s, e, planes["host"]), e - s]
+    longest = [[host_activity(s, e, planes["activity"]), e - s]
                for s, e in sorted(gaps, key=lambda g: g[0] - g[1])
                [:cfg["gaps_listed"]]]
 
