@@ -58,11 +58,18 @@ def build_parser(include_server_flags: bool = True,
                         "BaseKafkaApp.java:25)")
     p.add_argument("--num_features", type=int, default=1024)
     p.add_argument("--num_classes", type=int, default=5)
-    p.add_argument("--task", choices=["logreg", "mlp"], default="logreg",
+    p.add_argument("--task", choices=["logreg", "mlp", "glm4_moe_lite"],
+                   default="logreg",
                    help="model family (models/task.py registry); logreg "
                         "is the reference's task")
     p.add_argument("--hidden_dim", type=int, default=128,
                    help="hidden width of the mlp task")
+    p.add_argument("--model_json", default=None,
+                   help="the model family's own configuration file "
+                        "(--task glm4_moe_lite: the published keys and "
+                        "the cut held here, models/glm4_moe_lite.py); a "
+                        "relative path is taken from the repository's "
+                        "root")
     p.add_argument("--local_iterations", type=int, default=2,
                    help="k local solver steps per iteration "
                         "(numMaxIter, LogisticRegressionTaskSpark.java:35)")
@@ -361,10 +368,50 @@ def load_test_csv(path: str, num_features: int):
     return x, y
 
 
+# the levers a task's rows or size cannot go through, by flag: what
+# `args` holds when the lever is off, and why the task cannot hold it
+_PAGES_A_CLASSIFIER = ("tiered residency pages a flat classifier theta "
+                       "(kafka_ps_tpu/store/)")
+_NO_MESH = ("its workers are folded one at a time on one device; it has "
+            "no program over a mesh (parallel/bsp.py)")
+TASK_REFUSES = {"glm4_moe_lite": {
+    "pallas": (False, "the Pallas kernels implement the logreg and mlp "
+                      "local updates (ops/fused_update.py)"),
+    "compress": ("none", "the wire codecs were sized for deltas of "
+                         "megabytes, and this family's delta does not "
+                         "cross serde in one message"),
+    "slab_dtype": ("f32", "its rows are int32 tokens, stored as they are "
+                          "(compress/slab.py)"),
+    "tier_hot_bytes": (0, _PAGES_A_CLASSIFIER),
+    "tier_warm_bytes": (0, _PAGES_A_CLASSIFIER),
+    "remote": (False, _NO_MESH),
+    "param_shards": (1, _NO_MESH)}}
+
+
+def refuse_levers(args) -> None:
+    """One message, with the reason, for every lever asked for that
+    `--task` cannot hold — before any program is built."""
+    asked = [(flag, why) for flag, (off, why)
+             in TASK_REFUSES.get(args.task, {}).items()
+             if (getattr(args, flag, off) or off) != off]
+    if asked:
+        raise SystemExit(
+            f"--task {args.task} cannot run with "
+            + "; ".join(f"--{flag.replace('_', '-')}: {why}"
+                        for flag, why in asked))
+    if args.task == "glm4_moe_lite" and not args.model_json:
+        raise SystemExit("--task glm4_moe_lite needs --model_json FILE, "
+                         "the family's own configuration")
+    if args.model_json and args.task != "glm4_moe_lite":
+        raise SystemExit(f"--model_json configures --task glm4_moe_lite; "
+                         f"--task {args.task} has no file of its own")
+
+
 def cfg_from_args(args):
     from kafka_ps_tpu.utils.config import (BufferConfig, ModelConfig,
                                            PSConfig, ServingConfig,
                                            StreamConfig, TierConfig)
+    refuse_levers(args)
     return PSConfig(
         num_workers=args.num_workers,
         consistency_model=args.consistency_model,
@@ -373,7 +420,8 @@ def cfg_from_args(args):
                           num_classes=args.num_classes,
                           num_max_iter=args.local_iterations,
                           local_learning_rate=args.local_learning_rate,
-                          hidden_dim=args.hidden_dim),
+                          hidden_dim=args.hidden_dim,
+                          model_json=args.model_json),
         buffer=BufferConfig(min_size=args.min_buffer_size,
                             max_size=args.max_buffer_size,
                             coefficient=args.buffer_size_coefficient),
@@ -412,8 +460,13 @@ def make_app_from_args(args, resuming: bool = False,
                                            SERVER_HEADER, WORKER_HEADER)
 
     cfg = cfg_from_args(args)
+    # a row's width and dtype are the task's (token rows come as the
+    # same CSV, one token a column and a label column that is ignored)
+    from kafka_ps_tpu.models.task import get_task
+    task = get_task(cfg.task, cfg.model)
     test_x, test_y = load_test_csv(args.test_data_file_path,
-                                   args.num_features)
+                                   task.row_width)
+    test_x = test_x.astype(task.row_dtype)
     suffix = f".p{process_index}" if process_index else ""
     if process_index == 0:
         server_log = CsvLogSink(

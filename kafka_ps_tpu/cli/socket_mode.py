@@ -722,8 +722,11 @@ def run_worker(args) -> int:
             fh.write(str(bridge.server_run_id))
     log = CsvLogSink(log_path, WORKER_HEADER, append=append_log)
 
-    buffers = {w: SlidingBuffer(cfg.model.num_features, cfg.buffer,
-                                telemetry=telemetry, worker=w)
+    from kafka_ps_tpu.models.task import get_task
+    task = get_task(cfg.task, cfg.model)     # a row's width and dtype
+    buffers = {w: SlidingBuffer(task.row_width, cfg.buffer,
+                                telemetry=telemetry, worker=w,
+                                dtype=task.row_dtype)
                for w in ids}
     if restoring:
         from kafka_ps_tpu.utils import checkpoint as ckpt
@@ -1306,8 +1309,11 @@ def _run_worker_sharded(args, addrs: list[str],
             print(f"compression: {spec.name} (local sparsifier)",
                   file=sys.stderr, flush=True)
 
-    buffers = {w: SlidingBuffer(cfg.model.num_features, cfg.buffer,
-                                telemetry=telemetry, worker=w)
+    from kafka_ps_tpu.models.task import get_task
+    task = get_task(cfg.task, cfg.model)     # a row's width and dtype
+    buffers = {w: SlidingBuffer(task.row_width, cfg.buffer,
+                                telemetry=telemetry, worker=w,
+                                dtype=task.row_dtype)
                for w in ids}
 
     # worker-local durable state, exactly as in run_worker: a member

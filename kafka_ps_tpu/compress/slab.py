@@ -102,6 +102,8 @@ def decode_x(x) -> jax.Array:
     TRACE_COUNTS["decode"] += 1
     if isinstance(x, QuantizedSlab):
         return x.q.astype(jnp.float32) * x.scale
+    if jnp.issubdtype(x.dtype, jnp.integer):
+        return x          # token rows are stored, and used, as they are
     return x.astype(jnp.float32)
 
 
@@ -153,10 +155,18 @@ class SlabStore:
     measured number, not an estimate."""
 
     def __init__(self, dtype: str, capacity: int, num_features: int,
-                 telemetry=None):
+                 telemetry=None, row_dtype=np.float32):
         if dtype not in SLAB_DTYPES:
             raise ValueError(
                 f"slab dtype {dtype!r} not in {SLAB_DTYPES}")
+        # what a row is made of on the host (the task's `row_dtype`):
+        # float32 features take any storage dtype, integer token rows
+        # only their own
+        self.row_dtype = np.dtype(row_dtype)
+        if self.row_dtype != np.float32 and dtype != "f32":
+            raise ValueError(
+                f"slab dtype {dtype!r} stores float32 rows; rows of "
+                f"{self.row_dtype} are stored as they are")
         self.dtype = dtype
         self.capacity = capacity
         self.num_features = num_features
@@ -184,7 +194,7 @@ class SlabStore:
 
     def upload_full(self, x, y, mask) -> None:
         """Host slab copy -> device store (encode fused in one jit)."""
-        x = np.ascontiguousarray(x, dtype=np.float32)
+        x = np.ascontiguousarray(x, dtype=self.row_dtype)
         y = np.ascontiguousarray(y, dtype=np.int32)
         mask = np.ascontiguousarray(mask, dtype=np.float32)
         self.bytes_uploaded += x.nbytes + y.nbytes + mask.nbytes
@@ -212,8 +222,8 @@ class SlabStore:
             [np.asarray(slots, np.int32),
              np.full((pad,), self.capacity, np.int32)])
         xr_p = np.concatenate(
-            [np.asarray(xr, np.float32),
-             np.zeros((pad, self.num_features), np.float32)])
+            [np.asarray(xr, self.row_dtype),
+             np.zeros((pad, self.num_features), self.row_dtype)])
         yr_p = np.concatenate(
             [np.asarray(yr, np.int32), np.zeros((pad,), np.int32)])
         mr_p = np.concatenate(

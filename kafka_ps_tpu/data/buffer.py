@@ -49,9 +49,15 @@ class SlidingBuffer:
 
     def __init__(self, num_features: int, cfg: BufferConfig,
                  clock_ms: Callable[[], float] | None = None,
-                 telemetry=None, worker: int | None = None):
+                 telemetry=None, worker: int | None = None,
+                 dtype=np.float32):
+        """`num_features` and `dtype` are the row's width and dtype, as
+        the task states them (`MLTask.row_width` / `.row_dtype`):
+        float32 features, or int32 tokens whose labels are the row
+        itself."""
         self.cfg = cfg
         self.num_features = num_features
+        self.dtype = np.dtype(dtype)
         if telemetry is None:
             from kafka_ps_tpu.telemetry import NULL_TELEMETRY
             telemetry = NULL_TELEMETRY
@@ -60,7 +66,7 @@ class SlidingBuffer:
             "buffer_rows_ingested_total",
             worker="all" if worker is None else str(worker))
         cap = cfg.max_size
-        self.x = np.zeros((cap, num_features), dtype=np.float32)
+        self.x = np.zeros((cap, num_features), dtype=self.dtype)
         self.y = np.zeros((cap,), dtype=np.int32)
         # insertion_id[i] == 0 marks an empty slot (reference IDs start at 1).
         self.insertion_id = np.zeros((cap,), dtype=np.int64)
@@ -171,7 +177,7 @@ class SlidingBuffer:
         if isinstance(features, dict):
             row = sparse_to_dense([features], self.num_features)[0]
         else:
-            row = np.asarray(features, dtype=np.float32)
+            row = np.asarray(features, dtype=self.dtype)
         self.x[slot] = row
         self.y[slot] = label
         self.insertion_id[slot] = new_id

@@ -47,6 +47,22 @@ def weighted_f1_accuracy(preds: jax.Array, labels: jax.Array, n: int):
     return weighted_f1, accuracy
 
 
+def weighted_f1_accuracy_by_class(preds: jax.Array, labels: jax.Array, n: int):
+    """`weighted_f1_accuracy` from per-class counts alone (true
+    positives, support, predicted), for label spaces where an (n, n)
+    confusion matrix is out of the question: a vocabulary."""
+    ones = jnp.ones(labels.shape, jnp.float32)
+    support = jnp.zeros((n,), jnp.float32).at[labels].add(ones)
+    predicted = jnp.zeros((n,), jnp.float32).at[preds].add(ones)
+    tp = jnp.zeros((n,), jnp.float32).at[labels].add(
+        (preds == labels).astype(jnp.float32))
+    precision = tp / jnp.maximum(predicted, 1.0)
+    recall = tp / jnp.maximum(support, 1.0)
+    f1 = 2 * precision * recall / jnp.maximum(precision + recall, 1e-12)
+    total = jnp.maximum(support.sum(), 1.0)
+    return (f1 * support).sum() / total, tp.sum() / total
+
+
 def evaluate_leaves(params, x_test: jax.Array, y_test: jax.Array,
                     *, cfg: ModelConfig) -> Metrics:
     """Full-test-set metrics, same cadence as the reference (every server

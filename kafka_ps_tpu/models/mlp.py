@@ -79,7 +79,7 @@ def loss_onehot(params: MLPParams, x, onehot, mask):
     return (nll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
 
 
-class MLPTask:
+class MLPTask(task_mod.RowsWithClassLabel):
     """MLTask implementation (models/task.py protocol)."""
 
     def __init__(self, cfg: ModelConfig):
@@ -123,8 +123,8 @@ class MLPTask:
 
     def local_update(self, theta, x, y, mask):
         # slab-storage decode (f32 identity) fuses into the caller's jit
-        onehot = jax.nn.one_hot(y, self.cfg.num_rows, dtype=jnp.float32)
-        return self.local_update_onehot(theta, decode_x(x), onehot, mask)
+        return self.local_update_onehot(theta, decode_x(x),
+                                        self.encode_labels(y), mask)
 
     def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
         return _evaluate(theta, x_test, y_test, cfg=self.cfg)

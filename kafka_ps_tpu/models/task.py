@@ -15,7 +15,16 @@ this module makes it so in fact.  A task owns:
     difference from the old ones is the delta, the "gradient" the
     reference exchanges, LogisticRegressionTaskSpark.java:179-220),
   * test evaluation from the leaves (`evaluate_leaves` → weighted F1 /
-    accuracy / loss, Metrics.java:15-24).
+    accuracy / loss, Metrics.java:15-24),
+  * what a row is (`row_width`, `row_dtype`: a classifier's float32
+    features, a language model's int32 tokens) and how its label is
+    encoded for `fit` (`encode_labels`: one-hot for the classifiers,
+    nothing for token rows, whose labels are the row itself) — the
+    buffers, the slab and the step builders ask the task,
+  * whether its update batches over the worker axis
+    (`batches_workers`): the fused step `vmap`s the classifiers over
+    the workers and folds a family whose own matrix products fill the
+    chip one worker at a time (parallel/bsp.py).
 
 Inside a solver program the parameters are their leaves; the flat
 vector is what a program takes and returns (the wire and server
@@ -26,8 +35,9 @@ step, the server's eval, serving, the Pallas kernels' callers.
 
 Every entry point (runtime worker, fused BSP step, range-sharded step,
 server eval) dispatches through a task; `logreg` stays the default —
-the reference's model — and `mlp` is a second family proving the
-runtime generalizes.
+the reference's model — `mlp` is a second classifier, and
+`glm4_moe_lite` a language model over token rows
+(models/glm4_moe_lite.py).
 """
 
 from __future__ import annotations
@@ -36,6 +46,7 @@ from typing import Any, Protocol
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from kafka_ps_tpu.models import logreg
 from kafka_ps_tpu.models import metrics as metrics_mod
@@ -50,9 +61,16 @@ class MLTask(Protocol):
     `leaves` is the family's pytree of parameter arrays."""
 
     cfg: ModelConfig
+    batches_workers: bool
+    row_dtype: Any
 
     @property
     def num_params(self) -> int: ...
+
+    @property
+    def row_width(self) -> int: ...
+
+    def encode_labels(self, y) -> Any: ...
 
     def init_params(self) -> jax.Array: ...
 
@@ -60,7 +78,7 @@ class MLTask(Protocol):
 
     def flatten(self, leaves) -> jax.Array: ...
 
-    def fit(self, leaves, x, onehot, mask): ...
+    def fit(self, leaves, x, encoded, mask): ...
 
     def evaluate_leaves(self, leaves, x_test, y_test) \
             -> metrics_mod.Metrics: ...
@@ -85,6 +103,22 @@ def fit_delta(task: MLTask, leaves, x, onehot, mask):
         return jax.tree.map(jnp.subtract, new, leaves), loss
 
 
+class RowsWithClassLabel:
+    """What the classifier families share: float32 feature rows of
+    `cfg.num_features`, a class label one-hot over `cfg.num_rows`, an
+    update that batches over the worker axis."""
+
+    batches_workers = True
+    row_dtype = np.float32
+
+    @property
+    def row_width(self) -> int:
+        return self.cfg.num_features
+
+    def encode_labels(self, y):
+        return jax.nn.one_hot(y, self.cfg.num_rows, dtype=jnp.float32)
+
+
 def flat_local_update(task: MLTask, theta, x, onehot, mask):
     """The flat face of the solver: one flat theta in, one flat delta
     out."""
@@ -92,7 +126,7 @@ def flat_local_update(task: MLTask, theta, x, onehot, mask):
     return task.flatten(delta), loss
 
 
-class LogRegTask:
+class LogRegTask(RowsWithClassLabel):
     """The reference's model: multinomial LR over the flat
     (C+1)·F + (C+1) layout (models/logreg.py)."""
 
@@ -164,6 +198,9 @@ def get_task(name: str, cfg: ModelConfig) -> MLTask:
         if name == "mlp":
             from kafka_ps_tpu.models.mlp import MLPTask
             register("mlp", MLPTask)
+        elif name == "glm4_moe_lite":
+            from kafka_ps_tpu.models.glm4_moe_lite import Glm4MoeLiteTask
+            register("glm4_moe_lite", Glm4MoeLiteTask)
         else:
             raise ValueError(
                 f"unknown task {name!r}; registered: {sorted(_REGISTRY)}")

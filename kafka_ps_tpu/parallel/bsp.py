@@ -18,6 +18,17 @@ When there are fewer devices than logical workers (e.g. one TPU chip
 hosting 4 logical workers, like the reference's 4 stream threads in one
 JVM — BaseKafkaApp.java:70), the worker axis falls back to a `vmap`
 inside the device: same math, XLA parallelizes across the MXU.
+
+A task whose update does not batch (`task.batches_workers` false: a
+worker's own matrix products fill the chip, and W copies of its
+parameters and gradients would not fit it) takes the folded programs
+instead: the worker axis is a `lax.scan` over the slabs that adds each
+worker's delta leaves into one running sum, and a scan chunk carries
+the parameters as their leaves from round to round and from dispatch to
+dispatch (donated) — the flat vector is cut into leaves where a drive
+call begins and built again where it ends (`folded_edges`).  Those
+programs also return the task's counters (`task.counter_names`),
+summed over the dispatch's updates.
 """
 
 from __future__ import annotations
@@ -33,10 +44,11 @@ from kafka_ps_tpu.models.task import default_task, fit_delta
 from kafka_ps_tpu.parallel.mesh import WORKER_AXIS
 from kafka_ps_tpu.utils.config import ModelConfig
 
-# step(theta, x, y, mask) -> (theta', mean_loss)
-#   theta: [P] flat, replicated — what the program takes, carries from
-#   round to round and returns; inside a round the parameters are the
-#   task's leaves.  x: [N, cap, F]; y: [N, cap]; mask: [N, cap]
+# step(theta, x, y, mask) -> (theta', mean_loss), and for a folded
+# task (leaves', mean_loss, counters) from its leaves
+#   theta: [P] flat, replicated — what a vmapped program takes, carries
+#   from round to round and returns; inside a round the parameters are
+#   the task's leaves.  x: [N, cap, F]; y: [N, cap]; mask: [N, cap]
 BspStep = Callable[..., tuple[jax.Array, jax.Array]]
 
 
@@ -78,6 +90,88 @@ def _make_round(task, num_workers: int, server_lr: float, psum_axis: bool):
     return round_
 
 
+def _make_folded_round(task, num_workers: int, server_lr: float):
+    """One BSP clock on the parameters' leaves, one worker at a time:
+    (leaves, x, encoded, mask) -> (leaves', mean loss, counters).
+    Alive at once: the shared leaves, one worker's working copy and
+    gradient, and the running sum of deltas."""
+
+    def round_(leaves, x, encoded, mask):
+        def worker(carry, slab):
+            total, loss_sum, counted = carry
+            # the shared leaves are tied to the running sum, which
+            # changes from worker to worker: left loop-invariant, every
+            # relayout of a weight for the first local step is hoisted
+            # out of the loop and kept beside the leaves — two more
+            # copies of the parameters at the published widths
+            shared, total = jax.lax.optimization_barrier((leaves, total))
+            new, loss, counts = task.fit_counted(shared, *slab)
+            with jax.named_scope("kps.fit.delta"):
+                total = jax.tree.map(lambda t, n, o: t + (n - o),
+                                     total, new, shared)
+            return (total, loss_sum + loss, counted + counts), None
+
+        zero = (jax.tree.map(jnp.zeros_like, leaves), jnp.float32(0.0),
+                jnp.zeros((len(task.counter_names),), jnp.int32))
+        (total, loss_sum, counted), _ = jax.lax.scan(
+            worker, zero, (x, encoded, mask))
+        with jax.named_scope("kps.bsp.apply"):
+            return (jax.tree.map(lambda a, d: a + server_lr * d, leaves,
+                                 total),
+                    loss_sum / num_workers, counted)
+
+    return round_
+
+
+def _make_folded(task, num_workers: int, server_lr: float, rounds: int,
+                 mesh, whole_chunk: bool) -> BspStep:
+    """The folded step (`rounds` 1, one loss) or scan chunk (a loss a
+    round): (leaves, x, y, mask) -> (leaves', loss(es), counters).
+    The leaves are DONATED: a caller carries them from dispatch to
+    dispatch and holds no other use of them (`folded_edges` has the way
+    from and to the flat vector, and the evaluation from the leaves)."""
+    if mesh is not None:
+        raise ValueError(
+            f"task {type(task).__name__} folds its workers one at a time "
+            "on one device; it has no program over a mesh")
+    round_ = _make_folded_round(task, num_workers, server_lr)
+
+    def scanned(leaves, x, y, mask):    # the program's name: jit_scanned
+        encoded = task.encode_labels(y)
+
+        def clock(leaves, _):
+            leaves, loss, counted = round_(leaves, x, encoded, mask)
+            return leaves, (loss, counted)
+
+        leaves, (losses, counted) = jax.lax.scan(clock, leaves, None,
+                                                 length=rounds)
+        return (leaves, losses if whole_chunk else losses[0],
+                counted.sum(0))
+
+    return jax.jit(scanned, donate_argnums=0)
+
+
+def folded_edges(task):
+    """The edges of a folded task's drive call, each a program of its
+    own: (cut: flat theta -> leaves, join: leaves -> flat theta,
+    evaluate: (leaves, test_x, test_y) -> Metrics).  The flat vector
+    exists where a call begins and ends — the server, the wire and the
+    checkpoint take it — and nowhere between: beside the leaves, the
+    running sum, a worker's working copy and its gradient, a fifth and
+    sixth copy of the parameters inside the chunk program would not fit
+    the chip."""
+
+    def cut(theta):
+        with jax.named_scope("kps.bsp.flat"):
+            return task.unflatten(theta)
+
+    def join(leaves):
+        with jax.named_scope("kps.bsp.flat"):
+            return task.flatten(leaves)
+
+    return jax.jit(cut), jax.jit(join), jax.jit(task.evaluate_leaves)
+
+
 def _over_mesh(body, num_workers: int, mesh: Mesh) -> BspStep:
     if num_workers % mesh.devices.size != 0:
         raise ValueError(
@@ -100,12 +194,14 @@ def make_bsp_step(cfg: ModelConfig, num_workers: int, server_lr: float,
     """
 
     task = task or default_task(cfg)
+    if not task.batches_workers:
+        return _make_folded(task, num_workers, server_lr, 1, mesh,
+                            whole_chunk=False)
     round_ = _make_round(task, num_workers, server_lr,
                          psum_axis=mesh is not None)
 
     def step(theta, x, y, mask):
-        onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
-        return round_(theta, x, onehot, mask)
+        return round_(theta, x, task.encode_labels(y), mask)
 
     if mesh is None:
         return jax.jit(step)
@@ -127,11 +223,14 @@ def make_bsp_multi_step(cfg: ModelConfig, num_workers: int, server_lr: float,
     (WorkerTrainingProcessor.java:63-97), which is exactly a scan."""
 
     task = task or default_task(cfg)
+    if not task.batches_workers:
+        return _make_folded(task, num_workers, server_lr, rounds, mesh,
+                            whole_chunk=True)
 
     def scanned(theta, x, y, mask, psum_axis):
         round_ = _make_round(task, num_workers, server_lr, psum_axis)
-        # labels are fixed across rounds: one-hot once, above the scan
-        onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
+        # labels are fixed across rounds: encoded once, above the scan
+        onehot = task.encode_labels(y)
         return jax.lax.scan(lambda t, _: round_(t, x, onehot, mask),
                             theta, None, length=rounds)
 

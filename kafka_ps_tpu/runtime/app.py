@@ -62,10 +62,13 @@ class StreamingPSApp:
         # callers may supply a durable fabric (log/durable_fabric.py,
         # `--durable-log`); default stays the volatile in-memory one
         self.fabric = fabric or fabric_mod.Fabric(tracer=self.tracer)
+        # what a row is (width, dtype) is the task's to say
+        from kafka_ps_tpu.models.task import get_task
+        task = get_task(cfg.task, cfg.model)
         self.buffers = [
-            SlidingBuffer(cfg.model.num_features, cfg.buffer,
+            SlidingBuffer(task.row_width, cfg.buffer,
                           clock_ms=clock_ms, telemetry=self.telemetry,
-                          worker=w)
+                          worker=w, dtype=task.row_dtype)
             for w in range(cfg.num_workers)]
         # deferred sinks: the per-node hot path logs device futures
         # (loss/F1/accuracy) without blocking on them — flushed when
@@ -75,6 +78,10 @@ class StreamingPSApp:
         self.server = ServerNode(cfg, self.fabric, test_x, test_y, server_log,
                                  tracer=self.tracer,
                                  telemetry=self.telemetry)
+        if not task.batches_workers:
+            # a folded task's flat vector lives on the host whenever no
+            # drive call runs (`_run_fused_loop` has why), from the start
+            self.server.theta = np.asarray(self.server.theta)
         self.workers = [
             WorkerNode(w, cfg, self.fabric, self.buffers[w], test_x, test_y,
                        worker_log, tracer=self.tracer,
@@ -715,7 +722,10 @@ class StreamingPSApp:
                 progs["step"] = bsp.make_bsp_step(
                     self.cfg.model, len(active), self.cfg.server_lr,
                     mesh=mesh, task=task)
-            theta = jnp.asarray(self.server.theta)
+            # a folded task's loop cuts its leaves from the server's
+            # vector itself, and holds no flat copy on the device
+            theta = (jnp.asarray(self.server.theta)
+                     if task.batches_workers else None)
         step = progs["step"]
         # under BSP all active clocks are uniform; resume from the
         # restored one
@@ -755,6 +765,8 @@ class StreamingPSApp:
                 multiproc, step, theta, clock, active, feed, task, progs)
         finally:
             reporter.stop()
+        # a folded task's counters, summed over the call's updates,
+        # ride along under "counters" (models/task.py `counter_names`)
         self.last_run = {"path": "fused",
                          "seconds": time.perf_counter() - t_call, **refresh}
 
@@ -793,9 +805,28 @@ class StreamingPSApp:
                         CHUNK, mesh=mesh, task=task)
             return progs["multi_step"]
 
+        # A folded task (parallel/bsp.py) is carried through the call as
+        # its leaves, donated from dispatch to dispatch: `theta` below is
+        # then the leaves, cut from the flat vector here and joined
+        # again where the loop ends, and the evaluation reads the leaves
+        folded = not range_mode and not task.batches_workers
+        evaluate = None
+        if folded:
+            if "edges" not in progs:
+                progs["edges"] = bsp.folded_edges(task)
+            cut, join, evaluate = progs["edges"]
+            # between calls the flat vector lives on the HOST: on the
+            # device it would stand beside the leaves, the running sum,
+            # a worker's working copy and its gradient as a fifth copy
+            # of the parameters, which the chip cannot hold at the
+            # published widths.  An upload where the call begins and a
+            # download where it ends, once a call.
+            self.server.theta = np.asarray(self.server.theta)
+            theta = cut(self.server.theta)
         x = y = mask = None
         slab_versions: list[int] | None = None
         refresh = dict(NO_SLAB_REFRESH)
+        counted = []      # a folded task's counters, one array a dispatch
         while self.server.iterations < max_server_iterations:
             # one span a turn: the loop's own Python between its
             # children is this span's self time
@@ -867,11 +898,16 @@ class StreamingPSApp:
                 # queue — no span reads a device value
                 with self.tracer.span("bsp.step", clock=clock + 1,
                                       rounds=r):
+                    # a folded task's programs return its counters
+                    # third (parallel/bsp.py); they stay on the device
+                    # until the loop returns
                     if use_chunk:
-                        theta, losses = get_multi_step()(theta, x, y, mask)
+                        theta, losses, *more = get_multi_step()(
+                            theta, x, y, mask)
                         mean_loss = losses[-1]
                     else:
-                        theta, mean_loss = step(theta, x, y, mask)
+                        theta, mean_loss, *more = step(theta, x, y, mask)
+                    counted += more
                 if multiproc and log_metrics:
                     # Multi-process runs with logging sync here: the
                     # psum makes every process's step k finish together
@@ -892,8 +928,17 @@ class StreamingPSApp:
                     if range_mode:
                         self.server.theta = range_sharded.unshard_theta(
                             theta, task)
-                    else:
+                    elif not folded:
                         self.server.theta = theta
+                    elif (self.server.serving is not None
+                          or self.server.checkpoint_due()):
+                        # a reader is owed the parameters of THIS clock:
+                        # a snapshot or a checkpoint holds theta, the
+                        # clocks and the iterations of one moment, so
+                        # the leaves are joined and brought to the host
+                        # now (a 1 s stall at the published widths, paid
+                        # only where someone reads)
+                        self.server.theta = np.asarray(join(theta))
                     for w in active:
                         self.workers[w].iterations += r
                         self.server.tracker.tracker[w].vector_clock = clock
@@ -907,14 +952,31 @@ class StreamingPSApp:
                 if log_metrics and self.server.test_x is not None:
                     self._log_fused_rounds(
                         theta, clock, r, losses, mean_loss, feed,
-                        range_mode, multiproc)
+                        range_mode, multiproc, evaluate)
+        if folded:
+            # the flat vector, once a call unless a snapshot or a
+            # checkpoint was owed at a chunk's boundary (above): until
+            # here `server.theta` is the last of those, or the state
+            # the call began with
+            self.server.theta = np.asarray(join(theta))
+            del theta
+            self.server.publish_snapshot()
         self.flush_logs()    # deferred rows out before the loop returns
+        if counted:
+            totals = np.sum([np.asarray(c, np.int64) for c in counted],
+                            axis=0)
+            refresh["counters"] = {
+                name: int(n) for name, n in zip(task.counter_names, totals)}
+            for name, n in refresh["counters"].items():
+                self.tracer.count(name, n)
         return refresh
 
     def _log_fused_rounds(self, theta, clock, r, losses, mean_loss, feed,
-                          range_mode, multiproc) -> None:
+                          range_mode, multiproc, evaluate=None) -> None:
         """The server's eval row (on cadence) and every fed worker's
-        row for each of the `r` rounds that ended on `clock`."""
+        row for each of the `r` rounds that ended on `clock`.
+        `evaluate`: a folded task's evaluation from its leaves, which
+        `theta` then is."""
         import jax
         import jax.numpy as jnp
 
@@ -926,7 +988,7 @@ class StreamingPSApp:
                 # eval on the reassembled flat layout (just stored)
                 eval_theta = (jnp.asarray(self.server.theta)
                               if range_mode else theta)
-                m = self.server.task.evaluate(
+                m = (evaluate or self.server.task.evaluate)(
                     eval_theta, self.server.test_x, self.server.test_y)
                 self.server.last_metrics = m
         now = int(time.time() * 1000)

@@ -152,6 +152,22 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
             return (unstack(deltas, k), unstack(losses, k),
                     unstack(met.f1, k), unstack(met.accuracy, k))
     else:
+        def over_members(fn, in_axes):
+            """`fn` over the members' axis: a `vmap`, or, for a task
+            whose update does not batch (`task.batches_workers`), one
+            member at a time."""
+            if task.batches_workers:
+                return jax.vmap(fn, in_axes=in_axes)
+
+            def one_at_a_time(*args):
+                def one(mapped):
+                    mapped = iter(mapped)
+                    return fn(*[a if axis is None else next(mapped)
+                                for a, axis in zip(args, in_axes)])
+                return jax.lax.map(one, tuple(
+                    a for a, axis in zip(args, in_axes) if axis is not None))
+            return one_at_a_time
+
         def member_leaves(thetas, shared):
             """(leaves, their vmap axis): one set for a shared theta,
             else the members' own, stacked leaf by leaf."""
@@ -166,18 +182,18 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
         def update(thetas, shared, xs, ys, masks):
             k = len(xs)
             leaves, axis = member_leaves(thetas, shared)
-            deltas, losses = jax.vmap(
+            deltas, losses = over_members(
                 functools.partial(worker_mod.fit_slab, task),
-                in_axes=(axis, 0, 0, 0))(
+                (axis, 0, 0, 0))(
                     leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks))
             return fan_out(deltas, k), unstack(losses, k)
 
         def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
             k = len(xs)
             leaves, axis = member_leaves(thetas, shared)
-            deltas, losses, f1s, accs = jax.vmap(
+            deltas, losses, f1s, accs = over_members(
                 functools.partial(worker_mod.fit_and_eval, task),
-                in_axes=(axis, 0, 0, 0, None, None))(
+                (axis, 0, 0, 0, None, None))(
                     leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks),
                     test_x, test_y)
             return (fan_out(deltas, k), unstack(losses, k),

@@ -1279,11 +1279,16 @@ class ServerNode:
         """Save once every `checkpoint_every` applied iterations —
         crossing-based so any iteration stride (1 in the message path,
         num_workers in the fused path) triggers on schedule."""
-        if not self.checkpoint_path or self.checkpoint_every <= 0:
-            return
-        if (self.iterations - self._last_checkpoint_iteration
-                >= self.checkpoint_every):
+        if self.checkpoint_due():
             self.save_checkpoint_now()
+
+    def checkpoint_due(self) -> bool:
+        """Whether `maybe_checkpoint` would save now: a caller that
+        keeps theta elsewhere between saves (a folded task's fused
+        loop) stores it first."""
+        return bool(self.checkpoint_path and self.checkpoint_every > 0
+                    and (self.iterations - self._last_checkpoint_iteration
+                         >= self.checkpoint_every))
 
     def save_checkpoint_now(self) -> None:
         """Write the checkpoint, and on a durable fabric

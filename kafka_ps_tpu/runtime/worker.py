@@ -63,8 +63,8 @@ def solver_program(cfg: PSConfig) -> str:
 def fit_slab(task, leaves, x, y, mask):
     """The k-step solver on one member's slab as the worker stores it
     (labels, any slab storage form) → (delta leaves, loss)."""
-    onehot = jax.nn.one_hot(y, task.cfg.num_rows, dtype=jnp.float32)
-    return fit_delta(task, leaves, slab_mod.decode_x(x), onehot, mask)
+    return fit_delta(task, leaves, slab_mod.decode_x(x),
+                     task.encode_labels(y), mask)
 
 
 def fit_and_eval(task, leaves, x, y, mask, test_x, test_y):
@@ -174,7 +174,7 @@ class WorkerNode:
         self._slab_version: int | None = None
         self._slab_store = slab_mod.SlabStore(
             cfg.slab_dtype, buffer.cfg.max_size, buffer.num_features,
-            telemetry=self.telemetry)
+            telemetry=self.telemetry, row_dtype=buffer.dtype)
         self.iterations = 0
         # iterations counted at (re)admission: the supervisor grants the
         # jit-compile grace to the first iteration *since joining*, not

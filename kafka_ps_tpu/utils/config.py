@@ -20,20 +20,36 @@ EVENTUAL = -1
 
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
-    """LR task shape (LogisticRegressionTaskSpark.java:32-35).
+    """What every model family shares — the local solver (k steps, step
+    size) and the shape of a classifier's rows — and where a family's
+    own configuration is found.
 
-    The parameter vector is flat with (num_classes + 1) * num_features
-    coefficient keys followed by (num_classes + 1) intercept keys —
-    6*1024 + 6 = 6150 by default.  One extra row because reference labels
-    are 1..num_classes and Spark sizes the model 0..max_label
+    `num_features` / `num_classes` are the reference's LR task shape
+    (LogisticRegressionTaskSpark.java:32-35): the parameter vector is
+    flat with (num_classes + 1) * num_features coefficient keys
+    followed by (num_classes + 1) intercept keys — 6*1024 + 6 = 6150
+    by default.  One extra row because reference labels are
+    1..num_classes and Spark sizes the model 0..max_label
     (LogisticRegressionTaskSpark.java:98-104,122-140).
+
+    A family with more shape than that reads its own configuration
+    from ONE file, `model_json` (`--model_json`; a relative path is
+    taken from the repository's root): the published keys of the
+    architecture and the cut this process holds
+    (models/glm4_moe_lite.py).  There is no flag per width; the row's
+    width and dtype, the label encoding and whether the worker axis
+    batches are the task's to say (models/task.py).
     """
 
     num_features: int = 1024
     num_classes: int = 5
     num_max_iter: int = 2       # k local solver steps per iteration
     local_learning_rate: float = 0.5  # step size of the local k-step solver
-    hidden_dim: int = 128       # used by the mlp task family only
+    # the mlp family's one width of its own; it predates model_json and
+    # keeps its flag (`--hidden_dim`)
+    hidden_dim: int = 128
+    # a family's own configuration file (None: the family has none)
+    model_json: str | None = None
 
     @property
     def num_rows(self) -> int:
@@ -111,7 +127,7 @@ class PSConfig:
     num_workers: int = 4
     consistency_model: int = SEQUENTIAL   # -c: 0 BSP, k>0 SSP, -1 ASP
     # model family (models/task.py registry): "logreg" (the reference's
-    # task) or "mlp"
+    # task), "mlp" or "glm4_moe_lite"
     task: str = "logreg"
     model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
     buffer: BufferConfig = dataclasses.field(default_factory=BufferConfig)

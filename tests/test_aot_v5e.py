@@ -45,3 +45,48 @@ def test_no_relayout_of_every_workers_parameters(aot, topo, program):
     text = compiled.as_text()
     assert "fusion(" in text            # the reader sees the program
     assert aot.big_relayouts(text, WORKERS * W1_BYTES // 2) == []
+
+
+def test_the_folded_chunk_at_the_published_widths_fits_the_chip(topo):
+    """The scan chunk of the benchmark's language-model cell (591.3 M
+    parameters held, 4 workers folded one at a time, 1 row of 1,024
+    tokens, 8 clocks), compiled for the described chip: the leaves are
+    donated (argument and result share their bytes) and the program's
+    scratch stays under 10.5 GB, so that it fits the chip's 16.9 GB
+    beside nothing but its own leaves.  It reads 9.32 GB (10.21 GB at
+    2 rows when written, 11.39 GB there since the expert layer places
+    its rows under a bound or all of them, both branches compiled);
+    16.49 GB with the local steps scanned and the shared leaves left
+    loop-invariant in the fold over the workers (the compiler then
+    keeps a relayout of every weight beside the loop), and 4.7 GB more
+    with the flat vector cut into leaves without a barrier (PERF.md
+    section 6, PR 27).  About 50 s."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from kafka_ps_tpu.models import glm4_moe_lite as glm
+    from kafka_ps_tpu.models.task import get_task
+    from kafka_ps_tpu.parallel import bsp
+    from kafka_ps_tpu.utils.config import ModelConfig
+
+    cfg = ModelConfig(num_max_iter=2, local_learning_rate=0.001,
+                      model_json="benchmark/configs/"
+                                 "glm-4.7-flash-ep8.model.json")
+    task = get_task("glm4_moe_lite", cfg)
+    assert task.num_params == 591_294_976
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    leaves = {n: shaped(s, jnp.float32) for n, s in
+              glm.leaf_specs(task.arch)}
+    chunk = bsp.make_bsp_multi_step(cfg, 4, 0.25, 8, task=task)
+    compiled = chunk.lower(
+        leaves, shaped((4, 1, task.row_width), jnp.int32),
+        shaped((4, 1), jnp.int32), shaped((4, 1), jnp.float32)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * task.num_params
+    assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
+    # the grouped products are the chip's own kernel, not a dense product
+    assert "ragged-dot" in compiled.as_text()
