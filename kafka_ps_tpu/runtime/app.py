@@ -111,9 +111,10 @@ class StreamingPSApp:
         # MLP-4096) even when the XLA compile cache hits
         self._fused_programs: dict = {}
         # the last drive call, written as run_fused_bsp / run_serial
-        # return: path, seconds, and the slab refreshes at the head of a
+        # return: path, seconds, the slab refreshes at the head of a
         # fused call (count, seconds, bytes), which a trace of the
-        # steady state never holds
+        # steady state never holds, and how often and how long the call
+        # waited on a log sink's backlog
         self.last_run: dict = {}
         self._reroute_counter = 0
         # durable resume: leading stream rows to drop because the log
@@ -414,11 +415,31 @@ class StreamingPSApp:
         with self.tracer.span("app.flush_logs"):
             if self.eval_engine is not None:
                 self.eval_engine.drain()
-            for sink in (self.server.log, *{id(w.log): w.log
-                                            for w in self.workers}.values()):
+            for sink in self._log_sinks():
                 flush = getattr(sink, "flush", None)
                 if flush is not None:
                     flush()
+
+    def _log_sinks(self) -> list:
+        """The server's sink and the workers' distinct ones."""
+        return [self.server.log, *{id(w.log): w.log
+                                   for w in self.workers}.values()]
+
+    def _log_backlog(self) -> tuple[int, float]:
+        """How often, and for how many seconds, a submitter has waited
+        on a sink's backlog so far (utils/asynclog), over the sinks."""
+        waits = [sink.backlog_waits() for sink in self._log_sinks()
+                 if hasattr(sink, "backlog_waits")]
+        return sum(n for n, _ in waits), sum(s for _, s in waits)
+
+    def _record_run(self, path: str, t_call: float, backlog0, **more) -> None:
+        """`last_run` of the drive call that began at `t_call` with the
+        sinks' backlog counts at `backlog0`."""
+        waits, wait_s = self._log_backlog()
+        self.last_run = {"path": path,
+                         "seconds": time.perf_counter() - t_call, **more,
+                         "log_backlog_waits": waits - backlog0[0],
+                         "log_backlog_wait_s": wait_s - backlog0[1]}
 
     def close_logs(self) -> None:
         """Close the deferred sinks: joins their drain threads (which
@@ -458,7 +479,7 @@ class StreamingPSApp:
         batched apply (runtime/server.process_batch).  `--no-gang` keeps
         the original strictly per-message alternation."""
         reporter = self._start_status(status_every)
-        t_call = time.perf_counter()
+        t_call, backlog0 = time.perf_counter(), self._log_backlog()
         stalled_rounds = 0
         gang = self._make_gang()
         try:
@@ -478,9 +499,7 @@ class StreamingPSApp:
         finally:
             reporter.stop()
             self.flush_logs()
-        self.last_run = {"path": "serial",
-                         "seconds": time.perf_counter() - t_call,
-                         **NO_SLAB_REFRESH}
+        self._record_run("serial", t_call, backlog0, **NO_SLAB_REFRESH)
 
     def _serial_round(self, gang, max_server_iterations: int) -> bool:
         """One turn of the serial scheduler: weights out, gradients in.
@@ -758,7 +777,7 @@ class StreamingPSApp:
         # the bottleneck.  num_tuples_seen strictly increases on every
         # insert, so it is the buffer content version.
         reporter = self._start_status(status_every)
-        t_call = time.perf_counter()
+        t_call, backlog0 = time.perf_counter(), self._log_backlog()
         try:
             refresh = self._run_fused_loop(
                 max_server_iterations, mesh, log_metrics, range_mode,
@@ -767,8 +786,7 @@ class StreamingPSApp:
             reporter.stop()
         # a folded task's counters, summed over the call's updates,
         # ride along under "counters" (models/task.py `counter_names`)
-        self.last_run = {"path": "fused",
-                         "seconds": time.perf_counter() - t_call, **refresh}
+        self._record_run("fused", t_call, backlog0, **refresh)
 
     # rounds per fused chunk dispatch: several rounds share one
     # dispatch, few enough that stream arrivals are picked up promptly

@@ -482,3 +482,266 @@ def test_deferred_sink_keeps_a_fetch_failure_and_reraises(monkeypatch):
         direct.flush()
     direct.close()
     assert lines == []
+
+
+# -- the deferred sink's backlog (utils/asynclog) -----------------------------
+
+class _Future:
+    """A device scalar's stand-in: ready when the test says so, and it
+    remembers the threads that fetched it."""
+
+    def __init__(self, value, ready=False, fails=False):
+        import threading
+        self.value, self.fails = value, fails
+        self.fetched_on: list[str] = []
+        self._ready = threading.Event()
+        if ready:
+            self._ready.set()
+
+    def resolve(self):
+        self._ready.set()
+
+    def is_ready(self):
+        return self._ready.is_set()
+
+    def block_until_ready(self):
+        assert self._ready.wait(30.0)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        import threading
+        self.fetched_on.append(threading.current_thread().name)
+        assert self._ready.wait(30.0)
+        if self.fails:
+            raise FloatingPointError("injected fetch failure")
+        return np.asarray(self.value, np.float32)
+
+
+def _recording_sink(max_pending, drain_interval=3600.0):
+    """(sink, [(thread name, line)]): with the default interval only a
+    backlog wakes the drain thread."""
+    import threading
+
+    from kafka_ps_tpu.utils import asynclog
+    written = []
+    sink = asynclog.DeferredSink(
+        lambda line: written.append(
+            (threading.current_thread().name, line)),
+        max_pending=max_pending, drain_interval=drain_interval)
+    return sink, written
+
+
+def _in_thread(fn, name="submitter"):
+    """Run fn on a named thread; .error holds what it raised."""
+    import threading
+
+    def run():
+        try:
+            fn()
+        except BaseException as e:       # noqa: BLE001 — handed to the test
+            t.error = e
+    t = threading.Thread(target=run, name=name, daemon=True)
+    t.error = None
+    t.start()
+    return t
+
+
+def _wait_until(cond, seconds=10.0):
+    import time
+    deadline = time.monotonic() + seconds
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert cond()
+
+
+def test_deferred_sink_backlog_waits_for_the_oldest_rows_only():
+    """Over the bound with the oldest rows ready and the newest not:
+    submit returns once the drain thread has taken the oldest, the
+    newest still unready, and the submitting thread neither fetched nor
+    wrote."""
+    sink, written = _recording_sink(max_pending=4)
+    old, new = _Future(1.5, ready=True), _Future(2.5)
+
+    def submits():
+        for i in range(3):
+            sink.submit(f"old{i};{{}}", old)
+        for i in range(2):                   # the fifth meets the bound
+            sink.submit(f"new{i};{{}}", new)
+    t = _in_thread(submits)
+    t.join(10.0)
+    assert not t.is_alive() and t.error is None
+    assert not new.is_ready() and new.fetched_on == []
+    assert sink.backlog_waits()[0] == 1 and sink.backlog_waits()[1] > 0
+    _wait_until(lambda: len(written) == 3)
+    assert written == [("kps-log-drain", f"old{i};1.5") for i in range(3)]
+    assert old.fetched_on == ["kps-log-drain"]     # once for three rows
+    new.resolve()
+    sink.flush()
+    assert [line for _, line in written[3:]] == ["new0;2.5", "new1;2.5"]
+    sink.close()
+
+
+def test_deferred_sink_backlog_waits_until_the_oldest_resolve():
+    """With nothing ready the submitter waits; it returns when the
+    oldest rows' value resolves, later rows still unready."""
+    sink, written = _recording_sink(max_pending=4)
+    first, later = _Future(0.25), _Future(0.75)
+
+    def submits():
+        for i in range(3):
+            sink.submit(f"a{i};{{}}", first)
+        for i in range(2):
+            sink.submit(f"b{i};{{}}", later)
+    t = _in_thread(submits)
+    t.join(0.3)
+    assert t.is_alive() and written == [] and first.fetched_on == []
+    first.resolve()
+    t.join(10.0)
+    assert not t.is_alive() and t.error is None
+    assert not later.is_ready() and "submitter" not in first.fetched_on
+    _wait_until(lambda: len(written) == 3)
+    later.resolve()
+    sink.close()
+    assert [line for _, line in written] == [
+        "a0;0.25", "a1;0.25", "a2;0.25", "b0;0.75", "b1;0.75"]
+    assert {name for name, _ in written[:3]} == {"kps-log-drain"}
+
+
+@pytest.mark.parametrize("path", ["copy", "stack"])
+def test_deferred_sink_fetches_each_distinct_value_once(monkeypatch, path):
+    """N rows that share a value cost one fetched value, and the copies
+    and the stacked transfer write the same lines."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from kafka_ps_tpu.utils import asynclog
+
+    spans = []
+    monkeypatch.setattr(
+        asynclog.trace, "span",
+        lambda name, **args: (spans.append((name, args)),
+                              contextlib.nullcontext())[1])
+    assert asynclog._fetch_path(asynclog._MAX_COPY) == "copy"
+    assert asynclog._fetch_path(asynclog._MAX_COPY + 1) == "stack"
+    monkeypatch.setattr(asynclog, "_MAX_COPY",
+                        10 ** 6 if path == "copy" else 0)
+    numbers = [0.1, 1.0 / 3.0, 1e-7, 12345.678, -1.0]
+    shared = jnp.float32(numbers[0])
+    values = [shared] * 40 + [jnp.float32(v) for v in numbers[1:]]
+    lines = []
+    sink = asynclog.DeferredSink(lines.append, drain_interval=3600.0)
+    for i, v in enumerate(values):
+        sink.submit(f"{i};{{}};{{}}", v, 7)
+    sink.flush()
+    sink.close()
+    assert lines == [f"{i};{float(np.float32(v))};7.0"
+                     for i, v in enumerate([numbers[0]] * 40 + numbers[1:])]
+    fetches = [args for name, args in spans if name == "log.fetch"]
+    assert fetches == [{"scalars": 44, "distinct": 5, "path": path}]
+
+    # one value, many rows: one fetch of one value, on the copy path
+    monkeypatch.undo()
+    one = _Future(3.0, ready=True)
+    sink, written = _recording_sink(max_pending=4096)
+    for i in range(100):
+        sink.submit("{};{}", one, one)
+    sink.flush()
+    sink.close()
+    assert len(written) == 100 and len(one.fetched_on) == 1
+
+
+def test_deferred_sink_three_producers_and_a_small_bound():
+    """Every row written once, each producer's rows in its order, all of
+    them by the time flush() returns."""
+    sink, written = _recording_sink(max_pending=4, drain_interval=0.01)
+    rows = 60
+
+    def producer(k):
+        def run():
+            for i in range(rows):
+                value = _Future(float(i), ready=True) if i % 3 else float(i)
+                sink.submit(f"p{k};{i};{{}}", value)
+        return run
+    threads = [_in_thread(producer(k), name=f"producer-{k}")
+               for k in range(3)]
+    for t in threads:
+        t.join(30.0)
+        assert not t.is_alive() and t.error is None
+    sink.flush()
+    lines = [line for _, line in written]
+    assert len(lines) == 3 * rows == len(set(lines))
+    for k in range(3):
+        assert [ln for ln in lines if ln.startswith(f"p{k};")] == [
+            f"p{k};{i};{float(i)}" for i in range(rows)]
+    assert not any(name.startswith("producer") for name, _ in written)
+    assert sink.backlog_waits()[0] > 0
+    sink.close()
+
+
+def test_deferred_sink_fetch_failure_releases_a_waiting_submitter():
+    """The drain thread's failure ends a backlog wait with the error;
+    flush, close and the next submit raise it too; nothing is written."""
+    sink, written = _recording_sink(max_pending=4)
+    bad, never = _Future(1.0, ready=True, fails=True), _Future(2.0)
+
+    def submits():
+        sink.submit("bad;{}", bad)
+        for i in range(5):       # over the bound even without `bad`
+            sink.submit(f"r{i};{{}}", never)
+    t = _in_thread(submits)
+    t.join(10.0)
+    assert not t.is_alive()
+    assert isinstance(t.error, RuntimeError)
+    assert isinstance(t.error.__cause__, FloatingPointError)
+    assert not never.is_ready()
+    for entry in (lambda: sink.submit("row;{}", 2.0), sink.flush,
+                  sink.close):
+        with pytest.raises(RuntimeError, match="log drain failed"):
+            entry()
+    assert written == []
+
+
+def _fused_rows(max_pending):
+    """A fused run of 3 chunks at eval_every 8 with the sinks' bound at
+    `max_pending`: (server rows, worker rows) without their timestamps,
+    and the app's last_run."""
+    import dataclasses
+
+    from tests.test_runtime import fill_buffers, make_dataset
+    cfg = dataclasses.replace(small_cfg(0, num_workers=4), eval_every=8)
+    x, y = make_dataset()
+    logs = {"server": [], "worker": []}
+    app = StreamingPSApp(cfg, test_x=x, test_y=y,
+                         server_log=logs["server"].append,
+                         worker_log=logs["worker"].append)
+    for sink in app._log_sinks():
+        sink._max_pending = max_pending
+    fill_buffers(app, x, y)
+    app.run_fused_bsp(max_server_iterations=24 * cfg.num_workers)
+    app.close_logs()
+    strip = lambda rows: [ln.split(";", 1)[1] for ln in rows]  # noqa: E731
+    return strip(logs["server"]), strip(logs["worker"]), app.last_run
+
+
+@pytest.fixture(scope="module")
+def fused_rows_unbounded():
+    return _fused_rows(10 ** 9)
+
+
+@pytest.mark.parametrize("max_pending", [8, 4096])
+def test_fused_run_rows_do_not_depend_on_the_sinks_bound(
+        fused_rows_unbounded, max_pending):
+    """The backlog wait changes when a row is written, never what it
+    says or where it stands; the counter says whether it engaged."""
+    server, worker, last = _fused_rows(max_pending)
+    want_server, want_worker, unbounded = fused_rows_unbounded
+    assert server == want_server and len(server) == 3
+    assert worker == want_worker and len(worker) == 24 * 4
+    assert unbounded["log_backlog_waits"] == 0
+    assert unbounded["log_backlog_wait_s"] == 0.0
+    if max_pending == 8:
+        assert last["log_backlog_waits"] > 0
+        assert last["log_backlog_wait_s"] > 0.0
+    else:
+        assert last["log_backlog_waits"] == 0
