@@ -9,12 +9,9 @@ the models the repo supports, on data made from a seed:
   * the per-node message path (producer → buffers → k-step solver →
     consistency gate → apply → async eval), logreg F=1024 C=5, 4
     workers, default flags, once per consistency model (-c 0, 2, -1);
-  * the same path with `--pallas` (compiled Mosaic kernels, no
-    fallback);
   * fused BSP at the widest model, `--task mlp --hidden_dim 4096`
     (≈4.2 M parameters), at `--eval_every 1` (per-round program) and
     `--eval_every 8` (the 8-round scan chunk);
-  * every Pallas kernel variant, compiled, against the XLA solver;
   * with more than one chip: `--fused -r` and `--fused --param_shards`
     over all of them.
 
@@ -53,17 +50,14 @@ class Sizes:
 
     num_features: int = 1024
     fused_hidden: int = 4096      # widest supported model (fused BSP)
-    kernel_hidden: int = 128      # resident MLP kernel's width
     buffer_min: int = 128
     buffer_max: int = 1024        # slab rows per worker
     train_rows: int = 4096        # 4 workers x 1024 rows
     test_rows: int = 2000
     per_node_clocks: int = 32
-    pallas_clocks: int = 8
     fused_rounds: int = 24        # 3 scan chunks at --eval_every 8
     multichip_rounds: int = 8
     center_scale: float = 0.2     # synth.HARD_CENTER_SCALE: class overlap
-    interpret: bool = False       # kernels phase: Pallas interpreter
 
 
 class SmokeFailure(RuntimeError):
@@ -194,7 +188,7 @@ def check_run(phase: str, phase_dir: str, app, times: dict, *,
 
     first_row = float(worker["timestamp"].iloc[0]) / 1e3
     return {
-        "solver": app.solver_program,
+        "solver": app.solver,
         "wall_s": round(times["ended"] - times["started"], 2),
         # test CSV load + app construction
         "setup_s": round(times["built_at"] - times["started"], 2),
@@ -224,22 +218,16 @@ def _common_flags(train: str, test: str, sizes: Sizes) -> list[str]:
 
 
 def phase_per_node(workdir: str, train: str, test: str, sizes: Sizes,
-                   platform: str, consistency: int,
-                   pallas: bool = False) -> dict:
+                   platform: str, consistency: int) -> dict:
     """The per-node message path — the one that carries every
     subsystem — with default flags: threaded, gang on, async eval on,
     k=2, eval every clock."""
     workers = 4
-    clocks = sizes.pallas_clocks if pallas else sizes.per_node_clocks
-    name = f"per_node_c{consistency}" + ("_pallas" if pallas else "")
+    clocks = sizes.per_node_clocks
+    name = f"per_node_c{consistency}"
     argv = _common_flags(train, test, sizes) + [
         "-c", str(consistency), "--num_workers", str(workers),
         "--max_iterations", str(workers * clocks)]
-    if pallas:
-        argv.append("--pallas")
-
-    from kafka_ps_tpu.ops import fused_update
-    traced = dict(fused_update.TRACE_COUNTS)
     phase_dir = os.path.join(workdir, name)
     app, times = run_cli(name, phase_dir, argv)
 
@@ -254,16 +242,6 @@ def phase_per_node(workdir: str, train: str, test: str, sizes: Sizes,
         require(_platform_of(w.theta) == platform, name,
                 f"worker {w.worker_id} replica lives on "
                 f"{_platform_of(w.theta)}")
-    kernels = {k: fused_update.TRACE_COUNTS[k] - traced[k] for k in traced}
-    if pallas:
-        require(rec["solver"].startswith("pallas-"), name,
-                f"--pallas ran solver {rec['solver']!r}")
-        require(kernels["resident"] + kernels["batched"] > 0, name,
-                f"--pallas traced no kernel program: {kernels}")
-        rec["kernels_traced"] = kernels
-    else:
-        require(not any(kernels.values()), name,
-                f"XLA phase traced kernel programs: {kernels}")
     return rec
 
 
@@ -291,106 +269,6 @@ def phase_fused(workdir: str, train: str, test: str, sizes: Sizes,
                       else "bsp-step")
     rec["params"] = int(app.server.task.num_params)
     return rec
-
-
-def phase_kernels(sizes: Sizes, platform: str) -> dict:
-    """Every Pallas variant at a shape its own selector admits,
-    compiled (interpret only where `sizes` asks by name), against the
-    XLA solver on the same inputs.  Compared by tolerance: both sides
-    run default-precision MXU passes in different orders."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from kafka_ps_tpu.compress.slab import decode_x, encode_x
-    from kafka_ps_tpu.data import synth
-    from kafka_ps_tpu.models.task import get_task
-    from kafka_ps_tpu.ops import fused_update as fu
-    from kafka_ps_tpu.utils.config import ModelConfig
-
-    f, b, members = sizes.num_features, sizes.buffer_max, 4
-    cfg = ModelConfig(num_features=f, num_classes=NUM_CLASSES,
-                      hidden_dim=sizes.kernel_hidden)
-    tasks = {"logreg": get_task("logreg", cfg), "mlp": get_task("mlp", cfg)}
-    single = {"logreg": fu.local_update, "mlp": fu.mlp_local_update}
-    batched = {"logreg": fu.local_update_batched,
-               "mlp": fu.mlp_local_update_batched}
-    rng = np.random.default_rng(1)
-
-    def theta_for(task_name):
-        if task_name == "mlp":
-            return tasks["mlp"].init_params()
-        return jnp.asarray(rng.normal(scale=0.05, size=cfg.num_params),
-                           jnp.float32)
-
-    def slab(rows, seed):
-        x, y = synth.generate_hard(rows, f, NUM_CLASSES, seed=seed)
-        mask = (np.arange(rows) < rows - 7).astype(np.float32)
-        return jnp.asarray(x), jnp.asarray(y), jnp.asarray(mask)
-
-    # every variant must be traced here for the trace counters to name
-    # its program: drop what the --pallas phase already built at these
-    # shapes (the persistent cache still serves the compiles)
-    jax.clear_caches()
-
-    # the smallest doubling of the slab the resident selector refuses
-    big = b
-    while fu.select_program("logreg", cfg, big, "f32")[0] == "resident":
-        big *= 2
-
-    out = {}
-
-    def check(name, want_program, run, reference):
-        traced = dict(fu.TRACE_COUNTS)
-        t0 = time.perf_counter()
-        delta, loss = jax.block_until_ready(run())
-        first_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        jax.block_until_ready(run())
-        again_s = time.perf_counter() - t0
-        built = [k for k in traced if fu.TRACE_COUNTS[k] > traced[k]]
-        require(built == [want_program], name,
-                f"traced {built}, selector should pick {want_program}")
-        d_ref, l_ref = jax.block_until_ready(reference())
-        require(_platform_of(delta) == platform, name,
-                f"kernel output lives on {_platform_of(delta)}")
-        delta, d_ref = np.asarray(delta), np.asarray(d_ref)
-        require(bool(np.isfinite(delta).all()), name, "delta not finite")
-        err = float(np.max(np.abs(delta - d_ref)))
-        scale = float(np.max(np.abs(d_ref)))
-        require(err <= 0.03 * scale, name,
-                f"delta off the XLA solver: max|err| {err:.3g} vs "
-                f"max|ref| {scale:.3g}")
-        loss, l_ref = np.asarray(loss), np.asarray(l_ref)
-        require(bool(np.allclose(loss, l_ref, rtol=5e-3)), name,
-                f"loss {loss} vs XLA {l_ref}")
-        out[name] = {"program": want_program,
-                     "first_call_s": round(first_s, 3),
-                     "second_call_ms": round(again_s * 1e3, 3),
-                     "max_abs_err": err, "ref_max_abs": scale}
-
-    for task_name, task in tasks.items():
-        theta = theta_for(task_name)
-        xla = jax.jit(task.local_update)
-        for kind, rows, want in (("f32", b, "resident"),
-                                 ("f32", big, "streaming"),
-                                 ("bf16", b, "streaming"),
-                                 ("int8", b, "streaming")):
-            x, y, mask = slab(rows, seed=rows % 97)
-            stored = encode_x(kind, x)
-            check(f"{task_name}_{want}_{kind}_B{rows}", want,
-                  lambda: single[task_name](theta, stored, y, mask, cfg=cfg,
-                                            interpret=sizes.interpret),
-                  lambda: xla(theta, decode_x(stored), y, mask))
-        parts = [slab(b, seed=10 + i) for i in range(members)]
-        xs, ys, ms = (jnp.stack([p[i] for p in parts]) for i in range(3))
-        thetas = jnp.stack([theta * (1 + 0.1 * i) for i in range(members)])
-        xla_b = jax.jit(jax.vmap(task.local_update))
-        check(f"{task_name}_batched_k{members}_B{b}", "batched",
-              lambda: batched[task_name](thetas, xs, ys, ms, cfg=cfg,
-                                         interpret=sizes.interpret),
-              lambda: xla_b(thetas, xs, ys, ms))
-    return out
 
 
 def phase_multichip(workdir: str, train: str, test: str, sizes: Sizes,
@@ -461,12 +339,9 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
     for c in (0, 2, -1):
         phases[f"per_node_c{c}"] = phase_per_node(
             workdir, train, test, sizes, platform, c)
-    phases["per_node_c0_pallas"] = phase_per_node(
-        workdir, train, test, sizes, platform, 0, pallas=True)
     for eval_every in (1, 8):
         phases[f"fused_mlp{sizes.fused_hidden}_eval{eval_every}"] = \
             phase_fused(workdir, train, test, sizes, platform, eval_every)
-    phases["kernels"] = phase_kernels(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,
                                       platform, device_count))

@@ -16,7 +16,6 @@ async dispatch with per-device `device_put` for the stale paths.
 
 Package layout:
   models/    LR model family, metrics (the reference's ml/ package)
-  ops/       XLA/Pallas compute kernels (k-step local SGD)
   parallel/  mesh, collectives, consistency gating, vector-clock tracker
   data/      paced stream producer + dynamic sliding buffers (producer/)
   runtime/   server/worker processors, in-process fabric, apps (processors/, apps/)

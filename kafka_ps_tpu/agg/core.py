@@ -263,8 +263,8 @@ class LocalAggregator:
     # -- crash/restart seam ------------------------------------------------
 
     def reset(self) -> None:
-        """Drop all pending state (the SIGKILL simulation seam used by
-        bench aggregation_ab): a real restart loses pending deltas AND
+        """Drop all pending state (the SIGKILL simulation seam of
+        tests/test_agg.py): a real restart loses pending deltas AND
         EF residuals; workers re-send from their redelivery caches and
         the server gate deduplicates what had already been forwarded."""
         with self._lock:

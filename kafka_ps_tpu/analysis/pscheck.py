@@ -48,7 +48,7 @@ time; these rules catch the regressions at commit time instead:
          ``np.array``, ``.block_until_ready()``) inside the ARGUMENTS
          of a telemetry/trace call (``span``, ``count``, ``observe``,
          ``inc``, ``flow_*``) or a flight-recorder call (``record``,
-         telemetry/flight.py) in ``runtime/``, ``ops/``, ``serving/``
+         telemetry/flight.py) in ``runtime/``, ``serving/``
          or the derived observability modules
          (``telemetry/critpath.py``, ``profiler.py``, ``slo.py``,
          ``modelhealth.py``, ``drift.py``) —
@@ -94,7 +94,7 @@ RULES: dict[str, str] = {
              "derived observability modules in telemetry/)",
     "PS105": "blocking I/O while holding a lock",
     "PS106": "host-sync call inside the arguments of a telemetry/trace "
-             "or flight-recorder call in runtime/, ops/, serving/, "
+             "or flight-recorder call in runtime/, serving/, "
              "agg/ or the derived observability modules in telemetry/",
 }
 
@@ -599,10 +599,7 @@ def _rules_for(path: Path) -> set:
     parts = set(path.parts)
     rules = {"PS100", "PS101", "PS105"}
     if "runtime" in parts or "serving" in parts or "agg" in parts:
-        rules.add("PS102")
-    if ("runtime" in parts or "ops" in parts or "serving" in parts
-            or "agg" in parts):
-        rules.add("PS106")
+        rules.update(("PS102", "PS106"))
     if path.name in ("serde.py", "net.py"):
         rules.add("PS103")
     if ("log" in parts or "compress" in parts or "store" in parts

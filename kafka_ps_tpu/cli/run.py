@@ -93,8 +93,8 @@ def build_parser(include_server_flags: bool = True,
     p.add_argument("--no-eval-async", dest="eval_async",
                    action="store_false",
                    help="fuse evaluation back into the apply dispatch "
-                        "(the pre-engine behaviour; the A/B lever "
-                        "bench.py eval_ab measures)")
+                        "(the pre-engine behaviour; the bitwise "
+                        "reference of tests/test_eval_engine.py)")
     p.add_argument("--max_iterations", type=int, default=0,
                    help="stop after this many server iterations "
                         "(0 = run until Ctrl-C, like the reference)")
@@ -156,9 +156,9 @@ def build_parser(include_server_flags: bool = True,
                         "sampler, docs/OBSERVABILITY.md): collapsed-"
                         "stack text on /profilez (--health-port) and "
                         "the hottest stacks in every flight dump, so a "
-                        "watchdog trip ships its own profile; <2%% "
-                        "overhead asserted by the profiling_overhead "
-                        "bench block")
+                        "watchdog trip ships its own profile; under "
+                        "2%% of a CPU-host run at PR 14, not measured "
+                        "on the chip")
     p.add_argument("--slo-serving-p99-ms", dest="slo_serving_p99_ms",
                    type=float, default=None, metavar="MS",
                    help="arm the SLO plane (telemetry/slo.py) with a "
@@ -185,8 +185,8 @@ def build_parser(include_server_flags: bool = True,
                         "eval metrics and sampled arrivals (telemetry/"
                         "drift.py).  Surfaces on /modelz, the [status] "
                         "heartbeat, and a latched DRIFT ships one "
-                        "flight dump; <2%% overhead asserted by the "
-                        "modelhealth_overhead bench block")
+                        "flight dump; under 2%% of a CPU-host run at "
+                        "PR 15, not measured on the chip")
     p.add_argument("--drift-detector", dest="drift_detector",
                    choices=["ph", "adwin"], default="ph",
                    help="drift detector for --model-health: ph (Page-"
@@ -201,13 +201,6 @@ def build_parser(include_server_flags: bool = True,
     p.add_argument("--device_trace", default=None, metavar="LOGDIR",
                    help="capture a jax.profiler device trace (TensorBoard "
                         "logdir) for the whole run")
-    p.add_argument("--pallas", action="store_true",
-                   help="use the Pallas fused local-update kernel for "
-                        "worker iterations — logreg and mlp families "
-                        "(ops/fused_update.py).  TPU only, and no "
-                        "fallback: a backend or shape no kernel admits "
-                        "stops the run with the reason; the start-up "
-                        "line and [status] name the program in use")
     p.add_argument("--compress", default="none", metavar="CODEC",
                    help="compressed delta transport "
                         "(kafka_ps_tpu/compress/, docs/COMPRESSION.md): "
@@ -233,8 +226,8 @@ def build_parser(include_server_flags: bool = True,
                    help="disable incremental device-slab updates: "
                         "re-upload the whole slab whenever the buffer "
                         "changes instead of scattering only dirty rows "
-                        "(the pre-PERFORMANCE.md behavior; the A/B lever "
-                        "behind the slab_ab bench block)")
+                        "(the pre-PERFORMANCE.md behavior; the bitwise "
+                        "reference of tests/test_slab.py)")
     p.add_argument("--tier-hot-bytes", dest="tier_hot_bytes", type=int,
                    default=0, metavar="BYTES",
                    help="tiered parameter residency (kafka_ps_tpu/store/, "
@@ -375,8 +368,6 @@ _PAGES_A_CLASSIFIER = ("tiered residency pages a flat classifier theta "
 _NO_MESH = ("its workers are folded one at a time on one device; it has "
             "no program over a mesh (parallel/bsp.py)")
 TASK_REFUSES = {"glm4_moe_lite": {
-    "pallas": (False, "the Pallas kernels implement the logreg and mlp "
-                      "local updates (ops/fused_update.py)"),
     "compress": ("none", "the wire codecs were sized for deltas of "
                          "megabytes, and this family's delta does not "
                          "cross serde in one message"),
@@ -426,7 +417,6 @@ def cfg_from_args(args):
                             max_size=args.max_buffer_size,
                             coefficient=args.buffer_size_coefficient),
         stream=StreamConfig(time_per_event_ms=args.producer_time_per_event),
-        use_pallas=args.pallas,
         eval_every=getattr(args, "eval_every", 1),
         eval_async=getattr(args, "eval_async", True),
         use_gang=not getattr(args, "no_gang", False),
@@ -532,36 +522,21 @@ def announce_device(cfg, fused: bool = False) -> None:
     """The ONE start-up line every entry point prints to stderr: where
     the process runs (platform, device_kind, count — first backend use
     happens here), the stack versions, the compile cache, and which
-    solver program `cfg` selects.  A `--pallas` request no kernel can
-    serve stops here, with the shape and the reason."""
-    from kafka_ps_tpu.ops.fused_update import PallasUnavailable
-    from kafka_ps_tpu.runtime.worker import solver_program
+    solver programs the run dispatches: the per-node path's ("xla") or
+    the fused BSP step's ("fused-bsp").  `cfg` is what every caller
+    holds here (the roles, benchmark/run.py); nothing of it is printed."""
     from kafka_ps_tpu.utils import device
-    try:
-        solver = "fused-bsp" if fused else solver_program(cfg)
-    except PallasUnavailable as e:
-        print(device.startup_line(solver="refused"), file=sys.stderr,
-              flush=True)
-        raise SystemExit(f"--pallas: {e}") from None
-    print(device.startup_line(solver=solver), file=sys.stderr, flush=True)
+    print(device.startup_line(solver="fused-bsp" if fused else "xla"),
+          file=sys.stderr, flush=True)
 
 
 def run_with_args(args) -> int:
     apply_platform_env()
     if getattr(args, "eval_every", 1) < 1:
         raise SystemExit("--eval_every must be >= 1")
-    if args.fused and args.pallas:
-        raise SystemExit(
-            "--pallas applies to the per-node worker path only; the "
-            "--fused BSP path runs its own shard_map program "
-            "(parallel/bsp.py) — drop one of the two flags")
     if getattr(args, "param_shards", 1) > 1 and not args.fused:
         raise SystemExit("--param_shards requires --fused (the "
                          "range-sharded server is a fused-mesh mode)")
-    if args.pallas and args.task not in ("logreg", "mlp"):
-        raise SystemExit(
-            "--pallas implements the logreg and mlp local updates "
-            f"(ops/fused_update.py); got --task {args.task}")
     if getattr(args, "serve_port", None) is not None \
             and not getattr(args, "serve", False):
         raise SystemExit("--serve_port requires --serve")

@@ -62,7 +62,6 @@ def _make_cfg(args):
             args, "producer_time_per_event", 200)),
         eval_every=getattr(args, "eval_every", 1),
         eval_async=getattr(args, "eval_async", True),
-        use_pallas=getattr(args, "pallas", False),
         # the wire protocol has no gang-notice frame (runtime/serde.py),
         # and a notice crossing a socket could not promise anything
         # about remote queue contents anyway — split mode stays

@@ -15,16 +15,15 @@ two tools in this module:
 * **HBM->VMEM bytes**: the solver re-reads the slab from HBM every
   step.  `--slab-dtype bf16|int8` stores the device slab reduced
   (encode fused into the scatter/upload program), and decode is fused
-  into the training step (models/logreg.py, models/mlp.py,
-  ops/fused_update.py call `decode_x`), halving or quartering the
-  bytes every matmul streams.
+  into the training step (models/task.py `fit_slab` calls `decode_x`),
+  halving or quartering the bytes every matmul streams.
 
 This is the device-side refactor of the wire codec's quantizers
 (compress/codecs.py): `quantize_rows`/`dequantize_rows` are the shared
 int8 primitive — the wire codec applies them to the flat vector
 reshaped to [nchunks, 256] chunks, the slab codec to [cap, F] with the
-slab ROW as the chunk (a per-row scale broadcasts over lanes inside
-the Pallas streaming kernel, where a mid-row chunk boundary would not).
+slab ROW as the chunk (a per-row scale broadcasts over a row's lanes,
+where a mid-row chunk boundary would not).
 
 Numerics contract: `--slab-dtype f32` is bitwise-identical to the
 pre-slab-store behavior — encode/decode are identity (an f32->f32
@@ -96,7 +95,7 @@ def slab_batch_shape(x) -> tuple[int, int]:
 
 def decode_x(x) -> jax.Array:
     """Stored slab -> f32, fused into whatever program traces it
-    (models/*.local_update, the Pallas fallbacks).  Identity for f32
+    (models/task.py `fit_slab`).  Identity for f32
     input — the astype leaves the traced jaxpr unchanged, which is the
     f32 bitwise contract."""
     TRACE_COUNTS["decode"] += 1
@@ -150,9 +149,9 @@ class SlabStore:
     `upload_full` replaces the whole slab (bootstrap, restore,
     mass-delete fallback); `apply_rows` scatters a drained dirty set
     (SlidingBuffer.drain_dirty) into it.  `bytes_uploaded` counts the
-    HOST bytes each path shipped — the quantity the slab_ab bench block
-    audits (bench.py) — so the ~cap/changed-rows upload reduction is a
-    measured number, not an estimate."""
+    HOST bytes each path shipped (tests/test_slab.py holds the counts),
+    so the ~cap/changed-rows upload reduction is a measured number, not
+    an estimate."""
 
     def __init__(self, dtype: str, capacity: int, num_features: int,
                  telemetry=None, row_dtype=np.float32):
@@ -264,8 +263,8 @@ class ParamPageSlab:
     (kafka_ps_tpu/store/, docs/TIERING.md): page index -> f32 device
     array, with the same measured-bytes discipline as SlabStore —
     `bytes_uploaded` counts actual host->device traffic and
-    `device_bytes()` the resident HBM footprint, so the tiering_ab
-    bench audits counters, not estimates.
+    `device_bytes()` the resident HBM footprint: counters, not
+    estimates.
 
     This is SlabStore's parameter-side sibling: per-PAGE residency of
     the server's theta slice instead of the worker's full training

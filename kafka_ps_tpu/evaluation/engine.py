@@ -29,8 +29,8 @@ serving/engine.py) applied to evaluation:
 
 Coalescing widths bucket to powers of two (pad by REPEATING the last
 theta and discard the extra rows — vmap rows are independent, so
-padding never changes the kept rows) and are capped by the
-fused-update tile budget (`coalesce_width_cap`): chunking happens over
+padding never changes the kept rows) and are capped by a byte budget
+(`coalesce_width_cap`): chunking happens over
 pending thetas, NEVER over the test set — splitting X_test would
 change what the loss mean averages over, not just its rounding.
 
@@ -68,31 +68,19 @@ _MAX_COALESCE = 32
 # coalesce-width histogram buckets (powers of two up to the ceiling)
 WIDTH_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0)
 
-_FALLBACK_VMEM_BUDGET = 12 * 1024 * 1024
-
-
-def _vmem_budget() -> int:
-    """The fused-update tile budget (ops/fused_update.py) — guarded so
-    an environment without the pallas toolchain still gets the same
-    constant."""
-    try:
-        from kafka_ps_tpu.ops.fused_update import _VMEM_BYTE_BUDGET
-        return int(_VMEM_BYTE_BUDGET)
-    except Exception:                      # pragma: no cover - no pallas
-        return _FALLBACK_VMEM_BUDGET
+# bytes one batched eval dispatch's stacked working set may take
+_COALESCE_BYTE_BUDGET = 12 * 1024 * 1024
 
 
 def coalesce_width_cap(num_params: int, n_test: int,
-                       budget: int | None = None) -> int:
+                       budget: int = _COALESCE_BYTE_BUDGET) -> int:
     """Widest power-of-two batch such that the stacked working set
     (k thetas + k per-example score rows against the resident test set)
-    stays inside the fused-update tile budget.  The estimate charges
+    stays inside `budget` bytes.  The estimate charges
     one f32 per test row per lane — the score/prediction row the
     confusion-matrix build materializes (models/metrics.py) — plus the
     lane's theta; deliberately coarse, it only has to keep `n_test x k`
-    from outgrowing the tile budget, not model VMEM exactly."""
-    if budget is None:
-        budget = _vmem_budget()
+    from outgrowing the budget, not model fast memory exactly."""
     lane_bytes = 4 * (int(num_params) + int(n_test))
     cap = max(1, int(budget) // max(lane_bytes, 1))
     width = 1

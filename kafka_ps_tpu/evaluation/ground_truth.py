@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kafka_ps_tpu.models import logreg
-from kafka_ps_tpu.models import metrics as metrics_mod
+from kafka_ps_tpu.models.task import default_task
 from kafka_ps_tpu.utils.config import ModelConfig
 
 
@@ -86,8 +86,8 @@ def compute(train_x: np.ndarray, train_y: np.ndarray,
     cfg = cfg or ModelConfig()
     theta = train_offline(train_x, train_y, cfg, steps=steps,
                           learning_rate=learning_rate)
-    m = metrics_mod.evaluate(jnp.asarray(theta), jnp.asarray(test_x),
-                             jnp.asarray(test_y), cfg=cfg)
+    m = default_task(cfg).evaluate(jnp.asarray(theta), jnp.asarray(test_x),
+                                   jnp.asarray(test_y))
     return GroundTruth(
         theta=theta,
         f1=float(m.f1),

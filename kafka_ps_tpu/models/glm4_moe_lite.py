@@ -512,7 +512,7 @@ def evaluate_leaves(leaves: dict, test_rows, c: Glm4Config):
 
 # -- the task ----------------------------------------------------------------------
 
-class Glm4MoeLiteTask:
+class Glm4MoeLiteTask(task_mod.FlatFace):
     """MLTask (models/task.py) over `ModelConfig.model_json`."""
 
     batches_workers = False      # a worker's own products fill the MXU
@@ -563,38 +563,12 @@ class Glm4MoeLiteTask:
     def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
         return evaluate_leaves(leaves, x_test, self.arch)
 
-    def local_update(self, theta, x, y, mask):
-        return _local_update(theta, x, mask, cfg=self.cfg)
-
-    local_update_onehot = local_update     # a token row has no label
-
-    def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
-        return _evaluate(theta, x_test, cfg=self.cfg)
-
-    def evaluate_batch(self, thetas, x_test, y_test) -> metrics_mod.Metrics:
-        # one theta at a time: the update does not batch, nor does this
-        return jax.lax.map(lambda t: self.evaluate(t, x_test, y_test),
-                           thetas)
-
-    def predict_logits(self, theta, x):
+    def logits(self, leaves, x):
         """`[B, S + 2]` rows → `[B, vocab_held]` scores of the token
-        after position S - 1 (the serving plane's forward pass)."""
-        out = forward(self.unflatten(theta), x, self.arch, with_logits=True)
-        return out["logits"][:, -1]
+        after position S - 1."""
+        return forward(leaves, x, self.arch, with_logits=True)["logits"][:, -1]
 
 
 @functools.partial(jax.jit, static_argnames=("c",))
 def _flatten(leaves: dict, *, c: Glm4Config):
     return flatten(leaves, c)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _local_update(theta, x, mask, *, cfg: ModelConfig):
-    task = Glm4MoeLiteTask(cfg)
-    return task_mod.flat_local_update(task, theta, x, None, mask)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _evaluate(theta, x_test, *, cfg: ModelConfig):
-    task = Glm4MoeLiteTask(cfg)
-    return evaluate_leaves(task.unflatten(theta), x_test, task.arch)

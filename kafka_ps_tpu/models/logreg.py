@@ -17,14 +17,12 @@ Spark model sized 0..maxLabel.  The flat view is the PS key-value contract
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kafka_ps_tpu.compress.slab import decode_x
 from kafka_ps_tpu.utils.config import ModelConfig
 
 
@@ -137,31 +135,6 @@ def fit(params: LogRegParams, x: jax.Array, onehot: jax.Array,
     with jax.named_scope("kps.fit.loss"):
         _, final_loss = grad_loss_onehot(new, x, onehot, mask)
     return new, final_loss
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def local_update(theta: jax.Array, x: jax.Array, y: jax.Array, mask: jax.Array,
-                 *, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
-    """`fit` from a flat theta → (flat delta, loss at the updated
-    parameters).
-
-    `x` may arrive in any device-slab storage form (f32/bf16 array or
-    QuantizedSlab) — decode fuses into this program, and for f32 it is
-    the identity, leaving the jaxpr bitwise-unchanged.
-    """
-    x = decode_x(x)
-    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
-    return local_update_onehot(theta, x, onehot, mask, cfg=cfg)
-
-
-def local_update_onehot(theta: jax.Array, x: jax.Array, onehot: jax.Array,
-                        mask: jax.Array, *, cfg: ModelConfig
-                        ) -> tuple[jax.Array, jax.Array]:
-    """local_update with the one-hot precomputed by the caller."""
-    params = unflatten(theta, cfg)
-    new, loss = fit(params, x, onehot, mask, cfg=cfg)
-    with jax.named_scope("kps.fit.delta"):
-        return jax.tree.map(jnp.subtract, new, params).flat, loss
 
 
 def sparse_to_dense(rows: list[dict[int, float]], num_features: int) -> np.ndarray:

@@ -11,13 +11,12 @@ whole evaluation is a single fused XLA program on device.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from kafka_ps_tpu.models.logreg import logits, loss_fn, unflatten
+from kafka_ps_tpu.models.logreg import logits, loss_fn
 from kafka_ps_tpu.utils.config import ModelConfig
 
 
@@ -72,10 +71,3 @@ def evaluate_leaves(params, x_test: jax.Array, y_test: jax.Array,
         loss = loss_fn(params, x_test, y_test, jnp.ones(x_test.shape[0]))
         f1, acc = weighted_f1_accuracy(preds, y_test, cfg.num_rows)
         return Metrics(f1=f1, accuracy=acc, loss=loss)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def evaluate(theta: jax.Array, x_test: jax.Array, y_test: jax.Array,
-             *, cfg: ModelConfig) -> Metrics:
-    """`evaluate_leaves` of a flat theta."""
-    return evaluate_leaves(unflatten(theta, cfg), x_test, y_test, cfg=cfg)

@@ -27,13 +27,11 @@ hazard logreg.grad_loss_onehot documents).
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from kafka_ps_tpu.compress.slab import decode_x
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.models import task as task_mod
 from kafka_ps_tpu.utils.config import ModelConfig
@@ -118,27 +116,9 @@ class MLPTask(task_mod.RowsWithClassLabel):
     def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
         return evaluate_leaves(leaves, x_test, y_test, cfg=self.cfg)
 
-    def local_update_onehot(self, theta, x, onehot, mask):
-        return _local_update_onehot(theta, x, onehot, mask, cfg=self.cfg)
-
-    def local_update(self, theta, x, y, mask):
-        # slab-storage decode (f32 identity) fuses into the caller's jit
-        return self.local_update_onehot(theta, decode_x(x),
-                                        self.encode_labels(y), mask)
-
-    def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
-        return _evaluate(theta, x_test, y_test, cfg=self.cfg)
-
-    def evaluate_batch(self, thetas, x_test, y_test) -> metrics_mod.Metrics:
-        """Stacked eval over (k, P) thetas — see LogRegTask.evaluate_batch
-        (the async eval engine's coalesced dispatch)."""
-        return jax.vmap(
-            lambda t: self.evaluate(t, x_test, y_test))(thetas)
-
-    def predict_logits(self, theta, x):
-        """(B, F) → (B, C) class scores — the serving plane's forward
-        pass (kafka_ps_tpu/serving/engine.py)."""
-        return logits(self.unflatten(theta), x)
+    def logits(self, leaves, x):
+        """(B, F) → (B, C+1) class scores."""
+        return logits(leaves, x)
 
 
 def fit(params: MLPParams, x, onehot, mask, *, cfg: ModelConfig):
@@ -170,18 +150,3 @@ def evaluate_leaves(params: MLPParams, x_test, y_test, *, cfg: ModelConfig):
         f1, acc = metrics_mod.weighted_f1_accuracy(preds, y_test,
                                                    cfg.num_rows)
         return metrics_mod.Metrics(f1=f1, accuracy=acc, loss=loss)
-
-
-# The flat entry points, jitted like logreg.local_update so that a
-# caller holding one flat theta pays one cached XLA program (re-jitting
-# inside an enclosing jit — the per-node solver programs — is free: it
-# inlines).
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _local_update_onehot(theta, x, onehot, mask, *, cfg: ModelConfig):
-    return task_mod.flat_local_update(MLPTask(cfg), theta, x, onehot, mask)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _evaluate(theta, x_test, y_test, *, cfg: ModelConfig):
-    return evaluate_leaves(unflatten(theta, cfg), x_test, y_test, cfg=cfg)

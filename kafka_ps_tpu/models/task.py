@@ -28,10 +28,11 @@ this module makes it so in fact.  A task owns:
 
 Inside a solver program the parameters are their leaves; the flat
 vector is what a program takes and returns (the wire and server
-contract).  The flat entry points (`local_update`, `evaluate`, …) are
-that surface wrapped — unflatten, fit, flatten the delta — for callers
-that hold one flat theta and want one flat delta: the range-sharded
-step, the server's eval, serving, the Pallas kernels' callers.
+contract).  The flat entry points (`local_update`, `evaluate`,
+`evaluate_batch`, `predict_logits`) are that surface wrapped —
+unflatten, fit, flatten the delta — for callers that hold one flat
+theta: the range-sharded step, the server's eval, serving.  They are
+written once, in `FlatFace`, from the members a family owns.
 
 Every entry point (runtime worker, fused BSP step, range-sharded step,
 server eval) dispatches through a task; `logreg` stays the default —
@@ -42,12 +43,14 @@ the reference's model — `mlp` is a second classifier, and
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Protocol
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kafka_ps_tpu.compress.slab import decode_x
 from kafka_ps_tpu.models import logreg
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.utils.config import ModelConfig
@@ -83,9 +86,9 @@ class MLTask(Protocol):
     def evaluate_leaves(self, leaves, x_test, y_test) \
             -> metrics_mod.Metrics: ...
 
-    def local_update(self, theta, x, y, mask): ...
+    def logits(self, leaves, x) -> jax.Array: ...
 
-    def local_update_onehot(self, theta, x, onehot, mask): ...
+    def local_update(self, theta, x, y, mask): ...
 
     def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics: ...
 
@@ -103,7 +106,70 @@ def fit_delta(task: MLTask, leaves, x, onehot, mask):
         return jax.tree.map(jnp.subtract, new, leaves), loss
 
 
-class RowsWithClassLabel:
+def fit_slab(task: MLTask, leaves, x, y, mask):
+    """The k-step solver on one slab as a worker stores it (labels, any
+    slab storage form: the decode fuses into the program that traces
+    this, and is the identity for f32) → (delta leaves, loss)."""
+    return fit_delta(task, leaves, decode_x(x), task.encode_labels(y), mask)
+
+
+class FlatFace:
+    """A family's flat entry points, written once from its leaf-level
+    members: one flat theta in, a flat delta, the metrics or the scores
+    out.  `local_update` and `evaluate` are jitted, so that a caller
+    holding one flat theta outside any program pays one cached XLA
+    program per (family, cfg); inside a caller's own jit they inline.
+    The task is those programs' static argument: two tasks are equal
+    when their family and their cfg are."""
+
+    def __eq__(self, other):
+        return type(self) is type(other) and self.cfg == other.cfg
+
+    def __hash__(self):
+        return hash((type(self), self.cfg))
+
+    def local_update(self, theta, x, y, mask):
+        """`fit_slab` from a flat theta → (flat delta, loss at the
+        updated parameters)."""
+        return _local_update(self, theta, x, y, mask)
+
+    def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
+        """`evaluate_leaves` of a flat theta."""
+        return _evaluate(self, theta, x_test, y_test)
+
+    def evaluate_batch(self, thetas, x_test, y_test) -> metrics_mod.Metrics:
+        """Stacked eval: (k, P) thetas against one test set -> Metrics
+        with (k,)-leading fields.  The SAME per-element program as
+        `evaluate` over the leading axis (a `vmap`; one theta at a time
+        for a family whose update does not batch either), so row i is
+        bitwise-identical to `evaluate(thetas[i], ...)` — the async eval
+        engine's coalesced dispatch rides on this (evaluation/engine.py,
+        the vmap-of-kernel construction the gang solvers proved,
+        runtime/gang.py)."""
+        def one(theta):
+            return self.evaluate(theta, x_test, y_test)
+        if self.batches_workers:
+            return jax.vmap(one)(thetas)
+        return jax.lax.map(one, thetas)
+
+    def predict_logits(self, theta, x):
+        """Rows → the scores `logits` gives them — the serving plane's
+        forward pass (kafka_ps_tpu/serving/engine.py)."""
+        return self.logits(self.unflatten(theta), x)
+
+
+@functools.partial(jax.jit, static_argnames=("task",))
+def _local_update(task, theta, x, y, mask):
+    delta, loss = fit_slab(task, task.unflatten(theta), x, y, mask)
+    return task.flatten(delta), loss
+
+
+@functools.partial(jax.jit, static_argnames=("task",))
+def _evaluate(task, theta, x_test, y_test):
+    return task.evaluate_leaves(task.unflatten(theta), x_test, y_test)
+
+
+class RowsWithClassLabel(FlatFace):
     """What the classifier families share: float32 feature rows of
     `cfg.num_features`, a class label one-hot over `cfg.num_rows`, an
     update that batches over the worker axis."""
@@ -117,13 +183,6 @@ class RowsWithClassLabel:
 
     def encode_labels(self, y):
         return jax.nn.one_hot(y, self.cfg.num_rows, dtype=jnp.float32)
-
-
-def flat_local_update(task: MLTask, theta, x, onehot, mask):
-    """The flat face of the solver: one flat theta in, one flat delta
-    out."""
-    delta, loss = fit_delta(task, task.unflatten(theta), x, onehot, mask)
-    return task.flatten(delta), loss
 
 
 class LogRegTask(RowsWithClassLabel):
@@ -153,30 +212,9 @@ class LogRegTask(RowsWithClassLabel):
         return metrics_mod.evaluate_leaves(leaves, x_test, y_test,
                                            cfg=self.cfg)
 
-    def local_update(self, theta, x, y, mask):
-        return logreg.local_update(theta, x, y, mask, cfg=self.cfg)
-
-    def local_update_onehot(self, theta, x, onehot, mask):
-        return logreg.local_update_onehot(theta, x, onehot, mask,
-                                          cfg=self.cfg)
-
-    def evaluate(self, theta, x_test, y_test) -> metrics_mod.Metrics:
-        return metrics_mod.evaluate(theta, x_test, y_test, cfg=self.cfg)
-
-    def evaluate_batch(self, thetas, x_test, y_test) -> metrics_mod.Metrics:
-        """Stacked eval: (k, P) thetas against one test set -> Metrics
-        with (k,)-leading fields.  vmap of the SAME per-element program
-        as `evaluate`, so row i is bitwise-identical to
-        `evaluate(thetas[i], ...)` — the async eval engine's coalesced
-        dispatch rides on this (evaluation/engine.py, the vmap-of-kernel
-        construction the gang solvers proved, runtime/gang.py)."""
-        return jax.vmap(
-            lambda t: self.evaluate(t, x_test, y_test))(thetas)
-
-    def predict_logits(self, theta, x):
-        """(B, F) → (B, C+1) class scores — the serving plane's forward
-        pass (kafka_ps_tpu/serving/engine.py)."""
-        return logreg.logits(self.unflatten(theta), x)
+    def logits(self, leaves, x):
+        """(B, F) → (B, C+1) class scores."""
+        return logreg.logits(leaves, x)
 
 
 _REGISTRY = {"logreg": LogRegTask}

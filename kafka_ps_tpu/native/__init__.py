@@ -1,6 +1,6 @@
 """Native (C++) components of the runtime.
 
-The compute path is JAX/XLA/Pallas; the IO-side hot paths are native:
+The compute path is JAX/XLA; the IO-side hot paths are native:
 csvparse.cpp replaces the JVM CsvProducer + Jackson parsing layer of the
 reference (producer/CsvProducer.java, serialization/JSONSerde.java) with
 a one-pass C++ CSV → CSR parser exposed through ctypes (binding.py).

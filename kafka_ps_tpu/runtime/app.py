@@ -30,7 +30,7 @@ from kafka_ps_tpu.data.stream import CsvStreamProducer
 from kafka_ps_tpu.parallel import bsp
 from kafka_ps_tpu.runtime import fabric as fabric_mod
 from kafka_ps_tpu.runtime.server import LogSink, ServerNode
-from kafka_ps_tpu.runtime.worker import WorkerNode, solver_program
+from kafka_ps_tpu.runtime.worker import WorkerNode
 from kafka_ps_tpu.telemetry import NULL_TELEMETRY
 from kafka_ps_tpu.utils import asynclog
 from kafka_ps_tpu.utils.asynclog import DeferredSink
@@ -104,9 +104,9 @@ class StreamingPSApp:
             # the server-side checkpoint next to the buffers
             self.server.checkpoint_residuals = self.compressors
         self._stop = threading.Event()
-        # fused-program cache: re-entering run_fused_bsp (resume, bench
-        # trials, alternating with other drive modes) must reuse the
-        # SAME jit wrappers — a fresh jax.jit(shard_map(...)) re-traces
+        # fused-program cache: re-entering run_fused_bsp (resume, the
+        # benchmark's probe and window calls, alternating with other
+        # drive modes) must reuse the SAME jit wrappers — a fresh jax.jit(shard_map(...)) re-traces
         # the whole multi-round program every call (hundreds of ms at
         # MLP-4096) even when the XLA compile cache hits
         self._fused_programs: dict = {}
@@ -135,10 +135,10 @@ class StreamingPSApp:
         # rolling critical-path sampler, built lazily on first status()
         # heartbeat with telemetry on (telemetry/critpath.py)
         self._critpath = None
-        # which solver program the per-node path dispatches (raises
-        # PallasUnavailable when use_pallas asks for a kernel no shape
-        # rule admits) — printed at start-up and in [status]
-        self.solver_program = solver_program(cfg)
+        # which solver programs the drive dispatches ("xla": the
+        # per-node path's; run_fused_bsp sets "fused-bsp") — the
+        # start-up line's field, printed in [status]
+        self.solver = "xla"
         # Multi-host: the subset of logical workers this process hosts
         # (None = all).  Every host streams the same CSV with the same
         # global round-robin, keeping only its own workers' rows — the
@@ -373,7 +373,7 @@ class StreamingPSApp:
                 "gradients": self.fabric.total_pending(
                     fabric_mod.GRADIENTS_TOPIC)},
             "buffers": [b.count for b in self.buffers],
-            "solver": self.solver_program,
+            "solver": self.solver,
         }
         if self.eval_engine is not None:
             out["eval_lag"] = self.eval_engine.lag_clocks
@@ -524,9 +524,9 @@ class StreamingPSApp:
                 progressed = True
             return progressed
         # drain the whole backlog, capped so a full batch cannot
-        # overshoot the iteration budget (bench runs rely on exact
-        # counts); drops (zombies/duplicates) under-fill a round and
-        # the outer loop tops it up
+        # overshoot the iteration budget (the benchmark's slices rely
+        # on exact counts); drops (zombies/duplicates) under-fill a
+        # round and the outer loop tops it up
         batch = []
         with self.tracer.span("serial.collect"):
             while (self.server.iterations + len(batch)
@@ -717,7 +717,7 @@ class StreamingPSApp:
         if self.cfg.consistency_model != SEQUENTIAL:
             raise ValueError("fused path implements the sequential model only")
         range_mode = mesh is not None and PARAM_AXIS in mesh.shape
-        self.solver_program = "fused-bsp"
+        self.solver = "fused-bsp"
         if range_mode and jax.process_count() > 1:
             raise ValueError(
                 "range-sharded fused mode is single-process (the params "

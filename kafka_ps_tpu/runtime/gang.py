@@ -76,7 +76,7 @@ class GangMemberError(RuntimeError):
 
 
 @functools.lru_cache(maxsize=None)
-def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
+def _gang_solver_fns(task_name: str, cfg):
     """Batched counterparts of worker._solver_fns, one compile per
     (task, cfg, member-count) — four jit'd entry points over TUPLES of
     per-member arrays (stacked inside the jit, so stacking costs no
@@ -89,25 +89,21 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
       update_eval_stacked(thetas, xs, ys, masks, test_x, test_y)
       update_eval_bcast(theta, xs, ys, masks, test_x, test_y)
 
-    The non-pallas variants vmap the SAME leaf-level function the
-    single-dispatch path jits (worker.fit_and_eval; vmap preserves
-    per-element semantics — the bitwise-equivalence test in
-    tests/test_gang.py is the contract).  The flat vectors stop at the
+    They vmap the SAME leaf-level function the single-dispatch path
+    jits (worker.fit_and_eval; vmap preserves per-element semantics —
+    the bitwise-equivalence test in tests/test_gang.py is the
+    contract).  The flat vectors stop at the
     program's edges: a shared theta is unflattened once, member thetas
     one by one and their leaves stacked; the deltas leave as stacked
     leaves [k, …], and member i's flat delta is flattened from row i of
     every leaf.  What the fan-out costs on the device is that
     concatenation, 16.9 MB written a member at H=4096; cut out of a
     [k, P] array, whose TPU tiles interleave eight members, it cost 16%
-    of the program (PERF.md §6, PR 25).  With use_pallas the solver
-    goes through the batched kernels (ops/fused_update.*_batched: the
-    grid over the worker axis where the member slab is resident-sized,
-    the streaming kernel per member otherwise), which take and return
-    [k, P]; a kernel that fails to compile fails the run."""
+    of the program (PERF.md §6, PR 25)."""
     import jax
     import jax.numpy as jnp
 
-    from kafka_ps_tpu.models.task import get_task
+    from kafka_ps_tpu.models.task import fit_slab, get_task
     task = get_task(task_name, cfg)
 
     def unstack(a, k):
@@ -121,83 +117,52 @@ def _gang_solver_fns(task_name: str, cfg, use_pallas: bool | str):
         # per-element semantics
         return jax.tree.map(lambda *leaves: jnp.stack(leaves), *items)
 
-    if use_pallas:
-        from kafka_ps_tpu.ops import fused_update
-        interpret = use_pallas == "interpret"
-        batched = {"logreg": fused_update.local_update_batched,
-                   "mlp": fused_update.mlp_local_update_batched
-                   }[task_name]
+    def over_members(fn, in_axes):
+        """`fn` over the members' axis: a `vmap`, or, for a task whose
+        update does not batch (`task.batches_workers`), one member at a
+        time."""
+        if task.batches_workers:
+            return jax.vmap(fn, in_axes=in_axes)
 
-        def solve(thetas, shared, xs, ys, masks):
-            k = len(xs)
-            T = (jnp.broadcast_to(thetas[None], (k,) + thetas.shape)
-                 if shared else jnp.stack(thetas))
-            with jax.named_scope("kps.gang.fit"):
-                deltas, losses = batched(
-                    T, tstack(xs), jnp.stack(ys), jnp.stack(masks),
-                    cfg=cfg, interpret=interpret)
-            return T, deltas, losses
+        def one_at_a_time(*args):
+            def one(mapped):
+                mapped = iter(mapped)
+                return fn(*[a if axis is None else next(mapped)
+                            for a, axis in zip(args, in_axes)])
+            return jax.lax.map(one, tuple(
+                a for a, axis in zip(args, in_axes) if axis is not None))
+        return one_at_a_time
 
-        def update(thetas, shared, xs, ys, masks):
-            k = len(xs)
-            _, deltas, losses = solve(thetas, shared, xs, ys, masks)
-            return unstack(deltas, k), unstack(losses, k)
+    def member_leaves(thetas, shared):
+        """(leaves, their vmap axis): one set for a shared theta, else
+        the members' own, stacked leaf by leaf."""
+        if shared:
+            return task.unflatten(thetas), None
+        return tstack([task.unflatten(t) for t in thetas]), 0
 
-        def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
-            k = len(xs)
-            T, deltas, losses = solve(thetas, shared, xs, ys, masks)
-            with jax.named_scope("kps.gang.eval"):
-                met = jax.vmap(lambda t, d: task.evaluate(
-                    t + d, test_x, test_y))(T, deltas)
-            return (unstack(deltas, k), unstack(losses, k),
-                    unstack(met.f1, k), unstack(met.accuracy, k))
-    else:
-        def over_members(fn, in_axes):
-            """`fn` over the members' axis: a `vmap`, or, for a task
-            whose update does not batch (`task.batches_workers`), one
-            member at a time."""
-            if task.batches_workers:
-                return jax.vmap(fn, in_axes=in_axes)
+    def fan_out(deltas, k):
+        return tuple(task.flatten(jax.tree.map(lambda a: a[i], deltas))
+                     for i in range(k))
 
-            def one_at_a_time(*args):
-                def one(mapped):
-                    mapped = iter(mapped)
-                    return fn(*[a if axis is None else next(mapped)
-                                for a, axis in zip(args, in_axes)])
-                return jax.lax.map(one, tuple(
-                    a for a, axis in zip(args, in_axes) if axis is not None))
-            return one_at_a_time
+    def update(thetas, shared, xs, ys, masks):
+        k = len(xs)
+        leaves, axis = member_leaves(thetas, shared)
+        deltas, losses = over_members(
+            functools.partial(fit_slab, task),
+            (axis, 0, 0, 0))(
+                leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks))
+        return fan_out(deltas, k), unstack(losses, k)
 
-        def member_leaves(thetas, shared):
-            """(leaves, their vmap axis): one set for a shared theta,
-            else the members' own, stacked leaf by leaf."""
-            if shared:
-                return task.unflatten(thetas), None
-            return tstack([task.unflatten(t) for t in thetas]), 0
-
-        def fan_out(deltas, k):
-            return tuple(task.flatten(jax.tree.map(lambda a: a[i], deltas))
-                         for i in range(k))
-
-        def update(thetas, shared, xs, ys, masks):
-            k = len(xs)
-            leaves, axis = member_leaves(thetas, shared)
-            deltas, losses = over_members(
-                functools.partial(worker_mod.fit_slab, task),
-                (axis, 0, 0, 0))(
-                    leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks))
-            return fan_out(deltas, k), unstack(losses, k)
-
-        def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
-            k = len(xs)
-            leaves, axis = member_leaves(thetas, shared)
-            deltas, losses, f1s, accs = over_members(
-                functools.partial(worker_mod.fit_and_eval, task),
-                (axis, 0, 0, 0, None, None))(
-                    leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks),
-                    test_x, test_y)
-            return (fan_out(deltas, k), unstack(losses, k),
-                    unstack(f1s, k), unstack(accs, k))
+    def update_eval(thetas, shared, xs, ys, masks, test_x, test_y):
+        k = len(xs)
+        leaves, axis = member_leaves(thetas, shared)
+        deltas, losses, f1s, accs = over_members(
+            functools.partial(worker_mod.fit_and_eval, task),
+            (axis, 0, 0, 0, None, None))(
+                leaves, tstack(xs), jnp.stack(ys), jnp.stack(masks),
+                test_x, test_y)
+        return (fan_out(deltas, k), unstack(losses, k),
+                unstack(f1s, k), unstack(accs, k))
 
     @jax.jit
     def update_stacked(thetas, xs, ys, masks):
@@ -398,7 +363,7 @@ class GangDispatcher:
         if k == 1:
             w, msg, theta, x, y, mask, _, _ = grp[0]
             update_fn, update_eval_fn = worker_mod._solver_fns(
-                self.cfg.task, self.cfg.model, self.cfg.use_pallas)
+                self.cfg.task, self.cfg.model)
             with self.tracer.span("worker.local_update",
                                   worker=w.worker_id,
                                   clock=msg.vector_clock):
@@ -423,8 +388,7 @@ class GangDispatcher:
         shared = all(t is thetas[0] for t in thetas)
         lead = grp[0][0]
 
-        fns = _gang_solver_fns(self.cfg.task, self.cfg.model,
-                               self.cfg.use_pallas)
+        fns = _gang_solver_fns(self.cfg.task, self.cfg.model)
         # same span name as the per-message path — one entry now covers
         # k members (the `gang` arg distinguishes the two in traces)
         with self.tracer.span("worker.local_update", gang=k,

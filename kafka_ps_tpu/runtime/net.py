@@ -401,7 +401,7 @@ class ServerBridge:
         # inc per frame on the hot path (null metrics when telemetry off)
         self._m_sent, self._m_recv = _frame_counters(self._telemetry)
         # bytes on the wire per frame topic, both directions, including
-        # the 13-byte frame header (the compression_ab bench reads this)
+        # the 13-byte frame header (tests/test_net_framing.py reads this)
         self.wire_bytes: dict[int, int] = {}
         self._wire_lock = OrderedLock("ServerBridge.wire")
         self._listener = socket.create_server((host, port))
@@ -693,7 +693,7 @@ class ServerBridge:
             # ships batches in scatter-gather syscalls.  Wire-byte /
             # telemetry accounting happens HERE at enqueue time, so an
             # arm with coalescing on is number-for-number comparable to
-            # one with it off (bench wire_ab).  PINGs are advisory:
+            # one with it off (tests/test_net_framing.py).  PINGs are advisory:
             # regenerated next interval, so a full queue drops them
             # (typed counter) instead of blocking the heartbeat thread.
             if not writer.send(topic, key, payload,

@@ -843,8 +843,9 @@ class ServerNode:
         clock at gate-decision time (list index = worker id, evicted
         workers' clocks frozen where they stopped) plus this worker's
         lag — all host ints read off the tracker (no device values,
-        PS106).  Kept to a flat int list: this runs per gradient, and
-        the flight_overhead bench gates it at < 2% of server iters/s."""
+        PS106).  Kept to a flat int list: this runs per gradient (the
+        armed recorder cost under 2% of server iters/s on the CPU dev
+        host at PR 10; not measured on the chip)."""
         states = self.tracker.tracker
         clocks = [s.vector_clock for s in states]
         waiting = sum(1 for s in states

@@ -98,7 +98,9 @@ class ShardPlan:
         offsets.  Shards outside the survivor set get an EMPTY slice —
         their gate still needs the (worker, clock) message, but the
         apply is skipped (the work-reduction that makes sharded topk
-        scale on one host, bench.py sharding_ab)."""
+        scale on one host; seen in a builder's run on the CPU dev host
+        at PR 8, block `sharding_ab` of the deleted
+        `git show 3337831:bench.py`, never measured on the chip)."""
         idx, vals = msg.encoded.parts
         idx = np.asarray(idx, dtype=np.int32)
         vals = np.asarray(vals, dtype=np.float32)

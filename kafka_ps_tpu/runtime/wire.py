@@ -30,8 +30,9 @@ how frames cross the syscall boundary:
 The byte CONTENT of the stream is identical to the sequential
 `send_frame` path — same frames, same order per connection — so a
 coalescing fleet interoperates bit-for-bit with a `--no-wire-coalesce`
-one, and the bench's `wire_ab` block pins theta + eval CSV bitwise
-across the lever.
+one (tests/test_net_framing.py holds the byte stream), and
+`scripts/tier1.sh --wire` pins theta + eval CSV bitwise across the
+lever.
 
 Telemetry: `wire_frames_per_syscall` (histogram, per flush),
 `wire_send_queue_depth` (gauge, bytes queued), `wire_advisory_dropped`

@@ -32,7 +32,7 @@ import numpy as np
 
 from kafka_ps_tpu.compress import slab as slab_mod
 from kafka_ps_tpu.data.buffer import SlidingBuffer
-from kafka_ps_tpu.models.task import fit_delta
+from kafka_ps_tpu.models.task import fit_slab, get_task
 from kafka_ps_tpu.runtime import fabric as fabric_mod
 from kafka_ps_tpu.runtime.messages import GradientMessage, KeyRange, WeightsMessage
 from kafka_ps_tpu.telemetry import NULL_MODEL_HEALTH, NULL_TELEMETRY
@@ -42,30 +42,6 @@ from kafka_ps_tpu.utils.trace import NULL_TRACER
 
 LogSink = Callable[[str], None]
 
-def solver_program(cfg: PSConfig) -> str:
-    """Name of the solver program this configuration's per-node path
-    dispatches — what the start-up line and [status] print: "xla", or
-    "pallas-resident" / "pallas-streaming" (ops/fused_update
-    .select_program on the slab the worker will hold), "+batched" when
-    gang release sets take the grid kernel.  Raises PallasUnavailable
-    when `use_pallas` asks for a kernel no shape rule admits."""
-    if not cfg.use_pallas:
-        return "xla"
-    from kafka_ps_tpu.ops import fused_update
-    name = fused_update.program_name(
-        cfg.task, cfg.model, cfg.buffer.max_size, cfg.slab_dtype,
-        interpret=cfg.use_pallas == "interpret")
-    if cfg.use_gang and cfg.num_workers > 1 and name == "resident":
-        name += "+batched"
-    return f"pallas-{name}"
-
-
-def fit_slab(task, leaves, x, y, mask):
-    """The k-step solver on one member's slab as the worker stores it
-    (labels, any slab storage form) → (delta leaves, loss)."""
-    return fit_delta(task, leaves, slab_mod.decode_x(x),
-                     task.encode_labels(y), mask)
-
 
 def fit_and_eval(task, leaves, x, y, mask, test_x, test_y):
     """One member's iteration on the parameters' leaves: the k-step
@@ -74,10 +50,10 @@ def fit_and_eval(task, leaves, x, y, mask, test_x, test_y):
     the gang programs (runtime/gang.py, vmapped over the members) run
     this one function.  The evaluated model is `leaves + delta`, what
     the server holds after applying this delta alone — bitwise the
-    `theta + delta` the flat formulation evaluated (and the Pallas arm
-    still does); the fit's own result differs from it by one float32
-    rounding.  The two scopes split the program's device time into
-    training and the members' evaluations (metadata only)."""
+    `theta + delta` of the flat vectors; the fit's own result differs
+    from it by one float32 rounding.  The two scopes split the
+    program's device time into training and the members' evaluations
+    (metadata only)."""
     with jax.named_scope("kps.gang.fit"):
         delta, loss = fit_slab(task, leaves, x, y, mask)
     with jax.named_scope("kps.gang.eval"):
@@ -87,7 +63,7 @@ def fit_and_eval(task, leaves, x, y, mask, test_x, test_y):
 
 
 @functools.lru_cache(maxsize=None)
-def _solver_fns(task_name: str, cfg, use_pallas: bool | str):
+def _solver_fns(task_name: str, cfg):
     """One compiled program per (task, cfg) — shared by every WorkerNode
     so N logical workers pay one trace/compile, not N.
 
@@ -97,36 +73,15 @@ def _solver_fns(task_name: str, cfg, use_pallas: bool | str):
     Metric semantics are unchanged — each worker still evaluates its
     own post-fit model, like the reference's in-iteration eval
     (LogisticRegressionTaskSpark.java:186).  Both take and return flat
-    vectors (the wire contract); the XLA arm works on the leaves
-    between.
-
-    `use_pallas`: False = the XLA solver; True = the compiled Mosaic
-    kernel (TPU only — no fallback, ops/fused_update.py);
-    "interpret" = the same kernel in the Pallas interpreter."""
-    from kafka_ps_tpu.models.task import get_task
+    vectors (the wire contract) and work on the leaves between."""
     task = get_task(task_name, cfg)
-    if use_pallas:
-        from kafka_ps_tpu.ops import fused_update
-        kernel = {"logreg": fused_update.local_update,
-                  "mlp": fused_update.mlp_local_update}[task_name]
-        interpret = use_pallas == "interpret"
 
-        def update_fn(theta, x, y, mask):
-            return kernel(theta, x, y, mask, cfg=cfg, interpret=interpret)
+    def update_and_eval(theta, x, y, mask, test_x, test_y):
+        delta, *scalars = fit_and_eval(task, task.unflatten(theta),
+                                       x, y, mask, test_x, test_y)
+        return task.flatten(delta), *scalars
 
-        def update_and_eval(theta, x, y, mask, test_x, test_y):
-            delta, loss = update_fn(theta, x, y, mask)
-            m = task.evaluate(theta + delta, test_x, test_y)
-            return delta, loss, m.f1, m.accuracy
-    else:
-        update_fn = task.local_update
-
-        def update_and_eval(theta, x, y, mask, test_x, test_y):
-            delta, *scalars = fit_and_eval(task, task.unflatten(theta),
-                                           x, y, mask, test_x, test_y)
-            return task.flatten(delta), *scalars
-
-    return jax.jit(update_fn), jax.jit(update_and_eval)
+    return jax.jit(task.local_update), jax.jit(update_and_eval)
 
 
 class WorkerNode:
@@ -155,12 +110,7 @@ class WorkerNode:
         self.cfg = cfg
         self.fabric = fabric
         self.buffer = buffer
-        from kafka_ps_tpu.models.task import get_task
         self.task = get_task(cfg.task, cfg.model)
-        if cfg.use_pallas and cfg.task not in ("logreg", "mlp"):
-            raise ValueError(
-                "use_pallas implements the logreg and mlp local updates "
-                f"(ops/fused_update.py), got task {cfg.task!r}")
         self.theta = np.zeros((self.task.num_params,), dtype=np.float32)
         self.test_x = jnp.asarray(test_x) if test_x is not None else None
         self.test_y = jnp.asarray(test_y) if test_y is not None else None
@@ -332,8 +282,8 @@ class WorkerNode:
         # is formatted when they resolve (utils/asynclog.DeferredSink).
         # Eval iterations fuse solver + evaluate into ONE dispatch
         # (_solver_fns).
-        update_fn, update_eval_fn = _solver_fns(
-            self.cfg.task, self.cfg.model, self.cfg.use_pallas)
+        update_fn, update_eval_fn = _solver_fns(self.cfg.task,
+                                                self.cfg.model)
         f1, acc = -1.0, -1.0
         t0 = time.perf_counter()
         with self.tracer.span("worker.local_update", worker=self.worker_id,

@@ -210,7 +210,7 @@ class PredictionEngine:
         self._thread.start()
 
     # model-0 aliases — the single-tenant surface every existing caller
-    # (runtime/app.py, cli/, bench.py, tests) keeps using unchanged
+    # (runtime/app.py, cli/, tests) keeps using unchanged
     @property
     def task(self):
         return self._tenants[0].task

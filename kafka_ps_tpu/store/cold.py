@@ -47,7 +47,7 @@ class ColdStore:
     @classmethod
     def open(cls, directory: str, config: LogConfig | None = None
              ) -> "ColdStore":
-        """Standalone cold partition (tests, bench, runs without a
+        """Standalone cold partition (tests, runs without a
         durable fabric); `close()` then closes the log too."""
         store = cls(CommitLog(directory, config or LogConfig(fsync="none"),
                               name="param-cold"))

@@ -130,7 +130,7 @@ class TieredParamStore:
                 i, lo, hi,
                 vals[lo - key_range.start:hi - key_range.start].copy()))
 
-        # measured counters the bench/stats read (host ints, no device
+        # measured counters `stats()` reads (host ints, no device
         # sync anywhere near them)
         self.pins = {"hot": 0, "warm": 0, "cold": 0}
         # guarded-by: _lock (rebalance writes hold the residency lock; stats reads are snapshots)

@@ -49,8 +49,10 @@ from kafka_ps_tpu.telemetry.flight import FLIGHT
 STABLE, WARNING, DRIFT = 0, 1, 2
 _STATE_NAMES = {STABLE: "STABLE", WARNING: "WARNING", DRIFT: "DRIFT"}
 
-# detector defaults — tuned on the synthetic label-flip regime
-# (bench.py drift_detection): loss is O(1)-scaled, so a sustained
+# detector defaults — tuned on the synthetic label-flip regime (a
+# builder's run on the CPU dev host at PR 15, block `drift_detection`
+# of the deleted `git show 3337831:bench.py`): loss is O(1)-scaled, so
+# a sustained
 # +0.1 shift crosses PH_THRESHOLD within ~15 eval rows while the
 # stable arm's jitter never accumulates past the drift tolerance.
 PH_THRESHOLD = 1.5

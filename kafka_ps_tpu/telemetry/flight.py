@@ -228,8 +228,8 @@ class FlightRecorder:
 
     def total_events(self) -> int:
         """Events ever recorded across all rings, including ones the
-        wrap already overwrote (the flight_overhead bench's proof that
-        the measured arm actually recorded)."""
+        wrap already overwrote (proof that an armed run actually
+        recorded)."""
         with self._rings_lock:
             return sum(r.total for r in self._rings)
 

@@ -29,9 +29,10 @@ Costs and invariants:
   * the stack table is bounded (`max_stacks`): once full, new distinct
     stacks fold into an `(other)` bucket instead of growing the heap;
   * the profiler's own sampler thread is excluded from its samples;
-  * <2% overhead at the default 100 Hz is asserted by the bench's
-    `profiling_overhead` block, and bitwise theta-identity with the
-    profiler off is part of the same contract.
+  * the default 100 Hz cost under 2% in a builder's run on the CPU dev
+    host at PR 14, with theta bitwise the profiler-off run's (block
+    `profiling_overhead` of the deleted `git show 3337831:bench.py`;
+    not measured on the chip).
 """
 
 from __future__ import annotations

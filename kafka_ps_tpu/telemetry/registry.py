@@ -6,7 +6,7 @@ thread-safe counters, gauges and fixed-bucket histograms grouped into
 labeled families (`frames_sent{topic=...}`, `gate_wait_ms{model=...}`),
 exported three ways:
 
-  * `snapshot()` — nested dict for the status heartbeat and bench JSON;
+  * `snapshot()` — nested dict for the status heartbeat;
   * `prometheus_text()` — Prometheus text exposition (`--metrics-file`,
     rewritten every `--metrics-every` seconds);
   * `Telemetry.summary()` — a small flat dict the heartbeat can inline.
@@ -267,7 +267,7 @@ class MetricsRegistry:
     # -- exports ------------------------------------------------------------
     def snapshot(self) -> dict:
         """{name: {label-string: value-or-histogram-summary}} — the
-        bench-JSON / heartbeat form."""
+        heartbeat form."""
         out: dict[str, dict] = {}
         for name, fam in sorted(self.families().items()):
             entry: dict[str, object] = {}

@@ -146,13 +146,6 @@ class PSConfig:
     # chunk-amortized, runtime/app._run_fused_loop).
     eval_async: bool = True
     seed: int = 0
-    # Use the Pallas fused local-update kernel (ops/fused_update.py) for
-    # worker iterations.  True (`--pallas`) = the compiled Mosaic
-    # kernel: TPU only, and a shape no kernel admits stops the run —
-    # there is no fallback to the XLA solver.  "interpret" = the same
-    # kernel in the Pallas interpreter, for tests and the CPU smoke; no
-    # CLI flag selects it.
-    use_pallas: bool | str = False
     # Gang-scheduled dispatch (runtime/gang.py, docs/GANG_DISPATCH.md):
     # coalesce workers released by the consistency gate at the same
     # moment into one batched device step.  On by default for the

@@ -1,6 +1,6 @@
 """Where the process runs and where it keeps compiled programs — the
 two start-up facts every entry point reports (cli/run.py's hook for the
-runner and the socket roles, bench.py, chip_smoke.py).
+runner and the socket roles, chip_smoke.py).
 
 Compile cache: a cold process recompiles every solver, apply, eval-width
 and scatter-bucket program, and the chip tool keeps nothing between

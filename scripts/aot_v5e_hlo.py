@@ -81,7 +81,7 @@ def compile_program(program: str, topo, *, hidden: int = 4096,
 
     theta = shape((task.num_params,), jnp.float32, shared)
     if program == "gang":
-        fn = gang._gang_solver_fns("mlp", cfg, False)["update_eval_bcast"]
+        fn = gang._gang_solver_fns("mlp", cfg)["update_eval_bcast"]
         args = (theta,
                 (shape((rows, features), jnp.float32, shared),) * n,
                 (shape((rows,), jnp.int32, shared),) * n,

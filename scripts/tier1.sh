@@ -303,8 +303,8 @@ try:
     over = loadgen.run_closed_loop(target, 8, concurrency=32,
                                    duration_s=3.0)
     # offered-rate arm: memoryless Poisson arrivals at a modest rate —
-    # the steady-state traffic model (bench.py serving_load quotes its
-    # SLO against this shape).  Latency counts from the SCHEDULED
+    # the steady-state traffic model (docs/SERVING.md quotes its SLO
+    # against this shape).  Latency counts from the SCHEDULED
     # arrival (no coordinated omission), so the smoke SLO here also
     # covers queueing behind the shared training core.  Sheds are
     # legal (bursts can momentarily fill the 4-deep queue); errors are

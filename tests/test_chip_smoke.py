@@ -1,6 +1,6 @@
 """chip_smoke.py on the CPU: the script refuses to run off the chip,
 and its phase functions — the same code the chip run executes — hold at
-a tiny width with interpreted kernels.  Plus the start-up contract the
+a tiny width.  Plus the start-up contract the
 script shares with every CLI entry: the compile-cache hook."""
 
 import json
@@ -17,11 +17,10 @@ sys.path.insert(0, str(REPO))
 import chip_smoke  # noqa: E402
 
 TINY = chip_smoke.Sizes(
-    num_features=128, fused_hidden=32, kernel_hidden=32, buffer_min=8,
+    num_features=128, fused_hidden=32, buffer_min=8,
     buffer_max=64, train_rows=512, test_rows=128, per_node_clocks=12,
-    pallas_clocks=4, fused_rounds=16, multichip_rounds=8,
-    center_scale=1.0,           # too few rows to learn the hard regime
-    interpret=True)
+    fused_rounds=16, multichip_rounds=8,
+    center_scale=1.0)           # too few rows to learn the hard regime
 
 
 def _run(script_dir, env_extra, *args):
@@ -61,17 +60,6 @@ def test_per_node_phase(data, consistency):
     assert rec["eval_rows"] >= 1 and rec["loss"][1] < rec["loss"][0]
 
 
-def test_pallas_phase_refuses_off_the_chip(data, capsys):
-    """`--pallas` through the CLI means compiled kernels; on the CPU the
-    run stops with the reason instead of training on XLA."""
-    workdir, train, test = data
-    with pytest.raises(SystemExit, match="--pallas: compiled Mosaic "
-                                         "kernels need a TPU backend"):
-        chip_smoke.phase_per_node(workdir, train, test, TINY, "cpu", 0,
-                                  pallas=True)
-    assert "solver=refused" in capsys.readouterr().err
-
-
 @pytest.mark.parametrize("eval_every", [1, 8])
 def test_fused_phase(data, eval_every):
     workdir, train, test = data
@@ -80,13 +68,6 @@ def test_fused_phase(data, eval_every):
     assert rec["program"] == ("bsp-step" if eval_every == 1
                               else "bsp-scan-8")
     assert rec["eval_rows"] == TINY.fused_rounds // eval_every
-
-
-def test_kernels_phase_interpreted():
-    out = chip_smoke.phase_kernels(TINY, "cpu")
-    programs = {rec["program"] for rec in out.values()}
-    assert programs == {"resident", "streaming", "batched"}
-    assert len(out) == 10            # 2 families x (4 single + 1 batched)
 
 
 def test_multichip_phase_on_the_virtual_mesh(data):

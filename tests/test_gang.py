@@ -18,7 +18,7 @@ from kafka_ps_tpu.utils.trace import Tracer
 
 
 def gang_cfg(consistency=0, use_gang=True, num_workers=4, task="logreg",
-             use_pallas=False, eval_every=1):
+             eval_every=1):
     return PSConfig(
         num_workers=num_workers,
         consistency_model=consistency,
@@ -28,7 +28,6 @@ def gang_cfg(consistency=0, use_gang=True, num_workers=4, task="logreg",
         buffer=BufferConfig(min_size=8, max_size=32),
         stream=StreamConfig(time_per_event_ms=1.0),
         use_gang=use_gang,
-        use_pallas=use_pallas,
         eval_every=eval_every,
     )
 
@@ -96,19 +95,9 @@ def test_serial_gang_reduces_dispatches(consistency):
     assert res[True][2].get("server.gang_batched_applies", 0) > 0
 
 
-@pytest.mark.parametrize("task,use_pallas", [("mlp", False),
-                                             ("logreg", "interpret"),
-                                             ("mlp", "interpret")])
-def test_serial_gang_bitwise_other_families(task, use_pallas):
-    # "interpret" asks for the kernels by name on the CPU: the gang arm
-    # runs the batched grid kernel, the per-message arm the resident
-    # one — the grid instance is the same kernel body on the same block
-    from kafka_ps_tpu.ops import fused_update
-    res = run_serial_pair(0, task=task, use_pallas=use_pallas)
-    if use_pallas:
-        # no silent XLA: both kernel programs were actually traced
-        assert fused_update.TRACE_COUNTS["batched"] >= 1
-        assert fused_update.TRACE_COUNTS["resident"] >= 1
+@pytest.mark.parametrize("task", ["mlp"])
+def test_serial_gang_bitwise_other_families(task):
+    res = run_serial_pair(0, task=task)
     assert res[True][0].tobytes() == res[False][0].tobytes()
     assert strip_ts(res[True][1]["worker"]) == \
         strip_ts(res[False][1]["worker"])
@@ -127,15 +116,13 @@ def test_serial_gang_bitwise_off_eval_cadence():
 
 
 @pytest.mark.parametrize("task", ["logreg", "mlp"])
-@pytest.mark.parametrize("use_pallas", [False, True])
-def test_vmapped_solver_matches_loop(task, use_pallas):
+def test_vmapped_solver_matches_loop(task):
     """A stacked gang dispatch is the looped single dispatches, bitwise
-    — for both model families, XLA and Pallas (interpret on CPU)."""
+    — for both model families."""
     import jax
     import jax.numpy as jnp
 
     from kafka_ps_tpu.models.task import get_task
-    from kafka_ps_tpu.ops import fused_update
 
     cfg = ModelConfig(num_features=8, num_classes=2,
                       local_learning_rate=0.5, hidden_dim=16)
@@ -148,18 +135,9 @@ def test_vmapped_solver_matches_loop(task, use_pallas):
     ys = jnp.asarray(rng.integers(1, 3, size=(k, B)).astype(np.int32))
     masks = jnp.asarray((rng.random((k, B)) < 0.8).astype(np.float32))
 
-    if use_pallas:
-        single = {"logreg": fused_update.local_update,
-                  "mlp": fused_update.mlp_local_update}[task]
-        batched = {"logreg": fused_update.local_update_batched,
-                   "mlp": fused_update.mlp_local_update_batched}[task]
-        ds, ls = batched(thetas, xs, ys, masks, cfg=cfg, interpret=True)
-        singles = [single(thetas[i], xs[i], ys[i], masks[i], cfg=cfg,
-                          interpret=True) for i in range(k)]
-    else:
-        ds, ls = jax.jit(jax.vmap(tsk.local_update))(thetas, xs, ys, masks)
-        fn = jax.jit(tsk.local_update)
-        singles = [fn(thetas[i], xs[i], ys[i], masks[i]) for i in range(k)]
+    ds, ls = jax.jit(jax.vmap(tsk.local_update))(thetas, xs, ys, masks)
+    fn = jax.jit(tsk.local_update)
+    singles = [fn(thetas[i], xs[i], ys[i], masks[i]) for i in range(k)]
 
     for i, (d1, l1) in enumerate(singles):
         assert np.asarray(d1).tobytes() == np.asarray(ds[i]).tobytes()

@@ -267,14 +267,13 @@ def test_the_task_runs_through_the_clis_drives(tmp_path, monkeypatch, task,
 def test_the_cli_refuses_the_levers_the_task_cannot_hold_in_one_message():
     from kafka_ps_tpu.cli import run as run_mod
     args = run_mod.build_parser().parse_args(
-        _cli("--pallas", "--compress", "int8", "--slab-dtype", "bf16",
+        _cli("--compress", "int8", "--slab-dtype", "bf16",
              "--tier-hot-bytes", "4096"))
     with pytest.raises(SystemExit) as e:
         run_mod.cfg_from_args(args)
     said = str(e.value)
     assert said.startswith("--task glm4_moe_lite cannot run with ")
-    for flag in ("--pallas", "--compress", "--slab-dtype",
-                 "--tier-hot-bytes"):
+    for flag in ("--compress", "--slab-dtype", "--tier-hot-bytes"):
         assert flag + ":" in said
     # and the task without its file, or a file without the task
     bare = [a for a in _cli() if a not in ("--model_json", TINY)]

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from kafka_ps_tpu.models import logreg, metrics
+from kafka_ps_tpu.models.task import LogRegTask
 from kafka_ps_tpu.utils.config import ModelConfig
 
 CFG = ModelConfig(num_features=16, num_classes=3, local_learning_rate=0.5)  # 4*16+4 = 68 params
@@ -69,7 +70,7 @@ def test_local_update_is_delta_and_descends():
     x, y = _rand_batch(64)
     mask = jnp.ones(64)
     theta = jnp.zeros(CFG.num_params)
-    delta, loss = logreg.local_update(theta, x, y, mask, cfg=CFG)
+    delta, loss = LogRegTask(CFG).local_update(theta, x, y, mask)
     assert delta.shape == theta.shape
     assert float(jnp.abs(delta).sum()) > 0
     l0 = float(logreg.loss_fn(logreg.unflatten(theta, CFG), x, y, mask))
@@ -85,9 +86,9 @@ def test_local_update_k_steps_composes():
     import dataclasses
     cfg2 = CFG_LR01
     cfg1 = dataclasses.replace(CFG_LR01, num_max_iter=1)
-    d2, _ = logreg.local_update(theta, x, y, mask, cfg=cfg2)
-    d1, _ = logreg.local_update(theta, x, y, mask, cfg=cfg1)
-    d1b, _ = logreg.local_update(theta + d1, x, y, mask, cfg=cfg1)
+    d2, _ = LogRegTask(cfg2).local_update(theta, x, y, mask)
+    d1, _ = LogRegTask(cfg1).local_update(theta, x, y, mask)
+    d1b, _ = LogRegTask(cfg1).local_update(theta + d1, x, y, mask)
     np.testing.assert_allclose(np.asarray(d2), np.asarray(d1 + d1b), atol=1e-5)
 
 
@@ -123,9 +124,9 @@ def test_evaluate_learns_separable_data():
     x, y = jnp.asarray(x), jnp.asarray(y)
     theta = jnp.zeros(cfg.num_params)
     for _ in range(20):
-        d, _ = logreg.local_update(theta, x, y, jnp.ones(n), cfg=cfg)
+        d, _ = LogRegTask(cfg).local_update(theta, x, y, jnp.ones(n))
         theta = theta + d
-    m = metrics.evaluate(theta, x, y, cfg=cfg)
+    m = LogRegTask(cfg).evaluate(theta, x, y)
     assert float(m.accuracy) > 0.95
     assert float(m.f1) > 0.95
 

@@ -97,9 +97,9 @@ def test_streaming_bsp_approaches_offline_ceiling_on_hard_data():
     theta, _ = step(jnp.zeros((cfg.num_params,), jnp.float32),
                     jnp.asarray(wx), jnp.asarray(wy), jnp.asarray(mask))
 
-    from kafka_ps_tpu.models import metrics as metrics_mod
-    m = metrics_mod.evaluate(theta, jnp.asarray(xte), jnp.asarray(yte),
-                             cfg=cfg)
+    from kafka_ps_tpu.models.task import default_task
+    m = default_task(cfg).evaluate(theta, jnp.asarray(xte),
+                                   jnp.asarray(yte))
     assert float(m.f1) >= 0.85 * skl, \
         f"streaming F1 {float(m.f1):.3f} < 85% of ceiling {skl:.3f}"
     assert float(m.f1) <= 1.02 * skl + 0.05   # sanity: same hypothesis class

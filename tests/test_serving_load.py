@@ -5,8 +5,8 @@ and the snapshot-ring wraparound edges of the staleness policy.
 The shed tests drive the DETERMINISTIC paths — a stalled dispatch fn
 so the admission queue fills on command, an injected EWMA so the
 predictive shed fires without timing games — because "sheds under
-load" as a wall-clock phenomenon is the bench's job (bench.py
-serving_load), not a unit test's.
+load" as a wall-clock phenomenon is a load run's job
+(serving/loadgen.py, `scripts/tier1.sh --load`), not a unit test's.
 """
 
 import threading

@@ -179,8 +179,7 @@ def test_incremental_slab_matches_full_upload_randomized(dtype):
 
 def test_incremental_bytes_far_below_full_upload():
     """The whole point: per-arrival host->device traffic is O(changed
-    rows), not O(capacity) (the slab_ab bench block measures the same
-    counter at reference shapes)."""
+    rows), not O(capacity)."""
     cap, nf = 1024, 64
     store = SlabStore("f32", cap, nf)
     store.upload_full(np.zeros((cap, nf), np.float32),
@@ -233,13 +232,14 @@ def test_full_upload_traces_once_per_shape():
 
 
 def test_decode_fused_into_solver_traces_once():
-    """decode_x is traced INSIDE models/*.local_update — per-arrival
+    """decode_x is traced INSIDE the task's local_update — per-arrival
     solver dispatches at a steady (shape, dtype) must not re-trace it
     (the no-per-arrival-re-jit half of the PS101 story)."""
-    from kafka_ps_tpu.models import logreg
+    from kafka_ps_tpu.models.task import LogRegTask
     from kafka_ps_tpu.utils.config import ModelConfig
 
     cfg = ModelConfig(num_features=4, num_classes=3)
+    task = LogRegTask(cfg)
     theta = jnp.zeros((cfg.num_params,), jnp.float32)
     y = jnp.zeros((8,), jnp.int32)
     mask = jnp.ones((8,), jnp.float32)
@@ -247,10 +247,10 @@ def test_decode_fused_into_solver_traces_once():
                    jnp.zeros((8, 4), jnp.bfloat16),
                    QuantizedSlab(q=jnp.zeros((8, 4), jnp.int8),
                                  scale=jnp.ones((8, 1), jnp.float32))):
-        logreg.local_update(theta, stored, y, mask, cfg=cfg)  # warm
+        task.local_update(theta, stored, y, mask)  # warm
         warm = slab_mod.TRACE_COUNTS["decode"]
         for _ in range(10):
-            logreg.local_update(theta, stored, y, mask, cfg=cfg)
+            LogRegTask(cfg).local_update(theta, stored, y, mask)
         assert slab_mod.TRACE_COUNTS["decode"] == warm
 
 

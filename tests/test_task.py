@@ -10,7 +10,7 @@ import pytest
 
 from kafka_ps_tpu.data.synth import generate
 from kafka_ps_tpu.models import logreg, mlp
-from kafka_ps_tpu.models.task import LogRegTask, get_task
+from kafka_ps_tpu.models.task import LogRegTask, fit_delta, get_task
 from kafka_ps_tpu.parallel import bsp, mesh as mesh_mod, range_sharded
 from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
 
@@ -31,11 +31,17 @@ def test_registry_and_unknown_task():
 
 
 def test_logreg_task_matches_direct_path():
+    """The task's flat update is models/logreg.py's own `fit`, called
+    directly on the leaves."""
     task = get_task("logreg", CFG)
     x, y, mask = _data()
     theta = jnp.zeros(CFG.num_params)
     d_task, l_task = task.local_update(theta, x, y, mask)
-    d_ref, l_ref = logreg.local_update(theta, x, y, mask, cfg=CFG)
+    old = logreg.unflatten(theta, CFG)
+    new, l_ref = jax.jit(logreg.fit, static_argnames="cfg")(
+        old, x, jax.nn.one_hot(y, CFG.num_rows, dtype=jnp.float32), mask,
+        cfg=CFG)
+    d_ref = jax.tree.map(jnp.subtract, new, old).flat
     np.testing.assert_array_equal(np.asarray(d_task), np.asarray(d_ref))
     assert float(l_task) == float(l_ref)
 
@@ -230,20 +236,60 @@ def test_flatten_inverts_unflatten_through_the_protocol(family):
                                   np.asarray(theta))
 
 
-@pytest.mark.parametrize("family", ["mlp", "logreg"])
+# -- the flat face, written once (models/task.py FlatFace) ---------------------
+
+GLM_TINY = "benchmark/families/glm4-moe-lite/tiny.model.json"
+ALL_FAMILIES = ["mlp", "logreg", "glm4_moe_lite"]
+
+
+def _flat_case(family):
+    """Every registered family at its tiny size: (task, theta, rows,
+    labels in range, mask)."""
+    if family != "glm4_moe_lite":
+        cfg, task, theta, x, y, mask = _solver_case(family, 2)
+        return task, theta, x, jnp.clip(y, 0, cfg.num_rows - 1), mask
+    task = get_task(family, ModelConfig(
+        num_max_iter=2, local_learning_rate=0.05, model_json=GLM_TINY))
+    rng = np.random.default_rng(11)
+    rows = jnp.asarray(rng.integers(
+        0, task.arch.vocab_held, size=(3, task.row_width)), jnp.int32)
+    theta = task.init_params() + 0.02 * jax.random.normal(
+        jax.random.PRNGKey(2), (task.num_params,))
+    # a token row carries its own labels; the label column is ignored
+    return (task, theta, rows, jnp.zeros((3,), jnp.int32),
+            jnp.asarray([1.0, 1.0, 0.0]))
+
+
+@pytest.mark.parametrize("family", ALL_FAMILIES)
 def test_flat_wrapper_is_flatten_of_the_leaf_level_fit(family):
     """One solver a family: `local_update` is unflatten, `fit`, the
-    difference, flatten — and `evaluate` is `evaluate_leaves`."""
-    cfg, task, theta, x, y, mask = _solver_case(family, 2)
-    onehot = jax.nn.one_hot(y, cfg.num_rows, dtype=jnp.float32)
+    difference (`fit_delta`), flatten — bitwise."""
+    task, theta, x, y, mask = _flat_case(family)
     leaves = task.unflatten(theta)
-    new, loss = jax.jit(task.fit)(leaves, x, onehot, mask)
-    delta, loss_flat = jax.jit(task.local_update)(theta, x, y, mask)
-    np.testing.assert_array_equal(
-        np.asarray(delta),
-        np.asarray(task.flatten(jax.tree.map(jnp.subtract, new, leaves))))
+    want, loss = jax.jit(lambda *a: fit_delta(task, *a))(
+        leaves, x, task.encode_labels(y), mask)
+    delta, loss_flat = task.local_update(theta, x, y, mask)
+    assert np.asarray(delta).any()
+    np.testing.assert_array_equal(np.asarray(delta),
+                                  np.asarray(task.flatten(want)))
     assert float(loss_flat) == float(loss)
-    y_ok = jnp.clip(y, 0, cfg.num_rows - 1)
-    m_flat = task.evaluate(theta, x, y_ok)
-    m_leaf = jax.jit(task.evaluate_leaves)(leaves, x, y_ok)
+
+
+@pytest.mark.parametrize("family", ALL_FAMILIES)
+def test_flat_evaluate_is_evaluate_leaves(family):
+    task, theta, x, y, _ = _flat_case(family)
+    m_flat = task.evaluate(theta, x, y)
+    m_leaf = jax.jit(task.evaluate_leaves)(task.unflatten(theta), x, y)
     assert [float(v) for v in m_flat] == [float(v) for v in m_leaf]
+
+
+@pytest.mark.parametrize("family", ALL_FAMILIES)
+def test_evaluate_batch_row_is_evaluate(family):
+    """Row i of the stacked evaluation is `evaluate(thetas[i])`,
+    bitwise — what lets the eval engine coalesce a backlog."""
+    task, theta, x, y, _ = _flat_case(family)
+    thetas = jnp.stack([theta, theta * 1.5, theta * 0.5])
+    batch = task.evaluate_batch(thetas, x, y)
+    for i in range(3):
+        one = task.evaluate(thetas[i], x, y)
+        assert [float(v[i]) for v in batch] == [float(v) for v in one]

@@ -251,8 +251,8 @@ def test_last_run_counts_the_refresh_and_its_bytes():
 
 @functools.lru_cache(maxsize=None)
 def lowered_text(program: str) -> str:
-    from kafka_ps_tpu.models import logreg, mlp
-    from kafka_ps_tpu.models.task import fit_delta
+    from kafka_ps_tpu.models import mlp
+    from kafka_ps_tpu.models.task import LogRegTask, fit_delta
     from kafka_ps_tpu.parallel import bsp
     from kafka_ps_tpu.runtime import gang
     cfg = ModelConfig(num_features=16, num_classes=3, hidden_dim=8)
@@ -270,8 +270,9 @@ def lowered_text(program: str) -> str:
                                          xx, oo, mm))(xs, os, ms)
                       ).lower(theta, x, onehot, mask)
     elif program == "logreg_local_update":
-        low = logreg.local_update.lower(
-            logreg.init_params(cfg).flat, x[0], y[0], mask[0], cfg=cfg)
+        logreg = LogRegTask(cfg)
+        low = jax.jit(logreg.local_update).lower(
+            logreg.init_params(), x[0], y[0], mask[0])
     elif program == "bsp_step":
         low = bsp.make_bsp_step(cfg, 2, 1.0, task=task).lower(
             theta, x, y, mask)
@@ -284,7 +285,7 @@ def lowered_text(program: str) -> str:
             cfg, 2, 1.0, 8, mesh=worker_mesh(2), task=task).lower(
                 theta, x, y, mask)
     elif program == "gang":
-        fns = gang._gang_solver_fns("mlp", cfg, False)
+        fns = gang._gang_solver_fns("mlp", cfg)
         low = fns["update_eval_bcast"].lower(
             theta, tuple(x), tuple(y), tuple(mask), x[0], y[0])
     return low.as_text(debug_info=True)
