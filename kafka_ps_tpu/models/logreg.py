@@ -3,8 +3,8 @@
 TPU-native re-design of the reference's ml/LogisticRegressionTaskSpark.java:
 instead of wrapping a JVM solver (Spark MLlib LBFGS, reference :179-184), the
 whole "k local solver iterations on the buffer → emit weight delta" contract
-(reference :179-220) is one jit'd XLA program: a `lax.scan` over k full-batch
-gradient steps.  Dead-simple dense math that XLA fuses onto the MXU — the
+(reference :179-220) is one jit'd XLA program: k full-batch gradient
+steps.  Dead-simple dense math that XLA fuses onto the MXU — the
 batch matmul (cap × F) @ (F × C+1) is the hot op.
 
 Parameter layout (LogisticRegressionTaskSpark.java:98-104,122-140): a flat
@@ -117,24 +117,32 @@ def fit(params: LogRegParams, x: jax.Array, onehot: jax.Array,
     The reference's "gradient" is a k-step local-solver delta
     (newWeights − oldWeights after maxIter=2 LBFGS steps,
     LogisticRegressionTaskSpark.java:179-220) — local-SGD/FedAvg-style.
-    We implement k full-batch gradient-descent steps as a `lax.scan`
-    so the whole thing is one fused XLA program; the capability
+    We implement k full-batch gradient-descent steps in one fused XLA
+    program, by the loop the classifier families share (models/task.py
+    `local_steps`: the first step reads `params` as handed in, shared
+    by every worker under a BSP `vmap`, and a worker's own copy of the
+    leaves first exists as that step's result; the steps after it are a
+    `lax.scan`); the capability
     ("k local solver steps, delta exchanged") is what is matched, not
     Spark's line-search trajectory (documented divergence, SURVEY §7).
     The `kps.fit.*` scopes are models/mlp.py's: metadata a device trace
     splits the time by."""
+    # imported here: models/task.py imports this module for its default
+    # family
+    from kafka_ps_tpu.models.task import local_steps
     lr = cfg.local_learning_rate
 
-    def step(p, _):
+    def step(p):
         with jax.named_scope("kps.fit.grad"):
             g, _ = grad_loss_onehot(p, x, onehot, mask)
         with jax.named_scope("kps.fit.param_step"):
-            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+            return jax.tree.map(lambda a, b: a - lr * b, p, g)
 
-    new, _ = jax.lax.scan(step, params, None, length=cfg.num_max_iter)
-    with jax.named_scope("kps.fit.loss"):
-        _, final_loss = grad_loss_onehot(new, x, onehot, mask)
-    return new, final_loss
+    def loss(p):
+        with jax.named_scope("kps.fit.loss"):
+            return grad_loss_onehot(p, x, onehot, mask)[1]
+
+    return local_steps(step, loss, params, cfg.num_max_iter)
 
 
 def sparse_to_dense(rows: list[dict[int, float]], num_features: int) -> np.ndarray:

@@ -18,6 +18,13 @@ and re-laid it out as a matrix, and re-laid the gradient out to be
 concatenated back — three 1 GB copies a step at 64 workers x H=4096,
 a third of the solver's device time (PERF.md §6, PR 25).
 
+A worker's own copy of the leaves comes into being as the result of its
+first local step, not before it: `fit` takes that step from the leaves
+as they were handed in, which every worker of a BSP clock shares, so
+its forward is one product over all workers' rows and no
+`[workers, H, F]` array is written for it to read (models/task.py
+`local_steps`; PERF.md §6, PR 30).
+
 Gradients come from `jax.grad`: safe here because every caller
 (parallel/bsp.py, parallel/range_sharded.py) marks the parameters
 device-varying with `pcast(..., to="varying")` before differentiating
@@ -123,21 +130,27 @@ class MLPTask(task_mod.RowsWithClassLabel):
 
 def fit(params: MLPParams, x, onehot, mask, *, cfg: ModelConfig):
     """cfg.num_max_iter full-batch steps on the leaves → (new leaves,
-    loss at them).  The `kps.fit.*` scopes name each part in the
-    operations' metadata, so a device trace splits the solver's time by
-    them (metadata only)."""
+    loss at them), by the loop every classifier family shares
+    (models/task.py `local_steps`): the first step reads `params` as
+    handed in — shared by every worker under the fused round's `vmap`,
+    so one product serves them all — and a worker's own copy of the
+    leaves first exists as that step's result.  The `kps.fit.*` scopes
+    name each part in the operations' metadata, so a device trace
+    splits the solver's time by them (metadata only)."""
     lr = cfg.local_learning_rate
     grad = jax.grad(loss_onehot)
 
-    def step(p, _):
+    def step(p):
         with jax.named_scope("kps.fit.grad"):
             g = grad(p, x, onehot, mask)
         with jax.named_scope("kps.fit.param_step"):
-            return jax.tree.map(lambda a, b: a - lr * b, p, g), None
+            return jax.tree.map(lambda a, b: a - lr * b, p, g)
 
-    new, _ = jax.lax.scan(step, params, None, length=cfg.num_max_iter)
-    with jax.named_scope("kps.fit.loss"):
-        return new, loss_onehot(new, x, onehot, mask)
+    def loss(p):
+        with jax.named_scope("kps.fit.loss"):
+            return loss_onehot(p, x, onehot, mask)
+
+    return task_mod.local_steps(step, loss, params, cfg.num_max_iter)
 
 
 def evaluate_leaves(params: MLPParams, x_test, y_test, *, cfg: ModelConfig):

@@ -98,6 +98,35 @@ class MLTask(Protocol):
     def predict_logits(self, theta, x) -> jax.Array: ...
 
 
+def local_steps(step, loss, params, k: int):
+    """A classifier family's k local steps, written once: `step` (leaves
+    → leaves) k times from `params`, then `loss` at the result →
+    (new leaves, loss).
+
+    The FIRST step is taken from `params` as they were handed in; only
+    the k − 1 steps after it are a `lax.scan`, whose carry is the first
+    step's result (no scan at k = 1).  A scan's carry has one type, so
+    under a `vmap` over workers whose leaves are shared (parallel/bsp.py
+    `_make_round`, the gang's `update_bcast` / `update_eval_bcast`) a
+    scan from `params` began with the leaves copied out to every worker
+    — 1.08 GB of W1 at 64 workers x H=4096, written, read by a batched
+    forward and read again by the parameter step, before any worker
+    differed from another.  Peeled, the first forward is one product
+    over all workers' rows that reads each weight once, and a worker's
+    first own copy of the parameters is what its first parameter step
+    writes: the shared leaf less its own gradient (PERF.md §6, PR 30).
+    Leaves that carry the worker axis already (the gang's stacked
+    members, a single worker's program) do in the first step what the
+    scan's first iteration did."""
+    if k < 1:
+        raise ValueError(f"a local update takes at least one step, not {k}")
+    new = step(params)
+    if k > 1:
+        new, _ = jax.lax.scan(lambda p, _: (step(p), None), new, None,
+                              length=k - 1)
+    return new, loss(new)
+
+
 def fit_delta(task: MLTask, leaves, x, onehot, mask):
     """k local steps from `leaves` → (delta leaves, loss at the new
     parameters)."""

@@ -62,7 +62,12 @@ def _make_round(task, num_workers: int, server_lr: float, psum_axis: bool):
     No array of shape [workers, num_params] is built: a TPU tiles one
     over (worker, key), and cutting W1 out of it and putting the
     gradient back were relayouts of every worker's parameters every
-    local step (PERF.md §6, PR 25)."""
+    local step (PERF.md §6, PR 25).  Nor is one of shape
+    [workers, …leaf] built before a worker has a gradient: the leaves
+    go into the `vmap` unbatched, the fit's first step reads them so
+    (one product over every worker's rows; models/task.py
+    `local_steps`), and a worker's first own copy of a leaf is what its
+    first parameter step writes (PERF.md §6, PR 30)."""
 
     def round_(theta, x, onehot, mask):
         leaves = task.unflatten(theta)

@@ -16,13 +16,14 @@ advisory `GangNotice` on GANG_TOPIC alongside the per-worker messages),
 runs `_prepare` on every member (each keeps its private buffer slab and
 `num_tuples_seen` version), stacks the member slabs, and runs ONE
 vmapped solver dispatch over the (k, …) batch — the parameters' leaves
-broadcast when the set shares one weights array (sequential
-consistency: the server aliases the same device theta into every
-member's message), stack otherwise (bounded/eventual sets with
-differing clocks).  The k flat deltas and metric futures are fanned out
-INSIDE the jit (one dispatch, k buffers out; `_gang_solver_fns` says
-what that costs on the device), then `_finish` runs per member in
-worker-id order — the
+go in once, unbatched, when the set shares one weights array
+(sequential consistency: the server aliases the same device theta into
+every member's message; the first local step reads that one set for
+all members, models/task.py `local_steps`), stacked otherwise
+(bounded/eventual sets with differing clocks).  The k flat deltas and
+metric futures are fanned out INSIDE the jit (one dispatch, k buffers
+out; `_gang_solver_fns` says what that costs on the device), then
+`_finish` runs per member in worker-id order — the
 same per-worker CSV rows and the same per-worker GradientMessages, in
 the same order, as the per-message path.  Bitwise equivalence with the
 per-message path is a tested invariant (tests/test_gang.py), not an

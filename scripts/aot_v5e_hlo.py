@@ -97,28 +97,38 @@ def compile_program(program: str, topo, *, hidden: int = 4096,
     return fn.lower(*args).compile()
 
 
+def computations(hlo_text: str) -> dict[str, list[str]]:
+    """Every computation's instruction lines, in the order of the text
+    (of a compiled module: the schedule's)."""
+    comps: dict[str, list[str]] = {}
+    comp = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head:
+            comp = comps[head.group(1)] = []
+        elif comp is not None and " = " in line:
+            comp.append(line)
+    return comps
+
+
 def top_level_instructions(hlo_text: str):
     """(computation, name, line, result bytes, XLA's estimated cycles)
     for every instruction that is not inside a fused computation: what
     the device runs one after another."""
     fused = set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", hlo_text))
     out = []
-    comp = None
-    for line in hlo_text.splitlines():
-        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
-        if head:
-            comp = head.group(1)
-            continue
-        m = _RESULT.match(line)
-        if comp is None or comp in fused or not m:
-            continue
-        name, dtype, dims = m.groups()
-        size = _DTYPE_BYTES.get(dtype, 4)
-        for d in filter(None, dims.split(",")):
-            size *= int(d)
-        cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
-        out.append((comp, name, line.strip(), size,
-                    int(cycles.group(1)) if cycles else 0))
+    for comp, lines in computations(hlo_text).items():
+        for line in lines:
+            m = _RESULT.match(line)
+            if comp in fused or not m:
+                continue
+            name, dtype, dims = m.groups()
+            size = _DTYPE_BYTES.get(dtype, 4)
+            for d in filter(None, dims.split(",")):
+                size *= int(d)
+            cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
+            out.append((comp, name, line.strip(), size,
+                        int(cycles.group(1)) if cycles else 0))
     return out
 
 
@@ -129,6 +139,45 @@ def big_relayouts(hlo_text: str, at_least_bytes: int):
             for comp, name, _, size, _ in top_level_instructions(hlo_text)
             if size >= at_least_bytes
             and re.match(r"(copy|slice)[.\d]*$", name)]
+
+
+def in_run_order(hlo_text: str):
+    """The instruction lines of a scheduled module in the order the
+    device meets them: the entry computation's, and behind a `while`
+    its body's (once).  Fused computations are not entered."""
+    comps = computations(hlo_text)
+    entry = re.search(r"^ENTRY %?([\w.\-]+) ", hlo_text, re.M).group(1)
+
+    def walk(name):
+        for line in comps[name]:
+            yield line
+            body = re.search(r" while\(.* body=%?([\w.\-]+)", line)
+            if body:
+                yield from walk(body.group(1))
+    return walk(entry)
+
+
+def made_before(hlo_text: str, dtype: str, dims: tuple, until: str):
+    """Names of the instructions that MAKE a `dtype[dims]` array (not a
+    parameter, a tuple's element or a bitcast of one) before the first
+    instruction whose `op_name` matches the pattern `until` runs.
+    Raises where none matches: the reader would see nothing."""
+    shape = ",".join(str(d) for d in dims)
+    made = []
+    for line in in_run_order(hlo_text):
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if op_name and re.search(until, op_name.group(1)):
+            return made
+        m = _RESULT.match(line)
+        if m and m.group(2) == dtype and m.group(3) == shape and not \
+                re.search(r" (parameter|get-tuple-element|bitcast)\(", line):
+            made.append(m.group(1))
+    raise ValueError(f"no instruction's op_name matches {until!r}")
+
+
+# the first matrix product of a local update's first gradient (a hoisted
+# sum of the mask lies under the scope too, and runs earlier)
+FIRST_GRAD_PRODUCT = r"kps\.fit\.grad.*dot_general"
 
 
 def main(argv=None) -> int:
@@ -173,6 +222,10 @@ def main(argv=None) -> int:
         bad = big_relayouts(text, (args.workers * w1_bytes) // 2)
         print(f"   copy/slice results of half of workers x W1 or more: "
               f"{[(c[:24], n) for c, n, _ in bad] or 'none'}")
+        early = made_before(text, "f32", (args.workers, args.hidden, 1024),
+                            FIRST_GRAD_PRODUCT)
+        print(f"   f32[workers, H, F] made before the first gradient's "
+              f"first product: {early or 'none'}")
     return 0
 
 

@@ -226,6 +226,102 @@ def test_local_update_matches_the_flat_carry_solver(family, k):
     assert float(l_new) == float(l_ref)
 
 
+# -- the k local steps, written once (models/task.py local_steps, PR 30) -------
+
+WORKERS = 3
+
+
+def _workers_case(family, k, stacked):
+    """WORKERS slabs and the leaves they start from: one set shared by
+    all, or each worker's own (stacked on a leading axis)."""
+    cfg, task, theta, x, y, mask = _solver_case(family, k)
+    slabs = [_data(cfg=cfg, seed=20 + w) for w in range(WORKERS)]
+    xs = jnp.stack([s[0] for s in slabs])
+    onehots = jnp.stack([task.encode_labels(s[1]) for s in slabs])
+    masks = jnp.stack([mask] * WORKERS)
+    if not stacked:
+        return cfg, task, task.unflatten(theta), xs, onehots, masks
+    own = [task.unflatten(theta + 0.01 * jax.random.normal(
+        jax.random.PRNGKey(40 + w), theta.shape)) for w in range(WORKERS)]
+    return (cfg, task, jax.tree.map(lambda *a: jnp.stack(a), *own),
+            xs, onehots, masks)
+
+
+def steps_written_out(family, cfg, leaves, x, onehot, mask):
+    """k steps one after another, no loop construct and no worker axis
+    → (delta leaves, loss at the last parameters)."""
+    if family == "mlp":
+        def grad_loss(p):
+            return (jax.grad(mlp.loss_onehot)(p, x, onehot, mask),
+                    mlp.loss_onehot(p, x, onehot, mask))
+    else:
+        def grad_loss(p):
+            return logreg.grad_loss_onehot(p, x, onehot, mask)
+    p = leaves
+    for _ in range(cfg.num_max_iter):
+        p = jax.tree.map(lambda a, g: a - cfg.local_learning_rate * g,
+                         p, grad_loss(p)[0])
+    return jax.tree.map(jnp.subtract, p, leaves), grad_loss(p)[1]
+
+
+@pytest.mark.parametrize("stacked", [False, True],
+                         ids=["leaves-shared", "leaves-stacked"])
+@pytest.mark.parametrize("k", [1, 2, 3])
+@pytest.mark.parametrize("family", ["mlp", "logreg"])
+def test_local_steps_are_k_steps_written_out(family, k, stacked):
+    """`fit_delta` under the worker `vmap` — leaves shared by every
+    worker, as the fused round and the gang's bcast programs hand them,
+    or each worker's own — against k hand-written steps a worker: the
+    same deltas and the same loss, to the bit on the CPU (the first
+    step's one product over all workers' rows gives each row what the
+    worker's own product gives it)."""
+    cfg, task, leaves, xs, onehots, masks = _workers_case(family, k, stacked)
+    deltas, losses = jax.jit(jax.vmap(
+        lambda l, x, o, m: fit_delta(task, l, x, o, m),
+        in_axes=(0 if stacked else None, 0, 0, 0)))(
+            leaves, xs, onehots, masks)
+    for w in range(WORKERS):
+        own = jax.tree.map(lambda a: a[w], leaves) if stacked else leaves
+        d_ref, l_ref = jax.jit(
+            lambda l, x, o, m: steps_written_out(family, cfg, l, x, o, m))(
+                own, xs[w], onehots[w], masks[w])
+        for got, ref in zip(jax.tree.leaves(deltas), jax.tree.leaves(d_ref)):
+            assert np.asarray(ref).any()
+            np.testing.assert_array_equal(np.asarray(got[w]),
+                                          np.asarray(ref))
+        assert float(losses[w]) == float(l_ref)
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+@pytest.mark.parametrize("k", [1, 2, 3])
+@pytest.mark.parametrize("family", ["mlp", "logreg"])
+def test_first_local_step_reads_the_shared_leaves(family, k):
+    """What the program is, read from its jaxpr: under a worker `vmap`
+    of shared leaves the first product multiplies ALL workers' rows by
+    the one shared matrix (no worker axis on the weights), and the
+    steps after the first are one scan of k - 1 (none at k = 1)."""
+    cfg, task, leaves, xs, onehots, masks = _workers_case(family, k, False)
+    eqns = list(_equations(jax.make_jaxpr(jax.vmap(
+        lambda x, o, m: fit_delta(task, leaves, x, o, m)))(
+            xs, onehots, masks).jaxpr))
+    first = next(e for e in eqns if e.primitive.name == "dot_general")
+    assert sorted(len(v.aval.shape) for v in first.invars) == [2, 3]
+    scans = [e for e in eqns if e.primitive.name == "scan"]
+    assert [e.params["length"] for e in scans] == ([k - 1] if k > 1 else [])
+
+
+def test_a_local_update_takes_at_least_one_step():
+    cfg, task, theta, x, y, mask = _solver_case("logreg", 0)
+    with pytest.raises(ValueError, match="at least one step"):
+        task.local_update(theta, x, y, mask)
+
+
 @pytest.mark.parametrize("family", ["mlp", "logreg"])
 def test_flatten_inverts_unflatten_through_the_protocol(family):
     task = get_task(family, CFG)
