@@ -58,7 +58,8 @@ def build_parser(include_server_flags: bool = True,
                         "BaseKafkaApp.java:25)")
     p.add_argument("--num_features", type=int, default=1024)
     p.add_argument("--num_classes", type=int, default=5)
-    p.add_argument("--task", choices=["logreg", "mlp", "glm4_moe_lite"],
+    p.add_argument("--task", choices=["logreg", "mlp", "glm4_moe_lite",
+                                      "nemotron_h"],
                    default="logreg",
                    help="model family (models/task.py registry); logreg "
                         "is the reference's task")
@@ -66,10 +67,10 @@ def build_parser(include_server_flags: bool = True,
                    help="hidden width of the mlp task")
     p.add_argument("--model_json", default=None,
                    help="the model family's own configuration file "
-                        "(--task glm4_moe_lite: the published keys and "
-                        "the cut held here, models/glm4_moe_lite.py); a "
-                        "relative path is taken from the repository's "
-                        "root")
+                        "(a language-model family, --task glm4_moe_lite "
+                        "or nemotron_h: the published keys and the cut "
+                        "held here, models/lm_common.py); a relative "
+                        "path is taken from the repository's root")
     p.add_argument("--local_iterations", type=int, default=2,
                    help="k local solver steps per iteration "
                         "(numMaxIter, LogisticRegressionTaskSpark.java:35)")
@@ -367,35 +368,55 @@ _PAGES_A_CLASSIFIER = ("tiered residency pages a flat classifier theta "
                        "(kafka_ps_tpu/store/)")
 _NO_MESH = ("its workers are folded one at a time on one device; it has "
             "no program over a mesh (parallel/bsp.py)")
-TASK_REFUSES = {"glm4_moe_lite": {
-    "compress": ("none", "the wire codecs were sized for deltas of "
-                         "megabytes, and this family's delta does not "
-                         "cross serde in one message"),
-    "slab_dtype": ("f32", "its rows are int32 tokens, stored as they are "
-                          "(compress/slab.py)"),
-    "tier_hot_bytes": (0, _PAGES_A_CLASSIFIER),
-    "tier_warm_bytes": (0, _PAGES_A_CLASSIFIER),
-    "remote": (False, _NO_MESH),
-    "param_shards": (1, _NO_MESH)}}
+
+
+def _token_rows(task) -> bool:
+    import numpy as np
+    return np.dtype(task.row_dtype).kind == "i"
+
+
+# (what a task says of itself, models/task.py; the levers it then
+# refuses: what `args` holds when the lever is off, and why)
+TASK_REFUSES = (
+    # a family with a file of its own
+    (lambda task: task.model_file, {
+        "compress": ("none", "the wire codecs were sized for deltas of "
+                             "megabytes, and this family's delta does not "
+                             "cross serde in one message"),
+        "tier_hot_bytes": (0, _PAGES_A_CLASSIFIER),
+        "tier_warm_bytes": (0, _PAGES_A_CLASSIFIER)}),
+    # rows that are tokens
+    (_token_rows, {
+        "slab_dtype": ("f32", "its rows are int32 tokens, stored as they "
+                              "are (compress/slab.py)")}),
+    # no program over a mesh
+    (lambda task: not task.batches_workers, {
+        "remote": (False, _NO_MESH),
+        "param_shards": (1, _NO_MESH)}))
 
 
 def refuse_levers(args) -> None:
     """One message, with the reason, for every lever asked for that
-    `--task` cannot hold — before any program is built."""
-    asked = [(flag, why) for flag, (off, why)
-             in TASK_REFUSES.get(args.task, {}).items()
+    `--task` cannot hold — before any program is built.  What a task
+    cannot hold follows from what its family says of itself, never from
+    its name."""
+    from kafka_ps_tpu.models.task import task_class
+    task = task_class(args.task)
+    asked = [(flag, why) for says, levers in TASK_REFUSES if says(task)
+             for flag, (off, why) in levers.items()
              if (getattr(args, flag, off) or off) != off]
     if asked:
         raise SystemExit(
             f"--task {args.task} cannot run with "
             + "; ".join(f"--{flag.replace('_', '-')}: {why}"
                         for flag, why in asked))
-    if args.task == "glm4_moe_lite" and not args.model_json:
-        raise SystemExit("--task glm4_moe_lite needs --model_json FILE, "
+    if task.model_file and not args.model_json:
+        raise SystemExit(f"--task {args.task} needs --model_json FILE, "
                          "the family's own configuration")
-    if args.model_json and args.task != "glm4_moe_lite":
-        raise SystemExit(f"--model_json configures --task glm4_moe_lite; "
-                         f"--task {args.task} has no file of its own")
+    if args.model_json and not task.model_file:
+        raise SystemExit(f"--model_json configures a family with a file of "
+                         f"its own; --task {args.task} has no file of its "
+                         "own")
 
 
 def cfg_from_args(args):

@@ -9,23 +9,15 @@ run as grouped matrix products over the experts HELD HERE, a
 multi-token-prediction (MTP) module, a sliced vocabulary, and a loss
 that is the mean next-token cross-entropy.
 
-The family's own configuration is one JSON file (`--model_json`,
-`ModelConfig.model_json`): the published keys and the cut —
-`experts_held` / `expert_offset` (which of the `n_routed_experts` this
-process holds), `vocab_held` (its slice of the vocabulary),
-`num_hidden_layers` and `sequence_length`.
+Token rows, the norm, the router, the expert layer that knows its
+share, the head and its loss, the flat key space, the solver with its
+counters and the task's frame are `models/lm_common.py`'s, shared with
+the other language-model family; what is here is this family's own:
+its configuration, its leaves, latent attention, its SwiGLU experts
+and the MTP module.
 
-The expert layer knows its share: it routes every token over ALL
-`n_routed_experts`, computes what its own experts give for the tokens
-routed to them plus the shared expert, and leaves out what the absent
-experts would add — that partial result is what goes on to the next
-layer.  No token is dropped and none is padded to a capacity: the
-assignments are sorted by expert and run through `jax.lax.ragged_dot`,
-which computes the rows of each held expert's group and no others.
-Nothing stands in for the absent chips or their exchange.
-
-Leaves are a flat dict `{dotted name: array}`; `leaf_specs` fixes their
-order in the flat key space (the wire contract).  The expert layers'
+`leaf_specs` fixes the leaves' order in the flat key space (the wire
+contract).  The expert layers'
 leaves are stacked on a leading layer axis and scanned; every layer is
 recomputed in the backward pass (`jax.checkpoint`), so one worker's
 activations stay a few layers' worth.
@@ -43,27 +35,13 @@ block, its own final norm, the shared head — its loss added at weight
 from __future__ import annotations
 
 import dataclasses
-import functools
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
-from kafka_ps_tpu.models import metrics as metrics_mod
-from kafka_ps_tpu.models import task as task_mod
-from kafka_ps_tpu.utils.config import ModelConfig
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-
-# what `fit_counted` returns beside the loss, in this order
-# (Tracer.count names; runtime/app.py sums them over a drive call)
-COUNTERS = ("moe.assignments_here", "moe.assignments_here_grad",
-            "moe.assignments_away", "moe.expert_load_max",
-            "data.tokens", "data.pad_tokens", "moe.passes_over_bound")
+from kafka_ps_tpu.models import lm_common as lm
+from kafka_ps_tpu.models.lm_common import rms_norm, sub
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,38 +99,13 @@ class Glm4Config:
             raise ValueError("num_hidden_layers must leave an expert layer")
         if self.num_nextn_predict_layers not in (0, 1):
             raise ValueError("num_nextn_predict_layers is 0 or 1")
-        if not (0 <= self.expert_offset and self.expert_offset
-                + self.experts_held <= self.n_routed_experts):
-            raise ValueError("expert_offset + experts_held must lie inside "
-                             "n_routed_experts")
-        if not 0 < self.vocab_held <= self.vocab_size:
-            raise ValueError("vocab_held must lie inside vocab_size")
+        lm.validate_cut(self)
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even")
 
 
-def resolve_model_json(path: str) -> str:
-    """An absolute path as it is; a relative one from the repository's
-    root (the benchmark's configurations carry a relative path and are
-    run from any directory)."""
-    return path if os.path.isabs(path) else os.path.join(REPO_ROOT, path)
-
-
-@functools.lru_cache(maxsize=None)
 def load_config(path: str) -> Glm4Config:
-    with open(resolve_model_json(path)) as fh:
-        body = json.load(fh)
-    if body.get("model_type") != "glm4_moe_lite":
-        raise ValueError(f"{path}: model_type {body.get('model_type')!r} is "
-                         "not glm4_moe_lite")
-    fields = {f.name for f in dataclasses.fields(Glm4Config)}
-    missing = [f.name for f in dataclasses.fields(Glm4Config)
-               if f.default is dataclasses.MISSING and f.name not in body]
-    if missing:
-        raise ValueError(f"{path}: missing keys {missing}")
-    c = Glm4Config(**{k: v for k, v in body.items() if k in fields})
-    c.validate()
-    return c
+    return lm.load_config(path, "glm4_moe_lite", Glm4Config)
 
 
 # -- the flat key space --------------------------------------------------------
@@ -198,34 +151,6 @@ def leaf_specs(c: Glm4Config) -> list[tuple[str, tuple[int, ...]]]:
     return out
 
 
-def num_params(c: Glm4Config) -> int:
-    return sum(math.prod(s) for _, s in leaf_specs(c))
-
-
-def unflatten(theta, c: Glm4Config) -> dict:
-    """The leaves of a flat vector.  Each is cut out before it is
-    shaped (the barrier): left to itself the compiler shapes the WHOLE
-    vector as `[P / 64, 64]` to cut a router out of it, a padded 4.7 GB
-    copy at the published widths."""
-    out, at = {}, 0
-    for name, shape in leaf_specs(c):
-        n = math.prod(shape)
-        out[name] = jax.lax.optimization_barrier(
-            theta[at:at + n]).reshape(shape)
-        at += n
-    return out
-
-
-def flatten(leaves: dict, c: Glm4Config) -> jax.Array:
-    return jnp.concatenate([leaves[name].reshape(-1)
-                            for name, _ in leaf_specs(c)])
-
-
-def sub(leaves: dict, prefix: str) -> dict:
-    return {k[len(prefix):]: v for k, v in leaves.items()
-            if k.startswith(prefix)}
-
-
 def init_leaves(c: Glm4Config) -> dict:
     """normal(0, init_std) from `init_seed`, one key a leaf by its
     place in the layout; norms one, the selection bias zero."""
@@ -244,11 +169,6 @@ def init_leaves(c: Glm4Config) -> dict:
 
 
 # -- the layers ------------------------------------------------------------------
-
-def rms_norm(x, w, eps: float):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                             + eps) * w
-
 
 def rope(x, theta: float):
     """Rotate-half RoPE over the whole last axis; positions run along
@@ -291,98 +211,23 @@ def swiglu(h, w_gate, w_up, w_down):
     return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
 
 
-def route(h, router, bias, c: Glm4Config):
-    """Every token over ALL experts → (chosen experts [T, K], their
-    weights [T, K]).  float32 at `highest` precision, as the published
-    gate computes it."""
-    with jax.named_scope("kps.moe.route"):
-        s = jax.nn.sigmoid(jnp.dot(h, router,
-                                   precision=jax.lax.Precision.HIGHEST))
-        _, idx = jax.lax.top_k(s + bias, c.num_experts_per_tok)
-        w = jnp.take_along_axis(s, idx, axis=-1)
-        if c.norm_topk_prob:
-            w = w / (w.sum(-1, keepdims=True) + 1e-20)
-        return idx, w * c.routed_scaling_factor
+def swiglu_experts(xs, p: dict, dot):
+    """What `lm_common.routed_experts` is handed: one held expert on its
+    own rows, every expert at once; `dot` is the grouped product over
+    the sorted assignments."""
+    return dot(jax.nn.silu(dot(xs, p["e_gate"])) * dot(xs, p["e_up"]),
+               p["e_down"])
 
 
-def live_rows_bound(slots: int, c: Glm4Config) -> int:
-    """How many of a pass's `slots` (token, chosen expert) assignments
-    the expert layer places without looking further: twice the even
-    share of the experts held here, in whole tiles of 8 rows.  A pass
-    that routes more here takes every slot instead (`routed_experts`)."""
-    even = slots * c.experts_held / c.n_routed_experts
-    return min(slots, 8 * math.ceil(2 * even / 8))
-
-
-def routed_experts(h, idx, w, p: dict, c: Glm4Config):
-    """The part of Σ w_e · SwiGLU_e(h) that the experts held here give
-    → ([T, H], (assignments here, largest expert's load, 1 if the pass
-    went over `live_rows_bound`)).
-
-    The (token, chosen expert) assignments are sorted by expert, absent
-    experts last; the held experts' products run as grouped products
-    over the sorted rows (`jax.lax.ragged_dot`: the chip's kernel
-    computes the rows of each group and no others, so its work follows
-    the routing and no assignment is dropped).  Only the sorted rows up
-    to `live_rows_bound` are placed and added back — the live ones come
-    first — unless the pass counts more assignments here than that:
-    then all T·K slots are, so none is ever dropped.  Tokens are placed
-    into sorted order, and results added back, by products with a 0/1
-    placement matrix rather than a gather and a scatter-add: a TPU
-    scatter costs over a microsecond a row, a fifth of the update when
-    it was written so.  Placing is exact at the default precision (one
-    term a row, and the grouped product rounds its operand the same
-    way); adding back runs at `HIGH`, which carries a float32 in three
-    pieces."""
-    with jax.named_scope("kps.moe.experts"):
-        t, k = idx.shape
-        held = c.experts_held
-        local = idx - c.expert_offset
-        here = (local >= 0) & (local < held)
-        # absent experts sort last, into a group that is never computed
-        key = jnp.where(here, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
-            axis=0, dtype=jnp.int32)
-        n_here = sizes.sum()
-        weight = jnp.where(here, w, 0.0).reshape(-1)[order]
-
-        def placed(rows: int):
-            """The sum from the first `rows` sorted assignments."""
-            live = (jnp.arange(rows) < n_here)[:, None]
-            place = jnp.where(live, jax.nn.one_hot(
-                order[:rows] // k, t, dtype=jnp.bfloat16), 0)
-            xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
-            act = (jax.nn.silu(jax.lax.ragged_dot(xs, p["e_gate"], sizes))
-                   * jax.lax.ragged_dot(xs, p["e_up"], sizes))
-            # rows past the last group are never computed: whatever
-            # the kernel leaves there must reach nothing
-            y = jnp.where(live, jax.lax.ragged_dot(act, p["e_down"], sizes),
-                          0.0)
-            return jnp.dot(place.T, y * weight[:rows, None],
-                           precision=jax.lax.Precision.HIGH,
-                           preferred_element_type=jnp.float32)
-
-        bound = live_rows_bound(t * k, c)
-        went_over = n_here > bound
-        out = (placed(t * k) if bound == t * k else
-               jax.lax.cond(went_over, functools.partial(placed, t * k),
-                            functools.partial(placed, bound)))
-        return out, jnp.stack([n_here, sizes.max(),
-                               went_over.astype(jnp.int32)])
+def _shared_expert(h, p: dict):
+    return swiglu(h, p["s_gate"], p["s_up"], p["s_down"])
 
 
 def moe(x, p: dict, c: Glm4Config):
     """Expert layer's MLP half on `[B, S, H]` (already normed) →
     (its output, (assignments here, largest load, went over the
     bound))."""
-    b, s, hd = x.shape
-    h = x.reshape(b * s, hd)
-    idx, w = route(h, p["router"], p["router_bias"], c)
-    y, load = routed_experts(h, idx, w, p, c)
-    with jax.named_scope("kps.moe.shared"):
-        y = y + swiglu(h, p["s_gate"], p["s_up"], p["s_down"])
-    return y.reshape(b, s, hd), load
+    return lm.expert_layer(x, p, c, swiglu_experts, _shared_expert)
 
 
 def dense_block(x, p: dict, c: Glm4Config):
@@ -398,11 +243,7 @@ def moe_block(x, p: dict, c: Glm4Config):
 
 
 def _head_nll(x, norm, head, targets, c: Glm4Config):
-    """Final norm, the head over the held slice, and each position's
-    negative log-likelihood of its target → ([B, S], logits)."""
-    logits = rms_norm(x, norm, c.rms_norm_eps) @ head
-    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return jax.nn.logsumexp(logits, axis=-1) - picked, logits
+    return lm.head_nll(x, norm, head, targets, c.rms_norm_eps)
 
 
 def forward(leaves: dict, rows, c: Glm4Config, *, with_logits=False):
@@ -459,116 +300,31 @@ def loss_and_counts(leaves: dict, rows, mask, c: Glm4Config):
     return (per_row * mask).sum() / positions, out["loads"].sum(0)
 
 
-def fit_counted(leaves: dict, rows, mask, *, c: Glm4Config, lr: float,
-                steps: int):
-    """`steps` full-batch SGD steps on a slab → (new leaves, the
-    objective at them, COUNTERS of the passes made)."""
-    grad = jax.value_and_grad(loss_and_counts, has_aux=True)
-    # the steps are written out, not scanned: a scan's carry starts as
-    # a copy of the shared leaves and is kept beside each step's result,
-    # two more copies of the parameters than the steps themselves need
-    new, counts = leaves, []
-    for _ in range(steps):
-        with jax.named_scope("kps.fit.grad"):
-            (_, counted), g = grad(new, rows, mask, c)
-        with jax.named_scope("kps.fit.param_step"):
-            new = jax.tree.map(lambda a, b: a - lr * b, new, g)
-        counts.append(counted)
-    counts = jnp.stack(counts)
-    with jax.named_scope("kps.fit.loss"):
-        loss, last = loss_and_counts(new, rows, mask, c)
-    blocks = c.num_moe_layers + c.num_nextn_predict_layers
-    per_pass = rows.shape[0] * c.sequence_length * c.num_experts_per_tok \
-        * blocks
-    here_grad = counts[:, 0].sum()
-    here = here_grad + last[0]
-    rows_in = mask.sum().astype(jnp.int32)
-    stats = jnp.stack([
-        here, here_grad, (steps + 1) * per_pass - here,
-        counts[:, 1].sum() + last[1],
-        rows_in * c.sequence_length,
-        (rows.shape[0] - rows_in) * c.sequence_length,
-        counts[:, 2].sum() + last[2]]).astype(jnp.int32)
-    return new, loss, stats
-
-
-def evaluate_leaves(leaves: dict, test_rows, c: Glm4Config):
-    """Next-token prediction on held-out rows `[n, S + 2]`, one row at
-    a time: mean cross-entropy, accuracy, and F1 weighted over the held
-    vocabulary by per-class counts (no `[V, V]` matrix)."""
-    with jax.named_scope("kps.eval"):
-        s = c.sequence_length
-
-        def one(row):
-            out = forward(leaves, row[None], c, with_logits=True)
-            return out["nll"][0].sum(), jnp.argmax(out["logits"][0], -1)
-        nll, preds = jax.lax.map(one, test_rows)
-        labels = test_rows[:, 1:s + 1].reshape(-1)
-        f1, acc = metrics_mod.weighted_f1_accuracy_by_class(
-            preds.reshape(-1), labels, c.vocab_held)
-        return metrics_mod.Metrics(f1=f1, accuracy=acc,
-                                   loss=nll.sum() / labels.shape[0])
+def slots_a_token(c: Glm4Config) -> int:
+    return c.num_experts_per_tok * (c.num_moe_layers
+                                    + c.num_nextn_predict_layers)
 
 
 # -- the task ----------------------------------------------------------------------
 
-class Glm4MoeLiteTask(task_mod.FlatFace):
-    """MLTask (models/task.py) over `ModelConfig.model_json`."""
+class Glm4MoeLiteTask(lm.TokenRowsTask):
+    """`lm_common.TokenRowsTask` over this family's leaves and blocks."""
 
-    batches_workers = False      # a worker's own products fill the MXU
-    row_dtype = np.int32
-    counter_names = COUNTERS
+    model_type = "glm4_moe_lite"
+    config_cls = Glm4Config
 
-    def __init__(self, cfg: ModelConfig):
-        if not cfg.model_json:
-            raise ValueError("--task glm4_moe_lite needs --model_json FILE "
-                             "(the family's own configuration)")
-        self.cfg = cfg
-        self.arch = load_config(cfg.model_json)
+    def leaf_specs(self):
+        return leaf_specs(self.arch)
+
+    def init_leaves(self) -> dict:
+        return init_leaves(self.arch)
+
+    def forward(self, leaves, rows, *, with_logits=False):
+        return forward(leaves, rows, self.arch, with_logits=with_logits)
+
+    def loss_and_counts(self, leaves, rows, mask):
+        return loss_and_counts(leaves, rows, mask, self.arch)
 
     @property
-    def num_params(self) -> int:
-        return num_params(self.arch)
-
-    @property
-    def row_width(self) -> int:
-        return self.arch.row_width
-
-    def init_params(self) -> jax.Array:
-        # a leaf at a time, then one concatenation: inside one program
-        # the compiler folds init_std into the normal's own constants,
-        # and the start would differ from the stated one by a rounding
-        return _flatten(init_leaves(self.arch), c=self.arch)
-
-    def unflatten(self, theta) -> dict:
-        return unflatten(theta, self.arch)
-
-    def flatten(self, leaves: dict) -> jax.Array:
-        return flatten(leaves, self.arch)
-
-    def encode_labels(self, y):
-        """A token row's labels are the row itself, shifted: the label
-        column carries nothing."""
-        return y
-
-    def fit_counted(self, leaves, x, enc, mask):
-        return fit_counted(leaves, x, mask, c=self.arch,
-                           lr=self.cfg.local_learning_rate,
-                           steps=self.cfg.num_max_iter)
-
-    def fit(self, leaves, x, enc, mask):
-        new, loss, _ = self.fit_counted(leaves, x, enc, mask)
-        return new, loss
-
-    def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
-        return evaluate_leaves(leaves, x_test, self.arch)
-
-    def logits(self, leaves, x):
-        """`[B, S + 2]` rows → `[B, vocab_held]` scores of the token
-        after position S - 1."""
-        return forward(leaves, x, self.arch, with_logits=True)["logits"][:, -1]
-
-
-@functools.partial(jax.jit, static_argnames=("c",))
-def _flatten(leaves: dict, *, c: Glm4Config):
-    return flatten(leaves, c)
+    def slots_a_token(self) -> int:
+        return slots_a_token(self.arch)

@@ -1,0 +1,396 @@
+"""What the language-model families share (`glm4_moe_lite`,
+`nemotron_h`): token rows, RMSNorm, the sliced head and its loss, the
+router, the expert layer that knows its share, the counters, the flat
+key space, the k-step solver with its counts, the evaluation, and the
+task's frame.  A family brings its own configuration, its leaves, its
+blocks and its expert's function; nothing here tests a family's name.
+
+Token rows are `int32[S + 2]`, whose labels are the row itself,
+shifted.  A family's configuration is one JSON file (`--model_json`,
+`ModelConfig.model_json`): the published keys and the cut —
+`experts_held` / `expert_offset` (which of the `n_routed_experts` this
+process holds), `vocab_held` (its slice of the vocabulary), the depth
+and `sequence_length`.
+
+The expert layer knows its share: it routes every token over ALL
+`n_routed_experts`, computes what its own experts give for the tokens
+routed to them plus the shared expert, and leaves out what the absent
+experts would add — that partial result is what goes on to the next
+layer.  No token is dropped and none is padded to a capacity: the
+assignments are sorted by expert and run through `jax.lax.ragged_dot`,
+which computes the rows of each held expert's group and no others.
+Nothing stands in for the absent chips or their exchange.  What an
+expert computes (`silu(gate) * up -> down`, `relu(up)**2 -> down`) is
+the family's own function, handed in.
+
+Leaves are a flat dict `{dotted name: array}`; a family's `leaf_specs`
+fixes their order in the flat key space (the wire contract).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from kafka_ps_tpu.models import metrics as metrics_mod
+from kafka_ps_tpu.models import task as task_mod
+from kafka_ps_tpu.utils.config import ModelConfig
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+# what `fit_counted` returns beside the loss, in this order, before a
+# family's own counters (Tracer.count names; runtime/app.py sums them
+# over a drive call)
+COUNTERS = ("moe.assignments_here", "moe.assignments_here_grad",
+            "moe.assignments_away", "moe.expert_load_max",
+            "data.tokens", "data.pad_tokens", "moe.passes_over_bound")
+
+
+def resolve_model_json(path: str) -> str:
+    """An absolute path as it is; a relative one from the repository's
+    root (the benchmark's configurations carry a relative path and are
+    run from any directory)."""
+    return path if os.path.isabs(path) else os.path.join(REPO_ROOT, path)
+
+
+@functools.lru_cache(maxsize=None)
+def load_config(path: str, model_type: str, config_cls):
+    """The family's configuration from its file: the dataclass's keys
+    are read, the others (comments, published keys the program does not
+    need) are left; a key without a default has to be there."""
+    with open(resolve_model_json(path)) as fh:
+        body = json.load(fh)
+    if body.get("model_type") != model_type:
+        raise ValueError(f"{path}: model_type {body.get('model_type')!r} is "
+                         f"not {model_type}")
+    fields = {f.name for f in dataclasses.fields(config_cls)}
+    missing = [f.name for f in dataclasses.fields(config_cls)
+               if f.default is dataclasses.MISSING and f.name not in body]
+    if missing:
+        raise ValueError(f"{path}: missing keys {missing}")
+    c = config_cls(**{k: v for k, v in body.items() if k in fields})
+    c.validate()
+    return c
+
+
+def validate_cut(c) -> None:
+    """The cut every family states: which experts and which rows of the
+    vocabulary are held here."""
+    if not (0 <= c.expert_offset and c.expert_offset
+            + c.experts_held <= c.n_routed_experts):
+        raise ValueError("expert_offset + experts_held must lie inside "
+                         "n_routed_experts")
+    if not 0 < c.vocab_held <= c.vocab_size:
+        raise ValueError("vocab_held must lie inside vocab_size")
+
+
+# -- the flat key space --------------------------------------------------------
+
+def num_params(specs) -> int:
+    return sum(math.prod(s) for _, s in specs)
+
+
+def unflatten(theta, specs) -> dict:
+    """The leaves of a flat vector.  Each is cut out before it is
+    shaped (the barrier): left to itself the compiler shapes the WHOLE
+    vector as `[P / 64, 64]` to cut a router out of it, a padded 4.7 GB
+    copy at the published widths."""
+    out, at = {}, 0
+    for name, shape in specs:
+        n = math.prod(shape)
+        out[name] = jax.lax.optimization_barrier(
+            theta[at:at + n]).reshape(shape)
+        at += n
+    return out
+
+
+def flatten(leaves: dict, specs) -> jax.Array:
+    return jnp.concatenate([leaves[name].reshape(-1) for name, _ in specs])
+
+
+def sub(leaves: dict, prefix: str) -> dict:
+    return {k[len(prefix):]: v for k, v in leaves.items()
+            if k.startswith(prefix)}
+
+
+# -- the layers both families have -----------------------------------------------
+
+def rms_norm(x, w, eps: float):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                             + eps) * w
+
+
+def route(h, router, bias, c):
+    """Every token over ALL experts → (chosen experts [T, K], their
+    weights [T, K]).  float32 at `highest` precision, as the published
+    gates compute it."""
+    with jax.named_scope("kps.moe.route"):
+        s = jax.nn.sigmoid(jnp.dot(h, router,
+                                   precision=jax.lax.Precision.HIGHEST))
+        _, idx = jax.lax.top_k(s + bias, c.num_experts_per_tok)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if c.norm_topk_prob:
+            w = w / (w.sum(-1, keepdims=True) + 1e-20)
+        return idx, w * c.routed_scaling_factor
+
+
+def live_rows_bound(slots: int, c) -> int:
+    """How many of a pass's `slots` (token, chosen expert) assignments
+    the expert layer places without looking further: twice the even
+    share of the experts held here, in whole tiles of 8 rows.  A pass
+    that routes more here takes every slot instead (`routed_experts`)."""
+    even = slots * c.experts_held / c.n_routed_experts
+    return min(slots, 8 * math.ceil(2 * even / 8))
+
+
+def routed_experts(h, idx, w, p: dict, c, expert):
+    """The part of Σ w_e · expert_e(h) that the experts held here give
+    → ([T, H], (assignments here, largest expert's load, 1 if the pass
+    went over `live_rows_bound`)).  `expert(xs, p, dot)` is the family's
+    own: what one expert computes on its rows, every product with the
+    held experts' stacked matrices through `dot(rows, matrices)`.
+
+    The (token, chosen expert) assignments are sorted by expert, absent
+    experts last; the held experts' products run as grouped products
+    over the sorted rows (`jax.lax.ragged_dot`: the chip's kernel
+    computes the rows of each group and no others, so its work follows
+    the routing and no assignment is dropped).  Only the sorted rows up
+    to `live_rows_bound` are placed and added back — the live ones come
+    first — unless the pass counts more assignments here than that:
+    then all T·K slots are, so none is ever dropped.  Tokens are placed
+    into sorted order, and results added back, by products with a 0/1
+    placement matrix rather than a gather and a scatter-add: a TPU
+    scatter costs over a microsecond a row, a fifth of the update when
+    it was written so.  Placing is exact at the default precision (one
+    term a row, and the grouped product rounds its operand the same
+    way); adding back runs at `HIGH`, which carries a float32 in three
+    pieces."""
+    with jax.named_scope("kps.moe.experts"):
+        t, k = idx.shape
+        held = c.experts_held
+        local = idx - c.expert_offset
+        here = (local >= 0) & (local < held)
+        # absent experts sort last, into a group that is never computed
+        key = jnp.where(here, local, held).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
+            axis=0, dtype=jnp.int32)
+        n_here = sizes.sum()
+        weight = jnp.where(here, w, 0.0).reshape(-1)[order]
+
+        def grouped(rows, matrices):
+            return jax.lax.ragged_dot(rows, matrices, sizes)
+
+        def placed(rows: int):
+            """The sum from the first `rows` sorted assignments."""
+            live = (jnp.arange(rows) < n_here)[:, None]
+            place = jnp.where(live, jax.nn.one_hot(
+                order[:rows] // k, t, dtype=jnp.bfloat16), 0)
+            xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
+            # rows past the last group are never computed: whatever
+            # the kernel leaves there must reach nothing
+            y = jnp.where(live, expert(xs, p, grouped), 0.0)
+            return jnp.dot(place.T, y * weight[:rows, None],
+                           precision=jax.lax.Precision.HIGH,
+                           preferred_element_type=jnp.float32)
+
+        bound = live_rows_bound(t * k, c)
+        went_over = n_here > bound
+        out = (placed(t * k) if bound == t * k else
+               jax.lax.cond(went_over, functools.partial(placed, t * k),
+                            functools.partial(placed, bound)))
+        return out, jnp.stack([n_here, sizes.max(),
+                               went_over.astype(jnp.int32)])
+
+
+def expert_layer(x, p: dict, c, expert, shared):
+    """An expert layer's MLP on `[B, S, H]` (already normed) → (its
+    output, (assignments here, largest load, went over the bound)).
+    `expert` as `routed_experts` takes it; `shared(h, p)` is the shared
+    expert on every token."""
+    b, s, hd = x.shape
+    h = x.reshape(b * s, hd)
+    idx, w = route(h, p["router"], p["router_bias"], c)
+    y, load = routed_experts(h, idx, w, p, c, expert)
+    with jax.named_scope("kps.moe.shared"):
+        y = y + shared(h, p)
+    return y.reshape(b, s, hd), load
+
+
+def head_nll(x, norm, head, targets, eps: float):
+    """Final norm, the head over the held slice, and each position's
+    negative log-likelihood of its target → ([B, S], logits)."""
+    logits = rms_norm(x, norm, eps) @ head
+    picked = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return jax.nn.logsumexp(logits, axis=-1) - picked, logits
+
+
+# -- the solver and the evaluation -----------------------------------------------
+
+def fit_counted(leaves: dict, rows, mask, *, loss_and_counts, lr: float,
+                steps: int, sequence_length: int, slots_a_token: int,
+                own_counts=()):
+    """`steps` full-batch SGD steps on a slab → (new leaves, the
+    objective at them, the counters of the passes made: COUNTERS, then
+    the family's `own_counts` of one pass, times the passes).
+    `loss_and_counts(leaves, rows, mask)` → (objective, (assignments
+    here, Σ largest load, expert layers over the bound)) of one pass;
+    `slots_a_token`: (token, chosen expert) assignments a token makes
+    in a pass, over every expert layer."""
+    grad = jax.value_and_grad(loss_and_counts, has_aux=True)
+    # the steps are written out, not scanned: a scan's carry starts as
+    # a copy of the shared leaves and is kept beside each step's result,
+    # two more copies of the parameters than the steps themselves need
+    new, counts = leaves, []
+    for _ in range(steps):
+        with jax.named_scope("kps.fit.grad"):
+            (_, counted), g = grad(new, rows, mask)
+        with jax.named_scope("kps.fit.param_step"):
+            new = jax.tree.map(lambda a, b: a - lr * b, new, g)
+        counts.append(counted)
+    counts = jnp.stack(counts)
+    with jax.named_scope("kps.fit.loss"):
+        loss, last = loss_and_counts(new, rows, mask)
+    per_pass = rows.shape[0] * sequence_length * slots_a_token
+    here_grad = counts[:, 0].sum()
+    here = here_grad + last[0]
+    rows_in = mask.sum().astype(jnp.int32)
+    stats = jnp.stack([
+        here, here_grad, (steps + 1) * per_pass - here,
+        counts[:, 1].sum() + last[1],
+        rows_in * sequence_length,
+        (rows.shape[0] - rows_in) * sequence_length,
+        counts[:, 2].sum() + last[2],
+        *((steps + 1) * n for n in own_counts)]).astype(jnp.int32)
+    return new, loss, stats
+
+
+def evaluate_leaves(leaves: dict, test_rows, *, forward,
+                    sequence_length: int, vocab_held: int):
+    """Next-token prediction on held-out rows `[n, S + 2]`, one row at
+    a time: mean cross-entropy, accuracy, and F1 weighted over the held
+    vocabulary by per-class counts (no `[V, V]` matrix).
+    `forward(leaves, rows, with_logits=True)` → {"nll", "logits"}."""
+    with jax.named_scope("kps.eval"):
+        s = sequence_length
+
+        def one(row):
+            out = forward(leaves, row[None], with_logits=True)
+            return out["nll"][0].sum(), jnp.argmax(out["logits"][0], -1)
+        nll, preds = jax.lax.map(one, test_rows)
+        labels = test_rows[:, 1:s + 1].reshape(-1)
+        f1, acc = metrics_mod.weighted_f1_accuracy_by_class(
+            preds.reshape(-1), labels, vocab_held)
+        return metrics_mod.Metrics(f1=f1, accuracy=acc,
+                                   loss=nll.sum() / labels.shape[0])
+
+
+# -- the task's frame ---------------------------------------------------------------
+
+class TokenRowsTask(task_mod.FlatFace):
+    """MLTask (models/task.py) over `ModelConfig.model_json`: what a
+    language-model family's task is, whatever its blocks.  A family
+    sets `model_type` and `config_cls` and writes `leaf_specs`,
+    `init_leaves`, `forward`, `loss_and_counts` and `slots_a_token`
+    (`own_counts` and `counter_names` where it counts more)."""
+
+    batches_workers = False      # a worker's own products fill the MXU
+    row_dtype = np.int32
+    model_file = True            # the family's widths are a file's
+    counter_names = COUNTERS
+    model_type: str
+    config_cls: type
+
+    def __init__(self, cfg: ModelConfig):
+        if not cfg.model_json:
+            raise ValueError(f"--task {self.model_type} needs --model_json "
+                             "FILE (the family's own configuration)")
+        self.cfg = cfg
+        self.arch = load_config(cfg.model_json, self.model_type,
+                                self.config_cls)
+        self.specs = tuple(self.leaf_specs())
+
+    # what a family writes
+    def leaf_specs(self) -> list[tuple[str, tuple[int, ...]]]:
+        """(dotted name, shape) of every leaf, in flat-layout order."""
+        raise NotImplementedError
+
+    def init_leaves(self) -> dict:
+        raise NotImplementedError
+
+    def forward(self, leaves: dict, rows, *, with_logits=False) -> dict:
+        raise NotImplementedError
+
+    def loss_and_counts(self, leaves: dict, rows, mask):
+        raise NotImplementedError
+
+    @property
+    def slots_a_token(self) -> int:
+        raise NotImplementedError
+
+    def own_counts(self, rows) -> tuple:
+        """The family's own counters of ONE pass over `rows`, after
+        COUNTERS in `counter_names`."""
+        return ()
+
+    # the frame
+    @property
+    def num_params(self) -> int:
+        return num_params(self.specs)
+
+    @property
+    def row_width(self) -> int:
+        return self.arch.sequence_length + 2
+
+    def init_params(self) -> jax.Array:
+        # a leaf at a time, then one concatenation: inside one program
+        # the compiler folds init_std into the normal's own constants,
+        # and the start would differ from the stated one by a rounding
+        return _flatten(self.init_leaves(), specs=self.specs)
+
+    def unflatten(self, theta) -> dict:
+        return unflatten(theta, self.specs)
+
+    def flatten(self, leaves: dict) -> jax.Array:
+        return flatten(leaves, self.specs)
+
+    def encode_labels(self, y):
+        """A token row's labels are the row itself, shifted: the label
+        column carries nothing."""
+        return y
+
+    def fit_counted(self, leaves, x, enc, mask):
+        return fit_counted(leaves, x, mask,
+                           loss_and_counts=self.loss_and_counts,
+                           lr=self.cfg.local_learning_rate,
+                           steps=self.cfg.num_max_iter,
+                           sequence_length=self.arch.sequence_length,
+                           slots_a_token=self.slots_a_token,
+                           own_counts=self.own_counts(x))
+
+    def fit(self, leaves, x, enc, mask):
+        new, loss, _ = self.fit_counted(leaves, x, enc, mask)
+        return new, loss
+
+    def evaluate_leaves(self, leaves, x_test, y_test) -> metrics_mod.Metrics:
+        return evaluate_leaves(leaves, x_test, forward=self.forward,
+                               sequence_length=self.arch.sequence_length,
+                               vocab_held=self.arch.vocab_held)
+
+    def logits(self, leaves, x):
+        """`[B, S + 2]` rows → `[B, vocab_held]` scores of the token
+        after position S - 1."""
+        return self.forward(leaves, x, with_logits=True)["logits"][:, -1]
+
+
+@functools.partial(jax.jit, static_argnames=("specs",))
+def _flatten(leaves: dict, *, specs):
+    return flatten(leaves, specs)

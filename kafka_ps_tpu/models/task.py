@@ -37,13 +37,20 @@ written once, in `FlatFace`, from the members a family owns.
 Every entry point (runtime worker, fused BSP step, range-sharded step,
 server eval) dispatches through a task; `logreg` stays the default —
 the reference's model — `mlp` is a second classifier, and
-`glm4_moe_lite` a language model over token rows
-(models/glm4_moe_lite.py).
+`glm4_moe_lite` and `nemotron_h` are language models over token rows
+(models/lm_common.py has what the two share).
+
+What a family says of itself, for whoever has to refuse a lever before
+any program is built (cli/run.py): `model_file` (its widths are a file
+of its own, `--model_json`), `row_dtype` (int32 rows are tokens, stored
+as they are) and `batches_workers` (a family that folds its workers has
+no program over a mesh).
 """
 
 from __future__ import annotations
 
 import functools
+import importlib
 from typing import Any, Protocol
 
 import jax
@@ -66,6 +73,7 @@ class MLTask(Protocol):
     cfg: ModelConfig
     batches_workers: bool
     row_dtype: Any
+    model_file: bool
 
     @property
     def num_params(self) -> int: ...
@@ -150,6 +158,8 @@ class FlatFace:
     program per (family, cfg); inside a caller's own jit they inline.
     The task is those programs' static argument: two tasks are equal
     when their family and their cfg are."""
+
+    model_file = False      # the widths are ModelConfig's own fields
 
     def __eq__(self, other):
         return type(self) is type(other) and self.cfg == other.cfg
@@ -247,6 +257,12 @@ class LogRegTask(RowsWithClassLabel):
 
 
 _REGISTRY = {"logreg": LogRegTask}
+# optional families, bound late so that importing task.py stays cheap:
+# name -> (module, class)
+_LATE = {"mlp": ("kafka_ps_tpu.models.mlp", "MLPTask"),
+         "glm4_moe_lite": ("kafka_ps_tpu.models.glm4_moe_lite",
+                           "Glm4MoeLiteTask"),
+         "nemotron_h": ("kafka_ps_tpu.models.nemotron_h", "NemotronHTask")}
 
 
 def default_task(cfg: ModelConfig) -> "MLTask":
@@ -259,16 +275,19 @@ def register(name: str, factory) -> None:
     _REGISTRY[name] = factory
 
 
-def get_task(name: str, cfg: ModelConfig) -> MLTask:
+def task_class(name: str):
+    """The family's factory, by name: what `get_task` calls, and what
+    says of the family what a caller must know before it has a cfg
+    (`model_file`, `row_dtype`, `batches_workers`)."""
     if name not in _REGISTRY:
-        # late-bind optional families so importing task.py stays cheap
-        if name == "mlp":
-            from kafka_ps_tpu.models.mlp import MLPTask
-            register("mlp", MLPTask)
-        elif name == "glm4_moe_lite":
-            from kafka_ps_tpu.models.glm4_moe_lite import Glm4MoeLiteTask
-            register("glm4_moe_lite", Glm4MoeLiteTask)
-        else:
+        if name not in _LATE:
             raise ValueError(
-                f"unknown task {name!r}; registered: {sorted(_REGISTRY)}")
-    return _REGISTRY[name](cfg)
+                f"unknown task {name!r}; registered: "
+                f"{sorted({*_REGISTRY, *_LATE})}")
+        module, cls = _LATE[name]
+        register(name, getattr(importlib.import_module(module), cls))
+    return _REGISTRY[name]
+
+
+def get_task(name: str, cfg: ModelConfig) -> MLTask:
+    return task_class(name)(cfg)

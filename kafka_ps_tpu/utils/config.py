@@ -36,9 +36,10 @@ class ModelConfig:
     from ONE file, `model_json` (`--model_json`; a relative path is
     taken from the repository's root): the published keys of the
     architecture and the cut this process holds
-    (models/glm4_moe_lite.py).  There is no flag per width; the row's
-    width and dtype, the label encoding and whether the worker axis
-    batches are the task's to say (models/task.py).
+    (models/lm_common.py, the language-model families).  There is no
+    flag per width; the row's width and dtype, the label encoding and
+    whether the worker axis batches are the task's to say
+    (models/task.py).
     """
 
     num_features: int = 1024
@@ -127,7 +128,7 @@ class PSConfig:
     num_workers: int = 4
     consistency_model: int = SEQUENTIAL   # -c: 0 BSP, k>0 SSP, -1 ASP
     # model family (models/task.py registry): "logreg" (the reference's
-    # task), "mlp" or "glm4_moe_lite"
+    # task), "mlp", "glm4_moe_lite" or "nemotron_h"
     task: str = "logreg"
     model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
     buffer: BufferConfig = dataclasses.field(default_factory=BufferConfig)

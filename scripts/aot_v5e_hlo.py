@@ -97,6 +97,36 @@ def compile_program(program: str, topo, *, hidden: int = 4096,
     return fn.lower(*args).compile()
 
 
+def compile_folded_chunk(task_name: str, model_json: str, topo, *,
+                         workers: int = 4, rows: int = 1, rounds: int = 8,
+                         local_iterations: int = 2, lr: float = 0.001):
+    """The folded scan chunk (`jit_scanned`, parallel/bsp.py) of a
+    language-model family's cell, compiled for the described chip from
+    the model file's shapes → (the task, the compiled program)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from kafka_ps_tpu.models.task import get_task
+    from kafka_ps_tpu.parallel import bsp
+    from kafka_ps_tpu.utils.config import ModelConfig
+
+    cfg = ModelConfig(num_max_iter=local_iterations, local_learning_rate=lr,
+                      model_json=model_json)
+    task = get_task(task_name, cfg)
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    leaves = {n: shaped(s, jnp.float32) for n, s in task.specs}
+    chunk = bsp.make_bsp_multi_step(cfg, workers, 1.0 / workers, rounds,
+                                    task=task)
+    return task, chunk.lower(
+        leaves, shaped((workers, rows, task.row_width), jnp.int32),
+        shaped((workers, rows), jnp.int32),
+        shaped((workers, rows), jnp.float32)).compile()
+
+
 def computations(hlo_text: str) -> dict[str, list[str]]:
     """Every computation's instruction lines, in the order of the text
     (of a compiled module: the schedule's)."""
@@ -188,12 +218,32 @@ def main(argv=None) -> int:
     ap.add_argument("--rows", type=int, default=1024)
     ap.add_argument("--programs", nargs="*", default=list(PROGRAMS),
                     choices=PROGRAMS)
+    ap.add_argument("--folded", nargs=2, metavar=("TASK", "MODEL_JSON"),
+                    help="compile a language-model family's folded scan "
+                         "chunk instead (4 workers, --rows rows a worker): "
+                         "scratch, donated bytes, seconds to compile")
     ap.add_argument("--dump", help="directory for each program's HLO text")
     ap.add_argument("--top", type=int, default=14,
                     help="costliest top-level instructions to list a program")
     args = ap.parse_args(argv)
 
     topo = describe_v5e()
+    if args.folded:
+        import time
+        t = time.time()
+        task, compiled = compile_folded_chunk(*args.folded, topo,
+                                              rows=args.rows)
+        mem = compiled.memory_analysis()
+        print(f"== folded chunk of {args.folded[0]}: {task.num_params} "
+              f"parameters, scratch {mem.temp_size_in_bytes / 1e9:.4f} GB "
+              f"+ donated leaves {mem.alias_size_in_bytes / 1e9:.4f} GB, "
+              f"compiled in {time.time() - t:.0f} s")
+        if args.dump:
+            os.makedirs(args.dump, exist_ok=True)
+            with open(os.path.join(args.dump, args.folded[0] + ".hlo.txt"),
+                      "w") as f:
+                f.write(compiled.as_text())
+        return 0
     w1_bytes = args.hidden * 1024 * 4
     for program in args.programs:
         compiled = compile_program(program, topo, hidden=args.hidden,

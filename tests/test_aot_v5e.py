@@ -75,7 +75,7 @@ def test_no_worker_has_a_w1_before_its_first_gradient(aot, program_text,
                            aot.FIRST_GRAD_PRODUCT) == []
 
 
-def test_the_folded_chunk_at_the_published_widths_fits_the_chip(topo):
+def test_the_folded_chunk_at_the_published_widths_fits_the_chip(aot, topo):
     """The scan chunk of the benchmark's language-model cell (591.3 M
     parameters held, 4 workers folded one at a time, 1 row of 1,024
     tokens, 8 clocks), compiled for the described chip: the leaves are
@@ -89,32 +89,45 @@ def test_the_folded_chunk_at_the_published_widths_fits_the_chip(topo):
     keeps a relayout of every weight beside the loop), and 4.7 GB more
     with the flat vector cut into leaves without a barrier (PERF.md
     section 6, PR 27).  About 50 s."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import SingleDeviceSharding
-
-    from kafka_ps_tpu.models import glm4_moe_lite as glm
-    from kafka_ps_tpu.models.task import get_task
-    from kafka_ps_tpu.parallel import bsp
-    from kafka_ps_tpu.utils.config import ModelConfig
-
-    cfg = ModelConfig(num_max_iter=2, local_learning_rate=0.001,
-                      model_json="benchmark/configs/"
-                                 "glm-4.7-flash-ep8.model.json")
-    task = get_task("glm4_moe_lite", cfg)
+    task, compiled = aot.compile_folded_chunk(
+        "glm4_moe_lite", "benchmark/configs/glm-4.7-flash-ep8.model.json",
+        topo)
     assert task.num_params == 591_294_976
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def shaped(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-    leaves = {n: shaped(s, jnp.float32) for n, s in
-              glm.leaf_specs(task.arch)}
-    chunk = bsp.make_bsp_multi_step(cfg, 4, 0.25, 8, task=task)
-    compiled = chunk.lower(
-        leaves, shaped((4, 1, task.row_width), jnp.int32),
-        shaped((4, 1), jnp.int32), shaped((4, 1), jnp.float32)).compile()
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * task.num_params
     assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
     # the grouped products are the chip's own kernel, not a dense product
     assert "ragged-dot" in compiled.as_text()
+
+
+def test_the_second_language_models_chunk_fits_the_chip_too(aot, topo):
+    """The scan chunk of `nemotron-3-nano-ep16.fused-bsp` (667.0 M
+    parameters held, 4 workers folded one at a time, 1 row a worker at
+    the cell's own sequence length, 8 clocks), compiled for the
+    described chip — PR 27's four findings as assertions for this family
+    too, which shares the frame they were made in.  The leaves are
+    donated (the flat vector is no argument and no result of the chunk:
+    argument and result share their bytes); scratch + donated leaves
+    stay under 15.0 GB; and there is no second copy of the shared
+    leaves: the scratch reads 7.66 GB at the cell's 1,024 tokens (15.5
+    bytes a parameter with the leaves; 9.01 GB at 2,048, to the byte
+    what the chip's backend reported, PR 31), and each of the other
+    three findings —
+    leaves cut without a barrier, the local steps as a scan, the shared
+    leaves loop-invariant in the fold — cost one to two more copies of
+    the parameters, 2.67 GB each, which 10.5 GB does not hold.  About
+    90 s."""
+    task, compiled = aot.compile_folded_chunk(
+        "nemotron_h", "benchmark/configs/nemotron-3-nano-ep16.model.json",
+        topo)
+    assert task.num_params == 666_963_456
+    memory = compiled.memory_analysis()
+    leaves = 4 * task.num_params
+    assert memory.alias_size_in_bytes >= leaves
+    assert memory.temp_size_in_bytes + leaves < 15.0e9, \
+        memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    # the chunked scan is in the program under its own scope
+    assert "kps.ssm.scan" in text and "kps.attn" in text

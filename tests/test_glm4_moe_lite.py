@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from kafka_ps_tpu.models import glm4_moe_lite as glm
+from kafka_ps_tpu.models import lm_common as lm
 from kafka_ps_tpu.models.task import get_task
 from kafka_ps_tpu.parallel import bsp
 from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
@@ -222,8 +223,9 @@ def test_the_shares_of_an_expert_layer_sum_to_the_uncut_layer(task, ref,
                                     expert_offset=offset)
         p = dict(full, **{k: full[k][offset:offset + held]
                           for k in ("e_gate", "e_up", "e_down")})
-        idx, w = glm.route(h, p["router"], p["router_bias"], share)
-        part, load = glm.routed_experts(h, idx, w, p, share)
+        idx, w = lm.route(h, p["router"], p["router_bias"], share)
+        part, load = lm.routed_experts(h, idx, w, p, share,
+                                        glm.swiglu_experts)
         total = total + part
         here += int(load[0])
     assert here == 40 * c.num_experts_per_tok     # every choice, once
@@ -248,14 +250,15 @@ def test_no_token_is_dropped_when_every_token_goes_one_way(task, favoured,
     bias[list(favoured)] = 10.0
     router = jnp.asarray(rng.standard_normal(
         (c.hidden_size, c.n_routed_experts)), jnp.float32)
-    idx, w = glm.route(h, router, jnp.asarray(bias), c)
+    idx, w = lm.route(h, router, jnp.asarray(bias), c)
     assert sorted(np.unique(np.asarray(idx))) == list(favoured)
-    got, load = glm.routed_experts(h, idx, w, p, c)
+    got, load = lm.routed_experts(h, idx, w, p, c,
+                                        glm.swiglu_experts)
     if not here:
         assert np.asarray(load).tolist() == [0, 0, 0] and not np.any(got)
         return
     # every slot is live, over the bound: the pass places them all
-    assert glm.live_rows_bound(t * 2, c) < t * 2
+    assert lm.live_rows_bound(t * 2, c) < t * 2
     assert np.asarray(load).tolist() == [t * 2, t, 1]
     # the weights follow the position an expert was chosen at
     w_of = jnp.zeros((t, 2)).at[jnp.arange(t)[:, None], idx].set(w)
@@ -281,8 +284,9 @@ def test_the_grouped_products_follow_any_routing(task, hot):
     bias = jnp.asarray([hot, hot] + [0.0] * 6, jnp.float32)
     router = jnp.asarray(rng.standard_normal((c.hidden_size, 8)),
                          jnp.float32)
-    idx, w = glm.route(h, router, bias, c)
-    got, load = glm.routed_experts(h, idx, w, p, c)
+    idx, w = lm.route(h, router, bias, c)
+    got, load = lm.routed_experts(h, idx, w, p, c,
+                                        glm.swiglu_experts)
     w_of = np.zeros((28, 8), np.float32)
     np.put_along_axis(w_of, np.asarray(idx), np.asarray(w), axis=1)
     want = sum(w_of[:, e, None] * np.asarray(glm.swiglu(
@@ -291,7 +295,7 @@ def test_the_grouped_products_follow_any_routing(task, hot):
     assert int(load[0]) == int((np.asarray(idx) < 2).sum())
     assert {-10.0: int(load[0]) < 14, 0.0: True,
             10.0: int(load[0]) == 56}[hot]
-    assert glm.live_rows_bound(56, c) == 32
+    assert lm.live_rows_bound(56, c) == 32
     assert int(load[2]) == (int(load[0]) > 32)
     assert int(load[2]) == {-10.0: 0, 0.0: int(load[2]), 10.0: 1}[hot]
     close(got, want)
