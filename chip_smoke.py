@@ -12,6 +12,9 @@ the models the repo supports, on data made from a seed:
   * fused BSP at the widest model, `--task mlp --hidden_dim 4096`
     (≈4.2 M parameters), at `--eval_every 1` (per-round program) and
     `--eval_every 8` (the 8-round scan chunk);
+  * the language models' grouped products told their tiles
+    (`models/lm_common.py` `grouped_tiles`) against the chip's kernel
+    left to itself, at the widths of the second language-model cell;
   * with more than one chip: `--fused -r` and `--fused --param_shards`
     over all of them.
 
@@ -58,6 +61,8 @@ class Sizes:
     fused_rounds: int = 24        # 3 scan chunks at --eval_every 8
     multichip_rounds: int = 8
     center_scale: float = 0.2     # synth.HARD_CENTER_SCALE: class overlap
+    grouped_rows: int = 768       # a pass's placed rows, 8 held experts
+    grouped_widths: tuple = (2688, 1856)      # hidden x expert width
 
 
 class SmokeFailure(RuntimeError):
@@ -271,6 +276,67 @@ def phase_fused(workdir: str, train: str, test: str, sizes: Sizes,
     return rec
 
 
+def phase_grouped_products(sizes: Sizes, platform: str) -> dict:
+    """The expert layer's grouped product with the kernel told its
+    tiles (`lm_common.told_grouped` under `grouped_tiles`) against the
+    kernel left to itself: the product, dx and dW, in both orientations
+    (up `[m, k] x [g, k, n]`, down `[m, n] x [g, n, k]`), over groups
+    that fill under half the rows, one of them empty, so that rows past
+    the last group and, at 1856 = 2.9 x 640, a partial last tile are
+    both there.  The live rows and dW equal the untold kernel's to
+    float32 rounding; the rows past the last group, NaN in both
+    operands, reach no live row and no dW, and dx is zero there."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.models import lm_common as lm
+
+    name, started = "grouped_products", time.time()
+    m, held = sizes.grouped_rows, 8
+    rng = np.random.default_rng(0)
+    group_sizes = jnp.asarray(
+        np.array([7, 4, 0, 5, 3, 4, 6, 3]) * (m // 74), jnp.int32)
+    n_live = int(group_sizes.sum())
+    live = (jnp.arange(m) < n_live)[:, None]
+    rec = {"rows": m, "live_rows": n_live}
+    for way, (k, n) in (("up", sizes.grouped_widths),
+                        ("down", sizes.grouped_widths[::-1])):
+        tiles = lm.grouped_tiles(m, k, n)
+        require(tiles is not None, name,
+                f"grouped_tiles({m}, {k}, {n}) tells the kernel nothing")
+        rows, mats, seen = (
+            jnp.asarray(scale * rng.standard_normal(shape), jnp.float32)
+            for scale, shape in ((1.0, (m, k)), (0.02, (held, k, n)),
+                                 (1.0, (m, n))))
+        rows, seen = (jnp.where(live, a, jnp.nan) for a in (rows, seen))
+        require(_platform_of(mats) == platform, name,
+                f"the matrices live on {_platform_of(mats)}")
+
+        def results(product):
+            def of(rows, mats, seen):
+                y, back = jax.vjp(product, rows, mats)
+                return (y, *back(seen))
+            return jax.jit(of)(rows, mats, seen)
+        told = results(lambda r, w: lm.told_grouped(r, w, group_sizes,
+                                                    tiles))
+        plain = results(lambda r, w: jax.lax.ragged_dot(r, w, group_sizes))
+        require(not np.asarray(told[1])[n_live:].any(), name,
+                f"{way} dx under tiles {tiles} is not zero past the last "
+                "group")
+        for what, a, b in zip(("product", "dx", "dW"), told, plain):
+            a, b = (np.asarray(x) if what == "dW" else np.asarray(x)[:n_live]
+                    for x in (a, b))
+            gap, largest = float(np.abs(a - b).max()), float(np.abs(b).max())
+            require(np.isfinite(a).all() and gap <= 1e-6 * largest, name,
+                    f"{way} {what} under tiles {tiles}: off the untold "
+                    f"kernel's by {gap} of {largest}")
+            rec[f"{way}_{what}_gap"] = gap
+        rec[f"{way}_tiles"] = tiles
+    rec["wall_s"] = round(time.time() - started, 2)
+    return rec
+
+
 def phase_multichip(workdir: str, train: str, test: str, sizes: Sizes,
                     platform: str, device_count: int) -> dict:
     """The fused path over every chip of the host: `--fused -r` (1-D
@@ -342,6 +408,7 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
     for eval_every in (1, 8):
         phases[f"fused_mlp{sizes.fused_hidden}_eval{eval_every}"] = \
             phase_fused(workdir, train, test, sizes, platform, eval_every)
+    phases["grouped_products"] = phase_grouped_products(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,
                                       platform, device_count))

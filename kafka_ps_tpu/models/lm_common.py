@@ -19,6 +19,12 @@ experts would add — that partial result is what goes on to the next
 layer.  No token is dropped and none is padded to a capacity: the
 assignments are sorted by expert and run through `jax.lax.ragged_dot`,
 which computes the rows of each held expert's group and no others.
+The chip's kernel behind it is told which tiles to walk the held
+experts' matrices in (`grouped_tiles`) where its own choice is small:
+left to itself it walks a width that 512 does not divide, 2688 or
+1856, in 128 x 128 blocks of 64 KB, a grid step each, and the call is
+bound by the steps, not by the bytes (0.94-1.09 ms for 0.23-0.29).  The
+shapes decide, and at widths that are multiples of 512 nothing is said.
 Nothing stands in for the absent chips or their exchange.  What an
 expert computes (`silu(gate) * up -> down`, `relu(up)**2 -> down`) is
 the family's own function, handed in.
@@ -38,6 +44,7 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.models import task as task_mod
@@ -151,6 +158,94 @@ def live_rows_bound(slots: int, c) -> int:
     return min(slots, 8 * math.ceil(2 * even / 8))
 
 
+# what a grouped product's grid step may take of the kernel's 16 MB of
+# scoped VMEM
+GROUPED_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def grouped_step_bytes(tm: int, tk: int, tn: int) -> int:
+    """What a grid step of the grouped-product kernel holds in VMEM
+    under tiles `tm,tk,tn`: float32 rows `[tm, tk]`, matrix `[tk, tn]`
+    and result `[tm, tn]`, each twice (one in flight), and an
+    accumulator as large as the matrix block, which is what the
+    product's dW carries under the same tiles."""
+    return 4 * (2 * tm * tk + 3 * tk * tn + 2 * tm * tn)
+
+
+def grouped_tiles(m: int, k: int, n: int) -> str | None:
+    """The tiles `tm,tk,tn` in which the chip's grouped-product kernel
+    should walk `[m, k] x [g, k, n]`, as its `ragged_dot_tiling` hint
+    takes them, or None where it is told nothing.
+
+    Left to itself the kernel walks a width that 512 divides in 512s
+    and one like 2688 or 1856 in 128s (read from compiles for a
+    described v5e), so a width that is a multiple of 512, or that one
+    block of 512 holds, is well served and `None` leaves a product of
+    such widths (and any whose rows no tile of 128 divides) to the
+    compiler.  Any other width gets the fewest tiles no wider
+    than a cap, evenly sized in whole 128s (2688 -> 3 x 896; 1856 ->
+    3 x 640, the last one partial), the cap starting at 1024 and
+    falling until a grid step (`grouped_step_bytes`) fits
+    GROUPED_VMEM_BUDGET.  `tm` is the rows' own: 256, or 128 where 256
+    does not divide `m`."""
+    tm = next((t for t in (256, 128) if m % t == 0), None)
+    if tm is None or all(d % 512 == 0 or d <= 512 for d in (k, n)):
+        return None
+    for cap in range(8, 0, -1):
+        tk, tn = (128 * math.ceil(blocks / math.ceil(blocks / cap))
+                  for blocks in (math.ceil(k / 128), math.ceil(n / 128)))
+        if grouped_step_bytes(tm, tk, tn) <= GROUPED_VMEM_BUDGET:
+            return f"{tm},{tk},{tn}"
+    return None
+
+
+# dW of a grouped product: the rows' and the result's gradient contracted
+# over the ragged rows, a group at a time -> [g, k, n]
+_GROUPED_DW = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def told_grouped(rows, matrices, sizes, tiles: str):
+    """`jax.lax.ragged_dot` with the kernel told its `tiles`
+    (`grouped_tiles`), and each of its transposes told its own.  Under
+    `jax.grad` alone dx and dW inherit the product's string, and dx,
+    whose contraction is the product's n and whose result its k, then
+    walks 1856 in 896s and 2688 in 640s: 0.33 ms a call for 0.23 under
+    the same tiles turned round (PERF.md, PR 32).
+
+    Rows past the last group belong to no product.  The untold kernel
+    happens to write finite numbers there; the told one leaves the row
+    tiles no group reaches as it found them, NaN bits included.  The
+    caller masks the product's rows by `where`; dx is masked here, so
+    that a sum over the rows with weight 0 on the dead ones (the
+    placement's transpose in `routed_experts`) stays finite.  The
+    kernels themselves never read a dead row into a live result
+    (chip_smoke.py feeds them NaN there)."""
+    with set_xla_metadata(ragged_dot_tiling=tiles):
+        return jax.lax.ragged_dot(rows, matrices, sizes)
+
+
+def _told_grouped_fwd(rows, matrices, sizes, tiles):
+    return told_grouped(rows, matrices, sizes, tiles), (rows, matrices, sizes)
+
+
+def _told_grouped_bwd(tiles, kept, dy):
+    rows, matrices, sizes = kept
+    tm, tk, tn = tiles.split(",")
+    with set_xla_metadata(ragged_dot_tiling=f"{tm},{tn},{tk}"):
+        d_rows = jax.lax.ragged_dot(dy, jnp.swapaxes(matrices, 1, 2), sizes)
+    live = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
+    d_rows = jnp.where(live, d_rows, 0.0)
+    with set_xla_metadata(ragged_dot_tiling=tiles):
+        d_matrices = jax.lax.ragged_dot_general(rows, dy, sizes, _GROUPED_DW)
+    return d_rows, d_matrices, None
+
+
+told_grouped.defvjp(_told_grouped_fwd, _told_grouped_bwd)
+
+
 def routed_experts(h, idx, w, p: dict, c, expert):
     """The part of Σ w_e · expert_e(h) that the experts held here give
     → ([T, H], (assignments here, largest expert's load, 1 if the pass
@@ -162,7 +257,12 @@ def routed_experts(h, idx, w, p: dict, c, expert):
     experts last; the held experts' products run as grouped products
     over the sorted rows (`jax.lax.ragged_dot`: the chip's kernel
     computes the rows of each group and no others, so its work follows
-    the routing and no assignment is dropped).  Only the sorted rows up
+    the routing and no assignment is dropped).  Each product tells the
+    kernel the tiles `grouped_tiles` gives for its shape, and says
+    nothing where that gives None: the same float32 operands, default
+    precision and float32 sums either way — on the chip the told
+    product, its dx and its dW equal the untold ones to the bit
+    (chip_smoke.py).  Only the sorted rows up
     to `live_rows_bound` are placed and added back — the live ones come
     first — unless the pass counts more assignments here than that:
     then all T·K slots are, so none is ever dropped.  Tokens are placed
@@ -187,7 +287,10 @@ def routed_experts(h, idx, w, p: dict, c, expert):
         weight = jnp.where(here, w, 0.0).reshape(-1)[order]
 
         def grouped(rows, matrices):
-            return jax.lax.ragged_dot(rows, matrices, sizes)
+            tiles = grouped_tiles(rows.shape[0], *matrices.shape[1:])
+            if tiles is None:
+                return jax.lax.ragged_dot(rows, matrices, sizes)
+            return told_grouped(rows, matrices, sizes, tiles)
 
         def placed(rows: int):
             """The sum from the first `rows` sorted assignments."""

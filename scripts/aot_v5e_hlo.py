@@ -205,6 +205,30 @@ def made_before(hlo_text: str, dtype: str, dims: tuple, until: str):
     raise ValueError(f"no instruction's op_name matches {until!r}")
 
 
+def ragged_dot_calls(hlo_text: str):
+    """((m, k, n), the kernel's tiles "tm,tk,tn") of every grouped
+    product the chip's kernel runs: the Mosaic calls `ragged-dot-*`
+    with their `ragged_dot_tiling` frontend attribute, which carries
+    the compiler's choice or the program's hint (models/lm_common.py
+    `grouped_tiles`).  The shape is the call's own: rows `[m, k]` by
+    matrices `[g, k, n]`, or for a dW rows `[m, k]` by `[m, n]` into
+    `[g, k, n]`."""
+    calls = []
+    for line in hlo_text.splitlines():
+        result = re.match(r"\s*(?:ROOT )?%ragged-dot-none[.\d]* = "
+                          r"f32\[([\d,]+)\].* custom-call\(", line)
+        if not result:
+            continue
+        laid = line.split("operand_layout_constraints={", 1)[1].split(
+            "frontend_attributes", 1)[0]
+        lhs = re.findall(r"f32\[([\d,]+)\]", laid)[-2].split(",")
+        tiles = re.search(r'ragged_dot_tiling="([\d,]+)"', line)
+        calls.append(((int(lhs[0]), int(lhs[1]),
+                       int(result.group(1).split(",")[-1])),
+                      tiles and tiles.group(1)))
+    return calls
+
+
 # the first matrix product of a local update's first gradient (a hoisted
 # sum of the mask lies under the scope too, and runs earlier)
 FIRST_GRAD_PRODUCT = r"kps\.fit\.grad.*dot_general"
@@ -238,11 +262,17 @@ def main(argv=None) -> int:
               f"parameters, scratch {mem.temp_size_in_bytes / 1e9:.4f} GB "
               f"+ donated leaves {mem.alias_size_in_bytes / 1e9:.4f} GB, "
               f"compiled in {time.time() - t:.0f} s")
+        text = compiled.as_text()
+        calls = ragged_dot_calls(text)
+        for call in sorted(set(calls)):
+            print(f"  {calls.count(call):3d} grouped products "
+                  f"[{call[0][0]}, {call[0][1]}] x [.., {call[0][2]}] "
+                  f"in tiles {call[1]}")
         if args.dump:
             os.makedirs(args.dump, exist_ok=True)
             with open(os.path.join(args.dump, args.folded[0] + ".hlo.txt"),
                       "w") as f:
-                f.write(compiled.as_text())
+                f.write(text)
         return 0
     w1_bytes = args.hidden * 1024 * 4
     for program in args.programs:

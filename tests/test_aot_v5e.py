@@ -11,6 +11,8 @@ import os
 
 import pytest
 
+from kafka_ps_tpu.models import lm_common as lm
+
 HIDDEN, WORKERS = 512, 8            # reduced: about 3 s a program
 W1_BYTES = HIDDEN * 1024 * 4
 
@@ -96,8 +98,11 @@ def test_the_folded_chunk_at_the_published_widths_fits_the_chip(aot, topo):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * task.num_params
     assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
-    # the grouped products are the chip's own kernel, not a dense product
-    assert "ragged-dot" in compiled.as_text()
+    # the grouped products are the chip's own kernel, not a dense
+    # product, and at 2048 x 1536 it is told nothing: its own tiles
+    calls = aot.ragged_dot_calls(compiled.as_text())
+    assert calls and {tiles for _, tiles in calls} == {"512,512,512"}
+    assert {lm.grouped_tiles(*shape) for shape, _ in calls} == {None}
 
 
 def test_the_second_language_models_chunk_fits_the_chip_too(aot, topo):
@@ -128,6 +133,19 @@ def test_the_second_language_models_chunk_fits_the_chip_too(aot, topo):
         memory.temp_size_in_bytes
     assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
     text = compiled.as_text()
-    assert "ragged-dot" in text
+    # every grouped product — the two of an expert, their dx and dW,
+    # under the bound's 768 rows and over it at 6,144 — runs the chip's
+    # kernel in the tiles `grouped_tiles` states for the call's OWN
+    # shape (dx: the product's turned round), none in the 128 x 128
+    # blocks the compiler takes at 2688 and 1856 (1.09 ms a call for
+    # 0.23-0.29, PERF.md section 5).  The hint is an undocumented
+    # frontend attribute: a libtpu that stops honouring it fails here
+    calls = aot.ragged_dot_calls(text)
+    assert {shape for shape, _ in calls} == {
+        (m, k, n) for m in (768, 6144)
+        for k, n in ((2688, 1856), (1856, 2688))}
+    assert all(tiles == lm.grouped_tiles(*shape) for shape, tiles in calls), \
+        sorted(set(calls))
+    assert not any(tiles.endswith(",128,128") for _, tiles in calls)
     # the chunked scan is in the program under its own scope
     assert "kps.ssm.scan" in text and "kps.attn" in text

@@ -20,7 +20,8 @@ TINY = chip_smoke.Sizes(
     num_features=128, fused_hidden=32, buffer_min=8,
     buffer_max=64, train_rows=512, test_rows=128, per_node_clocks=12,
     fused_rounds=16, multichip_rounds=8,
-    center_scale=1.0)           # too few rows to learn the hard regime
+    center_scale=1.0,           # too few rows to learn the hard regime
+    grouped_rows=128, grouped_widths=(640, 384))    # the rule still hints
 
 
 def _run(script_dir, env_extra, *args):
@@ -68,6 +69,17 @@ def test_fused_phase(data, eval_every):
     assert rec["program"] == ("bsp-step" if eval_every == 1
                               else "bsp-scan-8")
     assert rec["eval_rows"] == TINY.fused_rounds // eval_every
+
+
+def test_grouped_products_phase():
+    rec = chip_smoke.phase_grouped_products(TINY, "cpu")
+    assert (rec["up_tiles"], rec["down_tiles"]) == ("128,640,384",
+                                                    "128,384,640")
+    assert rec["live_rows"] == 32 and rec["up_dW_gap"] == 0.0
+    with pytest.raises(chip_smoke.SmokeFailure, match="tells the kernel"):
+        chip_smoke.phase_grouped_products(
+            chip_smoke.Sizes(grouped_rows=128, grouped_widths=(512, 64)),
+            "cpu")
 
 
 def test_multichip_phase_on_the_virtual_mesh(data):

@@ -387,6 +387,107 @@ def test_no_token_is_dropped_when_every_token_goes_one_way(task, favoured,
     close(got, want)
 
 
+@pytest.mark.parametrize("shape,tiles", [
+    # the cell's products, under the bound (768 rows) and over it (6,144)
+    ((768, 2688, 1856), "256,896,640"), ((768, 1856, 2688), "256,640,896"),
+    ((6144, 2688, 1856), "256,896,640"), ((6144, 1856, 2688), "256,640,896"),
+    # rows that only 128 divides; a width with a prime count of 128s
+    ((384, 2688, 1856), "128,896,640"), ((768, 2944, 1000), "256,768,512"),
+    # the small shape the CPU test below runs
+    ((128, 640, 384), "128,640,384"), ((512, 384, 640), "256,384,640"),
+    # multiples of 512 (the GLM cell's), widths one block holds (the
+    # tiny models'), rows no tile divides: the compiler's own choice
+    ((1024, 2048, 1536), None), ((1024, 1536, 2048), None),
+    ((4096, 2048, 1536), None), ((768, 64, 32), None), ((768, 32, 64), None),
+    ((768, 512, 384), None), ((24, 640, 384), None), ((776, 2688, 1856), None),
+])
+def test_the_grouped_products_tiles_follow_the_shapes(shape, tiles):
+    """`grouped_tiles`: a hint only where the kernel's own tiles are
+    small, in whole 128s, no wider than the width padded to 128, the
+    rows' tile dividing the rows, and the blocks of a grid step inside
+    the budget (the transposes' accumulator counted)."""
+    assert lm.grouped_tiles(*shape) == tiles
+    if tiles is None:
+        return
+    (m, k, n), (tm, tk, tn) = shape, map(int, tiles.split(","))
+    assert m % tm == 0 and tk % 128 == 0 and tn % 128 == 0
+    assert tk < k + 128 and tn < n + 128
+    assert lm.grouped_step_bytes(tm, tk, tn) <= lm.GROUPED_VMEM_BUDGET
+    # the accumulator of the transposes is counted: 10.0 MB at the cell
+    assert lm.grouped_step_bytes(256, 896, 640) == 10_027_008
+
+
+@pytest.mark.parametrize("over", [False, True])
+def test_a_hinted_grouped_product_is_the_dense_one(task, over):
+    """At widths the rule hints (640 x 384) `routed_experts` lowers
+    with the hint on its grouped products (the CPU takes it and ignores
+    it) and equals the dense sum, forward and through `jax.grad`: a
+    pass under `live_rows_bound` with an empty group, and one over it
+    (every token to both held experts), which places every slot."""
+    hd, inter, t = 640, 384, 128
+    c = dataclasses.replace(task.arch, n_routed_experts=16, experts_held=2,
+                            expert_offset=0, num_experts_per_tok=4)
+    rng = np.random.default_rng(13)
+    h = jnp.asarray(rng.standard_normal((t, hd)), jnp.float32)
+    p = {k: jnp.asarray(0.05 * rng.standard_normal(s), jnp.float32)
+         for k, s in (("e_up", (2, hd, inter)), ("e_down", (2, inter, hd)))}
+    bias = np.zeros((16,), np.float32)
+    bias[:2] = (10.0, 10.0) if over else (0.0, -10.0)
+    idx, w = lm.route(h, jnp.asarray(rng.standard_normal((hd, 16)),
+                                     jnp.float32), jnp.asarray(bias), c)
+    seen = jnp.asarray(rng.standard_normal((t, hd)), jnp.float32)
+    w_of = jnp.zeros((t, 16)).at[jnp.arange(t)[:, None], idx].set(w)
+
+    def sparse(h, p):
+        out, load = lm.routed_experts(h, idx, w, p, c, nh.relu2_experts)
+        return jnp.sum(out * seen), (out, load)
+
+    def dense(h, p):
+        out = sum(w_of[:, e, None] * nh.relu2(h, p["e_up"][e], p["e_down"][e])
+                  for e in range(2))
+        return jnp.sum(out * seen), out
+
+    bound = lm.live_rows_bound(t * 4, c)
+    assert bound == 128 < t * 4
+    text = jax.jit(sparse).lower(h, p).as_text()
+    for rows, (k, n) in ((bound, (hd, inter)), (t * 4, (inter, hd))):
+        assert f'ragged_dot_tiling = "{lm.grouped_tiles(rows, k, n)}"' in text
+    (_, (got, load)), g_got = jax.value_and_grad(
+        sparse, argnums=(0, 1), has_aux=True)(h, p)
+    (_, want), g_want = jax.value_and_grad(
+        dense, argnums=(0, 1), has_aux=True)(h, p)
+    here, largest, went_over = np.asarray(load).tolist()
+    assert went_over == over and (here > bound) == over
+    assert over or largest == here          # the other group is empty
+    close(got, want)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        close(a, b)
+
+
+def test_a_told_products_dx_turns_its_tiles_round():
+    """`told_grouped`: the product's lowering carries its own tiles
+    only; its gradient's carries them (dW) and the same turned round
+    (dx, whose contraction is the product's n and whose result its k),
+    where `jax.grad` alone would hand dx the product's string."""
+    rows, mats = jnp.ones((128, 640)), jnp.ones((2, 640, 384))
+    sizes = jnp.asarray([30, 0], jnp.int32)
+    own, round_ = ('ragged_dot_tiling = "128,640,384"',
+                   'ragged_dot_tiling = "128,384,640"')
+
+    def product(rows, mats):
+        return lm.told_grouped(rows, mats, sizes, "128,640,384").sum()
+    forward = jax.jit(product).lower(rows, mats).as_text()
+    assert own in forward and round_ not in forward
+    backward = jax.jit(jax.grad(product, argnums=(0, 1))).lower(
+        rows, mats).as_text()
+    assert own in backward and round_ in backward
+    d_rows, d_mats = jax.grad(product, argnums=(0, 1))(rows, mats)
+    want = jax.grad(lambda r, m: jax.lax.ragged_dot(r, m, sizes).sum(),
+                    argnums=(0, 1))(rows, mats)
+    close(d_rows, want[0])
+    close(d_mats, want[1])
+
+
 def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):
     s = ref.shapes(ps_cfg)
     test_rows = rows_of(task, 3, seed=4)
