@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from kafka_ps_tpu.models import lm_common as lm
-from kafka_ps_tpu.models.lm_common import rms_norm, sub
+from kafka_ps_tpu.models.lm_common import (rms_norm, rope, sub, swiglu,
+                                            swiglu_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -170,19 +171,6 @@ def init_leaves(c: Glm4Config) -> dict:
 
 # -- the layers ------------------------------------------------------------------
 
-def rope(x, theta: float):
-    """Rotate-half RoPE over the whole last axis; positions run along
-    axis -3 of `[..., S, heads, d]`."""
-    d = x.shape[-1]
-    s = x.shape[-3]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
-    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, None, :]
-    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
 def mla(x, p: dict, c: Glm4Config):
     """Latent attention on `[B, S, H]`, causal within a row."""
     with jax.named_scope("kps.mla"):
@@ -205,18 +193,6 @@ def mla(x, p: dict, c: Glm4Config):
         probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
         return out.reshape(b, s, nh * dv) @ p["wo"]
-
-
-def swiglu(h, w_gate, w_up, w_down):
-    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
-
-
-def swiglu_experts(xs, p: dict, dot):
-    """What `lm_common.routed_experts` is handed: one held expert on its
-    own rows, every expert at once; `dot` is the grouped product over
-    the sorted assignments."""
-    return dot(jax.nn.silu(dot(xs, p["e_gate"])) * dot(xs, p["e_up"]),
-               p["e_down"])
 
 
 def _shared_expert(h, p: dict):

@@ -1,9 +1,11 @@
 """What the language-model families share (`glm4_moe_lite`,
-`nemotron_h`): token rows, RMSNorm, the sliced head and its loss, the
-router, the expert layer that knows its share, the counters, the flat
-key space, the k-step solver with its counts, the evaluation, and the
-task's frame.  A family brings its own configuration, its leaves, its
-blocks and its expert's function; nothing here tests a family's name.
+`nemotron_h`, `afmoe`): token rows, RMSNorm, RoPE, the blocked
+attention core, the sliced head and its loss, the router, the expert
+layer that knows its share, the gated (SwiGLU) expert, the counters,
+the flat key space, the k-step solver with its counts, the evaluation,
+and the task's frame.  A family brings its own configuration, its
+leaves, its blocks and its expert's function; nothing here tests a
+family's name.
 
 Token rows are `int32[S + 2]`, whose labels are the row itself,
 shifted.  A family's configuration is one JSON file (`--model_json`,
@@ -135,6 +137,94 @@ def rms_norm(x, w, eps: float):
                              + eps) * w
 
 
+def rope(x, theta: float):
+    """Rotate-half RoPE over the whole last axis; positions run along
+    axis -3 of `[..., S, heads, d]`."""
+    d = x.shape[-1]
+    s = x.shape[-3]
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)[:, None, :]
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)[:, None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
+# -- the blocked attention core ------------------------------------------------
+
+def key_span(tile: int, block: int, window: int | None) -> tuple[int, int]:
+    """The keys `[lo, hi)` a tile of `block` queries is set against:
+    up to the tile's own end (causal), and in a sliding layer from the
+    whole block that holds the earliest key its first query sees
+    (itself and the `window - 1` before it)."""
+    first = tile * block
+    lo = 0 if window is None else max(0, first - window + 1) // block * block
+    return lo, first + block
+
+
+def attention_pairs(s: int, window: int | None) -> int:
+    """(query, key) pairs INSIDE the mask over one row of `s` tokens:
+    query i sees key j iff j <= i, and under a window iff also
+    i - j < window."""
+    return sum(min(i + 1, window or s) for i in range(s))
+
+
+def attention_block_pairs(s: int, window: int | None, block: int) -> int:
+    """The pairs inside every block `blocked_attention` computes at
+    all, over one row: each tile of queries times its `key_span`."""
+    spans = (key_span(t, block, window) for t in range(s // block))
+    return sum(block * (hi - lo) for lo, hi in spans)
+
+
+def _attend_tile(q, k, v, first: int, lo: int, window: int | None):
+    """One tile of queries `[B, T, G, R, D]` (already scaled), whose
+    first sits at position `first`, against the keys and values `[B, L,
+    G, D]` from position `lo` on: scores, mask, softmax, values.  The
+    tile's whole span of keys is in hand, so the softmax is plain: no
+    running maximum; every query sees at least its own key."""
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k)
+    at_q = first + jnp.arange(q.shape[1])[:, None]
+    at_k = lo + jnp.arange(k.shape[1])[None, :]
+    seen = at_k <= at_q
+    if window is not None:
+        seen &= at_q - at_k < window
+    scores = jnp.where(seen, scores, -jnp.inf)
+    p = jnp.exp(scores - scores.max(axis=-1, keepdims=True))
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", p, v)
+    return out / jnp.moveaxis(p.sum(axis=-1), 3, 1)[..., None]
+
+
+def blocked_attention(q, k, v, *, window: int | None, block: int):
+    """Causal softmax attention over grouped-query heads, a tile of
+    `block` queries at a time: `q` `[B, S, G, R, D]` (R query heads to
+    each of the G key/value heads), `k`, `v` `[B, S, G, D]` -> `[B, S,
+    G, R, D]`.  Query i sees key j iff `j <= i`, and under `window`
+    iff also `i - j < window` (None: a full layer).
+
+    Each tile is set against its own slice of keys (`key_span`) and no
+    other: in a sliding layer the blocks the band cannot reach are
+    never computed, stored or differentiated, so the work is S x
+    (window + block) and not S x S, and no array of S x S elements a
+    head exists anywhere — the largest is one tile's `[G, R, block,
+    hi - lo]` scores.  Each tile is recomputed in the backward pass
+    (`jax.checkpoint`, inside the block's own): what is kept of it is
+    its slice of q, k and v.  The tiles are written out, since their
+    spans differ in length; `block` must divide S."""
+    s, d = q.shape[1], q.shape[-1]
+    if s % block:
+        raise ValueError(f"block {block} must divide the row's {s} tokens")
+    q = q * (1.0 / math.sqrt(d))
+    tiles = []
+    for t in range(s // block):
+        lo, hi = key_span(t, block, window)
+        tiles.append(jax.checkpoint(functools.partial(
+            _attend_tile, first=t * block, lo=lo, window=window))(
+                q[:, t * block:(t + 1) * block], k[:, lo:hi], v[:, lo:hi]))
+    return jnp.concatenate(tiles, axis=1)
+
+
+# -- the expert layer ------------------------------------------------------------
+
 def route(h, router, bias, c):
     """Every token over ALL experts → (chosen experts [T, K], their
     weights [T, K]).  float32 at `highest` precision, as the published
@@ -227,6 +317,11 @@ def told_grouped(rows, matrices, sizes, tiles: str):
         return jax.lax.ragged_dot(rows, matrices, sizes)
 
 
+def _zero_past_the_last_group(d_rows, sizes):
+    live = (jnp.arange(d_rows.shape[0]) < sizes.sum())[:, None]
+    return jnp.where(live, d_rows, 0.0)
+
+
 def _told_grouped_fwd(rows, matrices, sizes, tiles):
     return told_grouped(rows, matrices, sizes, tiles), (rows, matrices, sizes)
 
@@ -236,8 +331,7 @@ def _told_grouped_bwd(tiles, kept, dy):
     tm, tk, tn = tiles.split(",")
     with set_xla_metadata(ragged_dot_tiling=f"{tm},{tn},{tk}"):
         d_rows = jax.lax.ragged_dot(dy, jnp.swapaxes(matrices, 1, 2), sizes)
-    live = (jnp.arange(rows.shape[0]) < sizes.sum())[:, None]
-    d_rows = jnp.where(live, d_rows, 0.0)
+    d_rows = _zero_past_the_last_group(d_rows, sizes)
     with set_xla_metadata(ragged_dot_tiling=tiles):
         d_matrices = jax.lax.ragged_dot_general(rows, dy, sizes, _GROUPED_DW)
     return d_rows, d_matrices, None
@@ -246,12 +340,39 @@ def _told_grouped_bwd(tiles, kept, dy):
 told_grouped.defvjp(_told_grouped_fwd, _told_grouped_bwd)
 
 
+@jax.custom_vjp
+def live_rows_only(rows, sizes):
+    """The sorted rows of a grouped product as they are; backward,
+    the cotangent of the rows past the last group is cut off.  A family
+    whose products are NOT told their tiles wraps its expert's input in
+    this (`dot.sizes` are the groups): the untold kernel skips the row
+    tiles no group reaches, what it leaves there is whatever the buffer
+    held, and the placement's transpose in `routed_experts` sums dx
+    over ALL rows, the dead ones with weight 0 — 0 x NaN (on the chip at
+    `[4096, 2048] x [8, 2048, 1024]`: NaN from the first clock on a
+    machine whose memory had held NaN, PERF.md PR 33).  `told_grouped`
+    masks its own dx the same way."""
+    return rows
+
+
+def _live_rows_only_fwd(rows, sizes):
+    return rows, sizes
+
+
+def _live_rows_only_bwd(sizes, d_rows):
+    return _zero_past_the_last_group(d_rows, sizes), None
+
+
+live_rows_only.defvjp(_live_rows_only_fwd, _live_rows_only_bwd)
+
+
 def routed_experts(h, idx, w, p: dict, c, expert):
     """The part of Σ w_e · expert_e(h) that the experts held here give
     → ([T, H], (assignments here, largest expert's load, 1 if the pass
     went over `live_rows_bound`)).  `expert(xs, p, dot)` is the family's
     own: what one expert computes on its rows, every product with the
-    held experts' stacked matrices through `dot(rows, matrices)`.
+    held experts' stacked matrices through `dot(rows, matrices)`
+    (`dot.sizes`: the rows each held expert's group has).
 
     The (token, chosen expert) assignments are sorted by expert, absent
     experts last; the held experts' products run as grouped products
@@ -291,6 +412,7 @@ def routed_experts(h, idx, w, p: dict, c, expert):
             if tiles is None:
                 return jax.lax.ragged_dot(rows, matrices, sizes)
             return told_grouped(rows, matrices, sizes, tiles)
+        grouped.sizes = sizes       # for a family's `live_rows_only`
 
         def placed(rows: int):
             """The sum from the first `rows` sorted assignments."""
@@ -312,6 +434,18 @@ def routed_experts(h, idx, w, p: dict, c, expert):
                             functools.partial(placed, bound)))
         return out, jnp.stack([n_here, sizes.max(),
                                went_over.astype(jnp.int32)])
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    return (jax.nn.silu(h @ w_gate) * (h @ w_up)) @ w_down
+
+
+def swiglu_experts(xs, p: dict, dot):
+    """What `routed_experts` is handed by a family whose experts are
+    gated: one held expert on its own rows, every expert at once; `dot`
+    is the grouped product over the sorted assignments."""
+    return dot(jax.nn.silu(dot(xs, p["e_gate"])) * dot(xs, p["e_up"]),
+               p["e_down"])
 
 
 def expert_layer(x, p: dict, c, expert, shared):

@@ -149,3 +149,55 @@ def test_the_second_language_models_chunk_fits_the_chip_too(aot, topo):
     assert not any(tiles.endswith(",128,128") for _, tiles in calls)
     # the chunked scan is in the program under its own scope
     assert "kps.ssm.scan" in text and "kps.attn" in text
+
+
+def test_the_third_language_models_chunk_holds_no_square_of_scores(aot, topo):
+    """The scan chunk of `trinity-mini-ep16.fused-bsp` (504.1 M
+    parameters held, 4 workers folded one at a time, 1 row of 4,096
+    tokens a worker, 8 clocks), compiled for the described chip — PR
+    27's four findings as assertions for this family too: the leaves
+    are donated, scratch + donated leaves stay under 15.0 GB (9.20 +
+    2.02 GB when written), and there is no second copy of the shared
+    leaves (2.02 GB each, which 10.5 GB of scratch does not hold).  And
+    the attention core is blocked: no array of three or more axes has
+    the row's 4,096 tokens on two of them — the largest score arrays are
+    single tiles `[1, 4, 8, 512, L]`, L the tile's span of keys, which a
+    sliding layer keeps to window + block = 2,560 — where the other two
+    families' attention would hold `[1, 32, 4096, 4096]`, 2.1 GB a layer
+    a pass.  About 150 s."""
+    import re
+    task, compiled = aot.compile_folded_chunk(
+        "afmoe", "benchmark/configs/trinity-mini-ep16.model.json", topo)
+    assert task.num_params == 504_147_712
+    c = task.arch
+    s, block = c.sequence_length, c.attention_block
+    assert (s, block, c.sliding_window) == (4096, 512, 2048)
+    memory = compiled.memory_analysis()
+    leaves = 4 * task.num_params
+    assert memory.alias_size_in_bytes >= leaves
+    assert memory.temp_size_in_bytes + leaves < 15.0e9, \
+        memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
+    assert not [sh for sh in shapes if len(sh) >= 3 and sh.count(s) >= 2]
+    assert not [sh for sh in shapes if s * s in sh]
+    # the tiles' scores, by their span of keys: every whole number of
+    # blocks up to the row (the full layer's, and a sliding layer's
+    # first five), none longer
+    spans = {sh[-1] for sh in shapes
+             if sh[:4] == (1, c.num_key_value_heads, 8, block)}
+    assert spans == set(range(block, s + block, block))
+    # the grouped products at `[rows, 2048] x [8, 2048, 1024]`, under
+    # the bound's 4,096 rows and over it at 32,768: the chip's own
+    # kernel in the compiler's own tiles, as at the GLM family's widths
+    calls = aot.ragged_dot_calls(text)
+    assert {shape for shape, _ in calls} == {
+        (m, k, n) for m in (4096, 32768)
+        for k, n in ((2048, 1024), (1024, 2048))}
+    assert {tiles for _, tiles in calls} == {"512,512,512"}
+    assert {lm.grouped_tiles(*shape) for shape, _ in calls} == {None}
+    for scope in ("kps.attn.window", "kps.attn.full", "kps.attn.proj",
+                  "kps.mlp", "kps.moe.experts"):
+        assert scope in text, scope
