@@ -15,6 +15,10 @@ the models the repo supports, on data made from a seed:
   * the language models' grouped products told their tiles
     (`models/lm_common.py` `grouped_tiles`) against the chip's kernel
     left to itself, at the widths of the second language-model cell;
+  * the attention core as the kernel (`models/attention_kernel.py`)
+    against its plain tiles at the third language-model cell's shapes,
+    a sliding and a full layer, forward and `jax.grad`: the gaps and
+    the ms a call of each;
   * with more than one chip: `--fused -r` and `--fused --param_shards`
     over all of them.
 
@@ -63,6 +67,12 @@ class Sizes:
     center_scale: float = 0.2     # synth.HARD_CENTER_SCALE: class overlap
     grouped_rows: int = 768       # a pass's placed rows, 8 held experts
     grouped_widths: tuple = (2688, 1856)      # hidden x expert width
+    # the attention core at the third language-model cell's shapes: q
+    # [B, S, G, R, D], the sliding layers' window, the tile
+    core_shape: tuple = (1, 4096, 4, 8, 128)
+    core_window: int = 2048
+    core_block: int = 512
+    core_calls: int = 5           # timed calls a program, after a warm one
 
 
 class SmokeFailure(RuntimeError):
@@ -337,6 +347,73 @@ def phase_grouped_products(sizes: Sizes, platform: str) -> dict:
     return rec
 
 
+def phase_attention_core(sizes: Sizes, platform: str, *,
+                         interpret: bool = False) -> dict:
+    """The attention core as the kernel (`attention_kernel.attend`)
+    against today's plain tiles (`lm_common._attend_tiles`) at the
+    third language-model cell's shapes, a sliding and a full layer:
+    the output and all three gradients of both, the largest gap of each
+    as a share of the tiles' largest value (the two round the same
+    operands to bfloat16 and sum in another order), and the ms a call
+    of each, forward and `jax.grad` — the sweep PERF.md records.
+    `interpret` runs the kernel in Pallas's interpreter (the CPU
+    test)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.models import attention_kernel
+    from kafka_ps_tpu.models import lm_common as lm
+
+    name, started = "attention_core", time.time()
+    shape, block = sizes.core_shape, sizes.core_block
+    require(attention_kernel.takes(shape, block), name,
+            f"the kernel does not take {shape} in tiles of {block}")
+    rng = np.random.default_rng(0)
+    b, s, g, r, d = shape
+    q, seen = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+               for _ in range(2))
+    q = q / np.sqrt(d)
+    k, v = (jnp.asarray(rng.standard_normal((b, s, g, d)), jnp.float32)
+            for _ in range(2))
+    require(_platform_of(q) == platform, name,
+            f"the queries live on {_platform_of(q)}")
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t = time.perf_counter()
+        for _ in range(sizes.core_calls):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t) / sizes.core_calls * 1e3
+
+    rec = {"shape": list(shape), "block": block}
+    for kind, window in (("window", sizes.core_window), ("full", None)):
+        cores = {
+            "kernel": lambda q, k, v: attention_kernel.attend(
+                q, k, v, window, block, interpret),
+            "tiles": lambda q, k, v: lm._attend_tiles(
+                q, k, v, window=window, block=block)}
+        got = {}
+        for way, core in cores.items():
+            out, rec[f"{kind}_{way}_forward_ms"] = timed(jax.jit(core),
+                                                         q, k, v)
+            grads, rec[f"{kind}_{way}_grad_ms"] = timed(jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(core(q, k, v) * seen),
+                argnums=(0, 1, 2))), q, k, v)
+            got[way] = (out, *grads)
+        for what, a, b in zip(("out", "dq", "dk", "dv"), got["kernel"],
+                              got["tiles"]):
+            a, b = np.asarray(a), np.asarray(b)
+            gap = float(np.abs(a - b).max() / np.abs(b).max())
+            require(np.isfinite(a).all() and gap <= 0.02, name,
+                    f"{kind} {what}: the kernel is off the tiles' by "
+                    f"{gap} of their largest")
+            rec[f"{kind}_{what}_gap"] = gap
+    rec["wall_s"] = round(time.time() - started, 2)
+    return rec
+
+
 def phase_multichip(workdir: str, train: str, test: str, sizes: Sizes,
                     platform: str, device_count: int) -> dict:
     """The fused path over every chip of the host: `--fused -r` (1-D
@@ -409,6 +486,7 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
         phases[f"fused_mlp{sizes.fused_hidden}_eval{eval_every}"] = \
             phase_fused(workdir, train, test, sizes, platform, eval_every)
     phases["grouped_products"] = phase_grouped_products(sizes, platform)
+    phases["attention_core"] = phase_attention_core(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,
                                       platform, device_count))

@@ -35,8 +35,11 @@ frame are `models/lm_common.py`'s, shared with `glm4_moe_lite` and
     normalised by `route_norm`, times `route_scale`), SwiGLU experts and
     one shared expert of the same form.
 
-Every layer is recomputed in the backward pass (`jax.checkpoint`), each
-tile of the attention core inside it once more.  The layers are written
+Every layer is recomputed in the backward pass (`jax.checkpoint`); where
+the attention core runs as plain tiles, each tile inside it once more,
+and where it runs as the chip's kernel (`models/attention_kernel.py`: a
+`head_dim` and a tile of whole lanes, on a TPU) the backward kernel
+forms a block's scores once more itself.  The layers are written
 out in their published order, each with leaves of its own
 (`l<i>.<name>`): their kinds differ, so there is no stack to scan.
 
@@ -333,7 +336,8 @@ class AfmoeTask(lm.TokenRowsTask):
     model_type = "afmoe"
     config_cls = AfmoeConfig
     counter_names = lm.COUNTERS + ("attn.pairs_window", "attn.pairs_full",
-                                   "attn.block_pairs")
+                                   "attn.block_pairs",
+                                   "attn.kernel_block_pairs")
 
     def leaf_specs(self):
         return leaf_specs(self.arch)
@@ -354,6 +358,13 @@ class AfmoeTask(lm.TokenRowsTask):
     def own_counts(self, rows) -> tuple:
         """`attn.pairs_window`, `attn.pairs_full`, `attn.block_pairs` of
         one pass, every row of the slab through every layer, in units of
-        PAIRS_UNIT pairs (rounded down once a pass)."""
-        return tuple(rows.shape[0] * n // PAIRS_UNIT
-                     for n in pair_counts(self.arch))
+        PAIRS_UNIT pairs (rounded down once a pass); and
+        `attn.kernel_block_pairs`, the blocks' pairs again where the
+        core ran them as the kernel, 0 where as plain tiles."""
+        c = self.arch
+        window, full, blocks = (rows.shape[0] * n // PAIRS_UNIT
+                                for n in pair_counts(c))
+        heads = c.num_attention_heads // c.num_key_value_heads
+        return window, full, blocks, blocks * lm.kernel_attends(
+            (rows.shape[0], c.sequence_length, c.num_key_value_heads, heads,
+             c.head_dim), c.attention_block)

@@ -1,6 +1,7 @@
 """What the language-model families share (`glm4_moe_lite`,
 `nemotron_h`, `afmoe`): token rows, RMSNorm, RoPE, the blocked
-attention core, the sliced head and its loss, the router, the expert
+attention core (on a TPU a kernel, `models/attention_kernel.py`, where
+the shapes allow), the sliced head and its loss, the router, the expert
 layer that knows its share, the gated (SwiGLU) expert, the counters,
 the flat key space, the k-step solver with its counts, the evaluation,
 and the task's frame.  A family brings its own configuration, its
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.xla_metadata import set_xla_metadata
 
+from kafka_ps_tpu.models import attention_kernel
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.models import task as task_mod
 from kafka_ps_tpu.utils.config import ModelConfig
@@ -194,6 +196,21 @@ def _attend_tile(q, k, v, first: int, lo: int, window: int | None):
     return out / jnp.moveaxis(p.sum(axis=-1), 3, 1)[..., None]
 
 
+def _attend_tiles(q, k, v, *, window: int | None, block: int):
+    """The core in plain `jax.numpy` on `q` already scaled, the tiles
+    written out since their spans differ in length: each tile's scores
+    `[G, R, block, hi - lo]` are the largest array, and each tile is
+    recomputed in the backward pass (`jax.checkpoint`, inside the
+    block's own); what is kept of it is its slice of q, k and v."""
+    tiles = []
+    for t in range(q.shape[1] // block):
+        lo, hi = key_span(t, block, window)
+        tiles.append(jax.checkpoint(functools.partial(
+            _attend_tile, first=t * block, lo=lo, window=window))(
+                q[:, t * block:(t + 1) * block], k[:, lo:hi], v[:, lo:hi]))
+    return jnp.concatenate(tiles, axis=1)
+
+
 def blocked_attention(q, k, v, *, window: int | None, block: int):
     """Causal softmax attention over grouped-query heads, a tile of
     `block` queries at a time: `q` `[B, S, G, R, D]` (R query heads to
@@ -205,22 +222,34 @@ def blocked_attention(q, k, v, *, window: int | None, block: int):
     other: in a sliding layer the blocks the band cannot reach are
     never computed, stored or differentiated, so the work is S x
     (window + block) and not S x S, and no array of S x S elements a
-    head exists anywhere — the largest is one tile's `[G, R, block,
-    hi - lo]` scores.  Each tile is recomputed in the backward pass
-    (`jax.checkpoint`, inside the block's own): what is kept of it is
-    its slice of q, k and v.  The tiles are written out, since their
-    spans differ in length; `block` must divide S."""
+    head exists anywhere; `block` must divide S.
+
+    One algorithm, two ways to run it, and the input says which.  On a
+    TPU, at shapes the kernel takes (`attention_kernel.takes`: a
+    `head_dim` and a `block` of whole lanes, 128), the core is
+    `attention_kernel.attend`: a block's scores are formed once, in
+    VMEM, under a running maximum, and only the output and the rows'
+    log-sum-exp are written.  Anywhere else — another platform, a
+    `head_dim` of 8 — it is `_attend_tiles`, plain `jax.numpy`, and
+    the program is what it was before there was a kernel."""
     s, d = q.shape[1], q.shape[-1]
     if s % block:
         raise ValueError(f"block {block} must divide the row's {s} tokens")
     q = q * (1.0 / math.sqrt(d))
-    tiles = []
-    for t in range(s // block):
-        lo, hi = key_span(t, block, window)
-        tiles.append(jax.checkpoint(functools.partial(
-            _attend_tile, first=t * block, lo=lo, window=window))(
-                q[:, t * block:(t + 1) * block], k[:, lo:hi], v[:, lo:hi]))
-    return jnp.concatenate(tiles, axis=1)
+    tiles = functools.partial(_attend_tiles, window=window, block=block)
+    if not attention_kernel.takes(q.shape, block):
+        return tiles(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=tiles, tpu=lambda q, k, v: attention_kernel.attend(
+            q, k, v, window, block))
+
+
+def kernel_attends(q_shape, block: int):
+    """1 where `blocked_attention` runs `q_shape` as the kernel, 0 where
+    as plain tiles: chosen as the core itself is chosen."""
+    if not attention_kernel.takes(q_shape, block):
+        return 0
+    return jax.lax.platform_dependent(tpu=lambda: 1, default=lambda: 0)
 
 
 # -- the expert layer ------------------------------------------------------------

@@ -5,6 +5,8 @@ one process)."""
 
 import os
 
+import pytest
+
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
@@ -24,3 +26,20 @@ pytest_plugins = ("kafka_ps_tpu.analysis.pytest_plugin",)
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process end-to-end jobs (seconds each)")
+
+
+@pytest.fixture
+def the_tpus_branch(monkeypatch):
+    """The attention core as the chip runs it, on the CPU: every
+    `jax.lax.platform_dependent` takes its `tpu` branch and the kernel
+    runs in Pallas's interpreter.  The test's own steering; the program
+    has no option that does this."""
+    import jax
+
+    from kafka_ps_tpu.models import attention_kernel
+    kernel = attention_kernel.attend
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    monkeypatch.setattr(
+        attention_kernel, "attend",
+        lambda q, k, v, window, block: kernel(q, k, v, window, block, True))

@@ -23,6 +23,7 @@ import numpy as np
 import pytest
 
 from kafka_ps_tpu.models import afmoe
+from kafka_ps_tpu.models import attention_kernel
 from kafka_ps_tpu.models import lm_common as lm
 from kafka_ps_tpu.models.task import get_task
 from kafka_ps_tpu.parallel import bsp
@@ -236,6 +237,7 @@ def test_fused_clocks_agree_with_the_reference(task, ref, ps_cfg, theta,
                         ("attn.pairs_full", full),
                         ("attn.block_pairs", blocks)):
         assert counted[name] == passes * (2 * pairs // afmoe.PAIRS_UNIT)
+    assert counted["attn.kernel_block_pairs"] == 0      # head_dim 16
 
 
 def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):
@@ -362,6 +364,44 @@ def test_a_sliding_layer_skips_the_blocks_the_band_cannot_reach():
         lm.blocked_attention(jnp.zeros((1, 12, 1, 1, 4)),
                              jnp.zeros((1, 12, 1, 4)),
                              jnp.zeros((1, 12, 1, 4)), window=4, block=8)
+
+
+def test_the_kernels_counter_is_the_blocks_where_the_kernel_ran(
+        tmp_path, request):
+    """`attn.kernel_block_pairs` through `fit_counted` at a tiny size
+    the kernel takes (a sliding and a full layer, `head_dim` 128, rows
+    of 256 in one tile): 0 where the plain tiles ran — this platform —
+    and `attn.block_pairs` with the TPU's branch taken, where the loss
+    and the step are the plain path's to bfloat16's rounding."""
+    body = json.load(open(os.path.join(ROOT, TINY)))
+    body.update(head_dim=128, num_attention_heads=2, num_key_value_heads=1,
+                sequence_length=256, sliding_window=200, num_hidden_layers=2,
+                layer_types=[afmoe.SLIDING, afmoe.FULL])
+    path = tmp_path / "kernel_legal.model.json"
+    path.write_text(json.dumps(body))
+    task = get_task("afmoe", ModelConfig(
+        num_max_iter=1, local_learning_rate=0.05, model_json=str(path)))
+    c = task.arch
+    assert attention_kernel.takes((2, 256, 1, 2, 128), c.attention_block)
+    leaves = task.unflatten(task.init_params())
+    x, mask = rows_of(task, 2), jnp.ones((2,), jnp.float32)
+
+    def fit():
+        new, loss, counted = task.fit_counted(leaves, x, None, mask)
+        return (np.asarray(task.flatten(new)), float(loss),
+                dict(zip(task.counter_names, np.asarray(counted))))
+    plain, plain_loss, counted = fit()
+    assert counted["attn.kernel_block_pairs"] == 0
+    blocks = 2 * (2 * afmoe.pair_counts(c)[2] // afmoe.PAIRS_UNIT)
+    assert counted["attn.block_pairs"] == blocks > 0
+    request.getfixturevalue("the_tpus_branch")
+    new, loss, counted = fit()
+    assert counted["attn.kernel_block_pairs"] \
+        == counted["attn.block_pairs"] == blocks
+    assert abs(loss - plain_loss) <= 1e-3 * plain_loss
+    start = np.asarray(task.init_params())
+    assert 0 < np.linalg.norm(new - plain) <= 0.02 * np.linalg.norm(
+        plain - start)
 
 
 def test_rope_is_in_the_sliding_layers_and_not_in_the_full_one(task, theta):

@@ -73,7 +73,8 @@ def test_the_three_families_import_one_frame():
                    "init_params", "encode_labels", "fit"):
         assert shared not in vars(afmoe.AfmoeTask), shared
     assert afmoe.AfmoeTask.counter_names == lm.COUNTERS + (
-        "attn.pairs_window", "attn.pairs_full", "attn.block_pairs")
+        "attn.pairs_window", "attn.pairs_full", "attn.block_pairs",
+        "attn.kernel_block_pairs")
 
 
 def test_the_single_step_is_one_round_of_the_chunk(task, ps_cfg):
@@ -301,6 +302,9 @@ def test_the_fused_loop_sums_the_familys_counters_over_a_call(task, ps_cfg):
     assert counters["attn.pairs_full"] == 32 * 3 * (2 * full // 1024)
     assert counters["attn.block_pairs"] == 32 * 3 * (2 * blocks // 1024)
     assert counters["attn.block_pairs"] > counters["attn.pairs_window"] > 0
+    # through the CPU runtime the core is its plain tiles, whatever the
+    # size: the kernel computed none of those blocks
+    assert counters["attn.kernel_block_pairs"] == 0
     assert tracer.counters()["attn.block_pairs"] \
         == counters["attn.block_pairs"]
     assert app.server.last_metrics is not None

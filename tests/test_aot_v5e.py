@@ -7,6 +7,7 @@ skips where libtpu offers no topology."""
 
 import functools
 import importlib.util
+import math
 import os
 
 import pytest
@@ -156,15 +157,23 @@ def test_the_third_language_models_chunk_holds_no_square_of_scores(aot, topo):
     parameters held, 4 workers folded one at a time, 1 row of 4,096
     tokens a worker, 8 clocks), compiled for the described chip — PR
     27's four findings as assertions for this family too: the leaves
-    are donated, scratch + donated leaves stay under 15.0 GB (9.20 +
+    are donated, scratch + donated leaves stay under 15.0 GB (9.17 +
     2.02 GB when written), and there is no second copy of the shared
-    leaves (2.02 GB each, which 10.5 GB of scratch does not hold).  And
-    the attention core is blocked: no array of three or more axes has
-    the row's 4,096 tokens on two of them — the largest score arrays are
-    single tiles `[1, 4, 8, 512, L]`, L the tile's span of keys, which a
-    sliding layer keeps to window + block = 2,560 — where the other two
-    families' attention would hold `[1, 32, 4096, 4096]`, 2.1 GB a layer
-    a pass.  About 150 s."""
+    leaves (2.02 GB each, which 10.5 GB of scratch does not hold).
+
+    And the attention core is the kernel (models/attention_kernel.py,
+    PR 34): lowered for the chip, `blocked_attention` is Mosaic calls —
+    a forward one a layer a pass, recomputed with the layer in a
+    gradient pass, and a backward one — each a `custom-call` whose
+    `op_name` lies under `kps.attn.window` or `kps.attn.full`, which is
+    what the benchmark's readers find its device time by.  No score
+    array is in the program at all: with the plain tiles (PR 33) the
+    largest were single tiles `[1, 4, 8, 512, L]`, L the tile's span of
+    keys up to window + block = 2,560 in a sliding layer and 4,096 in
+    the full one, computed three times forward; the other two families'
+    attention would hold `[1, 32, 4096, 4096]`, 2.1 GB a layer a pass.
+    The scratch stays at or under the plain tiles' 9,203,257,856 bytes.
+    About 150 s."""
     import re
     task, compiled = aot.compile_folded_chunk(
         "afmoe", "benchmark/configs/trinity-mini-ep16.model.json", topo)
@@ -177,18 +186,44 @@ def test_the_third_language_models_chunk_holds_no_square_of_scores(aot, topo):
     assert memory.alias_size_in_bytes >= leaves
     assert memory.temp_size_in_bytes + leaves < 15.0e9, \
         memory.temp_size_in_bytes
-    assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes <= 9_203_257_856, \
+        memory.temp_size_in_bytes
     text = compiled.as_text()
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
     assert not [sh for sh in shapes if len(sh) >= 3 and sh.count(s) >= 2]
     assert not [sh for sh in shapes if s * s in sh]
-    # the tiles' scores, by their span of keys: every whole number of
-    # blocks up to the row (the full layer's, and a sliding layer's
-    # first five), none longer
-    spans = {sh[-1] for sh in shapes
-             if sh[:4] == (1, c.num_key_value_heads, 8, block)}
-    assert spans == set(range(block, s + block, block))
+    # the core's calls, by kernel and scope: 2 gradient passes x
+    # (forward + recomputed) + the loss's forward = 5 forward calls a
+    # layer, 2 backward; 4 sliding layers and 1 full
+    calls = re.findall(
+        r"%(kps_attn_core_\w+?)[.\d]* = .* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*op_name=\"([^\"]*)\"", text)
+    scopes = ("kps.attn.window", "kps.attn.full")
+    assert all(sum(scope in op_name for scope in scopes) == 1
+               for _, op_name in calls), calls
+    assert {(kernel, scope): sum(k == kernel and scope in op_name
+                                 for k, op_name in calls)
+            for kernel in ("kps_attn_core_forward", "kps_attn_core_backward")
+            for scope in scopes} == {
+        ("kps_attn_core_forward", "kps.attn.window"): 20,
+        ("kps_attn_core_forward", "kps.attn.full"): 5,
+        ("kps_attn_core_backward", "kps.attn.window"): 8,
+        ("kps_attn_core_backward", "kps.attn.full"): 2}
+    assert text.count("kps_attn_core_") >= len(calls) == 35
+    # and nothing makes a tile's scores: no float32 array of a tile's
+    # 512 queries by a span of keys, under the core's scopes or anywhere
+    assert not [sh for sh in shapes
+                if len(sh) >= 2 and sh[-2] == block and sh[-1] >= block
+                and sh[-1] % block == 0 and 8 in sh[:-2]]
+    under_core = [line for line in text.splitlines()
+                  if any(scope in line for scope in scopes)]
+    made = {tuple(int(d) for d in dims.split(","))
+            for line in under_core
+            for dims in re.findall(r"= f32\[([\d,]+)\]", line)}
+    q_elements = s * c.num_attention_heads * c.head_dim
+    assert made and max(math.prod(sh) for sh in made) <= q_elements, \
+        sorted(made, key=math.prod)[-3:]
     # the grouped products at `[rows, 2048] x [8, 2048, 1024]`, under
     # the bound's 4,096 rows and over it at 32,768: the chip's own
     # kernel in the compiler's own tiles, as at the GLM family's widths

@@ -21,7 +21,9 @@ TINY = chip_smoke.Sizes(
     buffer_max=64, train_rows=512, test_rows=128, per_node_clocks=12,
     fused_rounds=16, multichip_rounds=8,
     center_scale=1.0,           # too few rows to learn the hard regime
-    grouped_rows=128, grouped_widths=(640, 384))    # the rule still hints
+    grouped_rows=128, grouped_widths=(640, 384),    # the rule still hints
+    core_shape=(1, 384, 1, 2, 128), core_window=200, core_block=128,
+    core_calls=1)
 
 
 def _run(script_dir, env_extra, *args):
@@ -80,6 +82,19 @@ def test_grouped_products_phase():
         chip_smoke.phase_grouped_products(
             chip_smoke.Sizes(grouped_rows=128, grouped_widths=(512, 64)),
             "cpu")
+
+
+def test_attention_core_phase():
+    """The kernel in Pallas's interpreter against the plain tiles: the
+    gaps are bfloat16's, the times are the CPU's and mean nothing."""
+    rec = chip_smoke.phase_attention_core(TINY, "cpu", interpret=True)
+    for kind in ("window", "full"):
+        for what in ("out", "dq", "dk", "dv"):
+            assert 0 < rec[f"{kind}_{what}_gap"] < 0.02
+    with pytest.raises(chip_smoke.SmokeFailure, match="does not take"):
+        chip_smoke.phase_attention_core(
+            chip_smoke.Sizes(core_shape=(1, 64, 1, 2, 8), core_block=8),
+            "cpu", interpret=True)
 
 
 def test_multichip_phase_on_the_virtual_mesh(data):
