@@ -230,23 +230,38 @@ def attention(u, p: dict, c: AfmoeConfig, kind: str):
     with jax.named_scope("kps.attn"):
         b, s, _ = u.shape
         nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+        # the parts of `kps.attn.proj`, each in the order the program
+        # was written in before they had names: `kps.attn.qkv` (the q,
+        # k, v and gate projections), `kps.attn.norm_rope` (the two
+        # head norms and RoPE), `kps.attn.out` (the gate applied and
+        # the output projection)
+        def project(w, heads):
+            with jax.named_scope("kps.attn.qkv"):
+                return (u @ p[w]).reshape(b, s, heads, d)
+
+        def head_norm(x, w):
+            with jax.named_scope("kps.attn.norm_rope"):
+                return rms_norm(x, p[w], c.rms_norm_eps)
+
         with jax.named_scope("kps.attn.proj"):
-            q = rms_norm((u @ p["wq"]).reshape(b, s, nh, d), p["q_norm"],
-                         c.rms_norm_eps)
-            k = rms_norm((u @ p["wk"]).reshape(b, s, nkv, d), p["k_norm"],
-                         c.rms_norm_eps)
-            v = (u @ p["wv"]).reshape(b, s, nkv, d)
-            gate = jax.nn.sigmoid(u @ p["wg"])
-            if sliding:
-                q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
-            # query head h reads key/value head h // (heads / kv heads)
-            q = q.reshape(b, s, nkv, nh // nkv, d)
+            q = head_norm(project("wq", nh), "q_norm")
+            k = head_norm(project("wk", nkv), "k_norm")
+            v = project("wv", nkv)
+            with jax.named_scope("kps.attn.qkv"):
+                gate = jax.nn.sigmoid(u @ p["wg"])
+            with jax.named_scope("kps.attn.norm_rope"):
+                if sliding:
+                    q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
+                # query head h reads key/value head h // (heads / kv
+                # heads)
+                q = q.reshape(b, s, nkv, nh // nkv, d)
         with jax.named_scope("kps.attn.window" if sliding
                              else "kps.attn.full"):
             out = lm.blocked_attention(
                 q, k, v, window=c.sliding_window if sliding else None,
                 block=c.attention_block)
-        with jax.named_scope("kps.attn.proj"):
+        with jax.named_scope("kps.attn.proj"), \
+                jax.named_scope("kps.attn.out"):
             return (out.reshape(b, s, nh * d) * gate) @ p["wo"]
 
 
@@ -266,15 +281,16 @@ def layer(x, p: dict, c: AfmoeConfig, kind: str, dense: bool):
     """One layer on `[B, S, H]` -> (its output, an expert layer's counts
     or None)."""
     eps = c.rms_norm_eps
-    a = x + rms_norm(attention(rms_norm(x, p["in_norm"], eps), p, c, kind),
-                     p["post_attn_norm"], eps)
-    u = rms_norm(a, p["pre_mlp_norm"], eps)
+    a = x + lm.block_norm(
+        attention(lm.block_norm(x, p["in_norm"], eps), p, c, kind),
+        p["post_attn_norm"], eps)
+    u = lm.block_norm(a, p["pre_mlp_norm"], eps)
     if dense:
         with jax.named_scope("kps.mlp"):
             y, load = swiglu(u, p["w_gate"], p["w_up"], p["w_down"]), None
     else:
         y, load = lm.expert_layer(u, p, c, _experts, _shared_expert)
-    return a + rms_norm(y, p["post_mlp_norm"], eps), load
+    return a + lm.block_norm(y, p["post_mlp_norm"], eps), load
 
 
 def forward(leaves: dict, rows, c: AfmoeConfig, *, with_logits=False):

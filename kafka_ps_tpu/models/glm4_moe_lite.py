@@ -177,22 +177,40 @@ def mla(x, p: dict, c: Glm4Config):
         b, s, _ = x.shape
         nh, dn, dr, dv = (c.num_attention_heads, c.qk_nope_head_dim,
                           c.qk_rope_head_dim, c.v_head_dim)
-        h = rms_norm(x, p["in_norm"], c.rms_norm_eps)
-        cq = rms_norm(h @ p["wq_a"], p["q_norm"], c.rms_norm_eps)
-        q = (cq @ p["wq_b"]).reshape(b, s, nh, dn + dr)
-        kva = h @ p["wkv_a"]
-        ckv = rms_norm(kva[..., :c.kv_lora_rank], p["kv_norm"],
-                       c.rms_norm_eps)
-        k_rope = rope(kva[..., None, c.kv_lora_rank:], c.rope_theta)
-        kv = (ckv @ p["wkv_b"]).reshape(b, s, nh, dn + dv)
-        q_rope = rope(q[..., dn:], c.rope_theta)
+        # the parts, in the order the program was written in before
+        # they had names: `kps.attn.qkv` (the four projections to and
+        # from the latents), `kps.attn.norm_rope` (the latents' norms
+        # and RoPE), `kps.attn.out` (the output projection); the core
+        # (scores, mask, softmax, values) is what is left under
+        # `kps.mla` alone
+        def project(x, w):
+            with jax.named_scope("kps.attn.qkv"):
+                return x @ p[w]
+
+        def norm(x, w):
+            with jax.named_scope("kps.attn.norm_rope"):
+                return rms_norm(x, p[w], c.rms_norm_eps)
+
+        def positions(x):
+            with jax.named_scope("kps.attn.norm_rope"):
+                return rope(x, c.rope_theta)
+
+        h = lm.block_norm(x, p["in_norm"], c.rms_norm_eps)
+        cq = norm(project(h, "wq_a"), "q_norm")
+        q = project(cq, "wq_b").reshape(b, s, nh, dn + dr)
+        kva = project(h, "wkv_a")
+        ckv = norm(kva[..., :c.kv_lora_rank], "kv_norm")
+        k_rope = positions(kva[..., None, c.kv_lora_rank:])
+        kv = project(ckv, "wkv_b").reshape(b, s, nh, dn + dv)
+        q_rope = positions(q[..., dn:])
         scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :dn], kv[..., :dn])
                   + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope[:, :, 0]))
         scores = scores / math.sqrt(dn + dr)
         causal = jnp.tril(jnp.ones((s, s), bool))
         probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs, kv[..., dn:])
-        return out.reshape(b, s, nh * dv) @ p["wo"]
+        with jax.named_scope("kps.attn.out"):
+            return out.reshape(b, s, nh * dv) @ p["wo"]
 
 
 def _shared_expert(h, p: dict):
@@ -208,13 +226,13 @@ def moe(x, p: dict, c: Glm4Config):
 
 def dense_block(x, p: dict, c: Glm4Config):
     x = x + mla(x, p, c)
-    h = rms_norm(x, p["post_norm"], c.rms_norm_eps)
+    h = lm.block_norm(x, p["post_norm"], c.rms_norm_eps)
     return x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
 
 
 def moe_block(x, p: dict, c: Glm4Config):
     x = x + mla(x, p, c)
-    y, load = moe(rms_norm(x, p["post_norm"], c.rms_norm_eps), p, c)
+    y, load = moe(lm.block_norm(x, p["post_norm"], c.rms_norm_eps), p, c)
     return x + y, load
 
 
@@ -233,9 +251,13 @@ def forward(leaves: dict, rows, c: Glm4Config, *, with_logits=False):
         x = leaves["embed"][tokens]
     x = jax.checkpoint(lambda x, p: dense_block(x, p, c))(
         x, sub(leaves, "dense."))
-    x, loads = jax.lax.scan(
-        jax.checkpoint(lambda x, p: moe_block(x, p, c)), x,
-        sub(leaves, "moe."))
+    # what lies under `kps.lm.layers` and no scope inside a block is the
+    # scan's own: a layer's leaves cut out of the stack, its gradients
+    # written back into theirs, the residual adds
+    with jax.named_scope("kps.lm.layers"):
+        x, loads = jax.lax.scan(
+            jax.checkpoint(lambda x, p: moe_block(x, p, c)), x,
+            sub(leaves, "moe."))
     with jax.named_scope("kps.lm.head"):
         nll, logits = jax.checkpoint(
             lambda x, n, hd, t: _head_nll(x, n, hd, t, c))(

@@ -139,6 +139,14 @@ def rms_norm(x, w, eps: float):
                              + eps) * w
 
 
+def block_norm(x, w, eps: float):
+    """`rms_norm` where a block norms its own input or output, under
+    the named scope `kps.lm.norm`: outside every mixer's scope these
+    would lie under `kps.fit.grad` alone."""
+    with jax.named_scope("kps.lm.norm"):
+        return rms_norm(x, w, eps)
+
+
 def rope(x, theta: float):
     """Rotate-half RoPE over the whole last axis; positions run along
     axis -3 of `[..., S, heads, d]`."""
@@ -422,19 +430,31 @@ def routed_experts(h, idx, w, p: dict, c, expert):
     it was written so.  Placing is exact at the default precision (one
     term a row, and the grouped product rounds its operand the same
     way); adding back runs at `HIGH`, which carries a float32 in three
-    pieces."""
+    pieces.
+
+    Named scopes inside `kps.moe.experts`, for the trace's readers
+    (benchmark/self_time.py): `kps.moe.sort` (key, argsort, group
+    sizes, the gathered weights), `kps.moe.place` (the live mask, the
+    0/1 matrix, the placing product), `kps.moe.expert_fn` (the family's
+    `expert` and the dead rows' mask; the chip's grouped-product calls
+    inside it keep the `ragged-dot` name the compiler gives them),
+    `kps.moe.combine` (the weighting and the add-back product) — the
+    same in both branches of the bound's `cond`, whose own time stays
+    under `kps.moe.experts` alone."""
     with jax.named_scope("kps.moe.experts"):
         t, k = idx.shape
         held = c.experts_held
-        local = idx - c.expert_offset
-        here = (local >= 0) & (local < held)
-        # absent experts sort last, into a group that is never computed
-        key = jnp.where(here, local, held).reshape(-1)
-        order = jnp.argsort(key, stable=True)
-        sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
-            axis=0, dtype=jnp.int32)
-        n_here = sizes.sum()
-        weight = jnp.where(here, w, 0.0).reshape(-1)[order]
+        with jax.named_scope("kps.moe.sort"):
+            local = idx - c.expert_offset
+            here = (local >= 0) & (local < held)
+            # absent experts sort last, into a group that is never
+            # computed
+            key = jnp.where(here, local, held).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            sizes = (key[:, None] == jnp.arange(held)[None, :]).sum(
+                axis=0, dtype=jnp.int32)
+            n_here = sizes.sum()
+            weight = jnp.where(here, w, 0.0).reshape(-1)[order]
 
         def grouped(rows, matrices):
             tiles = grouped_tiles(rows.shape[0], *matrices.shape[1:])
@@ -445,16 +465,19 @@ def routed_experts(h, idx, w, p: dict, c, expert):
 
         def placed(rows: int):
             """The sum from the first `rows` sorted assignments."""
-            live = (jnp.arange(rows) < n_here)[:, None]
-            place = jnp.where(live, jax.nn.one_hot(
-                order[:rows] // k, t, dtype=jnp.bfloat16), 0)
-            xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
-            # rows past the last group are never computed: whatever
-            # the kernel leaves there must reach nothing
-            y = jnp.where(live, expert(xs, p, grouped), 0.0)
-            return jnp.dot(place.T, y * weight[:rows, None],
-                           precision=jax.lax.Precision.HIGH,
-                           preferred_element_type=jnp.float32)
+            with jax.named_scope("kps.moe.place"):
+                live = (jnp.arange(rows) < n_here)[:, None]
+                place = jnp.where(live, jax.nn.one_hot(
+                    order[:rows] // k, t, dtype=jnp.bfloat16), 0)
+                xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
+            with jax.named_scope("kps.moe.expert_fn"):
+                # rows past the last group are never computed: whatever
+                # the kernel leaves there must reach nothing
+                y = jnp.where(live, expert(xs, p, grouped), 0.0)
+            with jax.named_scope("kps.moe.combine"):
+                return jnp.dot(place.T, y * weight[:rows, None],
+                               precision=jax.lax.Precision.HIGH,
+                               preferred_element_type=jnp.float32)
 
         bound = live_rows_bound(t * k, c)
         went_over = n_here > bound

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from kafka_ps_tpu.models import lm_common as lm
-from kafka_ps_tpu.models.lm_common import rms_norm, sub
+from kafka_ps_tpu.models.lm_common import rms_norm, sub  # noqa: F401
 
 
 @dataclasses.dataclass(frozen=True)
@@ -321,14 +321,19 @@ def attention(u, p: dict, c: NemotronHConfig):
         b, s, _ = u.shape
         nkv, d = c.num_key_value_heads, c.head_dim
         r = c.num_attention_heads // nkv
-        q = (u @ p["wq"]).reshape(b, s, nkv, r, d)
-        k = (u @ p["wk"]).reshape(b, s, nkv, d)
-        v = (u @ p["wv"]).reshape(b, s, nkv, d)
+        # `kps.attn.qkv` and `kps.attn.out` are the projections; the
+        # core (scores, mask, softmax, values) is what is left under
+        # `kps.attn` alone
+        with jax.named_scope("kps.attn.qkv"):
+            q = (u @ p["wq"]).reshape(b, s, nkv, r, d)
+            k = (u @ p["wk"]).reshape(b, s, nkv, d)
+            v = (u @ p["wv"]).reshape(b, s, nkv, d)
         scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k) / math.sqrt(d)
         causal = jnp.tril(jnp.ones((s, s), bool))
         probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
         out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v)
-        return out.reshape(b, s, nkv * r * d) @ p["wo"]
+        with jax.named_scope("kps.attn.out"):
+            return out.reshape(b, s, nkv * r * d) @ p["wo"]
 
 
 def relu2(h, w_up, w_down):
@@ -349,7 +354,7 @@ def _shared_expert(h, p: dict):
 def block(kind: str, x, p: dict, c: NemotronHConfig):
     """`x + mixer(RMSNorm(x))` → (the block's output, an expert layer's
     counts or None)."""
-    u = rms_norm(x, p["norm"], c.layer_norm_epsilon)
+    u = lm.block_norm(x, p["norm"], c.layer_norm_epsilon)
     if kind == "M":
         return x + mamba2(u, p, c), None
     if kind == "*":

@@ -109,17 +109,25 @@ def _make_folded_round(task, num_workers: int, server_lr: float):
             # relayout of a weight for the first local step is hoisted
             # out of the loop and kept beside the leaves — two more
             # copies of the parameters at the published widths
-            shared, total = jax.lax.optimization_barrier((leaves, total))
+            with jax.named_scope("kps.bsp.carry"):
+                shared, total = jax.lax.optimization_barrier(
+                    (leaves, total))
             new, loss, counts = task.fit_counted(shared, *slab)
             with jax.named_scope("kps.fit.delta"):
                 total = jax.tree.map(lambda t, n, o: t + (n - o),
                                      total, new, shared)
             return (total, loss_sum + loss, counted + counts), None
 
-        zero = (jax.tree.map(jnp.zeros_like, leaves), jnp.float32(0.0),
-                jnp.zeros((len(task.counter_names),), jnp.int32))
-        (total, loss_sum, counted), _ = jax.lax.scan(
-            worker, zero, (x, encoded, mask))
+        # the loop's own time has a name of its own — what lies under
+        # `kps.bsp.fold` and no scope of the solver's is the fold's:
+        # the running sum's zeros, the slabs sliced for a worker
+        # (benchmark/self_time.py) — and so have the copies the
+        # barrier above costs, `kps.bsp.carry`
+        with jax.named_scope("kps.bsp.fold"):
+            zero = (jax.tree.map(jnp.zeros_like, leaves), jnp.float32(0.0),
+                    jnp.zeros((len(task.counter_names),), jnp.int32))
+            (total, loss_sum, counted), _ = jax.lax.scan(
+                worker, zero, (x, encoded, mask))
         with jax.named_scope("kps.bsp.apply"):
             return (jax.tree.map(lambda a, d: a + server_lr * d, leaves,
                                  total),

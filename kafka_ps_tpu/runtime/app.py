@@ -41,6 +41,11 @@ from kafka_ps_tpu.utils.trace import NULL_TRACER
 # the slab-refresh part of `StreamingPSApp.last_run` before any refresh
 NO_SLAB_REFRESH = {"slab_refreshes": 0, "slab_refresh_s": 0.0,
                    "slab_refresh_bytes": 0}
+# and the part a folded task's fused call fills in, the seconds at its
+# two ends (`_run_fused_loop`): on every other path there is no flat
+# vector to bring up or down
+NO_CALL_EDGES = {"theta_up_s": 0.0, "device_wait_s": 0.0,
+                 "theta_down_s": 0.0}
 
 
 class StreamingPSApp:
@@ -434,7 +439,15 @@ class StreamingPSApp:
 
     def _record_run(self, path: str, t_call: float, backlog0, **more) -> None:
         """`last_run` of the drive call that began at `t_call` with the
-        sinks' backlog counts at `backlog0`."""
+        sinks' backlog counts at `backlog0`.  On every path it holds
+        `path` ("serial" / "fused"), `seconds` (the whole call),
+        `slab_refreshes` / `slab_refresh_s` / `slab_refresh_bytes`
+        (NO_SLAB_REFRESH where nothing was uploaded), `theta_up_s` /
+        `device_wait_s` / `theta_down_s` (a folded task's flat vector
+        up where the call begins, the queued chunks finishing, the
+        vector down where it ends; NO_CALL_EDGES elsewhere),
+        `log_backlog_waits` / `log_backlog_wait_s`; and `counters`
+        where the task counts (models/task.py `counter_names`)."""
         waits, wait_s = self._log_backlog()
         self.last_run = {"path": path,
                          "seconds": time.perf_counter() - t_call, **more,
@@ -499,7 +512,8 @@ class StreamingPSApp:
         finally:
             reporter.stop()
             self.flush_logs()
-        self._record_run("serial", t_call, backlog0, **NO_SLAB_REFRESH)
+        self._record_run("serial", t_call, backlog0, **NO_SLAB_REFRESH,
+                         **NO_CALL_EDGES)
 
     def _serial_round(self, gang, max_server_iterations: int) -> bool:
         """One turn of the serial scheduler: weights out, gradients in.
@@ -796,7 +810,8 @@ class StreamingPSApp:
                         range_mode, multiproc, step, theta, clock, active,
                         feed, task, progs) -> dict:
         """The fused drive loop; returns the call's slab-refresh counts
-        (`StreamingPSApp.last_run`)."""
+        and the seconds at its two ends (`StreamingPSApp.last_run`)."""
+        import jax
         import jax.numpy as jnp
 
         from kafka_ps_tpu.parallel import range_sharded
@@ -829,6 +844,7 @@ class StreamingPSApp:
         # again where the loop ends, and the evaluation reads the leaves
         folded = not range_mode and not task.batches_workers
         evaluate = None
+        refresh = {**NO_SLAB_REFRESH, **NO_CALL_EDGES}
         if folded:
             if "edges" not in progs:
                 progs["edges"] = bsp.folded_edges(task)
@@ -838,12 +854,22 @@ class StreamingPSApp:
             # a worker's working copy and its gradient as a fifth copy
             # of the parameters, which the chip cannot hold at the
             # published widths.  An upload where the call begins and a
-            # download where it ends, once a call.
-            self.server.theta = np.asarray(self.server.theta)
-            theta = cut(self.server.theta)
+            # download where it ends, once a call: for the three
+            # language-model cells' 2.02 / 2.37 / 2.67 GB, 0.32 / 0.38 /
+            # 0.44 s up (6.2 GB/s) and 0.58 / 0.72 / 0.81 s down (3.4
+            # GB/s), 6.5-7.1% of a 13-19 s call (`last_run`'s
+            # `theta_up_s` / `theta_down_s`; PERF.md section 5, PR 37).
+            # The upload is waited for, so that the span and the counter
+            # hold the copy and not its enqueueing; no chunk could start
+            # before it.
+            t_up = time.perf_counter()
+            with self.tracer.span("fused.theta_up",
+                                  bytes=4 * task.num_params):
+                self.server.theta = np.asarray(self.server.theta)
+                theta = jax.block_until_ready(cut(self.server.theta))
+            refresh["theta_up_s"] = time.perf_counter() - t_up
         x = y = mask = None
         slab_versions: list[int] | None = None
-        refresh = dict(NO_SLAB_REFRESH)
         counted = []      # a folded task's counters, one array a dispatch
         while self.server.iterations < max_server_iterations:
             # one span a turn: the loop's own Python between its
@@ -975,9 +1001,19 @@ class StreamingPSApp:
             # the flat vector, once a call unless a snapshot or a
             # checkpoint was owed at a chunk's boundary (above): until
             # here `server.theta` is the last of those, or the state
-            # the call began with
-            self.server.theta = np.asarray(join(theta))
-            del theta
+            # the call began with.  The wait for the queued chunks is
+            # named apart from the copy: the download below waited for
+            # the same, so this adds none
+            t_wait = time.perf_counter()
+            with self.tracer.span("fused.wait_device"):
+                jax.block_until_ready((theta, counted))
+            t_down = time.perf_counter()
+            with self.tracer.span("fused.theta_down",
+                                  bytes=4 * task.num_params):
+                self.server.theta = np.asarray(join(theta))
+                del theta
+            refresh["device_wait_s"] = t_down - t_wait
+            refresh["theta_down_s"] = time.perf_counter() - t_down
             self.server.publish_snapshot()
         self.flush_logs()    # deferred rows out before the loop returns
         if counted:

@@ -23,7 +23,13 @@ from kafka_ps_tpu.utils.trace import NULL_TRACER, Tracer
 
 FUSED_ROUNDS = 16          # two 8-round scan chunks at eval_every 8
 # the span that only the dispatching thread opens, by drive path
-MARKER = {"fused": "bsp.step", "serial": "serial.round"}
+# ("folded": the fused loop of a task whose workers are folded one at a
+# time, a language-model family at its tiny size)
+MARKER = {"fused": "bsp.step", "serial": "serial.round",
+          "folded": "bsp.step"}
+GLM_TINY = "benchmark/families/glm4-moe-lite/tiny.model.json"
+CALL_EDGES = ["fused.theta_up", "fused.wait_device", "fused.theta_down"]
+EDGE_SECONDS = ["theta_up_s", "device_wait_s", "theta_down_s"]
 DISPATCH_SPANS = {
     "fused": ["fused.chunk", "fused.slab_refresh", "bsp.step",
               "fused.publish", "fused.eval", "fused.log_rows",
@@ -32,6 +38,7 @@ DISPATCH_SPANS = {
                "serial.collect", "worker.local_update", "server.apply",
                "eval.submit", "app.flush_logs", "eval.drain", "log.flush",
                "log.fetch"],
+    "folded": CALL_EDGES + ["fused.chunk", "bsp.step", "fused.eval"],
 }
 # a child lies inside one event of its parent, on the same line
 NESTING = {
@@ -67,8 +74,27 @@ def make_app(tracer=None, task="logreg", eval_every=1, workers=2,
     return app
 
 
+def make_folded_app(tracer=None, server_log=None):
+    """Two workers of the first language-model family at its tiny
+    size, a row of tokens each."""
+    from kafka_ps_tpu.models.task import get_task
+    cfg = PSConfig(
+        num_workers=2, task="glm4_moe_lite", eval_every=8,
+        model=ModelConfig(num_max_iter=2, local_learning_rate=0.05,
+                          model_json=GLM_TINY),
+        buffer=BufferConfig(min_size=1, max_size=2))
+    c = get_task("glm4_moe_lite", cfg.model).arch
+    rows = np.random.default_rng(3).integers(
+        0, c.vocab_held, size=(6, c.row_width)).astype(np.int32)
+    app = StreamingPSApp(cfg, test_x=rows[4:], test_y=np.zeros(2, np.int32),
+                         tracer=tracer, server_log=server_log)
+    for i, row in enumerate(rows[:4]):
+        app.data_sink(i % 2, row, 0)
+    return app
+
+
 def drive(path: str, app) -> None:
-    if path == "fused":
+    if path in ("fused", "folded"):
         app.run_fused_bsp(
             max_server_iterations=FUSED_ROUNDS * app.cfg.num_workers)
     else:
@@ -99,18 +125,19 @@ def traced(tmp_path_factory):
     """Each drive path under a profiler session, with no Tracer built:
     {path: {"lines", "events_recorded", "app"}}."""
     out = {}
-    for path in ("fused", "serial"):
+    for path in ("fused", "serial", "folded"):
         rows: list[str] = []
-        app = make_app(task="mlp" if path == "fused" else "logreg",
-                       eval_every=8 if path == "fused" else 1,
-                       server_log=rows.append)
+        app = (make_folded_app(server_log=rows.append) if path == "folded"
+               else make_app(task="mlp" if path == "fused" else "logreg",
+                             eval_every=8 if path == "fused" else 1,
+                             server_log=rows.append))
         assert app.tracer is NULL_TRACER
         drive(path, app)                       # compile outside the session
         trace_dir = str(tmp_path_factory.mktemp("profile-" + path))
         with trace.device_trace(trace_dir):
             drive_again = (2 * FUSED_ROUNDS * app.cfg.num_workers
-                           if path == "fused" else 16)
-            if path == "fused":
+                           if path != "serial" else 16)
+            if path != "serial":
                 app.run_fused_bsp(max_server_iterations=drive_again)
             else:
                 app.run_serial(max_server_iterations=drive_again,
@@ -124,6 +151,7 @@ def traced(tmp_path_factory):
                 time.sleep(0.05)
             time.sleep(0.05)
         out[path] = {"lines": host_lines(trace_dir), "app": app,
+                     "last_run": dict(app.last_run),
                      "events_recorded": len(NULL_TRACER._events)}
         app.close_logs()
     return out
@@ -245,6 +273,69 @@ def test_last_run_counts_the_refresh_and_its_bytes():
     assert app.last_run["slab_refreshes"] == 0
     assert sorted(app.last_run) == sorted(last)
     app.close_logs()
+
+
+# -- the head and the tail of a folded task's fused call ----------------------
+
+def test_the_calls_edges_lie_outside_its_chunks_in_their_order(traced):
+    """The flat vector up before the first chunk; where the loop ends
+    the wait for the queued chunks, then the vector down: three spans
+    of the dispatching line, none inside a `fused.chunk`."""
+    line = dispatch_line(traced["folded"]["lines"], "bsp.step")
+    at = {name: [(s, e) for n, s, e, _ in line if n == name]
+          for name in CALL_EDGES + ["fused.chunk"]}
+    assert [len(at[name]) for name in CALL_EDGES] == [1, 1, 1]
+    chunks = at["fused.chunk"]
+    assert len(chunks) == 2
+    (up,), (wait,), (down,) = (at[name] for name in CALL_EDGES)
+    assert up[1] <= min(s for s, _ in chunks)
+    assert max(e for _, e in chunks) <= wait[0] and wait[1] <= down[0]
+    stats = [st for n, _, _, st in line if n in ("fused.theta_up",
+                                                 "fused.theta_down")]
+    assert all(int(st["bytes"]) == 4 * traced["folded"]["app"].server
+               .task.num_params for st in stats)
+
+
+def test_last_run_times_the_calls_edges_where_there_are_any(traced):
+    """Three counters, always on: a folded call fills them in and they
+    lie inside the call; every other path has the keys at 0.0."""
+    last = traced["folded"]["last_run"]
+    assert all(last[k] > 0.0 for k in EDGE_SECONDS)
+    assert sum(last[k] for k in EDGE_SECONDS) <= last["seconds"]
+    for path in ("fused", "serial"):            # unfolded, per-node
+        last = traced[path]["last_run"]
+        assert [last[k] for k in EDGE_SECONDS] == [0.0, 0.0, 0.0]
+        assert not any(name in CALL_EDGES
+                       for evs in traced[path]["lines"].values()
+                       for name, *_ in evs)
+    assert sorted(traced["folded"]["last_run"]) == sorted(
+        [*traced["fused"]["last_run"], "counters"])
+
+
+def test_the_folded_calls_theta_is_the_programs_own(traced):
+    """Timing the edges changed no number: the call's parameters are,
+    bit for bit, what the three programs give when they are called one
+    after another with nothing between them — as the loop called them
+    before its edges had spans — and a tracer changes none either."""
+    from kafka_ps_tpu.parallel import bsp
+    thetas = []
+    for tracer in (None, Tracer()):
+        app = make_folded_app(tracer=tracer)
+        start = np.asarray(app.server.theta).copy()
+        drive("folded", app)
+        app.close_logs()
+        thetas.append(np.asarray(app.server.theta))
+    assert np.array_equal(thetas[0], thetas[1])
+    task, cfg = app.server.task, app.cfg
+    cut, join, _ = bsp.folded_edges(task)
+    chunk = bsp.make_bsp_multi_step(cfg.model, 2, cfg.server_lr, 8,
+                                    task=task)
+    x, y, mask = (jnp.asarray(np.stack(part)) for part in zip(
+        *(b.snapshot() for b in app.buffers)))
+    leaves = cut(start)
+    for _ in range(FUSED_ROUNDS // 8):
+        leaves, _, _ = chunk(leaves, x, y, mask)
+    assert np.array_equal(np.asarray(join(leaves)), thetas[0])
 
 
 # -- named scopes in the device programs -------------------------------------
