@@ -17,8 +17,12 @@ its configuration, its leaves, latent attention, its SwiGLU experts
 and the MTP module.
 
 `leaf_specs` fixes the leaves' order in the flat key space (the wire
-contract).  The expert layers'
-leaves are stacked on a leading layer axis and scanned; every layer is
+contract): there, and only there, the expert layers' leaves are stacked
+on a leading layer axis (`moe.<name>`, `(layers,) + shape`).  The
+program holds them a layer at a time (`layer_specs`: `moe.<l>.<name>`,
+the stacked leaf's contiguous runs, so the flat vector is the same to
+the element) and its blocks are written out, a layer's matrices operands
+as they lie and its gradient a result of its own; every layer is
 recomputed in the backward pass (`jax.checkpoint`), so one worker's
 activations stay a few layers' worth.
 
@@ -135,8 +139,9 @@ def _moe_specs(c: Glm4Config) -> list[tuple[str, tuple[int, ...]]]:
 
 
 def leaf_specs(c: Glm4Config) -> list[tuple[str, tuple[int, ...]]]:
-    """(dotted name, shape) of every leaf, in flat-layout order.  The
-    expert layers are stacked on a leading axis of `num_moe_layers`."""
+    """(dotted name, shape) of every leaf of the WIRE, in flat-layout
+    order.  The expert layers are stacked on a leading axis of
+    `num_moe_layers`."""
     h, i, v = c.hidden_size, c.intermediate_size, c.vocab_held
     out = [("embed", (v, h))]
     out += [("dense." + n, s) for n, s in _attn_specs(c)]
@@ -152,20 +157,45 @@ def leaf_specs(c: Glm4Config) -> list[tuple[str, tuple[int, ...]]]:
     return out
 
 
+def _layer_names(name: str, c: Glm4Config) -> list[str] | None:
+    """The program's names for the layers of a stacked leaf of the
+    wire, in order; None for a leaf that is not stacked."""
+    if not name.startswith("moe."):
+        return None
+    return [f"moe.{at}.{name[4:]}" for at in range(c.num_moe_layers)]
+
+
+def layer_specs(c: Glm4Config) -> list[tuple[str, tuple[int, ...]]]:
+    """(dotted name, shape) of every leaf as the PROGRAM holds it, in
+    flat-layout order: `leaf_specs` with every stacked `moe.<name>` cut
+    into its layers `moe.<l>.<name>`.  A stacked leaf is row-major, so
+    its layers are contiguous runs of the flat vector, one after
+    another: both lists cover the same elements in the same order."""
+    out = []
+    for name, shape in leaf_specs(c):
+        layers = _layer_names(name, c)
+        out += ([(name, shape)] if layers is None
+                else [(layer, shape[1:]) for layer in layers])
+    return out
+
+
 def init_leaves(c: Glm4Config) -> dict:
-    """normal(0, init_std) from `init_seed`, one key a leaf by its
-    place in the layout; norms one, the selection bias zero."""
+    """normal(0, init_std) from `init_seed`, one key a leaf OF THE WIRE
+    by its place in the layout (a stacked leaf is drawn whole and cut
+    into its layers afterwards); norms one, the selection bias zero."""
     key = jax.random.PRNGKey(c.init_seed)
     out = {}
     for at, (name, shape) in enumerate(leaf_specs(c)):
         last = name.rsplit(".", 1)[-1]
         if last.endswith("norm"):
-            out[name] = jnp.ones(shape, jnp.float32)
+            leaf = jnp.ones(shape, jnp.float32)
         elif last == "router_bias":
-            out[name] = jnp.zeros(shape, jnp.float32)
+            leaf = jnp.zeros(shape, jnp.float32)
         else:
-            out[name] = c.init_std * jax.random.normal(
+            leaf = c.init_std * jax.random.normal(
                 jax.random.fold_in(key, at), shape, jnp.float32)
+        layers = _layer_names(name, c)
+        out.update([(name, leaf)] if layers is None else zip(layers, leaf))
     return out
 
 
@@ -213,6 +243,16 @@ def mla(x, p: dict, c: Glm4Config):
             return out.reshape(b, s, nh * dv) @ p["wo"]
 
 
+def _experts(xs, p: dict, dot):
+    """What `lm_common.routed_experts` is handed: the gated expert on
+    its own rows, which take no gradient past the last group.  The
+    products at these widths are left to the compiler's tiles, and the
+    untold kernel leaves those rows as it found them: with the layers
+    written out the buffer it finds has held NaN (on the chip: every
+    parameter NaN after clock 1), which the scan's never had."""
+    return swiglu_experts(lm.live_rows_only(xs, dot.sizes), p, dot)
+
+
 def _shared_expert(h, p: dict):
     return swiglu(h, p["s_gate"], p["s_up"], p["s_down"])
 
@@ -221,7 +261,7 @@ def moe(x, p: dict, c: Glm4Config):
     """Expert layer's MLP half on `[B, S, H]` (already normed) →
     (its output, (assignments here, largest load, went over the
     bound))."""
-    return lm.expert_layer(x, p, c, swiglu_experts, _shared_expert)
+    return lm.expert_layer(x, p, c, _experts, _shared_expert)
 
 
 def dense_block(x, p: dict, c: Glm4Config):
@@ -251,13 +291,15 @@ def forward(leaves: dict, rows, c: Glm4Config, *, with_logits=False):
         x = leaves["embed"][tokens]
     x = jax.checkpoint(lambda x, p: dense_block(x, p, c))(
         x, sub(leaves, "dense."))
-    # what lies under `kps.lm.layers` and no scope inside a block is the
-    # scan's own: a layer's leaves cut out of the stack, its gradients
-    # written back into theirs, the residual adds
+    # the expert layers written out: what lies under `kps.lm.layers`
+    # and no scope inside a block is the residual adds
     with jax.named_scope("kps.lm.layers"):
-        x, loads = jax.lax.scan(
-            jax.checkpoint(lambda x, p: moe_block(x, p, c)), x,
-            sub(leaves, "moe."))
+        block = jax.checkpoint(lambda x, p: moe_block(x, p, c))
+        loads = []
+        for at in range(c.num_moe_layers):
+            x, load = block(x, sub(leaves, f"moe.{at}."))
+            loads.append(load)
+        loads = jnp.stack(loads)
     with jax.named_scope("kps.lm.head"):
         nll, logits = jax.checkpoint(
             lambda x, n, hd, t: _head_nll(x, n, hd, t, c))(
@@ -312,7 +354,9 @@ class Glm4MoeLiteTask(lm.TokenRowsTask):
     config_cls = Glm4Config
 
     def leaf_specs(self):
-        return leaf_specs(self.arch)
+        """The program's list (the frame's `specs`, `unflatten`,
+        `flatten`); the wire's is the module's `leaf_specs`."""
+        return layer_specs(self.arch)
 
     def init_leaves(self) -> dict:
         return init_leaves(self.arch)

@@ -9,6 +9,7 @@ import functools
 import importlib.util
 import math
 import os
+import re
 import sys
 
 import pytest
@@ -102,22 +103,32 @@ def test_the_folded_chunk_at_the_published_widths_fits_the_chip(aot,
     tokens, 8 clocks), compiled for the described chip: the leaves are
     donated (argument and result share their bytes) and the program's
     scratch stays under 10.5 GB, so that it fits the chip's 16.9 GB
-    beside nothing but its own leaves.  It reads 9.32 GB (10.21 GB at
-    2 rows when written, 11.39 GB there since the expert layer places
-    its rows under a bound or all of them, both branches compiled);
+    beside nothing but its own leaves.  It reads 6.37 GB since the
+    expert layers are written out (PR 38); 9.32 GB scanned over their
+    stack (10.21 GB at 2 rows when written, 11.39 GB there since the
+    expert layer places its rows under a bound or all of them, both
+    branches compiled);
     16.49 GB with the local steps scanned and the shared leaves left
     loop-invariant in the fold over the workers (the compiler then
     keeps a relayout of every weight beside the loop), and 4.7 GB more
     with the flat vector cut into leaves without a barrier (PERF.md
-    section 6, PR 27).  About 50 s."""
+    section 6, PR 27).  About 105 s (50 s scanned)."""
     task, compiled = folded_chunk("glm4_moe_lite")
     assert task.num_params == 591_294_976
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 4 * task.num_params
     assert memory.temp_size_in_bytes < 10.5e9, memory.temp_size_in_bytes
+    # the expert layers are written out (PR 38): no array carries the
+    # wire's leading layer axis — the scan over the stack copied a
+    # layer's matrices out of `f32[4,8,2048,1536]` and wrote its
+    # gradient back into one, 259 such lines and 31% of an update
+    text = compiled.as_text()
+    assert task.arch.num_moe_layers == 4 and task.arch.experts_held == 8
+    assert "f32[8,2048,1536]" in text          # the reader sees a layer's
+    assert not re.search(r"f32\[4,8,[\d,]*\]", text)
     # the grouped products are the chip's own kernel, not a dense
     # product, and at 2048 x 1536 it is told nothing: its own tiles
-    calls = aot.ragged_dot_calls(compiled.as_text())
+    calls = aot.ragged_dot_calls(text)
     assert calls and {tiles for _, tiles in calls} == {"512,512,512"}
     assert {lm.grouped_tiles(*shape) for shape, _ in calls} == {None}
 
@@ -190,7 +201,6 @@ def test_the_third_language_models_chunk_holds_no_square_of_scores(
     attention would hold `[1, 32, 4096, 4096]`, 2.1 GB a layer a pass.
     The scratch stays at or under the plain tiles' 9,203,257,856 bytes.
     About 150 s."""
-    import re
     task, compiled = folded_chunk("afmoe")
     assert task.num_params == 504_147_712
     c = task.arch

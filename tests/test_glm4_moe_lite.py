@@ -12,7 +12,9 @@ formula would give."""
 
 import dataclasses
 import importlib.util
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +96,149 @@ def test_the_flat_layout_is_the_references(task, ref, ps_cfg):
     # and the stated start is the same to the last bit
     assert np.array_equal(np.asarray(task.init_params()),
                           ref.init_params(s))
+
+
+# -- the program's list against the wire's: three expert layers ---------------
+
+@pytest.fixture(scope="module")
+def deep_cfg(tmp_path_factory):
+    """The tiny size with THREE expert layers (the family's tiny file
+    has one, and a stack of one layer shows nothing of the stack)."""
+    body = json.load(open(os.path.join(ROOT, TINY)))
+    body["num_hidden_layers"] = 4
+    path = tmp_path_factory.mktemp("glm_deep") / "deep.model.json"
+    path.write_text(json.dumps(body))
+    return PSConfig(num_workers=2, task="glm4_moe_lite",
+                    model=ModelConfig(num_max_iter=2,
+                                      local_learning_rate=0.05,
+                                      model_json=str(path)),
+                    buffer=BufferConfig(min_size=1, max_size=2))
+
+
+@pytest.fixture(scope="module")
+def deep(deep_cfg):
+    return get_task("glm4_moe_lite", deep_cfg.model)
+
+
+def test_the_programs_list_covers_the_flat_vector_as_the_wires(deep, ref,
+                                                               deep_cfg):
+    """A stacked leaf of the wire is row-major, so the program's
+    `moe.<l>.<name>` are its contiguous runs: cutting a flat vector
+    into the program's leaves and joining them again gives the vector
+    to the bit, and every leaf is the reference's view of the same
+    vector (its layer of it, where the wire stacks)."""
+    s = ref.shapes(deep_cfg)
+    layers = deep.arch.num_moe_layers
+    assert layers == 3 and s.num_params == deep.num_params
+    assert lm.num_params(glm.leaf_specs(deep.arch)) == deep.num_params
+    theta = np.random.default_rng(1).standard_normal(
+        deep.num_params).astype(np.float32)
+    leaves = deep.unflatten(jnp.asarray(theta))
+    assert list(leaves) == [n for n, _ in glm.layer_specs(deep.arch)]
+    assert np.array_equal(np.asarray(deep.flatten(leaves)), theta)
+    seen = set()
+    for name, wire in ref.split(theta, s).items():
+        if not name.startswith("moe."):
+            assert np.array_equal(np.asarray(leaves[name]), wire), name
+            seen.add(name)
+            continue
+        assert wire.shape[0] == layers
+        for at in range(layers):
+            mine = f"moe.{at}.{name[4:]}"
+            assert np.array_equal(np.asarray(leaves[mine]), wire[at]), mine
+            seen.add(mine)
+    assert seen == set(leaves)
+    # and the stated start, drawn a stacked leaf at a time, is the
+    # reference's to the last bit at this depth too
+    assert np.array_equal(np.asarray(deep.init_params()), ref.init_params(s))
+
+
+def _scanned_nll(leaves, rows, c):
+    """The oracle: the trunk as one plain `lax.scan` over the expert
+    layers' leaves stacked again (what the program ran before its
+    layers were written out) -> (next-token losses, the layers' loads)."""
+    stacked = {name: jnp.stack([leaves[f"moe.{at}.{name}"]
+                                for at in range(c.num_moe_layers)])
+               for name, _ in glm._moe_specs(c)}
+    s = c.sequence_length
+    x = leaves["embed"][rows[:, :s]]
+    x = glm.dense_block(x, lm.sub(leaves, "dense."), c)
+    x, loads = jax.lax.scan(lambda x, p: glm.moe_block(x, p, c), x, stacked)
+    nll, _ = lm.head_nll(x, leaves["final_norm"], leaves["head"],
+                         rows[:, 1:s + 1], c.rms_norm_eps)
+    return nll, loads
+
+
+def test_the_written_out_layers_are_a_scan_over_the_stack(deep):
+    """`forward`, its gradient and the loads of the expert layers equal
+    the scan over the re-stacked leaves, kept here as the oracle."""
+    c = deep.arch
+    rng = np.random.default_rng(7)
+    start = np.asarray(deep.init_params())
+    leaves = deep.unflatten(jnp.asarray(
+        start + 0.05 * rng.standard_normal(start.shape).astype(np.float32)))
+    rows = rows_of(deep, 2)
+
+    def program(leaves):
+        out = glm.forward(leaves, rows, c)
+        return out["nll"].sum(), (out["nll"], out["loads"])
+
+    def oracle(leaves):
+        nll, loads = _scanned_nll(leaves, rows, c)
+        return nll.sum(), (nll, loads)
+    (_, (nll, loads)), grads = jax.value_and_grad(
+        program, has_aux=True)(leaves)
+    (_, (want_nll, want_loads)), want = jax.value_and_grad(
+        oracle, has_aux=True)(leaves)
+    close(nll, want_nll)
+    # the module's block comes after the trunk's in `loads`
+    assert loads.shape == (c.num_moe_layers + 1, 3)
+    assert np.array_equal(np.asarray(loads[:c.num_moe_layers]),
+                          np.asarray(want_loads))
+    moved = 0
+    for name in leaves:
+        if name.endswith("router_bias") or name.startswith("mtp."):
+            assert not np.any(grads[name]) and not np.any(want[name]), name
+        else:
+            close(grads[name], want[name])
+            moved += bool(np.any(want[name]))
+    assert moved == len(leaves) - c.num_moe_layers - sum(
+        n.startswith("mtp.") for n in leaves)
+
+
+def test_the_lowered_chunk_walks_no_stack_of_layers(deep, deep_cfg):
+    """The folded chunk as it is lowered: under `kps.lm.layers` no loop
+    (a `scan` lowers to a `while`) and no slice taken or written at a
+    running index, and nowhere in the program a dynamic slice or update
+    whose operand or result has a leaf's shape, the wire's stacked
+    shapes among them."""
+    c, w, cap = deep.arch, deep_cfg.num_workers, 2
+    shaped = jax.ShapeDtypeStruct
+    chunk = bsp.make_bsp_multi_step(deep_cfg.model, w, deep_cfg.server_lr, 8,
+                                    task=deep)
+    text = chunk.lower(
+        jax.eval_shape(deep.unflatten,
+                       shaped((deep.num_params,), jnp.float32)),
+        shaped((w, cap, deep.row_width), jnp.int32),
+        shaped((w, cap), jnp.int32),
+        shaped((w, cap), jnp.float32)).as_text(debug_info=True)
+    names = re.findall(r'loc\("([^"]*kps\.lm\.layers[^"]*)"', text)
+    assert len(names) > 100             # the reader sees the scope
+    assert any("/kps.mla" in n for n in names)
+    walked = [n for n in names if re.search(
+        r"kps\.lm\.layers.*/(scan|while|dynamic_slice|dynamic_update_slice)"
+        r"\b", n)]
+    assert walked == []
+    # the fold over the workers and the scan over the clocks are loops,
+    # and slice the slabs and write the losses: none touches a leaf
+    matrices = {"x".join(map(str, sh)) + "xf32"
+                for specs in (glm.leaf_specs(c), glm.layer_specs(c))
+                for _, sh in specs if len(sh) >= 2}
+    sliced = [line for line in text.splitlines()
+              if re.search(r"stablehlo\.dynamic_(update_)?slice", line)]
+    assert sliced and "stablehlo.while" in text
+    assert not [line for line in sliced
+                if set(re.findall(r"tensor<([\dx]+xf32)>", line)) & matrices]
 
 
 def test_loss_and_gradients_agree_with_the_reference(task, ref, ps_cfg,
@@ -299,6 +444,63 @@ def test_the_grouped_products_follow_any_routing(task, hot):
     assert int(load[2]) == (int(load[0]) > 32)
     assert int(load[2]) == {-10.0: 0, 0.0: int(load[2]), 10.0: 1}[hot]
     close(got, want)
+
+
+def _leaving_nan(expert):
+    """`expert` over a grouped product whose dx holds NaN in the rows
+    past the last group: what the chip's untold kernel, which leaves
+    those rows as it found them, hands back from a buffer that has held
+    NaN."""
+    def over_nan(xs, p, dot):
+        @jax.custom_vjp
+        def product(rows, matrices):
+            return dot(rows, matrices)
+
+        def forward(rows, matrices):
+            return jax.vjp(dot, rows, matrices)
+
+        def backward(back, dy):
+            d_rows, d_matrices = back(dy)
+            dead = jnp.arange(d_rows.shape[0]) >= dot.sizes.sum()
+            return jnp.where(dead[:, None], jnp.nan, d_rows), d_matrices
+        product.defvjp(forward, backward)
+        product.sizes = dot.sizes
+        return expert(xs, p, product)
+    return over_nan
+
+
+def test_rows_past_the_last_group_take_no_gradient(task):
+    """The family's expert wraps its rows in `lm.live_rows_only`: a dx
+    poisoned past the last group reaches no token and no matrix.  With
+    the layers scanned over their stack the chip's buffers happened to
+    hold finite numbers there; written out they hold NaN, and every
+    parameter was NaN after the first clock (PERF.md section 6, PR 38)."""
+    c = task.arch
+    rng = np.random.default_rng(3)
+    h = jnp.asarray(rng.standard_normal((32, c.hidden_size)), jnp.float32)
+    p = {k: jnp.asarray(0.1 * rng.standard_normal(s), jnp.float32)
+         for k, s in (("e_gate", (2, c.hidden_size, 32)),
+                      ("e_up", (2, c.hidden_size, 32)),
+                      ("e_down", (2, 32, c.hidden_size)))}
+    idx, w = lm.route(h, jnp.asarray(rng.standard_normal(
+        (c.hidden_size, c.n_routed_experts)), jnp.float32),
+        jnp.zeros((c.n_routed_experts,)), c)
+
+    def through(expert):
+        def loss(h, p):
+            out, load = lm.routed_experts(h, idx, w, p, c, expert)
+            return jnp.sum(out ** 2), load
+        return jax.grad(loss, argnums=(0, 1), has_aux=True)(h, p)
+    (dh, dp), load = through(glm._experts)
+    assert 0 < int(load[0]) < lm.live_rows_bound(64, c) == 32   # dead rows
+    assert np.any(dh) and all(np.any(g) for g in dp.values())
+    (got_h, got_p), _ = through(_leaving_nan(glm._experts))
+    close(got_h, dh)
+    for name in dp:
+        close(got_p[name], dp[name])
+    # the gated expert alone, as the family handed it over before
+    (bare_h, _), _ = through(_leaving_nan(glm.swiglu_experts))
+    assert np.isnan(np.asarray(bare_h)).all()
 
 
 def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):

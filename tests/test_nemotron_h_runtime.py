@@ -1,10 +1,10 @@
 """The `nemotron_h` family through the runtime, at its tiny size on the
 CPU: the task through the CLI's own parser and drives (fused and
 per-node, the gang with it), what both language-model tasks refuse, a
-save inside a fused call and the resume, and the proof that moving the
-shared frame out of models/glm4_moe_lite.py changed nothing of what
-that family traces.  tests/test_nemotron_h.py holds the model against
-its reference."""
+save inside a fused call and the resume, and the proof that an edit
+to the shared frame (models/lm_common.py) changes nothing of what the
+`glm4_moe_lite` family traces.  tests/test_nemotron_h.py holds the
+model against its reference."""
 
 import dataclasses
 import hashlib
@@ -113,11 +113,15 @@ def glm_stablehlo():
 @pytest.mark.parametrize("program", ["fit_counted", "evaluate_leaves",
                                      "folded_chunk"])
 def test_glm4_moe_lites_stablehlo_is_the_parents(glm_stablehlo, program):
-    """What the family shares with `nemotron_h` moved to
-    models/lm_common.py and its expert's function is handed in: the
-    program it traces is, character for character, the one the commit
-    before traced (tests/fixtures/glm4_tiny_stablehlo.json holds the
-    digests, written from that commit)."""
+    """What the family shares with `nemotron_h` and `afmoe` lies in
+    models/lm_common.py, and an edit there must leave what this family
+    traces alone: the program is, character for character, the one
+    tests/fixtures/glm4_tiny_stablehlo.json holds the digests of.  They
+    are PR 38's own tree's — that PR wrote the family's expert layers
+    out, which changed its programs on purpose and touched no shared
+    line (the other two families' fixtures passed untouched); until
+    then they were commit e9a1946's, the one before the shared frame
+    moved out of models/glm4_moe_lite.py."""
     stated = json.load(open(os.path.join(
         ROOT, "tests", "fixtures", "glm4_tiny_stablehlo.json")))
     if stated["jax"] != jax.__version__:

@@ -18,6 +18,7 @@ The contracts:
 
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -193,7 +194,13 @@ def test_sharded_replica_snapshots_never_torn_under_writers(tmp_path):
     rep = ReplicaFollower(str(tmp_path))
     seen = []
     try:
-        for _ in range(200):
+        # 200 polls, and on a loaded box (the writers starved of the
+        # interpreter while the replica polls) as many more as it takes
+        # to see the race run, for at most 20 s
+        deadline = time.monotonic() + 20.0
+        polls = 0
+        while polls < 200 or (len(seen) < 2 and time.monotonic() < deadline):
+            polls += 1
             if rep.catch_up():
                 seen.append(rep.registry.latest)
     finally:
