@@ -1,5 +1,5 @@
 """What the language-model families share (`glm4_moe_lite`,
-`nemotron_h`, `afmoe`): token rows, RMSNorm, RoPE, the blocked
+`nemotron_h`, `afmoe`, `ouro`): token rows, RMSNorm, RoPE, the blocked
 attention core (on a TPU a kernel, `models/attention_kernel.py`, where
 the shapes allow), the sliced head and its loss, the router, the expert
 layer that knows its share, the gated (SwiGLU) expert, the counters,
@@ -93,10 +93,10 @@ def load_config(path: str, model_type: str, config_cls):
 
 
 def validate_cut(c) -> None:
-    """The cut every family states: which experts and which rows of the
-    vocabulary are held here."""
-    if not (0 <= c.expert_offset and c.expert_offset
-            + c.experts_held <= c.n_routed_experts):
+    """The cut a family states: which rows of the vocabulary are held
+    here and, where it has an expert layer, which of the experts."""
+    if hasattr(c, "experts_held") and not (
+            0 <= c.expert_offset <= c.n_routed_experts - c.experts_held):
         raise ValueError("expert_offset + experts_held must lie inside "
                          "n_routed_experts")
     if not 0 < c.vocab_held <= c.vocab_size:

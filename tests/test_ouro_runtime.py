@@ -1,10 +1,11 @@
-"""The `afmoe` family through the runtime, at its tiny size on the CPU:
+"""The `ouro` family through the runtime, at its tiny size on the CPU:
 the task through the CLI's own parser and drives (fused and per-node,
-the gang with it), what the three language-model tasks refuse, a save
-inside a fused call and the resume, and the proof that the blocked
-core, RoPE and the gated expert in models/lm_common.py changed nothing
-of what the other two families trace.  tests/test_afmoe.py holds the
-model against its reference."""
+the gang with it), what the four language-model tasks refuse, a save
+inside a fused call and the resume, and the proof that a dense family
+in models/lm_common.py's frame changed nothing of what the third
+family traces (the first two families' digests are held by
+tests/test_nemotron_h_runtime.py and tests/test_afmoe_runtime.py).
+tests/test_ouro.py holds the model against its reference."""
 
 import dataclasses
 import hashlib
@@ -20,23 +21,24 @@ from kafka_ps_tpu.models import afmoe
 from kafka_ps_tpu.models import glm4_moe_lite as glm
 from kafka_ps_tpu.models import lm_common as lm
 from kafka_ps_tpu.models import nemotron_h as nh
+from kafka_ps_tpu.models import ouro
 from kafka_ps_tpu.models.task import get_task, task_class
 from kafka_ps_tpu.parallel import bsp
 from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-MODEL_FILE = {"afmoe": "benchmark/families/afmoe/tiny.model.json",
+MODEL_FILE = {"ouro": "benchmark/families/ouro/tiny.model.json",
+              "afmoe": "benchmark/families/afmoe/tiny.model.json",
               "nemotron_h": "benchmark/families/nemotron-h/tiny.model.json",
               "glm4_moe_lite":
               "benchmark/families/glm4-moe-lite/tiny.model.json"}
-# the GLM family's digests are held by tests/test_nemotron_h_runtime.py
-FIXTURE = {"nemotron_h": "nemotron_tiny_stablehlo.json"}
-TINY = MODEL_FILE["afmoe"]
+FIXTURE = {"afmoe": "afmoe_tiny_stablehlo.json"}
+TINY = MODEL_FILE["ouro"]
 
 
 @pytest.fixture(scope="module")
 def ps_cfg():
-    return PSConfig(num_workers=3, task="afmoe",
+    return PSConfig(num_workers=3, task="ouro",
                     model=ModelConfig(num_max_iter=2,
                                       local_learning_rate=0.05,
                                       model_json=TINY),
@@ -45,7 +47,7 @@ def ps_cfg():
 
 @pytest.fixture(scope="module")
 def task(ps_cfg):
-    return get_task("afmoe", ps_cfg.model)
+    return get_task("ouro", ps_cfg.model)
 
 
 def rows_of(task, n, seed=3):
@@ -53,28 +55,25 @@ def rows_of(task, n, seed=3):
         0, task.arch.vocab_held, size=(n, task.row_width)).astype(np.int32)
 
 
-# -- one frame, three families ---------------------------------------------------
+# -- one frame, four families ----------------------------------------------------
 
-def test_the_three_families_import_one_frame():
-    """One attention core, one RoPE, one gated expert, one expert
-    layer, one task frame: the modules hold the shared module's own
-    objects, and none keeps a copy."""
-    assert afmoe.rope is lm.rope and glm.rope is lm.rope
-    assert afmoe.swiglu is lm.swiglu and glm.swiglu is lm.swiglu
-    assert glm.swiglu_experts is lm.swiglu_experts
-    for module in (glm, nh, afmoe):
-        assert module.rms_norm is lm.rms_norm
-        for shared in ("route", "live_rows_bound", "fit_counted",
-                       "evaluate_leaves", "head_nll", "blocked_attention",
-                       "key_span"):
+def test_the_four_families_import_one_frame():
+    """One attention core, one RoPE, one gated MLP, one task frame: the
+    new module holds the shared module's own objects and keeps no
+    copy."""
+    assert ouro.rope is lm.rope and ouro.swiglu is lm.swiglu
+    for module in (glm, nh, afmoe, ouro):
+        for shared in ("route", "fit_counted", "evaluate_leaves",
+                       "blocked_attention", "key_span", "head_nll"):
             assert shared not in vars(module), (module.__name__, shared)
-    assert issubclass(afmoe.AfmoeTask, lm.TokenRowsTask)
+    assert issubclass(ouro.OuroTask, lm.TokenRowsTask)
     for shared in ("fit_counted", "evaluate_leaves", "unflatten", "flatten",
                    "init_params", "encode_labels", "fit"):
-        assert shared not in vars(afmoe.AfmoeTask), shared
-    assert afmoe.AfmoeTask.counter_names == lm.COUNTERS + (
-        "attn.pairs_window", "attn.pairs_full", "attn.block_pairs",
-        "attn.kernel_block_pairs")
+        assert shared not in vars(ouro.OuroTask), shared
+    assert ouro.OuroTask.counter_names == afmoe.AfmoeTask.counter_names + (
+        "lm.layer_passes",)
+    assert ouro.PAIRS_UNIT == afmoe.PAIRS_UNIT
+    assert ouro.OuroTask.slots_a_token == 0         # no expert layer
 
 
 def test_the_single_step_is_one_round_of_the_chunk(task, ps_cfg):
@@ -124,14 +123,13 @@ def stablehlo():
 @pytest.mark.parametrize("program", ["fit_counted", "evaluate_leaves",
                                      "folded_chunk"])
 @pytest.mark.parametrize("name", sorted(FIXTURE))
-def test_the_other_families_stablehlo_is_the_parents(stablehlo, name,
-                                                     program):
-    """RoPE and the gated expert moved to models/lm_common.py and the
-    blocked core stands beside them: the programs the second family
-    traces are, character for character, the ones the commit before
-    traced (tests/fixtures/ holds the digests, written from the commit
-    its `_what` names; the first family's are held by
-    tests/test_nemotron_h_runtime.py)."""
+def test_the_third_familys_stablehlo_is_the_parents(stablehlo, name,
+                                                    program):
+    """`validate_cut` asks a family for its experts only where it has
+    some, and nothing else of models/lm_common.py moved: the programs
+    the third family traces are, character for character, the ones the
+    commit before traced (tests/fixtures/ holds the digests, written
+    from the commit its `_what` names)."""
     stated = json.load(open(os.path.join(ROOT, "tests", "fixtures",
                                          FIXTURE[name])))
     if stated["jax"] != jax.__version__:
@@ -141,6 +139,23 @@ def test_the_other_families_stablehlo_is_the_parents(stablehlo, name,
     assert "stablehlo." in text and len(text) > 50_000
     assert hashlib.sha256(text.encode()).hexdigest() \
         == stated["programs"][program]
+
+
+def test_the_cut_asks_a_family_for_its_experts_only_where_it_has_some():
+    """`lm_common.validate_cut`: the three expert families are held to
+    their share as before; a dense family states its vocabulary and no
+    dummy expert count."""
+    dense = ouro.load_config(TINY)
+    lm.validate_cut(dense)
+    with pytest.raises(ValueError, match="vocab_held"):
+        lm.validate_cut(dataclasses.replace(dense, vocab_held=0))
+    sparse = afmoe.load_config(MODEL_FILE["afmoe"])
+    lm.validate_cut(sparse)
+    for change in ({"experts_held": 9}, {"expert_offset": 7},
+                   {"expert_offset": -1}):
+        with pytest.raises(ValueError, match="expert_offset"):
+            lm.validate_cut(dataclasses.replace(sparse, **change))
+    lm.validate_cut(dataclasses.replace(sparse, expert_offset=6))
 
 
 # -- through the CLI's own parser and drives ---------------------------------
@@ -153,7 +168,7 @@ def _write_token_csvs(task, train_rows=24, test_rows=3):
     write_csv("test.csv", rows[train_rows:], zeros[train_rows:])
 
 
-def _cli(*more, name="afmoe"):
+def _cli(*more, name="ouro"):
     return ["-training", "train.csv", "-test", "test.csv", "--task", name,
             "--model_json", MODEL_FILE[name], "--num_workers", "2",
             "-min", "1", "-max", "2", "--local_learning_rate", "0.05",
@@ -227,26 +242,26 @@ def _refusal(name):
     return str(e.value)
 
 
-@pytest.mark.parametrize("other", ["glm4_moe_lite", "nemotron_h"])
-def test_the_three_language_model_tasks_refuse_the_same_levers(other):
+@pytest.mark.parametrize("other", ["glm4_moe_lite", "nemotron_h", "afmoe"])
+def test_the_four_language_model_tasks_refuse_the_same_levers(other):
     """What a task cannot run with follows from what its family says of
     itself — a file of its own, rows that are tokens, no program over a
-    mesh — so the new family refuses the same levers with the same
-    words as each of the other two."""
+    mesh — so the dense family refuses the same levers with the same
+    words as each of the other three."""
     from kafka_ps_tpu.cli import run as run_mod
-    family = task_class("afmoe")
+    family = task_class("ouro")
     assert family.model_file and not family.batches_workers
     assert family.row_dtype is np.int32
-    said = _refusal("afmoe")
-    assert said.startswith("--task afmoe cannot run with ")
+    said = _refusal("ouro")
+    assert said.startswith("--task ouro cannot run with ")
     levers = {flag: why for _, what in run_mod.TASK_REFUSES
               for flag, (_, why) in what.items()}
     for flag in ("compress", "slab_dtype", "tier_hot_bytes", "param_shards"):
         assert f"--{flag.replace('_', '-')}: {levers[flag]}" in said
-    assert said.replace("afmoe", "X") == _refusal(other).replace(other, "X")
+    assert said.replace("ouro", "X") == _refusal(other).replace(other, "X")
     # the task without its file, or a file without such a task
     bare = [a for a in _cli() if a not in ("--model_json", TINY)]
-    with pytest.raises(SystemExit, match="--task afmoe needs --model_json"):
+    with pytest.raises(SystemExit, match="--task ouro needs --model_json"):
         run_mod.cfg_from_args(run_mod.build_parser().parse_args(bare))
     plain = ["--task", "mlp", "--model_json", TINY]
     with pytest.raises(SystemExit, match="no file of its own"):
@@ -255,22 +270,30 @@ def test_the_three_language_model_tasks_refuse_the_same_levers(other):
     # the parent's
     parser = run_mod.build_parser()
     task_flag = next(a for a in parser._actions if a.dest == "task")
-    assert task_flag.choices[:5] == ["logreg", "mlp", "glm4_moe_lite",
-                                     "nemotron_h", "afmoe"]
-    assert "afmoe" in next(a for a in parser._actions
-                           if a.dest == "model_json").help
+    assert task_flag.choices == ["logreg", "mlp", "glm4_moe_lite",
+                                 "nemotron_h", "afmoe", "ouro"]
+    assert "ouro" in next(a for a in parser._actions
+                          if a.dest == "model_json").help
+
+
+def test_the_parser_has_the_parents_options_and_one_task_more():
+    """No new flag or option: the CLI's parser has the 75 option
+    strings the parent's has (counted in a checkout of 9637bd4), and
+    `--task` takes one name more."""
+    from kafka_ps_tpu.cli import run as run_mod
+    options = [s for a in run_mod.build_parser()._actions
+               for s in a.option_strings]
+    assert len(options) == len(set(options)) == 75
 
 
 def test_a_relative_model_file_is_taken_from_the_repositorys_root(
         tmp_path, monkeypatch):
     monkeypatch.chdir(tmp_path)
     assert not os.path.exists(TINY)
-    c = afmoe.load_config(TINY)
-    assert c.hidden_size == 64 and c.sliding_window == 8
-    assert c.layers(afmoe.SLIDING) == 4 and c.layers(afmoe.FULL) == 1
-    assert c.num_moe_layers == 4 and c.attention_block == 8
-    assert (c.n_routed_experts, c.norm_topk_prob, c.routed_scaling_factor) \
-        == (8, True, 2.826)
+    c = ouro.load_config(TINY)
+    assert c.hidden_size == 64 and c.total_ut_steps == 4
+    assert c.layer_applications == 12 and c.attention_block == 8
+    assert c.layer_types == (ouro.FULL,) * 3
 
 
 def _folded_app(task, ps_cfg, **more):
@@ -295,18 +318,22 @@ def test_the_fused_loop_sums_the_familys_counters_over_a_call(task, ps_cfg):
     assert set(counters) == set(task.counter_names)
     c = task.arch
     assert counters["data.tokens"] == 32 * 2 * c.sequence_length
-    # 32 updates x (k + 1) passes x 2 rows x the pairs of a row's pass,
-    # in units of 1,024 pairs rounded down a pass
-    window, full, blocks = afmoe.pair_counts(c)
-    assert counters["attn.pairs_window"] == 32 * 3 * (2 * window // 1024)
+    # 32 updates x (k + 1) passes x 2 rows x the pairs of a row's pass
+    # (12 layer applications), in units of 1,024 pairs rounded down a
+    # pass
+    full, blocks = ouro.pair_counts(c)
+    assert counters["attn.pairs_window"] == 0
     assert counters["attn.pairs_full"] == 32 * 3 * (2 * full // 1024)
     assert counters["attn.block_pairs"] == 32 * 3 * (2 * blocks // 1024)
-    assert counters["attn.block_pairs"] > counters["attn.pairs_window"] > 0
-    # through the CPU runtime the core is its plain tiles, whatever the
-    # size: the kernel computed none of those blocks
+    assert counters["attn.block_pairs"] > counters["attn.pairs_full"] > 0
+    # 3 layers x 4 steps x 3 passes = 36 a row an update
+    assert counters["lm.layer_passes"] == 32 * 2 * 36
+    for name in task.counter_names:
+        if name.startswith("moe."):
+            assert counters[name] == 0, name
+    # through the CPU runtime the core is its plain tiles
     assert counters["attn.kernel_block_pairs"] == 0
-    assert tracer.counters()["attn.block_pairs"] \
-        == counters["attn.block_pairs"]
+    assert tracer.counters()["lm.layer_passes"] == counters["lm.layer_passes"]
     assert app.server.last_metrics is not None
     app.close_logs()
 
