@@ -439,8 +439,24 @@ def routed_experts(h, idx, w, p: dict, c, expert):
     `expert` and the dead rows' mask; the chip's grouped-product calls
     inside it keep the `ragged-dot` name the compiler gives them),
     `kps.moe.combine` (the weighting and the add-back product) — the
-    same in both branches of the bound's `cond`, whose own time stays
-    under `kps.moe.experts` alone."""
+    same in both branches of the bound's `cond`.  What a trace reads
+    under `kps.moe.experts` ALONE is the add-back product of the branch
+    that ran: a branch's ROOT takes the `cond`'s name, not its own
+    scope's.
+
+    Under `jax.grad` the two branches differ in what they keep.  The
+    branch under the bound — the one a pass takes unless more
+    assignments land here than `live_rows_bound` — keeps its residuals
+    as any traced code does and recomputes nothing.  The branch over
+    the bound is a `jax.checkpoint`: it keeps its inputs (`h`, the
+    matrices, `order`, `weight` — what the other branch holds anyway)
+    and runs its forward once more in the backward pass.  A `cond`
+    returns ONE tuple of residuals for both branches, each filling the
+    other's entries with zeros, and the two place different row counts,
+    so no entry is shared: with the rare branch's residuals crossing
+    the `cond` the common one wrote all of them as zeros, T·K rows
+    wide, on every gradient pass of every expert layer (24.8 ms of the
+    third language-model cell's 370 ms update, PERF.md PR 40)."""
     with jax.named_scope("kps.moe.experts"):
         t, k = idx.shape
         held = c.experts_held
@@ -482,8 +498,10 @@ def routed_experts(h, idx, w, p: dict, c, expert):
         bound = live_rows_bound(t * k, c)
         went_over = n_here > bound
         out = (placed(t * k) if bound == t * k else
-               jax.lax.cond(went_over, functools.partial(placed, t * k),
-                            functools.partial(placed, bound)))
+               jax.lax.cond(
+                   went_over,
+                   jax.checkpoint(functools.partial(placed, t * k)),
+                   functools.partial(placed, bound)))
         return out, jnp.stack([n_here, sizes.max(),
                                went_over.astype(jnp.int32)])
 

@@ -141,6 +141,13 @@ def computations(hlo_text: str) -> dict[str, list[str]]:
     return comps
 
 
+def _result_bytes(dtype: str, dims: str) -> int:
+    size = _DTYPE_BYTES.get(dtype, 4)
+    for d in filter(None, dims.split(",")):
+        size *= int(d)
+    return size
+
+
 def top_level_instructions(hlo_text: str):
     """(computation, name, line, result bytes, XLA's estimated cycles)
     for every instruction that is not inside a fused computation: what
@@ -153,11 +160,8 @@ def top_level_instructions(hlo_text: str):
             if comp in fused or not m:
                 continue
             name, dtype, dims = m.groups()
-            size = _DTYPE_BYTES.get(dtype, 4)
-            for d in filter(None, dims.split(",")):
-                size *= int(d)
             cycles = re.search(r'"estimated_cycles":"(\d+)"', line)
-            out.append((comp, name, line.strip(), size,
+            out.append((comp, name, line.strip(), _result_bytes(dtype, dims),
                         int(cycles.group(1)) if cycles else 0))
     return out
 
@@ -169,6 +173,30 @@ def big_relayouts(hlo_text: str, at_least_bytes: int):
             for comp, name, _, size, _ in top_level_instructions(hlo_text)
             if size >= at_least_bytes
             and re.match(r"(copy|slice)[.\d]*$", name)]
+
+
+def zeros_in_taken_branches(hlo_text: str, width: int):
+    """(computation, name, result bytes) of every `broadcast` of a
+    constant that stands alone (inside no fusion: an array written to
+    memory) in a computation that is branch 0 of a `conditional` — of a
+    `jax.lax.cond` the branch its predicate's False takes, in
+    models/lm_common.py `routed_experts` the pass under the bound — and
+    whose result has `width` rows or columns.  What a `cond` under
+    `jax.grad` makes of the OTHER branch's residuals: the taken branch
+    hands each back as zeros (PERF.md section 6, PR 40)."""
+    comps = computations(hlo_text)
+    taken = re.findall(r" conditional\(.*?branch_computations=\{%?([\w.\-]+),",
+                       hlo_text)
+    made = []
+    for comp in taken:
+        for line in comps[comp]:
+            m = _RESULT.match(line)
+            if not m or not re.search(r" broadcast\(%?constant[\w.\-]*\)", line):
+                continue
+            name, dtype, dims = m.groups()
+            if dims.count(",") and str(width) in dims.split(","):
+                made.append((comp, name, _result_bytes(dtype, dims)))
+    return made
 
 
 def in_run_order(hlo_text: str):

@@ -24,7 +24,9 @@ import pytest
 
 from kafka_ps_tpu.models import afmoe
 from kafka_ps_tpu.models import attention_kernel
+from kafka_ps_tpu.models import glm4_moe_lite
 from kafka_ps_tpu.models import lm_common as lm
+from kafka_ps_tpu.models import nemotron_h
 from kafka_ps_tpu.models.task import get_task
 from kafka_ps_tpu.parallel import bsp
 from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
@@ -507,6 +509,100 @@ def test_rows_past_the_last_group_take_no_gradient(task):
     assert np.isfinite(np.asarray(sound)).all() and np.any(sound)
     close(through(poisoned), sound)
     close(sound, through(lm.swiglu_experts))   # the CPU leaves zeros there
+
+
+def _gated(h, m, e):
+    return (jax.nn.silu(h @ m["e_gate"][e]) * (h @ m["e_up"][e])) \
+        @ m["e_down"][e]
+
+
+def _relu2(h, m, e):
+    return jnp.square(jax.nn.relu(h @ m["e_up"][e])) @ m["e_down"][e]
+
+
+# the three expert families on the one expert layer, by task: (its tiny
+# model file, what it hands `routed_experts`, expert `e` on every token)
+EXPERT_FAMILIES = {
+    "afmoe": (TINY, afmoe._experts, _gated),
+    "glm4_moe_lite": ("benchmark/families/glm4-moe-lite/tiny.model.json",
+                      glm4_moe_lite._experts, _gated),
+    "nemotron_h": ("benchmark/families/nemotron-h/tiny.model.json",
+                   nemotron_h.relu2_experts, _relu2)}
+
+
+@pytest.mark.parametrize("over", [False, True], ids=["under", "over"])
+@pytest.mark.parametrize("name", sorted(EXPERT_FAMILIES))
+def test_either_branch_of_the_bound_is_the_dense_sum(name, over):
+    """`routed_experts` at a family's tiny widths, three experts held:
+    a pass UNDER `live_rows_bound` (a third of the tokens choose expert
+    1, nobody 0 or 2: the bound's rows, most of them dead) and a pass
+    forced OVER it (every token chooses 0 and 1, nobody 2: all T·K
+    slots placed) — the branch that, since PR 40, keeps only its inputs
+    and runs its forward once more backward.  Each equals the plain sum
+    over the held experts of weight x expert(every token), written here
+    with no `cond`, no sort and no placement — the value and the
+    gradient in `h`, in `w` and in every expert matrix, all finite —
+    and the load's third entry says which branch ran."""
+    model_json, expert, one_expert = EXPERT_FAMILIES[name]
+    c = dataclasses.replace(
+        get_task(name, ModelConfig(model_json=model_json)).arch,
+        experts_held=3, expert_offset=0)
+    t, k, held = 24, c.num_experts_per_tok, 3
+    assert (k, c.n_routed_experts) == (2, 8)
+    bound = lm.live_rows_bound(t * k, c)
+    assert bound == 40 < t * k
+    rng = np.random.default_rng(40)
+    h = jnp.asarray(rng.standard_normal((t, c.hidden_size)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.2, 1.0, (t, k)), jnp.float32)
+    inter = c.moe_intermediate_size
+    shapes = {"e_gate": (held, c.hidden_size, inter),
+              "e_up": (held, c.hidden_size, inter),
+              "e_down": (held, inter, c.hidden_size)}
+    if one_expert is _relu2:
+        del shapes["e_gate"]
+    p = {key: jnp.asarray(0.1 * rng.standard_normal(shape), jnp.float32)
+         for key, shape in shapes.items()}
+    # experts 3.. are held elsewhere
+    idx = jnp.asarray(np.tile([0, 1], (t, 1)) if over else
+                      [[1, 4] if i % 3 == 0 else [5, 6] for i in range(t)],
+                      jnp.int32)
+    seen = jnp.asarray(rng.standard_normal((t, c.hidden_size)), jnp.float32)
+
+    def sparse(h, w, p):
+        out, load = lm.routed_experts(h, idx, w, p, c, expert)
+        return jnp.sum(out * seen), (out, load)
+
+    def dense(h, w, p):
+        out = sum(jnp.sum(jnp.where(idx == e, w, 0.0), axis=1)[:, None]
+                  * one_expert(h, p, e) for e in range(held))
+        return jnp.sum(out * seen), out
+
+    (_, (got, load)), g_got = jax.jit(jax.value_and_grad(
+        sparse, argnums=(0, 1, 2), has_aux=True))(h, w, p)
+    (_, want), g_want = jax.value_and_grad(
+        dense, argnums=(0, 1, 2), has_aux=True)(h, w, p)
+    here, largest, went_over = np.asarray(load).tolist()
+    assert (here, largest, went_over) == (
+        (t * k, t, 1) if over else (t // 3, t // 3, 0))
+    assert (here > bound) == over
+    close(got, want)
+    assert jax.tree.structure(g_got) == jax.tree.structure(g_want)
+    for a, b in zip(jax.tree.leaves(g_got), jax.tree.leaves(g_want)):
+        assert np.isfinite(np.asarray(a)).all() and np.any(a)
+        close(a, b)
+    # the matrices of the expert nobody chose take no gradient at all
+    for key in p:
+        assert not np.any(g_got[2][key][2])
+    # what crosses the `cond` for the backward pass: the branch under
+    # the bound's residuals, `bound` rows each, and of the branch over
+    # it its inputs only — nothing T·K rows or columns wide but the
+    # sort's own `order` and `weight`
+    traced = jax.make_jaxpr(jax.grad(lambda h: sparse(h, w, p)[0]))(h)
+    forward, _ = [eqn for eqn in traced.jaxpr.eqns
+                  if eqn.primitive.name == "cond"]
+    kept = [v.aval.shape for v in forward.outvars]
+    assert any(len(shape) == 2 and bound in shape for shape in kept)
+    assert not [shape for shape in kept if len(shape) > 1 and t * k in shape]
 
 
 def test_a_program_that_is_not_finite_has_no_gap_of_zero(ref, ps_cfg, theta):

@@ -117,11 +117,13 @@ def test_glm4_moe_lites_stablehlo_is_the_parents(glm_stablehlo, program):
     models/lm_common.py, and an edit there must leave what this family
     traces alone: the program is, character for character, the one
     tests/fixtures/glm4_tiny_stablehlo.json holds the digests of.  They
-    are PR 38's own tree's — that PR wrote the family's expert layers
-    out, which changed its programs on purpose and touched no shared
-    line (the other two families' fixtures passed untouched); until
-    then they were commit e9a1946's, the one before the shared frame
-    moved out of models/glm4_moe_lite.py."""
+    are PR 40's own tree's — that PR made the over-the-bound branch of
+    `routed_experts`' `cond` a `jax.checkpoint`, which changed every
+    expert family's programs on purpose (all three fixtures were
+    rewritten with it); from PR 38, which wrote this family's expert
+    layers out and touched no shared line, they were that tree's, and
+    until then commit e9a1946's, the one before the shared frame moved
+    out of models/glm4_moe_lite.py."""
     stated = json.load(open(os.path.join(
         ROOT, "tests", "fixtures", "glm4_tiny_stablehlo.json")))
     if stated["jax"] != jax.__version__:

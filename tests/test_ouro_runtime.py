@@ -129,7 +129,10 @@ def test_the_third_familys_stablehlo_is_the_parents(stablehlo, name,
     some, and nothing else of models/lm_common.py moved: the programs
     the third family traces are, character for character, the ones the
     commit before traced (tests/fixtures/ holds the digests, written
-    from the commit its `_what` names)."""
+    from the commit its `_what` names: PR 40's tree's since that PR
+    changed the expert layer's program on purpose, `routed_experts`'
+    branch over the bound a `jax.checkpoint`; until then commit
+    9637bd4's, PR 38)."""
     stated = json.load(open(os.path.join(ROOT, "tests", "fixtures",
                                          FIXTURE[name])))
     if stated["jax"] != jax.__version__:
