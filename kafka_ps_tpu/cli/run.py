@@ -59,7 +59,7 @@ def build_parser(include_server_flags: bool = True,
     p.add_argument("--num_features", type=int, default=1024)
     p.add_argument("--num_classes", type=int, default=5)
     p.add_argument("--task", choices=["logreg", "mlp", "glm4_moe_lite",
-                                      "nemotron_h", "afmoe", "ouro"],
+                                      "nemotron_h", "afmoe", "ouro", "mellum"],
                    default="logreg",
                    help="model family (models/task.py registry); logreg "
                         "is the reference's task")
@@ -68,9 +68,9 @@ def build_parser(include_server_flags: bool = True,
     p.add_argument("--model_json", default=None,
                    help="the model family's own configuration file "
                         "(a language-model family, --task glm4_moe_lite, "
-                        "nemotron_h, afmoe or ouro: the published keys and "
-                        "the cut held here, models/lm_common.py); a relative "
-                        "path is taken from the repository's root")
+                        "nemotron_h, afmoe, ouro or mellum: the published "
+                        "keys and the cut held here, models/lm_common.py); a "
+                        "relative path is taken from the repository's root")
     p.add_argument("--local_iterations", type=int, default=2,
                    help="k local solver steps per iteration "
                         "(numMaxIter, LogisticRegressionTaskSpark.java:35)")

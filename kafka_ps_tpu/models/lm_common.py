@@ -1,5 +1,5 @@
-"""What the language-model families share (`glm4_moe_lite`,
-`nemotron_h`, `afmoe`, `ouro`): token rows, RMSNorm, RoPE, the blocked
+"""What the language-model families share (`glm4_moe_lite`, `nemotron_h`,
+`afmoe`, `ouro`, `mellum`): token rows, RMSNorm, RoPE, the blocked
 attention core (on a TPU a kernel, `models/attention_kernel.py`, where
 the shapes allow), the sliced head and its loss, the router, the expert
 layer that knows its share, the gated (SwiGLU) expert, the counters,

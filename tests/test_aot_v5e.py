@@ -54,7 +54,8 @@ CHUNKS = {
     "glm4_moe_lite": "benchmark/configs/glm-4.7-flash-ep8.model.json",
     "nemotron_h": "benchmark/configs/nemotron-3-nano-ep16.model.json",
     "afmoe": "benchmark/configs/trinity-mini-ep16.model.json",
-    "ouro": "benchmark/configs/ouro-2.6b.model.json"}
+    "ouro": "benchmark/configs/ouro-2.6b.model.json",
+    "mellum": "benchmark/configs/mellum2-12b-ep4.model.json"}
 # the scopes every chunk names, and those only a family with an expert
 # layer does
 NAMED = {"kps.attn.qkv", "kps.attn.out", "kps.lm.norm", "kps.bsp.carry",
@@ -277,7 +278,7 @@ def test_the_third_language_models_chunk_holds_no_square_of_scores(
 
 @pytest.mark.parametrize("family,slots,scratch", [
     ("afmoe", 32768, 7.5e9), ("glm4_moe_lite", 4096, 6.4e9),
-    ("nemotron_h", 6144, 8.3e9)])
+    ("nemotron_h", 6144, 8.3e9), ("mellum", 32768, 9.9e9)])
 def test_the_taken_branch_of_the_bound_writes_no_zeros_for_the_other(
         aot, folded_chunk, family, slots, scratch):
     """An expert family's chunk, compiled for the described chip (the
@@ -400,6 +401,90 @@ def test_the_fourth_language_models_chunk_holds_its_layers_once(
     beyond = (memory.temp_size_in_bytes
               - plain_compiled.memory_analysis().temp_size_in_bytes)
     assert 1 * layers < beyond < 3 * layers, beyond / layers
+
+
+def test_the_fifth_language_models_chunk_walks_its_widths_in_told_tiles(
+        aot, folded_chunk):
+    """The scan chunk of `mellum2-12b-ep4.fused-bsp` (595.2 M parameters
+    held, 16 of 64 experts, 4 workers folded one at a time, 1 row of
+    4,096 tokens a worker, 8 clocks), compiled for the described chip.
+    The leaves are donated and scratch + donated leaves stay under 15.0
+    GB: 8,976,765,440 + 2,380,616,704 bytes when written (8.98 + 2.38 =
+    11.36 GB, 19.1 bytes a parameter; the limit is that reading and a
+    tenth).  A quarter of the experts held makes the expert layer's
+    rows four times the third family's: the bound places 16,384 rows
+    (a `bf16[16384,4096]` 0/1 matrix, 134 MB, a layer a pass) and a
+    pass over it all 32,768.  At 8,192-token rows the same chunk
+    compiles to 12.79 GB of scratch + the 2.38 GB of leaves, 15.17 GB
+    (compiled once by hand with scripts/aot_v5e_hlo.py, PR 41, not
+    here: the 0/1 matrix is then `bf16[32768,8192]`, 537 MB, and its
+    products 4 x the operations) — it would not fit the chip beside the
+    flat vector's copy, so such rows wait on the placement.
+
+    Every grouped product — the three of a SwiGLU expert, their dx and
+    dW, under the bound's 16,384 rows and over it at 32,768 — runs the
+    chip's kernel in the tiles `grouped_tiles` states for the call's
+    OWN shape: hidden 2304 = 4.5 x 512 in 3 x 768 and the expert width
+    896 = 7 x 128 whole, none in the 128 x 128 blocks the compiler
+    takes at such widths.  The rule PR 32 wrote for the second family's
+    2688 / 1856 meets its second family here.
+
+    The attention core is the kernel in BOTH kinds of layer, at a
+    window of TWO tiles (1,024 in tiles of 512): 5 forward calls a
+    layer (2 gradient passes x (forward + recomputed) + the loss's) and
+    2 backward, 3 sliding layers and 1 full, and no array of S x S
+    elements a head anywhere.  About 90 s."""
+    task, compiled = folded_chunk("mellum")
+    assert task.num_params == 595_154_176
+    c = task.arch
+    s, block = c.sequence_length, c.attention_block
+    assert (s, block, c.sliding_window) == (4096, 512, 1024)
+    memory = compiled.memory_analysis()
+    leaves = 4 * task.num_params
+    assert memory.alias_size_in_bytes >= leaves
+    assert memory.temp_size_in_bytes + leaves < 15.0e9, \
+        memory.temp_size_in_bytes
+    assert memory.temp_size_in_bytes < 9.9e9, memory.temp_size_in_bytes
+    text = compiled.as_text()
+    slots = s * c.num_experts_per_tok
+    bound = lm.live_rows_bound(slots, c)
+    assert (bound, slots) == (16384, 32768)
+    calls = aot.ragged_dot_calls(text)
+    assert {shape for shape, _ in calls} == {
+        (m, k, n) for m in (bound, slots)
+        for k, n in ((2304, 896), (896, 2304))}
+    assert all(tiles == lm.grouped_tiles(*shape) for shape, tiles in calls), \
+        sorted(set(calls))
+    assert {tiles for _, tiles in calls} == {"256,768,896", "256,896,768"}
+    assert not any(tiles.endswith(",128,128") for _, tiles in calls)
+    # the placement's 0/1 matrix, under the bound and over it
+    assert f"bf16[{bound},{s}]" in text and f"bf16[{slots},{s}]" in text
+    # the core's calls, by kernel and scope
+    kernel_calls = re.findall(
+        r"%(kps_attn_core_\w+?)[.\d]* = .* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*op_name=\"([^\"]*)\"", text)
+    scopes = ("kps.attn.window", "kps.attn.full")
+    assert all(sum(scope in op_name for scope in scopes) == 1
+               for _, op_name in kernel_calls), kernel_calls
+    assert {(kernel, scope): sum(k == kernel and scope in op_name
+                                 for k, op_name in kernel_calls)
+            for kernel in ("kps_attn_core_forward", "kps_attn_core_backward")
+            for scope in scopes} == {
+        ("kps_attn_core_forward", "kps.attn.window"): 15,
+        ("kps_attn_core_forward", "kps.attn.full"): 5,
+        ("kps_attn_core_backward", "kps.attn.window"): 6,
+        ("kps_attn_core_backward", "kps.attn.full"): 2}
+    shapes = {tuple(int(d) for d in dims.split(","))
+              for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
+    assert not [sh for sh in shapes if len(sh) >= 3 and sh.count(s) >= 2]
+    assert not [sh for sh in shapes if s * s in sh]
+    for scope in ("kps.attn.qkv", "kps.attn.norm_rope", "kps.attn.out",
+                  "kps.moe.route", "kps.moe.sort", "kps.moe.place",
+                  "kps.moe.expert_fn", "kps.moe.combine", "kps.lm.norm",
+                  "kps.lm.embed", "kps.lm.head"):
+        assert scope in text, scope
+    for absent in ("kps.moe.shared", "kps.mlp", "kps.lm.layers"):
+        assert absent not in text, absent
 
 
 @pytest.mark.parametrize("family", sorted(CHUNKS))

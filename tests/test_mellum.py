@@ -1,0 +1,596 @@
+"""The `mellum` family (models/mellum.py) at its tiny size on the CPU:
+against the benchmark's plain reference
+(benchmark/families/mellum/reference.py) on seeded random weights —
+forward, loss, gradients and fused clocks — YaRN's frequencies against
+numbers worked by hand for the published parameters, the sliding mask
+at the published window's edges, the softmax router's weights, the
+share of an expert layer against the uncut layer at a QUARTER held,
+the placement's counter, and each of the reference's controls.
+
+Both sides run float32 at the CPU's full precision, so they agree to
+round-off: 1e-5 relative is ten times the worst seen (a few 1e-6: sums
+in another order), and far under anything a changed formula would
+give."""
+
+import dataclasses
+import importlib.util
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kafka_ps_tpu.models import afmoe
+from kafka_ps_tpu.models import lm_common as lm
+from kafka_ps_tpu.models import mellum
+from kafka_ps_tpu.models.task import get_task
+from kafka_ps_tpu.parallel import bsp
+from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILY = os.path.join(ROOT, "benchmark", "families", "mellum")
+TINY = "benchmark/families/mellum/tiny.model.json"
+PUBLISHED = "benchmark/configs/mellum2-12b-ep4.model.json"
+RTOL = 1e-5
+CONTROL_NAMES = ["theta_bf16", "window_ignored", "plain_rope_on_full",
+                 "sigmoid_router", "top7", "no_norm_topk"]
+
+
+def family(part):
+    """A file of the benchmark's family, as a module (the harness loads
+    it the same way)."""
+    name = "mellum_family_test_" + part
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(FAMILY, part + ".py"))
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[name] = module
+        spec.loader.exec_module(module)
+    return sys.modules[name]
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return family("reference")
+
+
+@pytest.fixture(scope="module")
+def ps_cfg():
+    return PSConfig(num_workers=3, task="mellum",
+                    model=ModelConfig(num_max_iter=2,
+                                      local_learning_rate=0.05,
+                                      model_json=TINY),
+                    buffer=BufferConfig(min_size=1, max_size=2))
+
+
+@pytest.fixture(scope="module")
+def task(ps_cfg):
+    return get_task("mellum", ps_cfg.model)
+
+
+@pytest.fixture(scope="module")
+def theta(task):
+    """Seeded random weights: the stated start, moved off it so that no
+    norm weight is one.  (Seed 8: under seed 7 one token's second and
+    third probabilities lie a rounding apart at clock 6, the program and
+    the reference pick differently there, and the 8-clock comparison
+    reads 1.3e-3 where every other seed tried reads 5e-6: a top-k
+    choice is discrete, which is what the benchmark's `routing_differs`
+    reports.)"""
+    rng = np.random.default_rng(8)
+    start = np.asarray(task.init_params())
+    return (start + 0.05 * rng.standard_normal(start.shape)).astype(
+        np.float32)
+
+
+def rows_of(task, n, seed=3):
+    return np.random.default_rng(seed).integers(
+        0, task.arch.vocab_held, size=(n, task.row_width)).astype(np.int32)
+
+
+def close(got, want, scale=None):
+    got, want = np.asarray(got), np.asarray(want)
+    scale = float(np.max(np.abs(want))) if scale is None else scale
+    assert np.max(np.abs(got - want)) <= RTOL * max(scale, 1e-30), (
+        float(np.max(np.abs(got - want))), scale)
+
+
+# -- the model against its reference --------------------------------------------
+
+def test_the_flat_layout_is_the_references(task, ref, ps_cfg):
+    s = ref.shapes(ps_cfg)
+    assert [(n, tuple(sh)) for n, sh in s.leaves()] == mellum.leaf_specs(
+        task.arch)
+    assert s.num_params == task.num_params
+    # and the stated start is the same to the last bit
+    assert np.array_equal(np.asarray(task.init_params()),
+                          ref.init_params(s))
+    leaves = mellum.init_leaves(task.arch)
+    for name in ("l0.in_norm", "l2.q_norm", "l1.k_norm",
+                 "l3.post_attn_norm", "final_norm"):
+        assert np.all(np.asarray(leaves[name]) == 1.0)
+    assert 0.01 < float(np.asarray(leaves["l0.wq"]).std()) < 0.03
+    # one frame: the family keeps no copy of what the frame gives
+    assert issubclass(mellum.MellumTask, lm.TokenRowsTask)
+    for shared in ("routed_experts", "blocked_attention", "head_nll",
+                   "evaluate_leaves", "swiglu_experts"):
+        assert shared not in vars(mellum), shared
+    assert mellum.MellumTask.counter_names == \
+        afmoe.AfmoeTask.counter_names + ("moe.place_pairs",)
+    assert mellum.PAIRS_UNIT == afmoe.PAIRS_UNIT
+
+
+def test_the_count_of_file_program_reference_and_costs_agree(ref):
+    """At the published widths, from shapes alone: the configuration's
+    `num_params`, the program's flat key space, the reference's and the
+    benchmark's cost functions count the same 595,154,176."""
+    stated = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "mellum2-12b-ep4.json")))
+    assert stated["num_params"] == 595_154_176
+    c = mellum.load_config(PUBLISHED)
+    assert mellum.num_params(c) == stated["num_params"]
+    cfg = PSConfig(task="mellum", model=ModelConfig(model_json=PUBLISHED))
+    assert ref.shapes(cfg).num_params == stated["num_params"]
+    costs = family("costs")
+    m = costs.model_file(cfg)
+    assert costs.num_params(m) == stated["num_params"]
+    assert lm.num_params(mellum.layer_specs(c)) == 120_476_416
+    # one whole period of the published pattern, every MLP sparse
+    assert c.layer_types == (mellum.SLIDING,) * 3 + (mellum.FULL,)
+    assert c.mlp_layer_types == ("sparse",) * 4
+    assert c.attention_block == 512 and c.sliding_window == 1024
+    assert (c.experts_held, c.num_experts, c.num_experts_per_tok) == (16, 64,
+                                                                      8)
+    # every number of the catalog's config the file carries as it is;
+    # the cut's keys are the ones BENCHMARK.json lists as reduced
+    model = json.load(open(os.path.join(ROOT, PUBLISHED)))
+    for key in ("hidden_size", "head_dim", "moe_intermediate_size",
+                "intermediate_size", "num_attention_heads",
+                "num_key_value_heads", "num_experts_per_tok",
+                "sliding_window", "rms_norm_eps", "rope_parameters",
+                "max_position_embeddings", "norm_topk_prob"):
+        assert stated[key] == model[key], key
+    assert (model["hidden_size"], model["moe_intermediate_size"],
+            model["intermediate_size"]) == (2304, 896, 7168)
+    assert stated["num_experts"] == model["experts_held"] == 16
+    assert stated["vocab_size"] == model["vocab_held"] == 24576
+    assert (model["num_experts"], model["vocab_size"]) == (64, 98304)
+    # the same operations an update: 461 MFLOP a token forward, 7 passes
+    flops, bytes_ = costs.update_cost(m, 1, 2, 4)
+    assert costs.forward_flops_per_token(m) == pytest.approx(461.0e6,
+                                                             rel=1e-3)
+    assert flops == pytest.approx(13.22e12, rel=1e-3)
+    assert bytes_ == 39.0 * 595_154_176
+    # the placement's 0/1 products at a quarter share: 16,384 rows x
+    # 4,096 tokens a layer a pass, (2 x 2 + 2) products of them a layer
+    # an update, 4 layers
+    assert costs.live_rows_bound(m, 4096) == lm.live_rows_bound(32768, c) \
+        == 16384
+    pairs = 4 * 3 * 16384 * 4096 // costs.PAIRS_UNIT
+    placed, _ = costs.placement_products(m, pairs, 1, 2)
+    assert placed == 4 * 10 * 2.0 * 16384 * 4096 * 2304
+
+
+def test_loss_and_gradients_agree_with_the_reference(task, ref, ps_cfg,
+                                                     theta):
+    s = ref.shapes(ps_cfg)
+    rows, mask = rows_of(task, 2), jnp.asarray([1.0, 1.0])
+    switches = ref.Reference(s).switches
+
+    def program(t):
+        return mellum.loss_and_counts(task.unflatten(t), rows, mask,
+                                      task.arch)[0]
+
+    def reference(t):
+        return ref._objective(ref.split(t, s), jnp.asarray(rows), mask, s,
+                              switches)
+    got, got_g = jax.value_and_grad(program)(jnp.asarray(theta))
+    want, want_g = jax.value_and_grad(reference)(jnp.asarray(theta))
+    close(got, want)
+    for (name, _), g, w in zip(
+            s.leaves(), ref.split(np.asarray(got_g), s).values(),
+            ref.split(np.asarray(want_g), s).values()):
+        assert np.any(w), name                     # every leaf is used
+        close(g, w)
+
+
+def test_the_forward_pass_agrees_with_the_reference_row_by_row(task, ref,
+                                                               ps_cfg,
+                                                               theta):
+    s = ref.shapes(ps_cfg)
+    rows = rows_of(task, 2, seed=5)
+    out = mellum.forward(task.unflatten(jnp.asarray(theta)), rows, task.arch,
+                         with_logits=True)
+    for i, (nll, preds, choices) in enumerate(
+            ref.Reference(s).forward_rows(theta, rows)):
+        close(out["nll"][i], nll)
+        assert np.array_equal(np.argmax(np.asarray(out["logits"][i]), -1),
+                              preds)
+        assert choices.shape == (4, 24, 8) and np.all(
+            choices.sum(-1) == s.num_experts_per_tok)
+        held = choices[..., s.expert_offset:s.expert_offset
+                       + s.experts_held].sum()
+        if i == 0:
+            alone = mellum.forward(task.unflatten(jnp.asarray(theta)),
+                                   rows[:1], task.arch)
+            assert int(alone["loads"][:, 0].sum()) == int(held)
+    # the logits themselves, not only their largest
+    p = ref.split(jnp.asarray(theta), s)
+    _, logits, _ = ref._row(p, jnp.asarray(rows[0]), s,
+                            ref.Reference(s).switches)
+    close(out["logits"][0], logits)
+
+
+def test_a_masked_row_adds_nothing(task, theta):
+    rows = rows_of(task, 2)
+    leaves = task.unflatten(jnp.asarray(theta))
+    both, _ = mellum.loss_and_counts(leaves, rows, jnp.asarray([1.0, 0.0]),
+                                     task.arch)
+    alone, _ = mellum.loss_and_counts(leaves, rows[:1], jnp.asarray([1.0]),
+                                      task.arch)
+    close(both, alone)
+
+
+@pytest.mark.parametrize("rounds", [1, 2, 8])
+def test_fused_clocks_agree_with_the_reference(task, ref, ps_cfg, theta,
+                                               rounds):
+    """The folded scan chunk of `rounds` clocks (8: the cell's chunk)
+    against as many reference rounds on the same slabs."""
+    s = ref.shapes(ps_cfg)
+    w = ps_cfg.num_workers
+    x = np.stack([rows_of(task, 2, seed=10 + i) for i in range(w)])
+    y = np.zeros((w, 2), np.int32)
+    mask = np.ones((w, 2), np.float32)
+    mask[1, 1] = 0.0                    # one worker's buffer half full
+    slabs = [(x[i], y[i], mask[i]) for i in range(w)]
+    want_t, want_l = ref.Reference(s).run(theta, slabs, rounds,
+                                          keep_every=rounds)
+    chunk = bsp.make_bsp_multi_step(ps_cfg.model, w, ps_cfg.server_lr,
+                                    rounds, task=task)
+    leaves, losses, counted = chunk(task.unflatten(jnp.asarray(theta)),
+                                    x, y, mask)
+    got = np.asarray(task.flatten(leaves))
+    # round-off grows with the clocks: each starts from the last one's
+    scale = 3 * RTOL * rounds
+    assert np.max(np.abs((got - theta) - (want_t[-1] - theta))) <= scale \
+        * np.max(np.abs(want_t[-1] - theta))
+    assert ref.param_gap(got, want_t[-1], theta, s) <= scale
+    np.testing.assert_allclose(np.asarray(losses), want_l, rtol=scale)
+    counted = dict(zip(task.counter_names, np.asarray(counted)))
+    c = task.arch
+    passes = rounds * w * (ps_cfg.model.num_max_iter + 1)
+    assert task.counter_names[:len(lm.COUNTERS)] == lm.COUNTERS
+    slots = 2 * c.sequence_length * c.num_experts_per_tok
+    assert (counted["moe.assignments_here"] + counted["moe.assignments_away"]
+            == passes * slots * c.num_hidden_layers)
+    over = counted["moe.passes_over_bound"]
+    assert 0 <= over <= passes * c.num_hidden_layers
+    assert counted["data.tokens"] == rounds * 5 * c.sequence_length
+    assert counted["data.pad_tokens"] == rounds * 1 * c.sequence_length
+    # every row of a slab, masked or not, rides through every layer; the
+    # pair counters count in units of 1,024 pairs, rounded down a pass
+    window, full, blocks = mellum.pair_counts(c)
+    assert (window, full, blocks) == (3 * 164, 300, 3 * 320 + 384)
+    for name, pairs in (("attn.pairs_window", window),
+                        ("attn.pairs_full", full),
+                        ("attn.block_pairs", blocks)):
+        assert counted[name] == passes * (2 * pairs // mellum.PAIRS_UNIT)
+    assert counted["attn.kernel_block_pairs"] == 0      # head_dim 16
+    # the placement: 2 rows x 24 tokens, 96 slots, a quarter held: a
+    # bound of 48 rows x 48 tokens a layer a pass, all 96 x 48 in a
+    # pass over it
+    under, beyond = mellum.place_pairs(2 * c.sequence_length, c)
+    assert (under, beyond) == (48 * 48, 96 * 48)
+    assert counted["moe.place_pairs"] == (
+        passes * (4 * under // mellum.PAIRS_UNIT)
+        + over * ((beyond - under) // mellum.PAIRS_UNIT))
+
+
+def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):
+    s = ref.shapes(ps_cfg)
+    test_rows = rows_of(task, 3, seed=4)
+    got = task.evaluate(jnp.asarray(theta), test_rows, None)
+    want = ref.Reference(s).evaluate(theta, (test_rows, None))
+    close(got.loss, want["loss"])
+    close(got.f1, want["f1"], scale=1.0)
+    close(got.accuracy, want["accuracy"], scale=1.0)
+
+
+def test_logits_at_a_position_do_not_see_later_tokens(task, theta):
+    """The prefix property: both kinds of attention (over a tile's
+    boundary too) and the per-token layers are causal."""
+    c = task.arch
+    leaves = task.unflatten(jnp.asarray(theta))
+    row = rows_of(task, 1)
+    cut = 9                             # inside the second tile of 8
+    other = row.copy()
+    other[:, cut + 1:] = (other[:, cut + 1:] + 17) % c.vocab_held
+    a = mellum.forward(leaves, row, c, with_logits=True)["logits"]
+    b = mellum.forward(leaves, other, c, with_logits=True)["logits"]
+    close(a[:, :cut + 1], b[:, :cut + 1])
+    assert np.max(np.abs(np.asarray(a[:, cut + 1:] - b[:, cut + 1:]))) > 1e-3
+
+
+def test_a_model_file_the_family_cannot_run_is_refused(tmp_path):
+    body = json.load(open(os.path.join(ROOT, TINY)))
+    path = tmp_path / "model.json"
+    yarn = body["rope_parameters"]["full_attention"]
+    for change, said in (
+            ({"layer_types": body["layer_types"][:3]}, "layer_types"),
+            ({"layer_types": ["chunked_attention"] * 4}, "layer_types"),
+            ({"mlp_layer_types": ["sparse"] * 3 + ["dense"]}, "sparse"),
+            ({"num_key_value_heads": 3}, "divide over"),
+            ({"experts_held": 9}, "expert_offset"),
+            ({"rope_parameters": {"full_attention": yarn}}, "rope_type"),
+            ({"rope_parameters": dict(
+                body["rope_parameters"],
+                full_attention=dict(yarn, rope_type="llama3"))},
+             "rope_type"),
+            ({"model_type": "afmoe"}, "is not mellum")):
+        path.write_text(json.dumps(dict(body, **change)))
+        with pytest.raises(ValueError, match=said):
+            mellum.load_config(str(path))
+
+
+# -- positions -------------------------------------------------------------------
+
+def test_yarns_frequencies_are_the_numbers_worked_by_hand():
+    """The published full layer: factor 16 over 8,192 positions at theta
+    500,000, beta_fast 32, beta_slow 1.  d(32) = 18.08 and d(1) = 34.98,
+    so low 18 and high 35: frequency 18 turns as it did (500000^(-36 /
+    128) = 0.0249554), frequency 19 is 16/17 of itself and 1/17 of a
+    sixteenth (0.0203291 -> 0.0192080), frequency 35 and every one
+    after it a sixteenth (500000^(-70 / 128) / 16 = 4.77811e-5), and
+    cos and sin carry 0.1 ln 16 + 1."""
+    c = mellum.load_config(PUBLISHED)
+    rule = c.rope(mellum.FULL)
+    assert mellum.yarn_correction_dim(32, 128, 5e5, 8192) == pytest.approx(
+        18.08, abs=0.005)
+    assert mellum.yarn_correction_dim(1, 128, 5e5, 8192) == pytest.approx(
+        34.98, abs=0.005)
+    inv, scale = mellum.rope_tables(rule, 128)
+    plain, one = mellum.rope_tables(c.rope(mellum.SLIDING), 128)
+    assert inv.shape == plain.shape == (64,) and one == 1.0
+    assert scale == 1.2772588722239782 == pytest.approx(
+        0.1 * math.log(16) + 1)
+    np.testing.assert_allclose(plain[[0, 1, 18, 63]], [
+        1.0, 0.8146172, 0.0249554, 2.4551407e-06], rtol=2e-6)
+    np.testing.assert_array_equal(inv[:19], plain[:19])
+    np.testing.assert_allclose(inv[[18, 19, 35]],
+                               [0.0249554, 0.0192080, 4.77811e-05],
+                               rtol=3e-6)
+    np.testing.assert_allclose(inv[35:], plain[35:] / 16, rtol=1e-6)
+    ramp = (plain[19:35] - inv[19:35]) / (plain[19:35] * (1 - 1 / 16))
+    np.testing.assert_allclose(ramp, np.arange(1, 17) / 17, rtol=1e-4)
+    # the scaled tables at a position worked by hand: position 1000 in
+    # channel pair 19 turns 1000 x 0.0192080 = 19.2080 rad
+    x = jnp.zeros((1, 1001, 1, 128)).at[..., 19].set(1.0)
+    turned = np.asarray(mellum.rope(x, inv, scale))[0, 1000, 0]
+    assert turned[19] == pytest.approx(scale * math.cos(19.2080), abs=2e-4)
+    assert turned[19 + 64] == pytest.approx(scale * math.sin(19.2080),
+                                            abs=2e-4)
+    assert not np.any(np.delete(turned, [19, 83]))
+    # and the reference's own formulas give the same tables
+    ref = family("reference")
+    freq, factor = ref._frequencies(rule, 128, True)
+    np.testing.assert_allclose(np.asarray(freq), inv, rtol=2e-6)
+    assert factor == scale
+    freq, factor = ref._frequencies(rule, 128, False)
+    np.testing.assert_allclose(np.asarray(freq), plain, rtol=2e-6)
+    assert factor == 1.0
+
+
+def test_the_tiny_sizes_ramp_lies_inside_its_eight_frequencies(task):
+    c = task.arch
+    inv, scale = mellum.rope_tables(c.rope(mellum.FULL), c.head_dim)
+    plain, _ = mellum.rope_tables(c.rope(mellum.SLIDING), c.head_dim)
+    ramp = (plain - inv) / (plain * (1 - 1 / 16))
+    np.testing.assert_allclose(ramp, [0, 0, 1 / 3, 2 / 3, 1, 1, 1, 1],
+                               atol=1e-4)
+    assert scale == 1.2772588722239782
+
+
+def test_each_kind_of_layer_turns_by_its_own_rule(task, theta):
+    """A sliding layer and a full layer on the same leaves and input
+    differ by the rule alone where the window does not bind (rows of 8
+    tokens under a window of 8), and either differs from no rotation."""
+    c = dataclasses.replace(task.arch, sequence_length=8)
+    p = lm.sub(task.unflatten(jnp.asarray(theta)), "l0.")
+    u = jnp.asarray(np.random.default_rng(2).standard_normal(
+        (1, 8, c.hidden_size)), jnp.float32)
+    slid = mellum.attention(u, p, c, mellum.SLIDING)
+    full = mellum.attention(u, p, c, mellum.FULL)
+    assert np.max(np.abs(np.asarray(slid - full))) > 1e-3
+    same = dataclasses.replace(c, rope_parameters={
+        mellum.SLIDING: c.rope(mellum.SLIDING),
+        mellum.FULL: c.rope(mellum.SLIDING)})
+    close(mellum.attention(u, p, same, mellum.FULL), slid)
+
+
+# -- the band --------------------------------------------------------------------
+
+def test_the_sliding_mask_at_the_published_windows_edges():
+    """Window 1,024 in tiles of 512: query i sees key j iff j <= i and i
+    - j < 1024 — the key itself and the 1,023 before it.  Keys and
+    values that mark their position show which keys a query read."""
+    c = mellum.load_config(PUBLISHED)
+    s, w, block = 2048, c.sliding_window, c.attention_block
+    assert (w, block) == (1024, 512)
+    # uniform scores: the output is the mean of the values seen
+    q = jnp.zeros((1, s, 1, 1, 8), jnp.float32)
+    k = jnp.zeros((1, s, 1, 8), jnp.float32)
+    at = jnp.arange(s, dtype=jnp.float32)
+    v = jnp.stack([at, at * at] + [jnp.ones(s)] * 6, -1)[None, :, None, :]
+    out = np.asarray(lm.blocked_attention(q, k, v, window=w, block=block))
+    for i in (0, 1, 511, 512, 1023, 1024, 1025, 1535, 1536, 2047):
+        seen = np.arange(max(0, i - w + 1), i + 1, dtype=np.float64)
+        assert out[0, i, 0, 0, 0] == pytest.approx(seen.mean(), rel=1e-5)
+        assert out[0, i, 0, 0, 1] == pytest.approx((seen ** 2).mean(),
+                                                   rel=1e-5)
+    # query 1024 sees keys 1..1024 and not key 0; query 1023 sees key 0
+    assert lm.attention_pairs(s, w) == w * (w + 1) // 2 + (s - w) * w
+    assert [lm.key_span(t, block, w) for t in range(4)] == [
+        (0, 512), (0, 1024), (0, 1536), (512, 2048)]
+    # a window of two tiles: three key blocks a tile, 1.5 x the pairs
+    # in the mask in a long row's sliding layer
+    long = 4096
+    assert lm.attention_block_pairs(long, w, block) / lm.attention_pairs(
+        long, w) == pytest.approx(1.50, abs=0.04)
+
+
+# -- the router and the share ----------------------------------------------------
+
+def test_the_softmax_routers_weights_sum_to_one(task):
+    c = task.arch
+    rng = np.random.default_rng(5)
+    h = jnp.asarray(rng.standard_normal((40, c.hidden_size)), jnp.float32)
+    router = jnp.asarray(0.5 * rng.standard_normal(
+        (c.hidden_size, c.num_experts)), jnp.float32)
+    idx, w = mellum.route(h, router, c)
+    assert idx.shape == w.shape == (40, c.num_experts_per_tok)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+    prob = np.asarray(jax.nn.softmax(h @ router, axis=-1), np.float64)
+    top = np.sort(prob, axis=-1)[:, ::-1][:, :c.num_experts_per_tok]
+    np.testing.assert_allclose(np.asarray(w), top / top.sum(-1,
+                                                            keepdims=True),
+                               rtol=1e-5)
+    assert np.array_equal(np.sort(np.asarray(idx), -1), np.sort(
+        np.argsort(-prob, -1)[:, :c.num_experts_per_tok], -1))
+    # norm_topk_prob off: the probabilities as they are
+    _, raw = mellum.route(h, router, dataclasses.replace(
+        c, norm_topk_prob=False))
+    np.testing.assert_allclose(np.asarray(raw), top, rtol=1e-5)
+    # at the published width: 8 of 64, still one
+    wide = mellum.load_config(PUBLISHED)
+    h = jnp.asarray(rng.standard_normal((16, 64)), jnp.float32)
+    idx, w = mellum.route(h, jnp.eye(64, dtype=jnp.float32), wide)
+    assert idx.shape == (16, 8)
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
+
+
+@pytest.mark.parametrize("held", [1, 2, 4, 8])
+def test_the_shares_of_an_expert_layer_sum_to_the_uncut_layer(task, ref,
+                                                              ps_cfg, held):
+    """Over the shares of one expert layer (8 experts: 4 shares of two,
+    a QUARTER each, as the published 64 in shares of 16 at offsets 0,
+    16, 32, 48; and 8 of one, 2 of four, 1 of eight), the routed parts
+    summed equal the reference's layer with every expert held; there is
+    no shared expert to count once."""
+    c = task.arch
+    rng = np.random.default_rng(11)
+    h = jnp.asarray(rng.standard_normal((40, c.hidden_size)), jnp.float32)
+    e, hd, i = c.num_experts, c.hidden_size, c.moe_intermediate_size
+    full = {"router": 0.5 * rng.standard_normal((hd, e)),
+            "e_gate": 0.1 * rng.standard_normal((e, hd, i)),
+            "e_up": 0.1 * rng.standard_normal((e, hd, i)),
+            "e_down": 0.1 * rng.standard_normal((e, i, hd))}
+    full = {k: jnp.asarray(v, jnp.float32) for k, v in full.items()}
+    uncut = dataclasses.replace(ref.shapes(ps_cfg), experts_held=e,
+                                expert_offset=0)
+    want, _ = ref._experts(h, full, uncut, ref.Reference(uncut).switches)
+
+    total, here = 0.0, 0
+    for offset in range(0, e, held):
+        share = dataclasses.replace(c, experts_held=held,
+                                    expert_offset=offset)
+        p = dict(full, **{k: full[k][offset:offset + held]
+                          for k in ("e_gate", "e_up", "e_down")})
+        part, load = mellum.expert_layer(h[None], p, share)
+        total = total + part[0]
+        here += int(load[0])
+    assert here == 40 * c.num_experts_per_tok     # every choice, once
+    close(total, want)
+
+
+@pytest.mark.parametrize("over", [False, True], ids=["under", "over"])
+def test_either_branch_of_the_bound_places_every_assignment(task, ref,
+                                                            ps_cfg, over):
+    """A quarter held: the bound is twice the even share, half the
+    slots.  A router that sends every token to the held experts goes
+    over it and takes all T·K slots; either way the layer is the dense
+    sum, value and gradient."""
+    c = task.arch
+    rng = np.random.default_rng(13)
+    t, hd, i, e = 32, c.hidden_size, c.moe_intermediate_size, c.num_experts
+    h = jnp.asarray(rng.standard_normal((t, hd)), jnp.float32)
+    router = 0.5 * rng.standard_normal((hd, e))
+    if over:
+        router[:, :c.experts_held] += 4.0 * np.sign(
+            np.asarray(h).mean(0))[:, None]
+    p = {"router": router, "e_gate": 0.1 * rng.standard_normal(
+        (c.experts_held, hd, i)), "e_up": 0.1 * rng.standard_normal(
+            (c.experts_held, hd, i)), "e_down": 0.1 * rng.standard_normal(
+                (c.experts_held, i, hd))}
+    p = {k: jnp.asarray(v, jnp.float32) for k, v in p.items()}
+    s = ref.shapes(ps_cfg)
+    switches = ref.Reference(s).switches
+    got, load = mellum.expert_layer(h[None], p, c)
+    want, _ = ref._experts(h, p, s, switches)
+    close(got[0], want)
+    bound = lm.live_rows_bound(t * c.num_experts_per_tok, c)
+    assert bound == t * c.num_experts_per_tok // 2
+    assert bool(load[2]) == over and (int(load[0]) > bound) == over
+    g = jax.grad(lambda h: mellum.expert_layer(h[None], p, c)[0].sum())(h)
+    w = jax.grad(lambda h: ref._experts(h, p, s, switches)[0].sum())(h)
+    close(g, w)
+
+
+def test_the_cells_grouped_products_are_told_their_tiles():
+    """`[rows, 2304] x [16, 2304, 896]` and its transposes: 2304 = 4.5 x
+    512 and 896 = 7 x 128, widths 512 does not divide, so
+    `grouped_tiles` — the rule written for the second family's 2688 /
+    1856 — speaks for a second family: 3 x 768 and 1 x 896, 11.67 MB a
+    grid step under the 12 MiB budget, under the bound's 16,384 rows
+    and over it at 32,768."""
+    c = mellum.load_config(PUBLISHED)
+    slots = c.sequence_length * c.num_experts_per_tok
+    assert slots == 32_768 and lm.live_rows_bound(slots, c) == 16_384
+    for rows in (16_384, slots):
+        assert lm.grouped_tiles(rows, 2304, 896) == "256,768,896"
+        assert lm.grouped_tiles(rows, 896, 2304) == "256,896,768"
+    assert lm.grouped_step_bytes(256, 768, 896) == 11_665_408 \
+        <= lm.GROUPED_VMEM_BUDGET
+
+
+# -- the controls ----------------------------------------------------------------
+
+def test_a_program_that_is_not_finite_has_no_gap_of_zero(ref, ps_cfg, theta):
+    s = ref.shapes(ps_cfg)
+    moved = theta + np.float32(0.01)
+    assert ref.param_gap(moved, moved, theta, s) == 0.0
+    broken = moved.copy()
+    broken[-5] = np.nan
+    assert np.isnan(ref.param_gap(broken, moved, theta, s))
+
+
+def test_the_reference_has_the_controls_the_cell_names(ref):
+    assert list(ref.CONTROLS) == CONTROL_NAMES
+    # and it stands apart from the program
+    text = open(os.path.join(FAMILY, "reference.py")).read()
+    assert "kafka_ps_tpu" not in text.split('"""', 2)[2]
+
+
+@pytest.fixture(scope="module")
+def one_clock(ref, ps_cfg, task, theta):
+    """One worker, one row, one clock of the sound reference: what each
+    control is set against."""
+    s2 = dataclasses.replace(ref.shapes(ps_cfg), num_workers=1)
+    slabs = [(rows_of(task, 1, seed=30), None, np.ones(1, np.float32))]
+    return s2, slabs, ref.Reference(s2).run(theta, slabs, 1)
+
+
+@pytest.mark.parametrize("name", CONTROL_NAMES)
+def test_each_control_of_the_reference_moves_the_result(ref, theta,
+                                                        one_clock, name):
+    """What the benchmark's controls stand for is seen by the numbers
+    the cell compares, already at the tiny size."""
+    s2, slabs, (want_t, want_l) = one_clock
+    got_t, got_l = ref.Reference(s2, **ref.CONTROLS[name]).run(
+        theta, slabs, 1)
+    gap = ref.param_gap(got_t[-1], want_t[-1], theta, s2)
+    loss = max(abs(g - w) / w for g, w in zip(got_l, want_l))
+    assert gap > 1e-3 or loss > 1e-3, (name, gap, loss)
