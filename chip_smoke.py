@@ -19,6 +19,11 @@ the models the repo supports, on data made from a seed:
     against its plain tiles at the third language-model cell's shapes,
     a sliding and a full layer, forward and `jax.grad`: the gaps and
     the ms a call of each;
+  * the expert layer's placement as its two kernels
+    (`models/placement_kernel.py`) against the products with the 0/1
+    matrix written out, at the fifth language-model cell's shape: to
+    the bit where a row has one term, NaN in the dead rows, the ms a
+    call of each;
   * with more than one chip: `--fused -r` and `--fused --param_shards`
     over all of them.
 
@@ -40,6 +45,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -73,6 +79,11 @@ class Sizes:
     core_window: int = 2048
     core_block: int = 512
     core_calls: int = 5           # timed calls a program, after a warm one
+    # the expert layer's placement at the fifth language-model cell's
+    # shape: (rows under the bound, tokens, hidden), and the rows of the
+    # held groups as its traced run routed them (one hot expert, PERF.md)
+    placement_shape: tuple = (16384, 4096, 2304)
+    placement_groups: tuple = (3247,) + (253,) * 15
 
 
 class SmokeFailure(RuntimeError):
@@ -347,6 +358,18 @@ def phase_grouped_products(sizes: Sizes, platform: str) -> dict:
     return rec
 
 
+def _timed(calls: int, fn, *args):
+    """(`fn(*args)`, the ms a call over `calls` calls after a warm
+    one)."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return out, (time.perf_counter() - t) / calls * 1e3
+
+
 def phase_attention_core(sizes: Sizes, platform: str, *,
                          interpret: bool = False) -> dict:
     """The attention core as the kernel (`attention_kernel.attend`)
@@ -379,14 +402,7 @@ def phase_attention_core(sizes: Sizes, platform: str, *,
     require(_platform_of(q) == platform, name,
             f"the queries live on {_platform_of(q)}")
 
-    def timed(fn, *args):
-        jax.block_until_ready(fn(*args))
-        t = time.perf_counter()
-        for _ in range(sizes.core_calls):
-            out = fn(*args)
-        jax.block_until_ready(out)
-        return out, (time.perf_counter() - t) / sizes.core_calls * 1e3
-
+    timed = functools.partial(_timed, sizes.core_calls)
     rec = {"shape": list(shape), "block": block}
     for kind, window in (("window", sizes.core_window), ("full", None)):
         cores = {
@@ -410,6 +426,101 @@ def phase_attention_core(sizes: Sizes, platform: str, *,
                     f"{kind} {what}: the kernel is off the tiles' by "
                     f"{gap} of their largest")
             rec[f"{kind}_{what}_gap"] = gap
+    rec["wall_s"] = round(time.time() - started, 2)
+    return rec
+
+
+def phase_placement_products(sizes: Sizes, platform: str, *,
+                             interpret: bool = False) -> dict:
+    """The expert layer's two products with its 0/1 placement matrix as
+    the kernels (`placement_kernel.multiply`) against `jnp.dot` with
+    the matrix written out, as `lm_common.routed_experts` runs each
+    where the kernels do not, over sorted rows of which under half are
+    live, the dead ones NaN for the kernels.  In ONE pass (the default
+    precision): placing `P · x`, one term a row, TO THE BIT, and its
+    transpose `Pᵀ · x`, a token's sum in another order, to float32
+    rounding.  In TWO (`HIGH` beside a 0/1 operand): the add-back `Pᵀ ·
+    x` and its transpose — the compiler cuts the float32 operand's two
+    bfloat16 pieces another way than the kernels' round-to-nearest (on
+    the chip the two stand 6e-5 apart on values up to 5, PERF.md PR
+    42), so each is set against the sum in float64 and the kernel has
+    to stand no further from it than the product does.  And the ms a
+    call of each.  `interpret` runs the kernels in Pallas's interpreter
+    (the CPU test), where `jnp.dot` rounds nothing and is given the
+    operand as the chip's precision leaves it."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.models import placement_kernel
+
+    name, started = "placement_products", time.time()
+    rows, tokens, hidden = sizes.placement_shape
+    require(placement_kernel.takes(rows, tokens, hidden), name,
+            f"the kernels do not take {sizes.placement_shape}")
+    rng = np.random.default_rng(0)
+    n_here = sum(sizes.placement_groups)
+    tok = jnp.asarray(np.concatenate(
+        [np.sort(rng.choice(tokens, size=n, replace=False))
+         for n in sizes.placement_groups]
+        + [rng.integers(0, tokens, size=rows - n_here)]), jnp.int32)
+    live = (jnp.arange(rows) < n_here)[:, None]
+    matrix = jnp.where(live, jax.nn.one_hot(tok, tokens,
+                                            dtype=jnp.bfloat16), 0)
+    plan = jax.jit(lambda tok: placement_kernel.plan(tok, n_here, tokens))(
+        tok)
+    h = jnp.asarray(rng.standard_normal((tokens, hidden)), jnp.float32)
+    y = jnp.asarray(rng.standard_normal((rows, hidden)), jnp.float32)
+    require(_platform_of(h) == platform, name,
+            f"the tokens live on {_platform_of(h)}")
+
+    def as_the_chip_rounds(x, passes):
+        if not interpret:
+            return x
+        return sum(piece.astype(jnp.float32)
+                   for piece in placement_kernel._pieces_of(x)[:passes])
+
+    timed = functools.partial(_timed, sizes.core_calls)
+
+    rec = {"shape": list(sizes.placement_shape), "live_rows": n_here,
+           "visited_share": float(plan.visit.sum()) / plan.visit.size}
+    for what, back, passes in (("place", False, 1), ("place_t", True, 1),
+                               ("add_back", True, 2),
+                               ("add_back_t", False, 2)):
+        precision = jax.lax.Precision.HIGH if passes == 2 else None
+        x = y if back else h
+        got, rec[f"{what}_kernel_ms"] = timed(jax.jit(
+            lambda x: placement_kernel.multiply(x, plan, back, passes, None,
+                                                interpret)),
+            jnp.where(live, x, jnp.nan) if back else x)
+        want, rec[f"{what}_product_ms"] = timed(jax.jit(
+            lambda x: jnp.dot(matrix.T if back else matrix, x,
+                              precision=precision,
+                              preferred_element_type=jnp.float32)),
+            as_the_chip_rounds(jnp.where(live, x, 0.0) if back else x,
+                               passes))
+        got, want = np.asarray(got), np.asarray(want)
+        gap, largest = float(np.abs(got - want).max()), float(
+            np.abs(want).max())
+        require(np.isfinite(got).all(), name, f"{what}: not finite")
+        rec[f"{what}_gap"] = gap
+        if passes == 1:
+            require(gap <= (1e-6 * largest if back else 0.0), name,
+                    f"{what}: the kernel is off the product by {gap} of "
+                    f"{largest}")
+            continue
+        at = np.asarray(tok)[:n_here]
+        exact = np.zeros(got.shape)
+        if back:
+            np.add.at(exact, at, np.asarray(x, np.float64)[:n_here])
+        else:
+            exact[:n_here] = np.asarray(x, np.float64)[at]
+        off, product_off = (float(np.abs(a - exact).max())
+                            for a in (got, want))
+        require(off <= product_off + 1e-7 * largest, name,
+                f"{what}: the kernel stands {off} from the float64 sum, "
+                f"the product {product_off}")
+        rec[f"{what}_off_float64"] = [off, product_off]
     rec["wall_s"] = round(time.time() - started, 2)
     return rec
 
@@ -487,6 +598,7 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
             phase_fused(workdir, train, test, sizes, platform, eval_every)
     phases["grouped_products"] = phase_grouped_products(sizes, platform)
     phases["attention_core"] = phase_attention_core(sizes, platform)
+    phases["placement_products"] = phase_placement_products(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,
                                       platform, device_count))

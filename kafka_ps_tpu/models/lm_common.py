@@ -49,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.xla_metadata import set_xla_metadata
 
-from kafka_ps_tpu.models import attention_kernel
+from kafka_ps_tpu.models import attention_kernel, placement_kernel
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.models import task as task_mod
 from kafka_ps_tpu.utils.config import ModelConfig
@@ -406,7 +406,8 @@ live_rows_only.defvjp(_live_rows_only_fwd, _live_rows_only_bwd)
 def routed_experts(h, idx, w, p: dict, c, expert):
     """The part of Σ w_e · expert_e(h) that the experts held here give
     → ([T, H], (assignments here, largest expert's load, 1 if the pass
-    went over `live_rows_bound`)).  `expert(xs, p, dot)` is the family's
+    went over `live_rows_bound`; with the placement kernels a fourth:
+    the pairs they multiplied)).  `expert(xs, p, dot)` is the family's
     own: what one expert computes on its rows, every product with the
     held experts' stacked matrices through `dot(rows, matrices)`
     (`dot.sizes`: the rows each held expert's group has).
@@ -429,20 +430,42 @@ def routed_experts(h, idx, w, p: dict, c, expert):
     scatter costs over a microsecond a row, a fifth of the update when
     it was written so.  Placing is exact at the default precision (one
     term a row, and the grouped product rounds its operand the same
-    way); adding back runs at `HIGH`, which carries a float32 in three
-    pieces.
+    way); adding back runs at `HIGH`, which beside a 0/1 operand is two
+    bfloat16 passes (the float32 side's high and low piece).
+
+    One algorithm, two ways to run the two products, and the shape says
+    which (`placement_kernel.takes(rows, tokens, hidden)` of the rows
+    under the bound and of all T·K: one way for both branches).  Where
+    it takes them, on a TPU, they are the kernels of
+    `models/placement_kernel.py`: the matrix is never written to
+    memory, a piece of it is built in VMEM from the rows' token ids
+    and multiplied on the MXU, and a piece
+    that holds no one — the tiles of dead rows whole, and most blocks
+    of tokens of a tile inside one group — is neither fetched nor
+    multiplied; the same passes, the same float32 sums, and under
+    `jax.grad` each kernel is the other's transpose.  Anywhere else —
+    another platform, a smaller matrix — it is `jnp.dot` with the
+    matrix written out, and the program is what it was before there
+    were kernels.  With the kernels the counts gain a fourth entry: the
+    elements of the matrix that were multiplied, in units of
+    `placement_kernel.PAIRS_UNIT` (elsewhere than a TPU: all of them).
 
     Named scopes inside `kps.moe.experts`, for the trace's readers
     (benchmark/self_time.py): `kps.moe.sort` (key, argsort, group
     sizes, the gathered weights), `kps.moe.place` (the live mask, the
-    0/1 matrix, the placing product), `kps.moe.expert_fn` (the family's
-    `expert` and the dead rows' mask; the chip's grouped-product calls
-    inside it keep the `ragged-dot` name the compiler gives them),
-    `kps.moe.combine` (the weighting and the add-back product) — the
-    same in both branches of the bound's `cond`.  What a trace reads
-    under `kps.moe.experts` ALONE is the add-back product of the branch
-    that ran: a branch's ROOT takes the `cond`'s name, not its own
-    scope's.
+    0/1 matrix or the kernels' plan, the placing product or kernel),
+    `kps.moe.expert_fn` (the family's `expert` and the dead rows' mask;
+    the chip's grouped-product calls inside it keep the `ragged-dot`
+    name the compiler gives them), `kps.moe.combine` (the weighting and
+    the add-back product or kernel) — the same in both branches of the
+    bound's `cond`; a product's transpose under `jax.grad` keeps its
+    scope, so `d_h` reads under `kps.moe.place` and the add-back's
+    transpose under `kps.moe.combine`.  What a trace reads under
+    `kps.moe.experts` ALONE: with the product, the add-back of the
+    branch that ran (a branch's ROOT fusion takes the `cond`'s name,
+    not its own scope's); with the kernels the add-back is a Mosaic
+    call, which keeps `kps.moe.combine`, and what is left there is the
+    `cond` itself and what the compiler moves across its edge.
 
     Under `jax.grad` the two branches differ in what they keep.  The
     branch under the bound — the one a pass takes unless more
@@ -480,30 +503,81 @@ def routed_experts(h, idx, w, p: dict, c, expert):
         grouped.sizes = sizes       # for a family's `live_rows_only`
 
         def placed(rows: int):
-            """The sum from the first `rows` sorted assignments."""
+            """The sum from the first `rows` sorted assignments (where
+            the kernels run: and the pairs of the 0/1 matrix that were
+            multiplied, in units of PAIRS_UNIT)."""
             with jax.named_scope("kps.moe.place"):
                 live = (jnp.arange(rows) < n_here)[:, None]
-                place = jnp.where(live, jax.nn.one_hot(
-                    order[:rows] // k, t, dtype=jnp.bfloat16), 0)
-                xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
+                if kernels:
+                    plan = placement_kernel.plan(order[:rows] // k, n_here, t)
+                    xs = _placing(h, plan)
+                else:
+                    place = jnp.where(live, jax.nn.one_hot(
+                        order[:rows] // k, t, dtype=jnp.bfloat16), 0)
+                    xs = jnp.dot(place, h, preferred_element_type=jnp.float32)
             with jax.named_scope("kps.moe.expert_fn"):
                 # rows past the last group are never computed: whatever
                 # the kernel leaves there must reach nothing
                 y = jnp.where(live, expert(xs, p, grouped), 0.0)
             with jax.named_scope("kps.moe.combine"):
+                if kernels:
+                    return (_adding_back(y * weight[:rows, None], plan),
+                            _pairs_multiplied(plan))
                 return jnp.dot(place.T, y * weight[:rows, None],
                                precision=jax.lax.Precision.HIGH,
                                preferred_element_type=jnp.float32)
 
         bound = live_rows_bound(t * k, c)
+        # one way for both branches: the kernels where they take each
+        kernels = all(placement_kernel.takes(rows, t, h.shape[1])
+                      for rows in (bound, t * k))
         went_over = n_here > bound
         out = (placed(t * k) if bound == t * k else
                jax.lax.cond(
                    went_over,
                    jax.checkpoint(functools.partial(placed, t * k)),
                    functools.partial(placed, bound)))
-        return out, jnp.stack([n_here, sizes.max(),
-                               went_over.astype(jnp.int32)])
+        counts = [n_here, sizes.max(), went_over.astype(jnp.int32)]
+        if kernels:
+            out, pairs = out
+            counts.append(pairs)
+        return out, jnp.stack(counts)
+
+
+def _placement_matrix(plan):
+    """`routed_experts`' 0/1 matrix `[rows, tokens]` written out: a
+    dead row's token is -1, which is no column."""
+    return jax.nn.one_hot(plan.tok, plan.tokens, dtype=jnp.bfloat16)
+
+
+def _placing(h, plan):
+    """`P · h`: on a TPU the kernel, elsewhere the product."""
+    return jax.lax.platform_dependent(
+        h, plan,
+        tpu=lambda h, plan: placement_kernel.multiply(h, plan, False, 1),
+        default=lambda h, plan: jnp.dot(
+            _placement_matrix(plan), h, preferred_element_type=jnp.float32))
+
+
+def _adding_back(yw, plan):
+    """`Pᵀ · yw` at `HIGH`: on a TPU the kernel in its two passes,
+    elsewhere the product."""
+    return jax.lax.platform_dependent(
+        yw, plan,
+        tpu=lambda yw, plan: placement_kernel.multiply(yw, plan, True, 2),
+        default=lambda yw, plan: jnp.dot(
+            _placement_matrix(plan).T, yw, precision=jax.lax.Precision.HIGH,
+            preferred_element_type=jnp.float32))
+
+
+def _pairs_multiplied(plan):
+    """The elements of the 0/1 matrix that `_placing` and `_adding_back`
+    multiply, in units of PAIRS_UNIT: the pieces the kernels visit, the
+    whole matrix where the product runs."""
+    dense = plan.tok.shape[0] * plan.tokens // placement_kernel.PAIRS_UNIT
+    return jax.lax.platform_dependent(
+        plan, tpu=lambda plan: plan.pairs,
+        default=lambda plan: jnp.int32(dense))
 
 
 def swiglu(h, w_gate, w_up, w_down):
@@ -544,14 +618,17 @@ def head_nll(x, norm, head, targets, eps: float):
 
 def fit_counted(leaves: dict, rows, mask, *, loss_and_counts, lr: float,
                 steps: int, sequence_length: int, slots_a_token: int,
-                own_counts=()):
+                own_counts=(), beyond: int = 0):
     """`steps` full-batch SGD steps on a slab → (new leaves, the
     objective at them, the counters of the passes made: COUNTERS, then
-    the family's `own_counts` of one pass, times the passes).
+    the family's `own_counts` of one pass, times the passes, then the
+    first `beyond` of what its passes counted on the device beyond the
+    three, summed — as many as the family names).
     `loss_and_counts(leaves, rows, mask)` → (objective, (assignments
-    here, Σ largest load, expert layers over the bound)) of one pass;
-    `slots_a_token`: (token, chosen expert) assignments a token makes
-    in a pass, over every expert layer."""
+    here, Σ largest load, expert layers over the bound, and whatever
+    more the family's layers count: `routed_experts`' fourth)) of one
+    pass; `slots_a_token`: (token, chosen expert) assignments a token
+    makes in a pass, over every expert layer."""
     grad = jax.value_and_grad(loss_and_counts, has_aux=True)
     # the steps are written out, not scanned: a scan's carry starts as
     # a copy of the shared leaves and is kept beside each step's result,
@@ -576,7 +653,10 @@ def fit_counted(leaves: dict, rows, mask, *, loss_and_counts, lr: float,
         rows_in * sequence_length,
         (rows.shape[0] - rows_in) * sequence_length,
         counts[:, 2].sum() + last[2],
-        *((steps + 1) * n for n in own_counts)]).astype(jnp.int32)
+        *((steps + 1) * n for n in own_counts),
+        *(counts[:, i].sum() + last[i]
+          for i in range(3, min(last.shape[0], 3 + beyond)))]).astype(
+              jnp.int32)
     return new, loss, stats
 
 
@@ -675,13 +755,16 @@ class TokenRowsTask(task_mod.FlatFace):
         return y
 
     def fit_counted(self, leaves, x, enc, mask):
+        own = self.own_counts(x)
         return fit_counted(leaves, x, mask,
                            loss_and_counts=self.loss_and_counts,
                            lr=self.cfg.local_learning_rate,
                            steps=self.cfg.num_max_iter,
                            sequence_length=self.arch.sequence_length,
                            slots_a_token=self.slots_a_token,
-                           own_counts=self.own_counts(x))
+                           own_counts=own,
+                           beyond=len(self.counter_names) - len(COUNTERS)
+                           - len(own))
 
     def fit(self, leaves, x, enc, mask):
         new, loss, _ = self.fit_counted(leaves, x, enc, mask)

@@ -328,8 +328,7 @@ def _experts(xs, p: dict, dot):
 
 def expert_layer(u, p: dict, c: MellumConfig):
     """The expert layer on `[B, S, H]` (already normed) -> (the held
-    experts' part of its output, (assignments here, largest load, went
-    over the bound))."""
+    experts' part of its output, `lm_common.routed_experts`' counts)."""
     b, s, hd = u.shape
     h = u.reshape(b * s, hd)
     idx, w = route(h, p["router"], c)
@@ -348,10 +347,10 @@ def layer(x, p: dict, c: MellumConfig, kind: str):
 
 def forward(leaves: dict, rows, c: MellumConfig, *, with_logits=False):
     """`rows` `[B, S + 2]` int32 -> per-position losses and the routing
-    counts: {"nll" [B, S] next-token, "loads" [layers, 3], "logits" if
-    asked}.  Every layer is recomputed in the backward pass.  (A row's
-    last token is carried for another family's second head; nothing
-    here reads it.)"""
+    counts: {"nll" [B, S] next-token, "loads" [layers, 3 or 4: what
+    `lm_common.routed_experts` counts], "logits" if asked}.  Every
+    layer is recomputed in the backward pass.  (A row's last token is
+    carried for another family's second head; nothing here reads it.)"""
     s = c.sequence_length
     tokens, t1 = rows[:, :s], rows[:, 1:s + 1]
     with jax.named_scope("kps.lm.embed"):
@@ -375,7 +374,8 @@ def forward(leaves: dict, rows, c: MellumConfig, *, with_logits=False):
 def loss_and_counts(leaves: dict, rows, mask, c: MellumConfig):
     """The training objective over the unmasked rows of a slab — mean
     next-token cross-entropy — and (assignments here, Σ largest load,
-    expert layers that went over `live_rows_bound`) of the pass."""
+    expert layers that went over `live_rows_bound`; where the placement
+    kernels run, the pairs they multiplied) of the pass."""
     out = forward(leaves, rows, c)
     positions = jnp.maximum(mask.sum(), 1.0) * c.sequence_length
     return ((out["nll"].sum(-1) * mask).sum() / positions,
@@ -412,6 +412,7 @@ class MellumTask(lm.TokenRowsTask):
     counter_names = lm.COUNTERS + ("attn.pairs_window", "attn.pairs_full",
                                    "attn.block_pairs",
                                    "attn.kernel_block_pairs",
+                                   "moe.place_pairs_dense",
                                    "moe.place_pairs")
 
     def leaf_specs(self):
@@ -433,11 +434,11 @@ class MellumTask(lm.TokenRowsTask):
     def own_counts(self, rows) -> tuple:
         """`attn.pairs_window`, `attn.pairs_full`, `attn.block_pairs`
         and `attn.kernel_block_pairs` of one pass as the `afmoe` family
-        counts them, and `moe.place_pairs`: the (placed row, token)
-        pairs of every expert layer of one pass with each layer under
-        its bound (`fit_counted` adds what the passes over it placed
-        more) — all in units of PAIRS_UNIT pairs, rounded down once a
-        pass."""
+        counts them, and `moe.place_pairs_dense`: the (placed row,
+        token) pairs of every expert layer of one pass with each layer
+        under its bound (`fit_counted` adds what the passes over it
+        placed more) — all in units of PAIRS_UNIT pairs, rounded down
+        once a pass."""
         c = self.arch
         window, full, blocks = (rows.shape[0] * n // PAIRS_UNIT
                                 for n in pair_counts(c))
@@ -449,13 +450,22 @@ class MellumTask(lm.TokenRowsTask):
                 c.num_hidden_layers * under // PAIRS_UNIT)
 
     def fit_counted(self, leaves, x, enc, mask):
-        """The frame's, with `moe.place_pairs` (the last counter) made
-        whole: an expert layer's pass that went over `live_rows_bound`
-        placed all T·K slots, and `moe.passes_over_bound` says how many
-        did."""
+        """The frame's, with the two counters of `routed_experts`' 0/1
+        matrix made whole.  `moe.place_pairs_dense`, the whole matrix's
+        elements (the last of `own_counts`): an expert layer's pass
+        that went over `live_rows_bound` placed all T·K slots, and
+        `moe.passes_over_bound` says how many did.  `moe.place_pairs`,
+        the elements the program MULTIPLIED: where the placement
+        kernels run (`placement_kernel.takes`) the layers counted them
+        on the device, a pass's fourth count; where the product runs it
+        multiplied the whole matrix."""
         new, loss, stats = super().fit_counted(leaves, x, enc, mask)
         under, over = place_pairs(x.shape[0] * self.arch.sequence_length,
                                   self.arch)
         went_over = stats[lm.COUNTERS.index("moe.passes_over_bound")]
-        return new, loss, stats.at[-1].add(
+        dense = self.counter_names.index("moe.place_pairs_dense")
+        stats = stats.at[dense].add(
             went_over * ((over - under) // PAIRS_UNIT))
+        if stats.shape[0] == len(self.counter_names):
+            return new, loss, stats
+        return new, loss, jnp.concatenate([stats, stats[dense:dense + 1]])

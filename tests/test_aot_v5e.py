@@ -16,6 +16,7 @@ import sys
 import pytest
 
 from kafka_ps_tpu.models import lm_common as lm
+from kafka_ps_tpu.models import placement_kernel
 
 HIDDEN, WORKERS = 512, 8            # reduced: about 3 s a program
 W1_BYTES = HIDDEN * 1024 * 4
@@ -413,13 +414,13 @@ def test_the_fifth_language_models_chunk_walks_its_widths_in_told_tiles(
     11.36 GB, 19.1 bytes a parameter; the limit is that reading and a
     tenth).  A quarter of the experts held makes the expert layer's
     rows four times the third family's: the bound places 16,384 rows
-    (a `bf16[16384,4096]` 0/1 matrix, 134 MB, a layer a pass) and a
-    pass over it all 32,768.  At 8,192-token rows the same chunk
-    compiles to 12.79 GB of scratch + the 2.38 GB of leaves, 15.17 GB
-    (compiled once by hand with scripts/aot_v5e_hlo.py, PR 41, not
-    here: the 0/1 matrix is then `bf16[32768,8192]`, 537 MB, and its
-    products 4 x the operations) — it would not fit the chip beside the
-    flat vector's copy, so such rows wait on the placement.
+    and a pass over it all 32,768 (until PR 42 through a
+    `bf16[16384,4096]` 0/1 matrix, 134 MB, a layer a pass: the test
+    below).  At 8,192-token rows the same chunk compiled to 12.79 GB
+    of scratch + the 2.38 GB of leaves, 15.17 GB (compiled once by hand
+    with scripts/aot_v5e_hlo.py, PR 41, not here: the 0/1 matrix was
+    then `bf16[32768,8192]`, 537 MB, and its products 4 x the
+    operations).
 
     Every grouped product — the three of a SwiGLU expert, their dx and
     dW, under the bound's 16,384 rows and over it at 32,768 — runs the
@@ -457,8 +458,6 @@ def test_the_fifth_language_models_chunk_walks_its_widths_in_told_tiles(
         sorted(set(calls))
     assert {tiles for _, tiles in calls} == {"256,768,896", "256,896,768"}
     assert not any(tiles.endswith(",128,128") for _, tiles in calls)
-    # the placement's 0/1 matrix, under the bound and over it
-    assert f"bf16[{bound},{s}]" in text and f"bf16[{slots},{s}]" in text
     # the core's calls, by kernel and scope
     kernel_calls = re.findall(
         r"%(kps_attn_core_\w+?)[.\d]* = .* custom-call\(.*"
@@ -485,6 +484,85 @@ def test_the_fifth_language_models_chunk_walks_its_widths_in_told_tiles(
         assert scope in text, scope
     for absent in ("kps.moe.shared", "kps.mlp", "kps.lm.layers"):
         assert absent not in text, absent
+
+
+@pytest.mark.parametrize("family,scratch,placing,adding_back", [
+    ("mellum", 8_976_765_440, 40, 24), ("afmoe", 7.5e9, 48, 40)])
+def test_a_large_placement_is_the_kernels_and_no_matrix(
+        folded_chunk, family, scratch, placing, adding_back):
+    """The fifth and the third family's chunks (the fixture's compiles,
+    no second ones): at 16,384 and at 4,096 rows under the bound x
+    4,096 tokens `placement_kernel.takes` the expert layer's two
+    products with the 0/1 matrix, so lowered for the chip they are
+    Mosaic calls and NO 0/1 array of rows x tokens elements is in the
+    program any more — the parent held `bf16[16384,4096]` (134 MB a
+    layer a pass, four kept for the backward passes) and
+    `bf16[32768,4096]` in the branch over the bound, the third family
+    `bf16[4096,4096]`.  The calls, by kernel and scope, over both
+    branches of the bound: in the fifth family's each branch what the
+    parent's chunk held as products (counted from its text, PR 42), 20
+    placing (4 expert layers x (2 gradient passes x (forward +
+    recomputed) + the loss)) and 12 add-backs (the recomputed
+    forward's is dead code); in the third's, whose layer norms the
+    experts' sum and so needs it again backward, the recomputed
+    add-backs stay, 48 and 40 in all; and under `jax.grad` 8 a branch
+    of each as the other's transpose, which keep the forward product's
+    scope — so `kps.moe.place` and
+    `kps.moe.combine` go on holding what `moe_placement_self_share`
+    and `moe_placement_roofline_share` read.  The scratch fell by the
+    kept matrices in the fifth family, 8,976,765,440 -> 8,767,503,360
+    bytes when written (the limit: the parent's reading); the third's
+    stands at 6.75 GB for 6.72 (its kept `bf16[4096,4096]` were 34 MB
+    each, and the weighted rows the product fused are now written: the
+    limit is ISSUE 40's 7.5 GB)."""
+    task, compiled = folded_chunk(family)
+    c = task.arch
+    s = c.sequence_length
+    slots = s * c.num_experts_per_tok
+    bound = lm.live_rows_bound(slots, c)
+    assert placement_kernel.takes(bound, s, c.hidden_size)
+    assert placement_kernel.takes(slots, s, c.hidden_size)
+    text = compiled.as_text()
+    # the matrix was bfloat16 and its mask and one-hot compare `pred`,
+    # made under the expert layer's scopes (the third family's
+    # attention gate is `[4096 tokens, 32 x 128]` too)
+    made = re.compile(r"= (?:bf16|pred)\[(?:%s)\]" % "|".join(
+        f"{a},{b}" for rows in (bound, slots)
+        for a, b in ((rows, s), (s, rows))))
+    assert not [line for line in text.splitlines()
+                if "kps.moe" in line and made.search(line)]
+    calls = re.findall(
+        r"%(kps_moe_\w+?)[.\d]* = .* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*op_name=\"([^\"]*)\"", text)
+    scopes = ("kps.moe.place", "kps.moe.combine")
+    assert all(sum(scope in op_name for scope in scopes) == 1
+               for _, op_name in calls), calls
+    assert {(kernel, scope): sum(k == kernel and scope in op_name
+                                 for k, op_name in calls)
+            for kernel in ("kps_moe_place", "kps_moe_add_back")
+            for scope in scopes} == {
+        ("kps_moe_place", "kps.moe.place"): placing,
+        ("kps_moe_add_back", "kps.moe.combine"): adding_back,
+        ("kps_moe_add_back", "kps.moe.place"): 2 * 8,
+        ("kps_moe_place", "kps.moe.combine"): 2 * 8}
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < scratch, memory.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("family", ["glm4_moe_lite", "nemotron_h"])
+def test_a_smaller_placement_is_left_to_the_product(folded_chunk, family):
+    """The chunks of the cells whose 0/1 matrix `placement_kernel.takes`
+    not (1,024 x 1,024 and 768 x 1,024 under the bound): no kernel of
+    the placement is in them, the matrix is, and the program is the
+    parent's (the traced programs' digests:
+    tests/fixtures/*_stablehlo.json)."""
+    task, compiled = folded_chunk(family)
+    c = task.arch
+    s = c.sequence_length
+    bound = lm.live_rows_bound(s * c.num_experts_per_tok, c)
+    assert not placement_kernel.takes(bound, s, c.hidden_size)
+    text = compiled.as_text()
+    assert "kps_moe_" not in text and f"bf16[{bound},{s}]" in text
 
 
 @pytest.mark.parametrize("family", sorted(CHUNKS))

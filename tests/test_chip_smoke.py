@@ -23,7 +23,8 @@ TINY = chip_smoke.Sizes(
     center_scale=1.0,           # too few rows to learn the hard regime
     grouped_rows=128, grouped_widths=(640, 384),    # the rule still hints
     core_shape=(1, 384, 1, 2, 128), core_window=200, core_block=128,
-    core_calls=1)
+    core_calls=1,
+    placement_shape=(512, 1024, 128), placement_groups=(90, 0, 37, 60))
 
 
 def _run(script_dir, env_extra, *args):
@@ -95,6 +96,24 @@ def test_attention_core_phase():
         chip_smoke.phase_attention_core(
             chip_smoke.Sizes(core_shape=(1, 64, 1, 2, 8), core_block=8),
             "cpu", interpret=True)
+
+
+def test_placement_products_phase(monkeypatch):
+    """The two kernels in Pallas's interpreter against the products
+    with the matrix written out, at a shape of whole tiles and blocks
+    the rule is let take (the test's steering: it asks for a larger
+    matrix): placing to the bit, the sums to float32 rounding."""
+    from kafka_ps_tpu.models import placement_kernel
+    with pytest.raises(chip_smoke.SmokeFailure, match="do not take"):
+        chip_smoke.phase_placement_products(TINY, "cpu", interpret=True)
+    monkeypatch.setattr(placement_kernel, "MIN_MATRIX", 0)
+    rec = chip_smoke.phase_placement_products(TINY, "cpu", interpret=True)
+    assert rec["live_rows"] == 187 and 0 < rec["visited_share"] < 1
+    assert rec["place_gap"] == rec["add_back_t_gap"] == 0.0
+    assert rec["add_back_gap"] <= 1e-6 and rec["place_t_gap"] <= 1e-6
+    for what in ("add_back", "add_back_t"):
+        off, product_off = rec[f"{what}_off_float64"]
+        assert 0 < off <= product_off + 1e-6 and off < 1e-3
 
 
 def test_multichip_phase_on_the_virtual_mesh(data):

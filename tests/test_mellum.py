@@ -120,7 +120,8 @@ def test_the_flat_layout_is_the_references(task, ref, ps_cfg):
                    "evaluate_leaves", "swiglu_experts"):
         assert shared not in vars(mellum), shared
     assert mellum.MellumTask.counter_names == \
-        afmoe.AfmoeTask.counter_names + ("moe.place_pairs",)
+        afmoe.AfmoeTask.counter_names + ("moe.place_pairs_dense",
+                                         "moe.place_pairs")
     assert mellum.PAIRS_UNIT == afmoe.PAIRS_UNIT
 
 
@@ -285,9 +286,11 @@ def test_fused_clocks_agree_with_the_reference(task, ref, ps_cfg, theta,
     # pass over it
     under, beyond = mellum.place_pairs(2 * c.sequence_length, c)
     assert (under, beyond) == (48 * 48, 96 * 48)
-    assert counted["moe.place_pairs"] == (
+    assert counted["moe.place_pairs_dense"] == (
         passes * (4 * under // mellum.PAIRS_UNIT)
         + over * ((beyond - under) // mellum.PAIRS_UNIT))
+    # at this size the product multiplies the whole matrix
+    assert counted["moe.place_pairs"] == counted["moe.place_pairs_dense"]
 
 
 def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):

@@ -232,6 +232,7 @@ def test_the_fused_loop_sums_the_familys_counters_over_a_call(task, ps_cfg):
     assert counters["moe.place_pairs"] == (
         32 * 3 * (4 * under // 1024) + over * ((beyond - under) // 1024))
     assert counters["moe.place_pairs"] > 0
+    assert counters["moe.place_pairs_dense"] == counters["moe.place_pairs"]
     # through the CPU runtime the core is its plain tiles
     assert counters["attn.kernel_block_pairs"] == 0
     assert tracer.counters()["moe.place_pairs"] == counters["moe.place_pairs"]
