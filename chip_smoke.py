@@ -19,6 +19,11 @@ the models the repo supports, on data made from a seed:
     against its plain tiles at the third language-model cell's shapes,
     a sliding and a full layer, forward and `jax.grad`: the gaps and
     the ms a call of each;
+  * a head's norm and RoPE as its kernel (`models/norm_rope_kernel.py`)
+    against the plain lines at the third and fifth language-model
+    cells' shapes — q's 32 heads with the core's scale, k's 4, and a
+    full layer's norm alone — forward and `jax.grad`: the gaps and the
+    ms a call of each;
   * the expert layer's placement as its two kernels
     (`models/placement_kernel.py`) against the products with the 0/1
     matrix written out, at the fifth language-model cell's shape: to
@@ -84,6 +89,11 @@ class Sizes:
     # held groups as its traced run routed them (one hot expert, PERF.md)
     placement_shape: tuple = (16384, 4096, 2304)
     placement_groups: tuple = (3247,) + (253,) * 15
+    # a head's norm and RoPE: a row's positions, (q's heads, k's), the
+    # channels a head — the third and fifth language-model cells'
+    norm_rope_positions: int = 4096
+    norm_rope_heads: tuple = (32, 4)
+    norm_rope_dim: int = 128
 
 
 class SmokeFailure(RuntimeError):
@@ -430,6 +440,79 @@ def phase_attention_core(sizes: Sizes, platform: str, *,
     return rec
 
 
+def phase_norm_rope(sizes: Sizes, platform: str, *,
+                    interpret: bool = False) -> dict:
+    """A head's norm and RoPE as the kernel
+    (`norm_rope_kernel.norm_rope`) against the plain lines
+    (`lm_common._norm_rope_plain`) on one row at the third and fifth
+    language-model cells' shapes: q's heads with tables and the core's
+    scale, k's heads with tables, and q's heads with the norm alone
+    (the third cell's full layer) — the result, dx and the norm
+    weight's gradient of both, each gap as a share of the plain lines'
+    largest value (float32 on both sides: the sums' order differs), and
+    the ms a call of each, forward and `jax.grad`.  The rows come as
+    the projection writes them, `[1, S, heads x d]`, and the result
+    leaves as the attention core reads it — q `[1, S, heads, d]`, k
+    `[1, S, heads x d]` — so that neither side is charged a relayout
+    the program does not make; a call under half a millisecond is the
+    host's dispatch as much as the device's pass (PERF.md section 6, PR
+    43).  `interpret` runs the kernel in Pallas's interpreter (the CPU
+    test)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.models import lm_common as lm
+    from kafka_ps_tpu.models import norm_rope_kernel
+
+    name, started = "norm_rope", time.time()
+    s, d = sizes.norm_rope_positions, sizes.norm_rope_dim
+    q_heads, k_heads = sizes.norm_rope_heads
+    require(norm_rope_kernel.takes((1, s, q_heads, d)), name,
+            f"the kernel does not take rows of {s} x {d}")
+    rng = np.random.default_rng(0)
+    eps = 1e-6
+    tables = lm.rope_angles(s, 1.0 / (10000.0 ** (np.arange(
+        0, d, 2, dtype=np.float32) / d)))
+    w = jnp.asarray(1.0 + 0.1 * rng.standard_normal(d), jnp.float32)
+    timed = functools.partial(_timed, sizes.core_calls)
+    rec = {"positions": s, "dim": d}
+    for case, heads, (cos, sin), scale in (
+            ("q", q_heads, tables, 1.0 / np.sqrt(d)),
+            ("k", k_heads, tables, 1.0),
+            ("q_norm_only", q_heads, (None, None), 1.0 / np.sqrt(d))):
+        flat = (1, s, heads * d)
+        read = (1, s, heads, d) if norm_rope_kernel.by_head(heads) else flat
+        x = jnp.asarray(rng.standard_normal(flat), jnp.float32)
+        seen = jnp.asarray(rng.standard_normal(read), jnp.float32)
+        require(_platform_of(x) == platform, name,
+                f"the rows live on {_platform_of(x)}")
+        ways = {
+            "kernel": lambda x, w: norm_rope_kernel.norm_rope(
+                x, w, cos, sin, eps, scale, interpret),
+            "plain": lambda x, w: lm._norm_rope_plain(
+                x, w, cos, sin, eps=eps, scale=scale)}
+        got = {}
+        for way, fn in ways.items():
+            def laid(x, w, fn=fn):
+                return fn(x.reshape(1, s, heads, d), w).reshape(read)
+            out, rec[f"{case}_{way}_forward_ms"] = timed(jax.jit(laid), x, w)
+            grads, rec[f"{case}_{way}_grad_ms"] = timed(jax.jit(jax.grad(
+                lambda x, w: jnp.sum(laid(x, w) * seen), argnums=(0, 1))),
+                                                     x, w)
+            got[way] = (out, *grads)
+        for what, a, b in zip(("out", "dx", "dw"), got["kernel"],
+                              got["plain"]):
+            a, b = np.asarray(a), np.asarray(b)
+            gap = float(np.abs(a - b).max() / np.abs(b).max())
+            require(np.isfinite(a).all() and gap <= 1e-4, name,
+                    f"{case} {what}: the kernel is off the plain lines' by "
+                    f"{gap} of their largest")
+            rec[f"{case}_{what}_gap"] = gap
+    rec["wall_s"] = round(time.time() - started, 2)
+    return rec
+
+
 def phase_placement_products(sizes: Sizes, platform: str, *,
                              interpret: bool = False) -> dict:
     """The expert layer's two products with its 0/1 placement matrix as
@@ -598,6 +681,7 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
             phase_fused(workdir, train, test, sizes, platform, eval_every)
     phases["grouped_products"] = phase_grouped_products(sizes, platform)
     phases["attention_core"] = phase_attention_core(sizes, platform)
+    phases["norm_rope"] = phase_norm_rope(sizes, platform)
     phases["placement_products"] = phase_placement_products(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,

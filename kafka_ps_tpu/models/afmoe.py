@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from kafka_ps_tpu.models import lm_common as lm
-from kafka_ps_tpu.models.lm_common import rms_norm, rope, sub, swiglu
+from kafka_ps_tpu.models.lm_common import sub, swiglu
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the attention core's tile: 512 queries, or the largest tile under it
@@ -230,36 +230,37 @@ def attention(u, p: dict, c: AfmoeConfig, kind: str):
     with jax.named_scope("kps.attn"):
         b, s, _ = u.shape
         nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
-        # the parts of `kps.attn.proj`, each in the order the program
-        # was written in before they had names: `kps.attn.qkv` (the q,
-        # k, v and gate projections), `kps.attn.norm_rope` (the two
-        # head norms and RoPE), `kps.attn.out` (the gate applied and
-        # the output projection)
+        # the parts of `kps.attn.proj`: `kps.attn.qkv` (the q, k, v and
+        # gate projections), `kps.attn.norm_rope` (a sliding layer's
+        # tables, and each of q and k through its head norm and RoPE
+        # in one pass, `lm.head_norm_rope`), `kps.attn.out` (the gate
+        # applied and the output projection)
         def project(w, heads):
             with jax.named_scope("kps.attn.qkv"):
                 return (u @ p[w]).reshape(b, s, heads, d)
 
-        def head_norm(x, w):
+        def norm_rope(x, w, scale=1.0):
             with jax.named_scope("kps.attn.norm_rope"):
-                return rms_norm(x, p[w], c.rms_norm_eps)
+                return lm.head_norm_rope(x, p[w], c.rms_norm_eps, *tables,
+                                         scale=scale)
 
         with jax.named_scope("kps.attn.proj"):
-            q = head_norm(project("wq", nh), "q_norm")
-            k = head_norm(project("wk", nkv), "k_norm")
+            with jax.named_scope("kps.attn.norm_rope"):
+                tables = lm.rope_angles(s, 1.0 / (c.rope_theta ** (jnp.arange(
+                    0, d, 2, dtype=jnp.float32) / d))) if sliding else ()
+            # the core's scale rides q's pass
+            q = norm_rope(project("wq", nh), "q_norm", 1.0 / math.sqrt(d))
+            k = norm_rope(project("wk", nkv), "k_norm")
             v = project("wv", nkv)
             with jax.named_scope("kps.attn.qkv"):
                 gate = jax.nn.sigmoid(u @ p["wg"])
-            with jax.named_scope("kps.attn.norm_rope"):
-                if sliding:
-                    q, k = rope(q, c.rope_theta), rope(k, c.rope_theta)
-                # query head h reads key/value head h // (heads / kv
-                # heads)
-                q = q.reshape(b, s, nkv, nh // nkv, d)
+            # query head h reads key/value head h // (heads / kv heads)
+            q = q.reshape(b, s, nkv, nh // nkv, d)
         with jax.named_scope("kps.attn.window" if sliding
                              else "kps.attn.full"):
             out = lm.blocked_attention(
                 q, k, v, window=c.sliding_window if sliding else None,
-                block=c.attention_block)
+                block=c.attention_block, scaled=True)
         with jax.named_scope("kps.attn.proj"), \
                 jax.named_scope("kps.attn.out"):
             return (out.reshape(b, s, nh * d) * gate) @ p["wo"]
@@ -353,7 +354,9 @@ class AfmoeTask(lm.TokenRowsTask):
     config_cls = AfmoeConfig
     counter_names = lm.COUNTERS + ("attn.pairs_window", "attn.pairs_full",
                                    "attn.block_pairs",
-                                   "attn.kernel_block_pairs")
+                                   "attn.kernel_block_pairs",
+                                   "attn.norm_rope_rows",
+                                   "attn.norm_rope_kernel_rows")
 
     def leaf_specs(self):
         return leaf_specs(self.arch)
@@ -376,11 +379,13 @@ class AfmoeTask(lm.TokenRowsTask):
         one pass, every row of the slab through every layer, in units of
         PAIRS_UNIT pairs (rounded down once a pass); and
         `attn.kernel_block_pairs`, the blocks' pairs again where the
-        core ran them as the kernel, 0 where as plain tiles."""
+        core ran them as the kernel, 0 where as plain tiles; then
+        `norm_rope_counts`' two."""
         c = self.arch
         window, full, blocks = (rows.shape[0] * n // PAIRS_UNIT
                                 for n in pair_counts(c))
         heads = c.num_attention_heads // c.num_key_value_heads
-        return window, full, blocks, blocks * lm.kernel_attends(
+        return (window, full, blocks, blocks * lm.kernel_attends(
             (rows.shape[0], c.sequence_length, c.num_key_value_heads, heads,
-             c.head_dim), c.attention_block)
+             c.head_dim), c.attention_block),
+                *lm.norm_rope_counts(rows.shape[0], c))

@@ -49,7 +49,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental.xla_metadata import set_xla_metadata
 
-from kafka_ps_tpu.models import attention_kernel, placement_kernel
+from kafka_ps_tpu.models import (attention_kernel, norm_rope_kernel,
+                                 placement_kernel)
 from kafka_ps_tpu.models import metrics as metrics_mod
 from kafka_ps_tpu.models import task as task_mod
 from kafka_ps_tpu.utils.config import ModelConfig
@@ -160,6 +161,79 @@ def rope(x, theta: float):
     return x * cos + jnp.concatenate([-x2, x1], -1) * sin
 
 
+# -- a head's norm and RoPE in one pass ------------------------------------------
+
+def rope_angles(s: int, inv_freq, scale: float = 1.0):
+    """(cos, sin) `[s, d]` float32 of positions 0..s-1 at the
+    frequencies `inv_freq` `[d / 2]`, each half of the channels the
+    same angles (rotate-half), cos and sin each times `scale`: the
+    tables `rope` makes for itself, for `head_norm_rope`."""
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * jnp.asarray(
+        inv_freq)[None, :]
+    cos = jnp.concatenate([jnp.cos(ang), jnp.cos(ang)], -1)
+    sin = jnp.concatenate([jnp.sin(ang), jnp.sin(ang)], -1)
+    return (cos, sin) if scale == 1.0 else (scale * cos, scale * sin)
+
+
+def _norm_rope_plain(x, w, cos, sin, *, eps: float, scale: float):
+    y = rms_norm(x, w, eps)
+    if cos is not None:
+        d = y.shape[-1]
+        y1, y2 = y[..., :d // 2], y[..., d // 2:]
+        y = (y * cos[:, None, :]
+             + jnp.concatenate([-y2, y1], -1) * sin[:, None, :])
+    return y if scale == 1.0 else y * scale
+
+
+def head_norm_rope(x, w, eps: float, cos=None, sin=None, *,
+                   scale: float = 1.0):
+    """A projected q or k `[B, S, heads, d]` through its head-wise
+    RMSNorm (weight `w` `[d]`) and rotate-half RoPE by the tables
+    `cos`, `sin` `[S, d]` (`rope_angles`; both None: the layer norms
+    and does not rotate), the result times `scale` (the attention
+    core's `1 / sqrt(d)` where q goes on to `blocked_attention(...,
+    scaled=True)`), float32 throughout.
+
+    One computation, two ways to run it, and the input says which, as
+    `blocked_attention` chooses its core.  On a TPU, at shapes the
+    kernel takes (`norm_rope_kernel.takes`: whole lanes of channels,
+    whole sublanes of positions), it is `norm_rope_kernel.norm_rope`:
+    one pass over the rows as the projection wrote them, channels in
+    lanes, the rotation a lane roll — x read once, the result written
+    once, as the attention core reads it, and under `jax.grad` one
+    more such pass from x and the cotangent.  Anywhere else — another
+    platform, a `head_dim` of 16 — it is `rope(rms_norm(x, w, eps))` in
+    plain `jax.numpy`, two slices and a concatenation, and the program
+    is what it was before there was a kernel.  (Plain, a half of a 128-lane vector is what the
+    chip's compiler lays q and k tokens-minor for: PERF.md section 6,
+    PR 43.)  `rope` itself is as it was, for the families that rotate
+    without a head norm or over fewer channels."""
+    plain = functools.partial(_norm_rope_plain, eps=eps, scale=scale)
+    if not norm_rope_kernel.takes(x.shape):
+        return plain(x, w, cos, sin)
+    return jax.lax.platform_dependent(
+        x, w, cos, sin, default=plain,
+        tpu=lambda x, w, cos, sin: norm_rope_kernel.norm_rope(
+            x, w, cos, sin, eps, scale))
+
+
+def norm_rope_counts(rows: int, c) -> tuple:
+    """(`attn.norm_rope_rows`, `attn.norm_rope_kernel_rows`) of one
+    pass over `rows` rows of a family whose every layer sends q and k
+    through `head_norm_rope`: the head rows normed — positions x heads,
+    q's and k's, over every layer — in units of
+    `norm_rope_kernel.ROWS_UNIT`, and the same again where the kernel
+    ran them, 0 where plain: chosen as the pass itself is chosen."""
+    normed = (rows * c.sequence_length * c.num_hidden_layers
+              * (c.num_attention_heads + c.num_key_value_heads)
+              // norm_rope_kernel.ROWS_UNIT)
+    if not norm_rope_kernel.takes((rows, c.sequence_length,
+                                   c.num_attention_heads, c.head_dim)):
+        return normed, 0
+    return normed, normed * jax.lax.platform_dependent(
+        tpu=lambda: 1, default=lambda: 0)
+
+
 # -- the blocked attention core ------------------------------------------------
 
 def key_span(tile: int, block: int, window: int | None) -> tuple[int, int]:
@@ -219,12 +293,15 @@ def _attend_tiles(q, k, v, *, window: int | None, block: int):
     return jnp.concatenate(tiles, axis=1)
 
 
-def blocked_attention(q, k, v, *, window: int | None, block: int):
+def blocked_attention(q, k, v, *, window: int | None, block: int,
+                      scaled: bool = False):
     """Causal softmax attention over grouped-query heads, a tile of
     `block` queries at a time: `q` `[B, S, G, R, D]` (R query heads to
     each of the G key/value heads), `k`, `v` `[B, S, G, D]` -> `[B, S,
     G, R, D]`.  Query i sees key j iff `j <= i`, and under `window`
-    iff also `i - j < window` (None: a full layer).
+    iff also `i - j < window` (None: a full layer).  `scaled`: q comes
+    already times `1 / sqrt(D)` (`head_norm_rope`'s `scale`: on the
+    chip a pass of its own over q otherwise).
 
     Each tile is set against its own slice of keys (`key_span`) and no
     other: in a sliding layer the blocks the band cannot reach are
@@ -243,7 +320,8 @@ def blocked_attention(q, k, v, *, window: int | None, block: int):
     s, d = q.shape[1], q.shape[-1]
     if s % block:
         raise ValueError(f"block {block} must divide the row's {s} tokens")
-    q = q * (1.0 / math.sqrt(d))
+    if not scaled:
+        q = q * (1.0 / math.sqrt(d))
     tiles = functools.partial(_attend_tiles, window=window, block=block)
     if not attention_kernel.takes(q.shape, block):
         return tiles(q, k, v)

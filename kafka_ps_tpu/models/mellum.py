@@ -59,7 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from kafka_ps_tpu.models import lm_common as lm
-from kafka_ps_tpu.models.lm_common import rms_norm, sub
+from kafka_ps_tpu.models.lm_common import sub
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 # the attention core's tile: 512 queries, or the largest tile under it
@@ -251,22 +251,6 @@ def rope_tables(rule: dict, dim: int) -> tuple[np.ndarray, float]:
         np.float32), float(scale)
 
 
-def rope(x, inv_freq, scale: float):
-    """Rotate-half RoPE over the whole last axis at the frequencies
-    `inv_freq`, cos and sin each times `scale`; positions run along
-    axis -3 of `[..., S, heads, d]`."""
-    d = x.shape[-1]
-    s = x.shape[-3]
-    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * jnp.asarray(
-        inv_freq)[None, :]
-    cos = scale * jnp.concatenate([jnp.cos(ang), jnp.cos(ang)],
-                                  -1)[:, None, :]
-    sin = scale * jnp.concatenate([jnp.sin(ang), jnp.sin(ang)],
-                                  -1)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
-
-
 # -- the layers ------------------------------------------------------------------
 
 def attention(u, p: dict, c: MellumConfig, kind: str):
@@ -274,7 +258,6 @@ def attention(u, p: dict, c: MellumConfig, kind: str):
     normed), causal within a row; `kind` says whether the layer slides
     (plain RoPE, the window) or is full (YaRN, every earlier key)."""
     sliding = kind == SLIDING
-    inv_freq, scale = rope_tables(c.rope(kind), c.head_dim)
     with jax.named_scope("kps.attn"):
         b, s, _ = u.shape
         nh, nkv, d = c.num_attention_heads, c.num_key_value_heads, c.head_dim
@@ -283,13 +266,16 @@ def attention(u, p: dict, c: MellumConfig, kind: str):
             with jax.named_scope("kps.attn.qkv"):
                 return (u @ p[w]).reshape(b, s, heads, d)
 
-        def norm_rope(x, w):
+        def norm_rope(x, w, scale=1.0):
             with jax.named_scope("kps.attn.norm_rope"):
-                return rope(rms_norm(x, p[w], c.rms_norm_eps), inv_freq,
-                            scale)
+                return lm.head_norm_rope(x, p[w], c.rms_norm_eps, *tables,
+                                         scale=scale)
 
         with jax.named_scope("kps.attn.proj"):
-            q = norm_rope(project("wq", nh), "q_norm")
+            with jax.named_scope("kps.attn.norm_rope"):
+                tables = lm.rope_angles(s, *rope_tables(c.rope(kind), d))
+            # the core's scale rides q's pass
+            q = norm_rope(project("wq", nh), "q_norm", 1.0 / math.sqrt(d))
             k = norm_rope(project("wk", nkv), "k_norm")
             v = project("wv", nkv)
             # query head h reads key/value head h // (heads / kv heads)
@@ -298,7 +284,7 @@ def attention(u, p: dict, c: MellumConfig, kind: str):
                              else "kps.attn.full"):
             out = lm.blocked_attention(
                 q, k, v, window=c.sliding_window if sliding else None,
-                block=c.attention_block)
+                block=c.attention_block, scaled=True)
         with jax.named_scope("kps.attn.proj"), \
                 jax.named_scope("kps.attn.out"):
             return out.reshape(b, s, nh * d) @ p["wo"]
@@ -412,6 +398,8 @@ class MellumTask(lm.TokenRowsTask):
     counter_names = lm.COUNTERS + ("attn.pairs_window", "attn.pairs_full",
                                    "attn.block_pairs",
                                    "attn.kernel_block_pairs",
+                                   "attn.norm_rope_rows",
+                                   "attn.norm_rope_kernel_rows",
                                    "moe.place_pairs_dense",
                                    "moe.place_pairs")
 
@@ -434,7 +422,8 @@ class MellumTask(lm.TokenRowsTask):
     def own_counts(self, rows) -> tuple:
         """`attn.pairs_window`, `attn.pairs_full`, `attn.block_pairs`
         and `attn.kernel_block_pairs` of one pass as the `afmoe` family
-        counts them, and `moe.place_pairs_dense`: the (placed row,
+        counts them, `lm_common.norm_rope_counts`' two, and
+        `moe.place_pairs_dense`: the (placed row,
         token) pairs of every expert layer of one pass with each layer
         under its bound (`fit_counted` adds what the passes over it
         placed more) — all in units of PAIRS_UNIT pairs, rounded down
@@ -447,6 +436,7 @@ class MellumTask(lm.TokenRowsTask):
         return (window, full, blocks, blocks * lm.kernel_attends(
             (rows.shape[0], c.sequence_length, c.num_key_value_heads, heads,
              c.head_dim), c.attention_block),
+                *lm.norm_rope_counts(rows.shape[0], c),
                 c.num_hidden_layers * under // PAIRS_UNIT)
 
     def fit_counted(self, leaves, x, enc, mask):

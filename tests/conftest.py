@@ -30,19 +30,26 @@ def pytest_configure(config):
 
 @pytest.fixture
 def the_tpus_branch(monkeypatch):
-    """The attention core and the expert layer's placement as the chip
-    runs them, on the CPU: every `jax.lax.platform_dependent` takes its
-    `tpu` branch and the kernels run in Pallas's interpreter.  The
-    test's own steering; the program has no option that does this."""
+    """The attention core, a head's norm and RoPE and the expert
+    layer's placement as the chip runs them, on the CPU: every
+    `jax.lax.platform_dependent` takes its `tpu` branch and the kernels
+    run in Pallas's interpreter.  The test's own steering; the program
+    has no option that does this."""
     import jax
 
-    from kafka_ps_tpu.models import attention_kernel, placement_kernel
+    from kafka_ps_tpu.models import (attention_kernel, norm_rope_kernel,
+                                     placement_kernel)
     kernel, multiply = attention_kernel.attend, placement_kernel.multiply
+    norm_rope = norm_rope_kernel.norm_rope
     monkeypatch.setattr(jax.lax, "platform_dependent",
                         lambda *args, tpu, default: tpu(*args))
     monkeypatch.setattr(
         attention_kernel, "attend",
         lambda q, k, v, window, block: kernel(q, k, v, window, block, True))
+    monkeypatch.setattr(
+        norm_rope_kernel, "norm_rope",
+        lambda x, w, cos, sin, eps, scale=1.0: norm_rope(
+            x, w, cos, sin, eps, scale, True))
     monkeypatch.setattr(
         placement_kernel, "multiply",
         lambda x, plan, back, passes, chunk=None: multiply(

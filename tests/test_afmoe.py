@@ -240,6 +240,11 @@ def test_fused_clocks_agree_with_the_reference(task, ref, ps_cfg, theta,
                         ("attn.block_pairs", blocks)):
         assert counted[name] == passes * (2 * pairs // afmoe.PAIRS_UNIT)
     assert counted["attn.kernel_block_pairs"] == 0      # head_dim 16
+    # q's and k's head rows of both rows of a slab through every layer
+    assert counted["attn.norm_rope_rows"] == passes * (
+        2 * c.sequence_length * c.num_hidden_layers
+        * (c.num_attention_heads + c.num_key_value_heads) // 1024) > 0
+    assert counted["attn.norm_rope_kernel_rows"] == 0
 
 
 def test_evaluation_agrees_with_the_reference(task, ref, ps_cfg, theta):
@@ -374,7 +379,11 @@ def test_the_kernels_counter_is_the_blocks_where_the_kernel_ran(
     the kernel takes (a sliding and a full layer, `head_dim` 128, rows
     of 256 in one tile): 0 where the plain tiles ran — this platform —
     and `attn.block_pairs` with the TPU's branch taken, where the loss
-    and the step are the plain path's to bfloat16's rounding."""
+    and the step are the plain path's to bfloat16's rounding.  And
+    `attn.norm_rope_kernel_rows` beside `attn.norm_rope_rows` the same
+    way: the head norm and RoPE of q and k go through their kernel
+    (models/norm_rope_kernel.py) at the same sizes, with tables in the
+    sliding layer and without in the full one."""
     body = json.load(open(os.path.join(ROOT, TINY)))
     body.update(head_dim=128, num_attention_heads=2, num_key_value_heads=1,
                 sequence_length=256, sliding_window=200, num_hidden_layers=2,
@@ -396,10 +405,15 @@ def test_the_kernels_counter_is_the_blocks_where_the_kernel_ran(
     assert counted["attn.kernel_block_pairs"] == 0
     blocks = 2 * (2 * afmoe.pair_counts(c)[2] // afmoe.PAIRS_UNIT)
     assert counted["attn.block_pairs"] == blocks > 0
+    normed = 2 * (2 * 256 * 2 * (2 + 1) // 1024)
+    assert counted["attn.norm_rope_rows"] == normed > 0
+    assert counted["attn.norm_rope_kernel_rows"] == 0
     request.getfixturevalue("the_tpus_branch")
     new, loss, counted = fit()
     assert counted["attn.kernel_block_pairs"] \
         == counted["attn.block_pairs"] == blocks
+    assert counted["attn.norm_rope_kernel_rows"] \
+        == counted["attn.norm_rope_rows"] == normed
     assert abs(loss - plain_loss) <= 1e-3 * plain_loss
     start = np.asarray(task.init_params())
     assert 0 < np.linalg.norm(new - plain) <= 0.02 * np.linalg.norm(

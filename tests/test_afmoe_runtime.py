@@ -59,11 +59,15 @@ def test_the_three_families_import_one_frame():
     """One attention core, one RoPE, one gated expert, one expert
     layer, one task frame: the modules hold the shared module's own
     objects, and none keeps a copy."""
-    assert afmoe.rope is lm.rope and glm.rope is lm.rope
+    assert glm.rope is lm.rope
     assert afmoe.swiglu is lm.swiglu and glm.swiglu is lm.swiglu
     assert glm.swiglu_experts is lm.swiglu_experts
-    for module in (glm, nh, afmoe):
+    # a head's norm and RoPE are the frame's one pass in the third
+    # family (`lm.head_norm_rope`, PR 43), which keeps neither by name
+    assert not {"rope", "rms_norm", "head_norm_rope"} & set(vars(afmoe))
+    for module in (glm, nh):
         assert module.rms_norm is lm.rms_norm
+    for module in (glm, nh, afmoe):
         for shared in ("route", "live_rows_bound", "fit_counted",
                        "evaluate_leaves", "head_nll", "blocked_attention",
                        "key_span"):
@@ -74,7 +78,8 @@ def test_the_three_families_import_one_frame():
         assert shared not in vars(afmoe.AfmoeTask), shared
     assert afmoe.AfmoeTask.counter_names == lm.COUNTERS + (
         "attn.pairs_window", "attn.pairs_full", "attn.block_pairs",
-        "attn.kernel_block_pairs")
+        "attn.kernel_block_pairs", "attn.norm_rope_rows",
+        "attn.norm_rope_kernel_rows")
 
 
 def test_the_single_step_is_one_round_of_the_chunk(task, ps_cfg):
@@ -308,6 +313,11 @@ def test_the_fused_loop_sums_the_familys_counters_over_a_call(task, ps_cfg):
     # through the CPU runtime the core is its plain tiles, whatever the
     # size: the kernel computed none of those blocks
     assert counters["attn.kernel_block_pairs"] == 0
+    # q's and k's head rows through every layer, and no kernel either
+    assert counters["attn.norm_rope_rows"] == 32 * 3 * (
+        2 * c.sequence_length * c.num_hidden_layers
+        * (c.num_attention_heads + c.num_key_value_heads) // 1024) > 0
+    assert counters["attn.norm_rope_kernel_rows"] == 0
     assert tracer.counters()["attn.block_pairs"] \
         == counters["attn.block_pairs"]
     assert app.server.last_metrics is not None

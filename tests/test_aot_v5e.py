@@ -61,6 +61,8 @@ CHUNKS = {
 # layer does
 NAMED = {"kps.attn.qkv", "kps.attn.out", "kps.lm.norm", "kps.bsp.carry",
          "kps.bsp.fold"}
+# lines of the parent's chunks (f35376d) that name `kps.attn.norm_rope`
+OURO_NORM_ROPE_LINES, GLM_NORM_ROPE_LINES = 2567, 3329
 NAMED_BY_EXPERTS = {"kps.moe.sort", "kps.moe.place", "kps.moe.expert_fn",
                     "kps.moe.combine", "ragged-dot"}
 
@@ -230,7 +232,11 @@ def test_the_third_language_models_chunk_holds_no_square_of_scores(
     text = compiled.as_text()
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
-    assert not [sh for sh in shapes if len(sh) >= 3 and sh.count(s) >= 2]
+    # (q as its projection writes it and its norm's kernel reads it,
+    # `[1, S, 32 x 128]`, is as wide as the row is long: no scores)
+    q_flat = (1, s, c.num_attention_heads * c.head_dim)
+    assert not [sh for sh in shapes - {q_flat}
+                if len(sh) >= 3 and sh.count(s) >= 2]
     assert not [sh for sh in shapes if s * s in sh]
     # the core's calls, by kernel and scope: 2 gradient passes x
     # (forward + recomputed) + the loss's forward = 5 forward calls a
@@ -475,7 +481,10 @@ def test_the_fifth_language_models_chunk_walks_its_widths_in_told_tiles(
         ("kps_attn_core_backward", "kps.attn.full"): 2}
     shapes = {tuple(int(d) for d in dims.split(","))
               for dims in re.findall(r"= \w+\[([\d,]+)\]", text)}
-    assert not [sh for sh in shapes if len(sh) >= 3 and sh.count(s) >= 2]
+    # (but q as its norm's kernel reads it, `[1, S, 32 x 128]`)
+    q_flat = (1, s, c.num_attention_heads * c.head_dim)
+    assert not [sh for sh in shapes - {q_flat}
+                if len(sh) >= 3 and sh.count(s) >= 2]
     assert not [sh for sh in shapes if s * s in sh]
     for scope in ("kps.attn.qkv", "kps.attn.norm_rope", "kps.attn.out",
                   "kps.moe.route", "kps.moe.sort", "kps.moe.place",
@@ -547,6 +556,88 @@ def test_a_large_placement_is_the_kernels_and_no_matrix(
         ("kps_moe_place", "kps.moe.combine"): 2 * 8}
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < scratch, memory.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("family,rotating,plain", [
+    ("afmoe", 4, 1), ("mellum", 4, 0)])
+def test_a_heads_norm_and_rope_are_one_kernel_pass_that_rolls_the_lanes(
+        folded_chunk, family, rotating, plain):
+    """The third and the fifth family's chunks (the fixture's compiles,
+    no second ones): q `[4096, 32 x 128]` and k `[4096, 4 x 128]` go
+    through their head norm and RoPE as `lm_common.head_norm_rope`'s
+    kernel (models/norm_rope_kernel.py, PR 43), Mosaic calls whose
+    `op_name` lies under `kps.attn.norm_rope`, which is what the
+    benchmark's readers find their device time by — q's and k's a layer
+    a pass: 2 gradient passes x (forward + recomputed) + the loss's
+    forward = 5 forward passes and 2 backward, with the angles' two
+    tables where the layer rotates (4 operands; the third family's
+    full layer norms alone, 2).
+
+    What the plain lines cost is gone with them.  A half of a 128-lane
+    vector (`x[..., :64]`, `concatenate([-x2, x1])`) made the compiler
+    hold q and k tokens-minor, `f32[1,4096,32,128]{1,3,2,0}`, and copy
+    at every boundary that wants the channels in lanes: 40 (20 in the
+    fifth family's chunk) top-level copies `f32[1,4096,32,64]{3,2,1,0}`
+    an update named `…/kps.attn.norm_rope/slice` and as many `/neg`, 40
+    under `/concatenate` — 15.5 of the scope's 39.0 Mcyc (XLA's own
+    estimate) in the third family's chunk, 340.3 Mcyc an update in all
+    and 294.2 now; the fifth's 326.9 -> 286.4.  And q's result leaves
+    the kernel eight heads a tile, as the attention kernel reads it:
+    no copy `f32[…,32,128]` stands between the two, where 25 stood
+    between the projection and the plain norm (`f32[4096,32,128]
+    {2,1,0}` under `kps.attn.qkv`).  Counts from the text, never a
+    time."""
+    task, compiled = folded_chunk(family)
+    c = task.arch
+    assert (c.sequence_length, c.num_attention_heads, c.num_key_value_heads,
+            c.head_dim) == (4096, 32, 4, 128)
+    assert (c.layers("sliding_attention"), c.layers("full_attention")) \
+        == (rotating - (family == "mellum"), 1)
+    text = compiled.as_text()
+    calls = re.findall(
+        r"%(kps_norm_rope_\w+?)[.\d]* = (?:\()?f32\[([\d,]+)\].* custom-call\("
+        r"(.*?)\), custom_call_target=\"tpu_custom_call\".*"
+        r"op_name=\"([^\"]*)\"", text)
+    assert all("kps.attn.norm_rope" in op_name for *_, op_name in calls)
+    counted = {}
+    for kernel, made, operands, _ in calls:
+        key = (kernel, made, len(operands.split(", ")))
+        counted[key] = counted.get(key, 0) + 1
+    forward, backward = "kps_norm_rope_forward", "kps_norm_rope_backward"
+    q, k, q_flat = "1,131072,128", "1,4096,512", "1,4096,4096"
+    want = {(forward, q, 4): 5 * rotating, (forward, k, 4): 5 * rotating,
+            (backward, q_flat, 5): 2 * rotating,
+            (backward, k, 5): 2 * rotating,
+            (forward, q, 2): 5 * plain, (forward, k, 2): 5 * plain,
+            (backward, q_flat, 3): 2 * plain, (backward, k, 3): 2 * plain}
+    assert counted == {key: n for key, n in want.items() if n}
+    # nothing is laid tokens-minor, and no half of a head is copied
+    assert not re.search(r"f32\[1,4096,32,128\]\{1,3,2,0", text)
+    assert not re.search(r"f32\[1,4096,32,64\]", text)
+    copies = [line for line in text.splitlines()
+              if re.match(r"\s*(?:ROOT )?%copy[.\d]* = ", line)]
+    assert copies                       # the reader sees the program's
+    assert not [line for line in copies if re.search(
+        r"kps\.attn\.norm_rope/(slice|neg|concatenate)\"", line)]
+    assert not [line for line in copies if re.search(
+        r"= f32\[(1,)?4096,32,128\]", line)]
+
+
+@pytest.mark.parametrize("family,lines", [("ouro", OURO_NORM_ROPE_LINES),
+                                          ("glm4_moe_lite",
+                                           GLM_NORM_ROPE_LINES)])
+def test_the_other_families_norm_and_rope_are_the_plain_lines(
+        folded_chunk, family, lines):
+    """The fourth family rotates without a head norm (and stands at 82%
+    of its bytes), the first over 64 of a head's channels: both keep
+    `lm_common.rope`, no kernel of PR 43 is in their chunks, and the
+    instructions under `kps.attn.norm_rope` count what they counted in
+    the parent's chunks (f35376d, compiled the same way)."""
+    _, compiled = folded_chunk(family)
+    text = compiled.as_text()
+    assert "kps_norm_rope_" not in text
+    assert sum("kps.attn.norm_rope" in line
+               for line in text.splitlines()) == lines
 
 
 @pytest.mark.parametrize("family", ["glm4_moe_lite", "nemotron_h"])

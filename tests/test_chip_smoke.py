@@ -24,7 +24,8 @@ TINY = chip_smoke.Sizes(
     grouped_rows=128, grouped_widths=(640, 384),    # the rule still hints
     core_shape=(1, 384, 1, 2, 128), core_window=200, core_block=128,
     core_calls=1,
-    placement_shape=(512, 1024, 128), placement_groups=(90, 0, 37, 60))
+    placement_shape=(512, 1024, 128), placement_groups=(90, 0, 37, 60),
+    norm_rope_positions=40, norm_rope_heads=(8, 2))
 
 
 def _run(script_dir, env_extra, *args):
@@ -95,6 +96,19 @@ def test_attention_core_phase():
     with pytest.raises(chip_smoke.SmokeFailure, match="does not take"):
         chip_smoke.phase_attention_core(
             chip_smoke.Sizes(core_shape=(1, 64, 1, 2, 8), core_block=8),
+            "cpu", interpret=True)
+
+
+def test_norm_rope_phase():
+    """The kernel in Pallas's interpreter against the plain lines: the
+    gaps are float32's, the times are the CPU's and mean nothing."""
+    rec = chip_smoke.phase_norm_rope(TINY, "cpu", interpret=True)
+    for case in ("q", "k", "q_norm_only"):
+        for what in ("out", "dx", "dw"):
+            assert rec[f"{case}_{what}_gap"] < 1e-5
+    with pytest.raises(chip_smoke.SmokeFailure, match="does not take"):
+        chip_smoke.phase_norm_rope(
+            chip_smoke.Sizes(norm_rope_positions=40, norm_rope_dim=16),
             "cpu", interpret=True)
 
 

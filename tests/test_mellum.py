@@ -281,6 +281,10 @@ def test_fused_clocks_agree_with_the_reference(task, ref, ps_cfg, theta,
                         ("attn.block_pairs", blocks)):
         assert counted[name] == passes * (2 * pairs // mellum.PAIRS_UNIT)
     assert counted["attn.kernel_block_pairs"] == 0      # head_dim 16
+    assert counted["attn.norm_rope_rows"] == passes * (
+        2 * c.sequence_length * c.num_hidden_layers
+        * (c.num_attention_heads + c.num_key_value_heads) // 1024) > 0
+    assert counted["attn.norm_rope_kernel_rows"] == 0
     # the placement: 2 rows x 24 tokens, 96 slots, a quarter held: a
     # bound of 48 rows x 48 tokens a layer a pass, all 96 x 48 in a
     # pass over it
@@ -372,7 +376,9 @@ def test_yarns_frequencies_are_the_numbers_worked_by_hand():
     # the scaled tables at a position worked by hand: position 1000 in
     # channel pair 19 turns 1000 x 0.0192080 = 19.2080 rad
     x = jnp.zeros((1, 1001, 1, 128)).at[..., 19].set(1.0)
-    turned = np.asarray(mellum.rope(x, inv, scale))[0, 1000, 0]
+    turned = np.asarray(lm.head_norm_rope(
+        x, jnp.full((128,), 1 / math.sqrt(128)), 0.0,
+        *lm.rope_angles(1001, inv, scale)))[0, 1000, 0]
     assert turned[19] == pytest.approx(scale * math.cos(19.2080), abs=2e-4)
     assert turned[19 + 64] == pytest.approx(scale * math.sin(19.2080),
                                             abs=2e-4)

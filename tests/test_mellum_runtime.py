@@ -68,12 +68,15 @@ def test_the_five_families_import_one_frame():
     module holds the shared module's own objects and keeps no copy; its
     router and its RoPE rules are its own, and the frame's stay the
     other families'."""
-    assert mellum.rms_norm is lm.rms_norm and mellum.sub is lm.sub
+    assert mellum.sub is lm.sub
     for module in (glm, nh, afmoe, ouro, mellum):
         for shared in ("fit_counted", "evaluate_leaves", "routed_experts",
                        "blocked_attention", "key_span", "head_nll"):
             assert shared not in vars(module), (module.__name__, shared)
-    assert mellum.route is not lm.route and mellum.rope is not lm.rope
+    assert mellum.route is not lm.route
+    # its RoPE rules are tables (`rope_tables`) for the frame's one
+    # pass over a head's norm and RoPE (`lm.head_norm_rope`, PR 43)
+    assert not {"rope", "rms_norm", "head_norm_rope"} & set(vars(mellum))
     assert issubclass(mellum.MellumTask, lm.TokenRowsTask)
     for shared in ("evaluate_leaves", "unflatten", "flatten", "init_params",
                    "encode_labels", "fit"):
@@ -235,6 +238,11 @@ def test_the_fused_loop_sums_the_familys_counters_over_a_call(task, ps_cfg):
     assert counters["moe.place_pairs_dense"] == counters["moe.place_pairs"]
     # through the CPU runtime the core is its plain tiles
     assert counters["attn.kernel_block_pairs"] == 0
+    # q's and k's head rows through every layer, and no kernel either
+    assert counters["attn.norm_rope_rows"] == 32 * 3 * (
+        2 * c.sequence_length * c.num_hidden_layers
+        * (c.num_attention_heads + c.num_key_value_heads) // 1024) > 0
+    assert counters["attn.norm_rope_kernel_rows"] == 0
     assert tracer.counters()["moe.place_pairs"] == counters["moe.place_pairs"]
     assert app.server.last_metrics is not None
     app.close_logs()

@@ -70,8 +70,10 @@ def test_the_four_families_import_one_frame():
     for shared in ("fit_counted", "evaluate_leaves", "unflatten", "flatten",
                    "init_params", "encode_labels", "fit"):
         assert shared not in vars(ouro.OuroTask), shared
-    assert ouro.OuroTask.counter_names == afmoe.AfmoeTask.counter_names + (
-        "lm.layer_passes",)
+    assert ouro.OuroTask.counter_names == afmoe.AfmoeTask.counter_names[
+        :-2] + ("lm.layer_passes",)
+    assert afmoe.AfmoeTask.counter_names[-2:] == (
+        "attn.norm_rope_rows", "attn.norm_rope_kernel_rows")
     assert ouro.PAIRS_UNIT == afmoe.PAIRS_UNIT
     assert ouro.OuroTask.slots_a_token == 0         # no expert layer
 
@@ -129,10 +131,11 @@ def test_the_third_familys_stablehlo_is_the_parents(stablehlo, name,
     some, and nothing else of models/lm_common.py moved: the programs
     the third family traces are, character for character, the ones the
     commit before traced (tests/fixtures/ holds the digests, written
-    from the commit its `_what` names: PR 40's tree's since that PR
-    changed the expert layer's program on purpose, `routed_experts`'
-    branch over the bound a `jax.checkpoint`; until then commit
-    9637bd4's, PR 38)."""
+    from the commit its `_what` names: PR 43's tree's since that PR
+    changed the third family's program on purpose — q and k through
+    `lm_common.head_norm_rope`, two counters more; PR 40's until then,
+    which made `routed_experts`' branch over the bound a
+    `jax.checkpoint`; before it commit 9637bd4's, PR 38)."""
     stated = json.load(open(os.path.join(ROOT, "tests", "fixtures",
                                          FIXTURE[name])))
     if stated["jax"] != jax.__version__:
