@@ -22,6 +22,7 @@ import sys
 def build_parser(include_server_flags: bool = True,
                  include_worker_flags: bool = True,
                  prog: str = "kafka_ps_tpu") -> argparse.ArgumentParser:
+    from kafka_ps_tpu.models.task import task_names
     p = argparse.ArgumentParser(
         prog=prog, description="TPU-native streaming parameter server")
     if include_server_flags:
@@ -58,17 +59,16 @@ def build_parser(include_server_flags: bool = True,
                         "BaseKafkaApp.java:25)")
     p.add_argument("--num_features", type=int, default=1024)
     p.add_argument("--num_classes", type=int, default=5)
-    p.add_argument("--task", choices=["logreg", "mlp", "glm4_moe_lite",
-                                      "nemotron_h", "afmoe", "ouro", "mellum"],
-                   default="logreg",
+    p.add_argument("--task", choices=task_names(), default="logreg",
                    help="model family (models/task.py registry); logreg "
                         "is the reference's task")
     p.add_argument("--hidden_dim", type=int, default=128,
                    help="hidden width of the mlp task")
     p.add_argument("--model_json", default=None,
                    help="the model family's own configuration file "
-                        "(a language-model family, --task glm4_moe_lite, "
-                        "nemotron_h, afmoe, ouro or mellum: the published "
+                        "(a language-model family, --task "
+                        # the two classifiers come first and have no file
+                        + ", ".join(task_names()[2:]) + ": the published "
                         "keys and the cut held here, models/lm_common.py); a "
                         "relative path is taken from the repository's root")
     p.add_argument("--local_iterations", type=int, default=2,

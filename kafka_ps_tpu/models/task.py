@@ -278,6 +278,12 @@ def register(name: str, factory) -> None:
     _REGISTRY[name] = factory
 
 
+def task_names() -> list[str]:
+    """Every family's name: what is registered from the start, then the
+    late-bound families in `_LATE`'s order (the CLI's `--task`)."""
+    return [n for n in _REGISTRY if n not in _LATE] + list(_LATE)
+
+
 def task_class(name: str):
     """The family's factory, by name: what `get_task` calls, and what
     says of the family what a caller must know before it has a cfg

@@ -127,8 +127,8 @@ class PSConfig:
 
     num_workers: int = 4
     consistency_model: int = SEQUENTIAL   # -c: 0 BSP, k>0 SSP, -1 ASP
-    # model family (models/task.py registry): "logreg" (the reference's),
-    # "mlp", "glm4_moe_lite", "nemotron_h", "afmoe", "ouro" or "mellum"
+    # model family, by its name in models/task.py's registry
+    # (`task_names`); "logreg" is the reference's
     task: str = "logreg"
     model: ModelConfig = dataclasses.field(default_factory=ModelConfig)
     buffer: BufferConfig = dataclasses.field(default_factory=BufferConfig)

@@ -17,6 +17,10 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # chip tool copies the tree as it stands.
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
+# What the families' files share lies in two modules that are no test
+# files: their assertions are rewritten as a test file's are.
+pytest.register_assert_rewrite("lm_family_contract", "aot_described")
+
 # Lock-order detector: records every OrderedLock acquisition across the
 # whole session and fails it on acquisition-order cycles (potential
 # deadlocks).  Disable for one run with LOCKGRAPH=0.

@@ -1,0 +1,105 @@
+"""The `ouro-2.6b.fused-bsp` cell's scan chunk, compiled once for a
+described TPU v5e (tests/aot_described.py)."""
+
+import aot_described as described
+from aot_described import aot, chunk, topo  # noqa: F401 — fixtures
+from kafka_ps_tpu.models import lm_common as lm
+
+CELL = ("ouro", "benchmark/configs/ouro-2.6b.model.json")
+
+
+def test_ouros_chunk_holds_its_layers_once(chunk):
+    """612.4 M parameters held, 8 layers run 4 times on one set of
+    leaves, 1 row of 1,024 tokens a worker.  The leaves are donated and
+    scratch + donated leaves stay under 15.0 GB: 10.60 + 2.45 = 13.05
+    GB when written, 21.3 bytes a parameter, as a `lax.scan` over the
+    steps with the layers written out in its body and the leaves closed
+    over (73 s); with the 32 applications written out 8.34 + 2.45 GB in
+    184-220 s, over what the `afmoe` cell's chunk takes — why the loop
+    is a scan.
+
+    A layer's leaves are held ONCE: no array carries a leading axis of
+    the steps over a leaf's shape (a stack of the leaves, or of their
+    gradients), and what the loop keeps for the backward pass is one
+    `[4, 1, 1024, 2048]` stack of inputs a layer.  What the loop costs
+    beyond a once-through program — the same chunk at `total_ut_steps`
+    1 compiled to 6.02 GB of scratch (PR 39; it never holds its layers'
+    gradient whole: each leaf's is consumed by its parameter step as it
+    is made) — is 4.58 GB, 2.8 arrays of the layers' gradient (1.64
+    GB): the gradient itself, carried as the backward loop's state and
+    summed over the uses there (no four gradients side by side), the
+    loop-invariant leaves' bfloat16 roundings and relayouts that the
+    compiler hoists out of the forward and of the backward `while`
+    (`bf16[2048,5632]` in the loops' state, 0.82 GB each), and 24 more
+    saved inputs.  The limit below holds that cost: under 10.8 GB no
+    further float32 copy of the layers' leaves fits, so a fourth such
+    array fails here without the once-through chunk compiled beside
+    this one every run (60 s, until PR 44; scripts/aot_v5e_hlo.py
+    `--folded` compiles it by hand).
+
+    The attention core is the kernel at ONE query head a key/value
+    head (`[1, 1024, 16, 1, 128]`, tiles of 512), under
+    `kps.attn.full` inside `kps.lm.layers`: a loop's body holds each
+    layer's call once, so 2 gradient passes x (forward + recomputed) +
+    the loss's forward = 5 forward calls a layer and 2 backward.  No
+    array of S x S elements a head is in the program.  About 75 s."""
+    described.leaves_are_donated_and_fit(chunk, 612_435_968, 10.8e9)
+    task = chunk.task
+    c = task.arch
+    s, block = c.sequence_length, c.attention_block
+    assert (s, block, c.num_hidden_layers, c.total_ut_steps) == (1024, 512,
+                                                                 8, 4)
+    assert 4 * c.num_hidden_layers * lm.num_params(
+        [(n, sh) for n, sh in task.specs if n.startswith("l0.")]) \
+        == 8 * 51_388_416 * 4
+    shapes = described.shapes_made(chunk.text)
+    assert not described.square_of_scores(shapes, s)
+    # a leaf once: nothing stacks a leaf's shape over the steps, and
+    # the inputs kept for the backward pass are stacked over them
+    h, i = c.hidden_size, c.intermediate_size
+    assert (h, i) in shapes and (i, h) in shapes and (h, h) in shapes
+    assert not [sh for sh in shapes if len(sh) == 3 and sh[1:] in (
+        (h, i), (i, h), (h, h))]
+    assert (c.total_ut_steps, 1, s, h) in shapes
+    # the core's calls, by kernel and scope
+    calls = described.mosaic_calls(chunk.text, "kps_attn_core_")
+    assert all("kps.lm.layers" in op_name for *_, op_name in calls), calls
+    assert {made for _, made, *_ in calls} == {"f32[1,1024,16,1,128]"}
+    assert described.by_kernel_and_scope(calls, described.CORE_SCOPES) == {
+        ("kps_attn_core_forward", "kps.attn.full"): 5 * 8,
+        ("kps_attn_core_backward", "kps.attn.full"): 2 * 8}
+    assert "ragged-dot" not in chunk.text and "kps.moe" not in chunk.text
+    for scope in ("kps.lm.layers", "kps.attn.qkv", "kps.attn.norm_rope",
+                  "kps.attn.out", "kps.mlp", "kps.lm.norm", "kps.lm.head"):
+        assert scope in chunk.text, scope
+
+
+def test_ouros_norm_and_rope_are_the_plain_lines(chunk):
+    """It rotates without a head norm (and stands at 82% of its bytes)
+    and keeps `lm_common.rope`; 2,567 lines of its chunk name the
+    scope."""
+    described.norm_and_rope_are_the_plain_lines(chunk, 2567)
+
+
+def test_what_no_scope_names_is_under_a_tenth_of_ouros_bytes(chunk):
+    """And what lies under `kps.lm.layers` ALONE — the loop's own — is
+    NOT small: 24.5% of the result bytes (a loop's body counted once),
+    nearly all ADOPTED — what the compiler does to a loop's invariants
+    before it enters one: each leaf's bfloat16 rounding (`convert`
+    `bf16[2048,5632]`), relayouts and copies of the weights, their
+    prefetch in slices — where ISSUE 39 hoped for under a tenth; held
+    under three tenths here, so that a second such set shows."""
+    read = described.what_the_scopes_name(chunk)
+    assert described.NAMED | {"kps.lm.layers", "kps.attn.full",
+                              "kps.mlp"} <= read.named
+    assert not described.NAMED_BY_EXPERTS & read.named
+    total = alone = 0
+    for name in read.run:
+        inst = read.module["instructions"][name]
+        if inst["opcode"] in read.self_time.CONTAINERS \
+                or inst["opcode"].endswith("-start"):
+            continue
+        total += inst["bytes"]
+        alone += inst["bytes"] * (read.scopes[name][0] == "kps.lm.layers")
+    assert 0.10 < alone / total < 0.30, alone / total
+    assert 0.0 < read.unnamed_share < 0.10, read.unnamed_share

@@ -289,7 +289,7 @@ def test_elsewhere_than_a_tpu_the_taken_shape_runs_the_product(monkeypatch):
                                    rtol=1e-5, atol=1e-6)
 
 
-def test_the_fifth_familys_counters_read_the_layers_count(tiny_kernels):
+def test_mellums_counters_read_the_layers_count(tiny_kernels):
     """`mellum`'s `fit_counted` with the kernels taking its tiny
     shape: `moe.place_pairs` is what the layers counted on the device —
     here under the whole matrix — and `moe.place_pairs_dense` the whole
