@@ -81,6 +81,8 @@ class Sizes:
     # the attention core at the third language-model cell's shapes: q
     # [B, S, G, R, D], the sliding layers' window, the tile
     core_shape: tuple = (1, 4096, 4, 8, 128)
+    # and the sixth's: heads of 64 channels, two to a lane vector
+    core_shape_halves: tuple = (1, 4096, 8, 4, 64)
     core_window: int = 2048
     core_block: int = 512
     core_calls: int = 5           # timed calls a program, after a warm one
@@ -384,7 +386,8 @@ def phase_attention_core(sizes: Sizes, platform: str, *,
                          interpret: bool = False) -> dict:
     """The attention core as the kernel (`attention_kernel.attend`)
     against today's plain tiles (`lm_common._attend_tiles`) at the
-    third language-model cell's shapes, a sliding and a full layer:
+    third language-model cell's shapes (`run_phases` once more at the
+    sixth's, heads of 64 channels), a sliding and a full layer:
     the output and all three gradients of both, the largest gap of each
     as a share of the tiles' largest value (the two round the same
     operands to bfloat16 and sum in another order), and the ms a call
@@ -681,6 +684,9 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
             phase_fused(workdir, train, test, sizes, platform, eval_every)
     phases["grouped_products"] = phase_grouped_products(sizes, platform)
     phases["attention_core"] = phase_attention_core(sizes, platform)
+    phases["attention_core_halves"] = phase_attention_core(
+        dataclasses.replace(sizes, core_shape=sizes.core_shape_halves),
+        platform)
     phases["norm_rope"] = phase_norm_rope(sizes, platform)
     phases["placement_products"] = phase_placement_products(sizes, platform)
     if device_count > 1:

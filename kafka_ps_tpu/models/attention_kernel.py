@@ -22,6 +22,16 @@ of k and v is read once for all of them.  Only the tile's own block
 and the block the band's edge cuts are masked, by the same two
 inequalities; the blocks between them lie inside the mask whole.
 
+A head of 64 channels is half a lane vector, and two of them ride one:
+under an even G, `q` `[B, S, G, R, 64]` goes through the same two kernels
+as `[B, S, G / 2, 2R, 128]` (`_paired`: each row's own 64 channels in
+its half of the lanes, zeros in the other) beside k and v read as `[B,
+S, G / 2, 128]`, and of the output and dq each row's own half is kept
+(`_own_halves`); dk and dv come out as they lie.  The chip's MXU is 128
+wide, so a product over 64 channels fills half of it however it is laid
+out and the zeros cost no pass; what the custom_vjp keeps between the
+passes stays 64 wide.
+
 Precision: q, k, v and the cotangent come in float32 and out, dq, dk,
 dv leave in float32.  Each product's operands are rounded to bfloat16
 where the compiler rounds them in the plain path (a float32 product at
@@ -41,6 +51,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
+HALF = LANES // 2
 # what a masked score reads: finite, so that a row whose first block
 # lies wholly outside the band has a maximum to subtract
 MASKED = -0.7 * float(np.finfo(np.float32).max)
@@ -60,11 +71,41 @@ _TN = (((0,), (0,)), ((), ()))      # [k, m] x [k, n] -> [m, n]
 
 def takes(q_shape, block: int) -> bool:
     """Whether the kernel takes `q` `[B, S, G, R, D]` in tiles of
-    `block`: whole lanes of channels and of keys, and a row whose k and v
-    gradients fit VMEM."""
-    _, s, _, _, d = q_shape
+    `block`: whole lanes of channels — or half a lane vector, 64, under
+    an even G, whose heads ride two to a vector (`_paired`) — and of
+    keys, and a row whose k and v gradients fit VMEM."""
+    _, s, g, _, d = q_shape
+    if d == HALF and g % 2 == 0:
+        d = LANES
     return (d % LANES == 0 and block % LANES == 0 and s % block == 0
             and s * d <= RESIDENT_ELEMENTS)
+
+
+# -- heads of half a lane vector, two to a vector --------------------------------
+
+def _paired(x):
+    """`[B, S, G, R, 64]` -> `[B, S, G / 2, 2R, 128]`: the query heads
+    of two neighbouring key/value heads stacked on the rows, the first
+    head's in the low half of the lanes and zeros in the high half, the
+    second's the other way round.  Beside them k and v `[B, S, G, 64]`
+    are `[B, S, G / 2, 128]` as they lie in memory, and a row's product
+    with a pair's keys is its product with its own head's: the other
+    half adds exact zeros."""
+    b, s, g, r, d = x.shape
+    x = x.reshape(b, s, g // 2, 2, r, d)
+    zeros = jnp.zeros_like(x[:, :, :, 0])
+    return jnp.concatenate([
+        jnp.concatenate([x[:, :, :, 0], zeros], axis=-1),
+        jnp.concatenate([zeros, x[:, :, :, 1]], axis=-1)], axis=3)
+
+
+def _own_halves(x):
+    """`[B, S, G / 2, 2R, 128]` -> `[B, S, G, R, 64]`: of each row of
+    `_paired`'s layout the half of the lanes that is its own head's."""
+    b, s, g, r, d = x.shape
+    r, d = r // 2, d // 2
+    return jnp.stack([x[:, :, :, :r, :d], x[:, :, :, r:, d:]],
+                     axis=3).reshape(b, s, 2 * g, r, d)
 
 
 def _first_block(tile, block: int, window: int | None):
@@ -233,9 +274,14 @@ def _forward_call(shape, window, block, interpret):
 
 
 def _forward(q, k, v, window, block, interpret):
-    b, s, g, r, d = q.shape
-    return _forward_call(q.shape, window, block, interpret)(
-        q, k.reshape(b, s, g * d), v.reshape(b, s, g * d))
+    """(out as `q`, the rows' log-sum-exp as the kernels lay it)."""
+    halves = q.shape[-1] == HALF
+    if halves:
+        q = _paired(q)
+    b, s = q.shape[:2]
+    out, lse = _forward_call(q.shape, window, block, interpret)(
+        q, k.reshape(b, s, -1), v.reshape(b, s, -1))
+    return (_own_halves(out) if halves else out), lse
 
 
 # -- backward ------------------------------------------------------------------
@@ -333,12 +379,18 @@ def _attend_fwd(q, k, v, window, block, interpret):
 
 def _attend_bwd(window, block, interpret, kept, d_out):
     q, k, v, out, lse = kept
-    b, s, g, r, d = q.shape
     # delta: a row's sum of dP x P, which is its d_out . out
-    delta = jnp.moveaxis((d_out * out).sum(-1), 1, 2).reshape(b, g, 1, s * r)
+    delta = (d_out * out).sum(-1)
+    halves = q.shape[-1] == HALF
+    if halves:
+        q, d_out = _paired(q), _paired(d_out)
+    b, s, g, r, _ = q.shape
+    delta = jnp.moveaxis(delta.reshape(b, s, g, r), 1, 2).reshape(
+        b, g, 1, s * r)
     dq, dk, dv = _backward_call(q.shape, window, block, interpret)(
-        q, k.reshape(b, s, g * d), v.reshape(b, s, g * d), d_out, lse, delta)
-    return dq, dk.reshape(k.shape), dv.reshape(v.shape)
+        q, k.reshape(b, s, -1), v.reshape(b, s, -1), d_out, lse, delta)
+    return ((_own_halves(dq) if halves else dq), dk.reshape(k.shape),
+            dv.reshape(v.shape))
 
 
 attend.defvjp(_attend_fwd, _attend_bwd)

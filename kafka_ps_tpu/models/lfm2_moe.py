@@ -39,10 +39,10 @@ the other five families.  This family's own:
     rotate-half RoPE over all the channels (`rope_parameters`: one
     theta, `default`); scores / sqrt(64), query i sees key j iff `j <=
     i`; `out = softmax(scores) v W_o`; no bias, no window, no gate.
-    At 64 channels neither `attention_kernel` nor `norm_rope_kernel`
-    takes the shapes (whole lanes of 128 only): the core runs as
-    `lm.blocked_attention`'s plain tiles and the norm and RoPE as the
-    plain lines, on a TPU too;
+    At 64 channels the core rides `attention_kernel` two heads to a
+    lane vector (8 KV heads: four pairs); `norm_rope_kernel` takes
+    whole lanes of 128 only, so the norm and RoPE run as the plain
+    lines, on a TPU too;
   * a sigmoid router with a selection bias (`route`): scores over ALL
     `num_experts`, the `num_experts_per_tok` largest of score + bias,
     weights the scores at the chosen over (their sum + 1e-6), times

@@ -311,7 +311,8 @@ def blocked_attention(q, k, v, *, window: int | None, block: int,
 
     One algorithm, two ways to run it, and the input says which.  On a
     TPU, at shapes the kernel takes (`attention_kernel.takes`: a
-    `head_dim` and a `block` of whole lanes, 128), the core is
+    `head_dim` and a `block` of whole lanes, 128, or a `head_dim` of
+    64 under an even G, two heads to a lane vector), the core is
     `attention_kernel.attend`: a block's scores are formed once, in
     VMEM, under a running maximum, and only the output and the rows'
     log-sum-exp are written.  Anywhere else — another platform, a

@@ -1,6 +1,7 @@
 """The `lfm2-24b-a2b-ep8.fused-bsp` cell's scan chunk, compiled once
 for a described TPU v5e (tests/aot_described.py)."""
 
+import math
 import re
 
 import aot_described as described
@@ -10,27 +11,34 @@ from kafka_ps_tpu.models import lm_common as lm
 CELL = ("lfm2_moe", "benchmark/configs/lfm2-24b-a2b-ep8.model.json")
 
 
-def test_lfm2s_chunk_runs_its_attention_off_both_kernels(aot, chunk):
+def test_lfm2s_core_is_the_kernel_two_heads_to_a_lane_vector(aot, chunk):
     """469.3 M parameters held, 8 of 64 experts, 1 row of 4,096 tokens
-    a worker.  The leaves are donated and the scratch is 5,860,914,176
-    bytes when written (5.86 + 1.88 GB of leaves = 7.74 GB, 16.5 bytes
-    a parameter, PR 45).  At 8,192-token rows the same chunk compiled
-    to 7.47 GB of scratch + the 1.88 GB of leaves, 9.35 GB (compiled
-    once by hand with scripts/aot_v5e_hlo.py, PR 45, not here): it
-    fits, and what keeps such rows out of the cell is the plain core's
-    time, not its bytes.
+    a worker.  The leaves are donated and the scratch is 5,838,093,824
+    bytes when written (5.84 + 1.88 GB of leaves = 7.72 GB, 16.4 bytes
+    a parameter; 5,860,914,176 with the plain tiles, PR 45).  At
+    8,192-token rows the plain-tiled chunk compiled to 7.47 GB of
+    scratch + the 1.88 GB of leaves, 9.35 GB (compiled once by hand
+    with scripts/aot_v5e_hlo.py, PR 45, not here): it fits.
 
-    THE PIN A LATER PR FLIPS: heads of 64 channels are half a lane
-    vector, `attention_kernel.takes` and `norm_rope_kernel.takes` take
-    whole lanes only, and so NO call of the attention kernel and NO
-    call of the norm-and-RoPE kernel is in the chunk, lowered for the
-    chip.  The core is `lm_common._attend_tiles`: a tile's scores `[8,
-    4, 512, L]`, L the tile's span of keys from 512 to 4,096, are
-    written to memory — the largest 268 MB — where the kernel forms
-    them in VMEM; no array of S x S elements a head exists all the
-    same.  And the plain `rope(rms_norm(..))` cuts a 64-channel head at
-    32: the compiler lays q tokens-minor for it (`f32[1,4096,32,64]
-    {1,3,2,0}`, PR 43's finding, a hundred times in the text).
+    THE PIN PR 46 FLIPPED: heads of 64 channels are half a lane vector,
+    and under an even number of KV heads `attention_kernel.takes` takes
+    them two to a vector — q `[1, 4096, 8, 4, 64]` goes through the
+    kernels as `[1, 4096, 4, 8, 128]`, the Trinity cell's shape — so,
+    lowered for the chip, the ONE attention layer's core is Mosaic
+    calls under `kps.attn.full`: a forward one a pass, recomputed with
+    the layer in a gradient pass (2 x 2 + the loss's = 5), and a
+    backward one a gradient pass (2).  That Mosaic lowers them at this
+    shape is shown here without the chip.  No array of a tile's scores
+    `[8, 4, 512, L]` is in the program (the plain tiles wrote them to
+    memory, the largest 268 MB) and none of S x S elements a head; the
+    largest array under the scope is a q, a cotangent or an output with
+    its zero halves, `f32[1,4096,4,8,128]`, twice q's bytes.
+
+    `norm_rope_kernel.takes` still takes whole lanes only: NO call of
+    the norm-and-RoPE kernel is in the chunk, and the plain
+    `rope(rms_norm(..))` cuts a 64-channel head at 32: the compiler
+    lays q tokens-minor for it (`f32[1,4096,32,64]{1,3,2,0}`, PR 43's
+    finding, a hundred times in the text).
 
     Every grouped product — the three of a SwiGLU expert, their dx and
     dW, under the bound's 4,096 rows and over it at 16,384 — runs the
@@ -42,15 +50,22 @@ def test_lfm2s_chunk_runs_its_attention_off_both_kernels(aot, chunk):
     s, block = c.sequence_length, c.attention_block
     assert (s, block, c.head_dim) == (4096, 512, 64)
     assert (c.layers("conv"), c.layers("full_attention")) == (4, 1)
-    assert "kps_attn_core_" not in chunk.text
+    calls = described.mosaic_calls(chunk.text, "kps_attn_core_")
+    assert described.by_kernel_and_scope(calls, described.CORE_SCOPES) == {
+        ("kps_attn_core_forward", "kps.attn.full"): 5,
+        ("kps_attn_core_backward", "kps.attn.full"): 2}
+    assert {made for _, made, *_ in calls} == {"f32[1,4096,4,8,128]"}
     assert "kps_norm_rope_" not in chunk.text
-    assert "tpu_custom_call" in chunk.text      # the reader sees kernels
     shapes = described.shapes_made(chunk.text)
     assert not described.square_of_scores(shapes, s)
-    # a tile's scores, every span from one block to the whole row
-    tiles = {sh[-1] for sh in shapes
-             if sh[-4:-1] == (8, 4, block) and sh[-1] % block == 0}
-    assert tiles == set(range(block, s + 1, block))
+    # no tile's scores, of any span from one block to the whole row
+    assert not [sh for sh in shapes
+                if sh[-4:-1] == (8, 4, block) and sh[-1] % block == 0]
+    made = described.shapes_made("\n".join(
+        line for line in chunk.text.splitlines()
+        if "kps.attn.full" in line), "f32")
+    assert made and max(math.prod(sh) for sh in made) \
+        == 2 * s * c.num_attention_heads * c.head_dim
     # q laid tokens-minor for the half-lane slices of the plain RoPE
     assert re.search(r"f32\[1,4096,32,64\]\{1,3,2,0", chunk.text)
     slots = s * c.num_experts_per_tok
@@ -87,10 +102,11 @@ def test_lfm2s_placement_is_the_kernels_and_no_matrix(chunk):
 
 
 def test_lfm2s_norm_and_rope_are_the_plain_lines(chunk):
-    """691 instructions under `kps.attn.norm_rope` when written, for
+    """652 instructions under `kps.attn.norm_rope` when written, for
     ONE attention layer: slices, negations, concatenations and the
-    copies the compiler makes for them."""
-    described.norm_and_rope_are_the_plain_lines(chunk, 691)
+    copies the compiler makes for them (691 when the plain tiles read
+    q and k, PR 45)."""
+    described.norm_and_rope_are_the_plain_lines(chunk, 652)
 
 
 def test_what_no_scope_names_is_under_a_tenth_of_lfm2s_bytes(chunk):

@@ -1,8 +1,8 @@
 """The attention core as a kernel (models/attention_kernel.py) in
 Pallas's interpreter on the CPU, at sizes the kernel takes — head_dim
-128, tiles of 128 — against the masked `[S, S]` definition
-(tests/test_afmoe.py `defined`), and which of the two cores
-`lm_common.blocked_attention` traces at which size.
+128, and 64 two heads to a lane vector, tiles of 128 — against the
+masked `[S, S]` definition (tests/test_afmoe.py `defined`), and which
+of the two cores `lm_common.blocked_attention` traces at which size.
 
 The kernel rounds each product's operands to bfloat16 (the chip's
 default precision for a float32 product), so against the float32
@@ -26,30 +26,38 @@ KERNEL_BLOCK, KERNEL_DIM = 128, 128
 
 
 def kernel_core(q, k, v, window):
-    return attention_kernel.attend(q / np.sqrt(KERNEL_DIM), k, v, window,
+    return attention_kernel.attend(q / np.sqrt(q.shape[-1]), k, v, window,
                                    KERNEL_BLOCK, True)
 
 
-@pytest.mark.parametrize("heads", [1, 3])
+@pytest.mark.parametrize("shape", [
+    # (head_dim, KV heads, query heads a KV head)
+    (128, 2, 1), (128, 2, 3),
+    # half a lane vector: two KV heads' query heads to a vector
+    (64, 2, 1), (64, 2, 3), (64, 4, 4)],
+    ids=lambda shape: "d%d-g%d-r%d" % shape)
 @pytest.mark.parametrize("window", [
     256,            # a whole number of blocks
     200, 72,        # and not: the band's edge cuts a block
     None])          # a full layer
 @pytest.mark.parametrize("windows", [1, 1.5, 3])
-def test_the_kernel_is_the_masked_definition(windows, window, heads):
+def test_the_kernel_is_the_masked_definition(windows, window, shape):
     """`attention_kernel.attend` in interpret mode against the
     definition, values and all three gradients, on rows of 1, 1.5 and 3
     times 256 tokens, sliding with a window that is and is not a whole
-    number of blocks and full, 1 and 3 query heads a KV head.  And to
-    the last digit where no rounding is: with every score 0 a query's
-    output is the mean of the values it sees, and with one channel a
-    key position (mod 128) set to 1 that mean COUNTS the keys seen."""
+    number of blocks and full, 1, 3 and 4 query heads a KV head, heads
+    of a whole lane vector and of half of one (2 and 4 KV heads: one
+    pair and two).  And to the last digit where no rounding is: with
+    every score 0 a query's output is the mean of the values it sees,
+    and with one channel a key position (mod the head's channels) set
+    to 1 that mean COUNTS the keys seen."""
     s = int(windows * 256)
-    assert attention_kernel.takes((1, s, 2, heads, KERNEL_DIM), KERNEL_BLOCK)
+    dim, groups, heads = shape
+    assert attention_kernel.takes((1, s, groups, heads, dim), KERNEL_BLOCK)
     rng = np.random.default_rng(s + heads)
-    q = jnp.asarray(rng.standard_normal((1, s, 2, heads, KERNEL_DIM)),
+    q = jnp.asarray(rng.standard_normal((1, s, groups, heads, dim)),
                     jnp.float32)
-    k, v = (jnp.asarray(rng.standard_normal((1, s, 2, KERNEL_DIM)),
+    k, v = (jnp.asarray(rng.standard_normal((1, s, groups, dim)),
                         jnp.float32) for _ in range(2))
     seen = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
 
@@ -63,12 +71,38 @@ def test_the_kernel_is_the_masked_definition(windows, window, heads):
         assert float(jnp.max(jnp.abs(a - b))) <= 0.015 * float(
             jnp.max(jnp.abs(b)))
     counting = jnp.broadcast_to(jnp.asarray(
-        np.arange(s)[:, None] % KERNEL_DIM == np.arange(KERNEL_DIM),
+        np.arange(s)[:, None] % dim == np.arange(dim),
         jnp.float32)[None, :, None], v.shape)
     np.testing.assert_allclose(
         np.asarray(kernel_core(jnp.zeros_like(q), k, counting, window)),
         np.asarray(defined(jnp.zeros_like(q), k, counting, window)),
         rtol=1e-6, atol=0)
+
+
+@pytest.mark.parametrize("q_shape,block,taken", [
+    ((1, 4096, 4, 8, 128), 512, True),      # the Trinity cell's
+    ((1, 4096, 8, 4, 64), 512, True),       # the LFM2 cell's: run as that
+    ((1, 256, 2, 3, 64), 128, True),
+    ((1, 256, 3, 2, 64), 128, False),       # a head without a neighbour
+    ((1, 256, 1, 2, 64), 128, False),
+    ((1, 256, 2, 2, 32), 128, False),
+    ((1, 256, 2, 2, 96), 128, False),
+    ((1, 256, 2, 2, 8), 128, False),
+    ((1, 256, 2, 2, 256), 128, True),
+    ((1, 256, 2, 2, 128), 64, False),       # half a lane vector of keys
+    ((1, 256, 2, 2, 64), 64, False),
+    ((1, 384, 2, 2, 64), 256, False),       # the tile does not divide the row
+    ((1, 16384, 2, 2, 128), 512, True),     # a head's dk and dv: 2 M elements
+    ((1, 32768, 2, 2, 128), 512, False),
+    ((1, 16384, 2, 2, 64), 512, True),      # a pair's: a lane vector wide
+    ((1, 32768, 2, 2, 64), 512, False)])
+def test_what_the_kernel_takes(q_shape, block, taken):
+    """Heads of whole lane vectors, and of half of one where the KV
+    heads come in pairs — nothing else under 128 channels; tiles of
+    whole lanes that divide the row; a row whose resident dk and dv, a
+    lane vector wide for a pair, stay within RESIDENT_ELEMENTS."""
+    assert attention_kernel.takes(q_shape, block) is taken
+    assert int(lm.kernel_attends(q_shape, block)) == 0     # on the CPU
 
 
 def test_the_kernel_reads_no_key_outside_a_tiles_span():
@@ -138,14 +172,15 @@ def test_sizes_the_kernel_does_not_take_trace_the_plain_program(program):
         == stated["programs"][program]
 
 
-def test_the_input_says_which_core_runs(request):
-    """At a size the kernel takes the program branches on the platform:
-    lowered for the CPU it holds the plain tiles and no kernel; with the
-    TPU's branch taken (the test's own steering: no option of the
-    program does this) the same call is the kernel, and
-    `kernel_attends` counts it."""
-    q = jax.ShapeDtypeStruct((1, 256, 1, 2, KERNEL_DIM), jnp.float32)
-    kv = jax.ShapeDtypeStruct((1, 256, 1, KERNEL_DIM), jnp.float32)
+@pytest.mark.parametrize("groups,dim", [(1, KERNEL_DIM), (2, 64)])
+def test_the_input_says_which_core_runs(request, groups, dim):
+    """At a size the kernel takes — heads of 128 channels, and of 64 in
+    pairs — the program branches on the platform: lowered for the CPU
+    it holds the plain tiles and no kernel; with the TPU's branch taken
+    (the test's own steering: no option of the program does this) the
+    same call is the kernel, and `kernel_attends` counts it."""
+    q = jax.ShapeDtypeStruct((1, 256, groups, 2, dim), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, 256, groups, dim), jnp.float32)
 
     def core(q, k, v):
         return (lm.blocked_attention(q, k, v, window=200,

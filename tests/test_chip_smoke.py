@@ -3,6 +3,7 @@ and its phase functions — the same code the chip run executes — hold at
 a tiny width.  Plus the start-up contract the
 script shares with every CLI entry: the compile-cache hook."""
 
+import dataclasses
 import json
 import os
 import shutil
@@ -22,7 +23,8 @@ TINY = chip_smoke.Sizes(
     fused_rounds=16, multichip_rounds=8,
     center_scale=1.0,           # too few rows to learn the hard regime
     grouped_rows=128, grouped_widths=(640, 384),    # the rule still hints
-    core_shape=(1, 384, 1, 2, 128), core_window=200, core_block=128,
+    core_shape=(1, 384, 1, 2, 128), core_shape_halves=(1, 384, 2, 2, 64),
+    core_window=200, core_block=128,
     core_calls=1,
     placement_shape=(512, 1024, 128), placement_groups=(90, 0, 37, 60),
     norm_rope_positions=40, norm_rope_heads=(8, 2))
@@ -86,10 +88,13 @@ def test_grouped_products_phase():
             "cpu")
 
 
-def test_attention_core_phase():
-    """The kernel in Pallas's interpreter against the plain tiles: the
-    gaps are bfloat16's, the times are the CPU's and mean nothing."""
-    rec = chip_smoke.phase_attention_core(TINY, "cpu", interpret=True)
+@pytest.mark.parametrize("shape", [TINY.core_shape, TINY.core_shape_halves])
+def test_attention_core_phase(shape):
+    """The kernel in Pallas's interpreter against the plain tiles, at
+    heads of a lane vector and of half of one: the gaps are bfloat16's,
+    the times are the CPU's and mean nothing."""
+    rec = chip_smoke.phase_attention_core(
+        dataclasses.replace(TINY, core_shape=shape), "cpu", interpret=True)
     for kind in ("window", "full"):
         for what in ("out", "dq", "dk", "dv"):
             assert 0 < rec[f"{kind}_{what}_gap"] < 0.02

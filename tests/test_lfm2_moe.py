@@ -556,21 +556,23 @@ def test_the_cells_expert_layer_is_trinitys_shape():
 
 # -- the counters ----------------------------------------------------------------
 
-def test_the_counters_at_the_cells_size_and_no_kernel_at_heads_of_64(
+def test_the_counters_at_the_cells_size_and_the_kernel_takes_heads_of_64(
         request):
     """A pass over a worker's slab at the published widths, from shapes
     alone: the full layer's triangle of 4,096 tokens, q's and k's head
     rows of the ONE attention layer, 16 units of 1,024 positions through
     the four conv layers' chains (48 an update, 1,536 a chunk of 32
-    updates) — and, the pin a later PR flips: at heads of 64 channels
-    neither the attention kernel nor the norm-and-RoPE kernel takes the
-    shapes, so their counters read 0 with the TPU's branch taken too."""
+    updates).  Heads of 64 channels ride the attention kernel two to a
+    lane vector (PR 46: 8 KV heads, an even number), so with the TPU's
+    branch taken `attn.kernel_block_pairs` is `attn.block_pairs`, and 0
+    on the CPU; the norm-and-RoPE kernel still takes whole lanes only,
+    and its counter reads 0 on both."""
     task = get_task("lfm2_moe", ModelConfig(model_json=PUBLISHED))
     c = task.arch
     q_shape = (1, c.sequence_length, c.num_key_value_heads,
                c.num_attention_heads // c.num_key_value_heads, c.head_dim)
-    assert q_shape == (1, 4096, 8, 4, 64)
-    assert not attention_kernel.takes(q_shape, c.attention_block)
+    assert (q_shape, c.attention_block) == ((1, 4096, 8, 4, 64), 512)
+    assert attention_kernel.takes(q_shape, c.attention_block)
     assert not norm_rope_kernel.takes((1, 4096, 32, 64))
     assert attention_kernel.takes(q_shape[:-1] + (128,), c.attention_block)
     slab = jax.ShapeDtypeStruct((1, task.row_width), jnp.int32)
@@ -578,15 +580,16 @@ def test_the_counters_at_the_cells_size_and_no_kernel_at_heads_of_64(
             4096 * (32 + 8) // 1024, 0, 16)
     assert lfm2_moe.pair_counts(c) == (0, 8_390_656, 9_437_184)
     assert tuple(int(n) for n in task.own_counts(slab)) == want
-    request.getfixturevalue("the_tpus_branch")
-    assert tuple(int(n) for n in task.own_counts(slab)) == want
     assert dict(zip(task.counter_names[len(lm.COUNTERS):], want)) == {
         "attn.pairs_window": 0, "attn.pairs_full": 8194,
         "attn.block_pairs": 9216, "attn.kernel_block_pairs": 0,
         "attn.norm_rope_rows": 160, "attn.norm_rope_kernel_rows": 0,
         "conv.mix_rows": 16}
+    request.getfixturevalue("the_tpus_branch")
+    on_the_chip = want[:3] + (9216,) + want[4:]
+    assert tuple(int(n) for n in task.own_counts(slab)) == on_the_chip
     # int32 a dispatch: a chunk of 32 updates x 3 passes
-    assert 32 * 3 * max(want) < 2 ** 31
+    assert 32 * 3 * max(on_the_chip) < 2 ** 31
 
 
 def test_the_counters_count_through_fit_counted_at_rows_of_a_unit(tmp_path):
