@@ -99,19 +99,34 @@ def _make_folded_round(task, num_workers: int, server_lr: float):
     """One BSP clock on the parameters' leaves, one worker at a time:
     (leaves, x, encoded, mask) -> (leaves', mean loss, counters).
     Alive at once: the shared leaves, one worker's working copy and
-    gradient, and the running sum of deltas."""
+    gradient, and the running sum of deltas.
+
+    A barrier in the worker loop ties the shared leaves to a value that
+    changes from worker to worker (see `worker`).  Until PR 47 that
+    value was the running sum, and the sum paid for the ride: the
+    compiler sinks the scan's zero start into the body as
+    `select(i == 0, 0, total)`, a barrier fuses with nothing, and so
+    every worker update read the whole sum and wrote it back (zeroed
+    for the first worker) before `kps.fit.delta` read and wrote it
+    again — 83 `broadcast_select_fusion`s and 2.365 GB of results in
+    the GLM cell's chunk, 5.7 to 8.3 ms of every language-model update
+    (PERF.md section 6, PR 47).  The sum now goes from the loop's state
+    straight into its one consumer, and the select rides in that
+    fusion."""
 
     def round_(leaves, x, encoded, mask):
         def worker(carry, slab):
             total, loss_sum, counted = carry
-            # the shared leaves are tied to the running sum, which
-            # changes from worker to worker: left loop-invariant, every
-            # relayout of a weight for the first local step is hoisted
-            # out of the loop and kept beside the leaves — two more
-            # copies of the parameters at the published widths
+            # the shared leaves are tied to the running LOSS, a scalar
+            # that changes from worker to worker: left loop-invariant,
+            # every relayout of a weight for the first local step is
+            # hoisted out of the loop and kept beside the leaves — two
+            # more copies of the parameters at the published widths.
+            # The running sum of deltas stays out of the barrier: what
+            # goes through one is written to memory first
             with jax.named_scope("kps.bsp.carry"):
-                shared, total = jax.lax.optimization_barrier(
-                    (leaves, total))
+                shared, loss_sum = jax.lax.optimization_barrier(
+                    (leaves, loss_sum))
             new, loss, counts = task.fit_counted(shared, *slab)
             with jax.named_scope("kps.fit.delta"):
                 total = jax.tree.map(lambda t, n, o: t + (n - o),
@@ -120,9 +135,11 @@ def _make_folded_round(task, num_workers: int, server_lr: float):
 
         # the loop's own time has a name of its own — what lies under
         # `kps.bsp.fold` and no scope of the solver's is the fold's:
-        # the running sum's zeros, the slabs sliced for a worker
-        # (benchmark/self_time.py) — and so have the copies the
-        # barrier above costs, `kps.bsp.carry`
+        # the slabs sliced for a worker (benchmark/self_time.py).  The
+        # running sum's zeros are no array: the compiler sinks them
+        # into the body, where they are a select inside
+        # `kps.fit.delta`'s fusion; `kps.bsp.carry` holds the barrier's
+        # tuple elements and the copies of a few small leaves
         with jax.named_scope("kps.bsp.fold"):
             zero = (jax.tree.map(jnp.zeros_like, leaves), jnp.float32(0.0),
                     jnp.zeros((len(task.counter_names),), jnp.int32))

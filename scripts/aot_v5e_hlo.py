@@ -148,11 +148,15 @@ def _result_bytes(dtype: str, dims: str) -> int:
     return size
 
 
+def _fused_computations(hlo_text: str) -> set[str]:
+    return set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", hlo_text))
+
+
 def top_level_instructions(hlo_text: str):
     """(computation, name, line, result bytes, XLA's estimated cycles)
     for every instruction that is not inside a fused computation: what
     the device runs one after another."""
-    fused = set(re.findall(r"fusion\(.*?calls=%?([\w.\-]+)", hlo_text))
+    fused = _fused_computations(hlo_text)
     out = []
     for comp, lines in computations(hlo_text).items():
         for line in lines:
@@ -233,6 +237,106 @@ def made_before(hlo_text: str, dtype: str, dims: tuple, until: str):
     raise ValueError(f"no instruction's op_name matches {until!r}")
 
 
+def called_from(comps: dict, root: str) -> set[str]:
+    """`root` and every computation it reaches: the bodies and
+    conditions of its loops, the branches of its conditionals, its
+    fusions and calls."""
+    seen, todo = set(), [root]
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += re.findall(
+                r"(?:body|condition|to_apply|calls)=%?([\w.\-]+)", line)
+            for branches in re.findall(r"branch_computations=\{([^}]*)\}",
+                                       line):
+                todo += [b.strip().lstrip("%") for b in branches.split(",")]
+    return seen
+
+
+# `_RESULT`, then the layout's order of the axes, the opcode, the operands
+_MADE = re.compile(_RESULT.pattern
+                   + r"(?:\{([\d,]*))?\S* ([\w\-]+)\((.*)")
+_RELAYS = ("convert", "transpose", "copy")
+
+
+def _dims(text: str) -> tuple:
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+def worker_loop(comps: dict) -> set[str]:
+    """The computations of the folded round's loop over the workers
+    (parallel/bsp.py `_make_folded_round`): the body of the one `while`
+    traced right under `kps.bsp.fold`, and all it calls."""
+    bodies = [re.search(r"body=%?([\w.\-]+)", line).group(1)
+              for lines in comps.values() for line in lines
+              if " while(" in line
+              and re.search(r'op_name="[^"]*kps\.bsp\.fold/while"', line)]
+    if len(bodies) != 1:
+        raise ValueError(f"the fold's loop over the workers: {bodies}")
+    return called_from(comps, bodies[0])
+
+
+def under_the_carry(hlo_text: str, leaf_shapes):
+    """(name, opcode, result bytes) of what the device runs under
+    `kps.bsp.carry` — the barrier that ties the shared leaves inside
+    the worker loop — with a result of a leaf's shape, tuple elements
+    apart: what a pass through the barrier writes to memory."""
+    shapes = {tuple(s) for s in leaf_shapes}
+    made = []
+    for _, name, line, size, _ in top_level_instructions(hlo_text):
+        m = _MADE.match(line)
+        if "kps.bsp.carry" in line and m and _dims(m.group(3)) in shapes \
+                and m.group(5) != "get-tuple-element":
+            made.append((name, m.group(5), size))
+    return made
+
+
+def relayouts_outside_the_worker_loop(hlo_text: str, leaf_shapes):
+    """(computation, name, opcode) of every instruction OUTSIDE the
+    worker loop that makes an array of a leaf's dimensions, in any
+    order and of any type, by a `convert`, a `transpose` or a `copy`:
+    the instruction itself, a fusion that holds one of that size, or an
+    asynchronous copy whose result is laid out or typed other than its
+    source (a prefetch into faster memory and its way back are not).
+    What the barrier in the worker loop is there against: a relayout or
+    rounding of a weight for a worker's first step, hoisted out of the
+    loop and kept beside the leaves."""
+    comps = computations(hlo_text)
+    weights = {tuple(sorted(s)) for s in leaf_shapes if len(s) > 1}
+    fused = _fused_computations(hlo_text)
+
+    def relays(comp):
+        return [m for m in map(_MADE.match, comps.get(comp, ()))
+                if m and m.group(5) in _RELAYS
+                and tuple(sorted(_dims(m.group(3)))) in weights]
+
+    found = []
+    for comp in comps.keys() - worker_loop(comps) - fused:
+        lines = comps[comp]
+        by_name = {line.split(" = ", 1)[0].split("%")[-1]: line
+                   for line in lines}
+        for m in filter(None, map(_MADE.match, lines)):
+            name, dtype, dims, order, opcode, rest = m.groups()
+            if tuple(sorted(_dims(dims))) not in weights:
+                continue
+            if opcode == "fusion":
+                call = re.search(r"calls=%?([\w.\-]+)", rest)
+                hit = bool(call and relays(call.group(1)))
+            elif opcode == "copy-done":
+                start = by_name.get(rest.split(")")[0].lstrip("%"), "")
+                src = re.search(r" copy-start\(%?([\w.\-]+)", start)
+                src = src and _MADE.match(by_name.get(src.group(1), ""))
+                hit = not src or src.group(2, 3, 4) != (dtype, dims, order)
+            else:
+                hit = opcode in _RELAYS
+            if hit:
+                found.append((comp, name, opcode))
+    return found
+
+
 def ragged_dot_calls(hlo_text: str):
     """((m, k, n), the kernel's tiles "tm,tk,tn") of every grouped
     product the chip's kernel runs: the Mosaic calls `ragged-dot-*`
@@ -289,8 +393,16 @@ def main(argv=None) -> int:
         print(f"== folded chunk of {args.folded[0]}: {task.num_params} "
               f"parameters, scratch {mem.temp_size_in_bytes / 1e9:.4f} GB "
               f"+ donated leaves {mem.alias_size_in_bytes / 1e9:.4f} GB, "
+              f"alive at once {mem.peak_memory_in_bytes / 1e9:.4f} GB, "
               f"compiled in {time.time() - t:.0f} s")
         text = compiled.as_text()
+        shapes = [shape for _, shape in task.specs]
+        passed = under_the_carry(text, shapes)
+        print(f"  under kps.bsp.carry, of a leaf's shape: {len(passed)} "
+              f"instructions {sorted({op for _, op, _ in passed})}, "
+              f"{sum(size for *_, size in passed) / 1e9:.4f} GB of results; "
+              f"weight-shaped relayouts outside the worker loop: "
+              f"{len(relayouts_outside_the_worker_loop(text, shapes))}")
         calls = ragged_dot_calls(text)
         for call in sorted(set(calls)):
             print(f"  {calls.count(call):3d} grouped products "

@@ -100,19 +100,69 @@ def square_of_scores(shapes, s, but=()):
 
 # -- what several families' chunks are held to --------------------------------
 
-def leaves_are_donated_and_fit(chunk, num_params, scratch):
+def leaves_are_donated_and_fit(chunk, num_params, scratch, live,
+                               with_leaves=15.0e9):
     """The leaves are donated (argument and result share their bytes:
     the flat vector is no argument and no result of the chunk), scratch
-    + donated leaves stay under 15.0 GB of the chip's 16.9, and the
+    + donated leaves stay under 15.0 GB of the chip's 16.9 (or under
+    `with_leaves`, a reading seen to run the cell on the chip), and the
     scratch under the cell's own limit: PR 27's findings — leaves cut
     without a barrier, the local steps as a scan, the shared leaves
     loop-invariant in the fold over the workers — each cost one to two
-    more copies of the parameters, which no cell's limit holds."""
+    more copies of the parameters, which no cell's limit holds.
+
+    `scratch` holds `temp_size_in_bytes`, what the harness prints as
+    `memory_scratch_bytes`; `live` holds `peak_memory_in_bytes`, the
+    compiler's own count of what is alive at once, arguments and all:
+    the parent's to the byte, or to 1,536 of them, in every family
+    where PR 47 raised `scratch`.  The two part where a buffer lives
+    long: the first is libtpu's heap AND its fragmentation once more
+    (PERF.md section 6, PR 47: a toy that fails to fit prints `HLO temp
+    10.19G … 31.0% fragmentation (3.16G)` and reads 13.35 GiB here; the
+    check that refuses a program takes the 10.19), and the fold's
+    running sum keeps its carried buffer for the whole of a worker's
+    update, where the parent rewrote it at the top (a pass over 2.4 GB
+    a worker) and lent the buffer out in between: the heaps are as
+    large (`glm4_moe_lite` 5,587,092,480 → 5,593,531,392 bytes, `ouro`
+    9,033,253,888 → 9,026,962,432), the reading is one array of the
+    parameters higher in every family."""
     assert chunk.task.num_params == num_params
     leaves = 4 * num_params
-    assert chunk.compiled.memory_analysis().alias_size_in_bytes >= leaves
-    assert chunk.scratch + leaves < 15.0e9, chunk.scratch
+    memory = chunk.compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= leaves
+    assert chunk.scratch + leaves < with_leaves, chunk.scratch
     assert chunk.scratch < scratch, chunk.scratch
+    assert memory.peak_memory_in_bytes <= live, memory.peak_memory_in_bytes
+
+
+def the_barrier_ties_the_leaves_and_passes_nothing_else(
+        aot, chunk, relayouts_outside=0):
+    """The barrier in the worker loop (parallel/bsp.py, under
+    `kps.bsp.carry`) takes the shared leaves with the running loss.
+    Until PR 47 it took them with the running SUM of deltas: the scan's
+    zero start, sunk into the body as `select(i == 0, 0, total)`, could
+    fuse with nothing across a barrier and was a pass of its own over
+    every leaf of the sum a worker update — 83
+    `broadcast_select_fusion`s and 2.365 GB of results in
+    `glm4_moe_lite`'s chunk, 91 and 2.450 GB in `ouro`'s, 5.7 to 8.3 ms
+    of an update on the chip.  So under that scope nothing the device
+    runs has a leaf's shape but tuple elements and the `copy` of a few
+    small leaves (ten and 0.085 GB in `glm4_moe_lite`'s, none in
+    `ouro`'s), under a twentieth of the parameters' bytes.
+
+    And what the barrier is for still holds: outside the worker loop no
+    `convert`, `transpose` or `copy` makes an array of a weight's
+    dimensions beyond the `relayouts_outside` the parent's chunk had —
+    left loop-invariant, the leaves' relayouts for a worker's first
+    step are hoisted out and kept beside them, two more copies of the
+    parameters.  Counts from the text, never a time."""
+    shapes = [shape for _, shape in chunk.task.specs]
+    assert "kps.bsp.carry/optimization_barrier" in chunk.text
+    passed = aot.under_the_carry(chunk.text, shapes)
+    assert {opcode for _, opcode, _ in passed} <= {"copy"}, passed
+    assert sum(size for *_, size in passed) < 4 * chunk.task.num_params / 20
+    outside = aot.relayouts_outside_the_worker_loop(chunk.text, shapes)
+    assert len(outside) == relayouts_outside, outside
 
 
 def taken_branch_writes_no_zeros_for_the_other(aot, chunk, slots):

@@ -13,7 +13,11 @@ CELL = ("lfm2_moe", "benchmark/configs/lfm2-24b-a2b-ep8.model.json")
 
 def test_lfm2s_core_is_the_kernel_two_heads_to_a_lane_vector(aot, chunk):
     """469.3 M parameters held, 8 of 64 experts, 1 row of 4,096 tokens
-    a worker.  The leaves are donated and the scratch is 5,838,093,824
+    a worker.  The leaves are donated and the scratch is 7,698,161,664
+    bytes since the fold's running sum stays out of the barrier (PR 47:
+    the limit is that reading and a tenth; the reading counts the sum's
+    carried buffer twice, what is alive at once is 7,366,776,320 bytes,
+    1,536 over the parent's, tests/aot_described.py); 5,838,093,824
     bytes when written (5.84 + 1.88 GB of leaves = 7.72 GB, 16.4 bytes
     a parameter; 5,860,914,176 with the plain tiles, PR 45).  At
     8,192-token rows the plain-tiled chunk compiled to 7.47 GB of
@@ -45,7 +49,8 @@ def test_lfm2s_core_is_the_kernel_two_heads_to_a_lane_vector(aot, chunk):
     chip's kernel in the compiler's own tiles of 512: 2048 and 1536 are
     widths 512 divides, so `grouped_tiles` says nothing, as at the GLM
     and Trinity cells'.  About 115 s."""
-    described.leaves_are_donated_and_fit(chunk, 469_285_248, 6.0e9)
+    described.leaves_are_donated_and_fit(chunk, 469_285_248, 8.47e9,
+                                         7_366_776_320)
     c = chunk.task.arch
     s, block = c.sequence_length, c.attention_block
     assert (s, block, c.head_dim) == (4096, 512, 64)
@@ -90,6 +95,15 @@ def test_lfm2s_core_is_the_kernel_two_heads_to_a_lane_vector(aot, chunk):
 
 def test_lfm2s_taken_branch_of_the_bound_writes_no_zeros(aot, chunk):
     described.taken_branch_writes_no_zeros_for_the_other(aot, chunk, 16384)
+
+
+def test_lfm2s_barrier_ties_the_leaves_and_passes_nothing_else(aot, chunk):
+    """The parent's chunk ran 53 selects of the running sum, 1.877 GB of
+    results, under the barrier's scope; one copy of a small leaf (8 MB)
+    stays.  Four copies of a weight's shape stand in the entry
+    computation, once a dispatch, as in the parent's."""
+    described.the_barrier_ties_the_leaves_and_passes_nothing_else(
+        aot, chunk, relayouts_outside=4)
 
 
 def test_lfm2s_placement_is_the_kernels_and_no_matrix(chunk):

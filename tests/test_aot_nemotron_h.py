@@ -12,11 +12,16 @@ def test_nemotron_hs_chunk_fits_the_chip_and_walks_its_widths_in_told_tiles(
         aot, chunk):
     """667.0 M parameters held, 1 row a worker at the cell's own
     sequence length.  The leaves are donated and there is no second
-    copy of the shared leaves: the scratch reads 7.55 GB at the cell's
-    1,024 tokens (7.66 before PR 40; 9.01 GB at 2,048, to the byte what
-    the chip's backend reported, PR 31), a copy of the parameters is
-    2.67 GB, and the limit is the reading and a tenth.  About 90 s."""
-    described.leaves_are_donated_and_fit(chunk, 666_963_456, 8.3e9)
+    copy of the shared leaves: the scratch reads 9.09 GB at the cell's
+    1,024 tokens (7.55 while the fold's running sum went through the
+    barrier, before PR 47: the reading counts the sum's carried buffer
+    twice, what is alive at once is the parent's 8,839,067,136 bytes,
+    tests/aot_described.py; 7.66 before PR 40; 9.01 GB at 2,048, to the
+    byte what the chip's backend reported, PR 31), a copy of the
+    parameters is 2.67 GB, and the limit is the reading and a tenth.
+    About 90 s."""
+    described.leaves_are_donated_and_fit(chunk, 666_963_456, 10.0e9,
+                                         8_839_067_136)
     # every grouped product — the two of an expert, their dx and dW,
     # under the bound's 768 rows and over it at 6,144 — runs the chip's
     # kernel in the tiles `grouped_tiles` states for the call's OWN
@@ -37,6 +42,16 @@ def test_nemotron_hs_chunk_fits_the_chip_and_walks_its_widths_in_told_tiles(
 
 def test_nemotron_hs_taken_branch_of_the_bound_writes_no_zeros(aot, chunk):
     described.taken_branch_writes_no_zeros_for_the_other(aot, chunk, 6144)
+
+
+def test_nemotron_hs_barrier_ties_the_leaves_and_passes_nothing_else(
+        aot, chunk):
+    """The parent's chunk ran 72 selects of the running sum, 2.668 GB of
+    results, under the barrier's scope.  Twelve copies of a weight's
+    shape stand in the entry computation, once a dispatch, as in the
+    parent's."""
+    described.the_barrier_ties_the_leaves_and_passes_nothing_else(
+        aot, chunk, relayouts_outside=12)
 
 
 def test_nemotron_hs_placement_is_left_to_the_product(chunk):

@@ -11,8 +11,16 @@ CELL = ("ouro", "benchmark/configs/ouro-2.6b.model.json")
 def test_ouros_chunk_holds_its_layers_once(chunk):
     """612.4 M parameters held, 8 layers run 4 times on one set of
     leaves, 1 row of 1,024 tokens a worker.  The leaves are donated and
-    scratch + donated leaves stay under 15.0 GB: 10.60 + 2.45 = 13.05
-    GB when written, 21.3 bytes a parameter, as a `lax.scan` over the
+    scratch + donated leaves stay under the 13.034 + 2.450 = 15.48 GB
+    they read since the fold's running sum stays out of the barrier —
+    a reading seen to run the cell on the chip, `correct`, twice and
+    more (PR 47, PERF.md section 6), which is why it stands over the
+    15.0 GB the other families are held to and has no tenth above it.
+    The reading counts the sum's carried buffer twice: the compiler's
+    heap is 9,026,962,432 bytes (9,033,253,888 in the parent) and what
+    is alive at once the parent's 11,366,085,120 to the byte
+    (tests/aot_described.py).  10.60 + 2.45 = 13.05 GB when written,
+    21.3 bytes a parameter, as a `lax.scan` over the
     steps with the layers written out in its body and the leaves closed
     over (73 s); with the 32 applications written out 8.34 + 2.45 GB in
     184-220 s, over what the `afmoe` cell's chunk takes — why the loop
@@ -31,7 +39,9 @@ def test_ouros_chunk_holds_its_layers_once(chunk):
     loop-invariant leaves' bfloat16 roundings and relayouts that the
     compiler hoists out of the forward and of the backward `while`
     (`bf16[2048,5632]` in the loops' state, 0.82 GB each), and 24 more
-    saved inputs.  The limit below holds that cost: under 10.8 GB no
+    saved inputs.  The limits below hold that cost: under 13.04 GB of
+    scratch (10.8 before PR 47, on a reading of 10.60) and
+    11,366,085,120 bytes alive no
     further float32 copy of the layers' leaves fits, so a fourth such
     array fails here without the once-through chunk compiled beside
     this one every run (60 s, until PR 44; scripts/aot_v5e_hlo.py
@@ -43,7 +53,9 @@ def test_ouros_chunk_holds_its_layers_once(chunk):
     layer's call once, so 2 gradient passes x (forward + recomputed) +
     the loss's forward = 5 forward calls a layer and 2 backward.  No
     array of S x S elements a head is in the program.  About 75 s."""
-    described.leaves_are_donated_and_fit(chunk, 612_435_968, 10.8e9)
+    described.leaves_are_donated_and_fit(chunk, 612_435_968, 13.04e9,
+                                         11_366_085_120,
+                                         with_leaves=15.49e9)
     task = chunk.task
     c = task.arch
     s, block = c.sequence_length, c.attention_block
@@ -72,6 +84,15 @@ def test_ouros_chunk_holds_its_layers_once(chunk):
     for scope in ("kps.lm.layers", "kps.attn.qkv", "kps.attn.norm_rope",
                   "kps.attn.out", "kps.mlp", "kps.lm.norm", "kps.lm.head"):
         assert scope in chunk.text, scope
+
+
+def test_ouros_barrier_ties_the_leaves_and_passes_nothing_else(aot, chunk):
+    """The parent's chunk ran 91 selects of the running sum, 2.450 GB of
+    results, under the barrier's scope; nothing of a leaf's shape stays
+    there, and no weight's relayout stands outside the worker loop, as
+    in the parent's."""
+    described.the_barrier_ties_the_leaves_and_passes_nothing_else(
+        aot, chunk)
 
 
 def test_ouros_norm_and_rope_are_the_plain_lines(chunk):

@@ -66,6 +66,43 @@ def test_the_folded_worker_axis_equals_the_vmap_on_the_mlp():
     assert int(counted[0]) == 3 * 4
 
 
+def test_the_folded_chunk_is_the_round_written_plainly_bit_for_bit():
+    """2 clocks of 4 workers through the folded chunk against the round
+    as a Python loop: every worker fits from the clock's shared leaves,
+    the sum starts at zero and takes `new - shared` in the workers'
+    order, the apply adds `server_lr` times it.  To the bit: what ties
+    the shared leaves inside the worker loop (a barrier) and where the
+    sum's zero start ends up in the compiled program (PR 47) are no
+    part of the mathematics."""
+    cfg, x, y, mask = _mlp_inputs()
+    task = _FoldedMLP(cfg)
+    leaves0 = task.unflatten(task.init_params())
+    encoded = task.encode_labels(y)
+    fit = jax.jit(task.fit_counted)
+
+    leaves, want_l = leaves0, []
+    for _ in range(2):
+        total = jax.tree.map(jnp.zeros_like, leaves)
+        loss_sum = jnp.float32(0.0)
+        for w in range(4):
+            new, loss, _ = fit(leaves, x[w], encoded[w], mask[w])
+            total = jax.tree.map(lambda t, n, o: t + (n - o), total, new,
+                                 leaves)
+            loss_sum = loss_sum + loss
+        leaves = jax.tree.map(lambda a, d: a + jnp.float32(0.25) * d,
+                              leaves, total)
+        want_l.append(loss_sum / 4)
+
+    got, got_l, counted = bsp.make_bsp_multi_step(
+        cfg, 4, 0.25, 2, task=task)(jax.tree.map(jnp.copy, leaves0),
+                                    x, y, mask)
+    assert jax.tree.structure(got) == jax.tree.structure(leaves)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(leaves)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(np.asarray(got_l), np.asarray(want_l))
+    assert int(counted[0]) == 2 * 4
+
+
 def test_a_folded_task_has_no_program_over_a_mesh():
     cfg, *_ = _mlp_inputs()
     from kafka_ps_tpu.parallel.mesh import worker_mesh
