@@ -1,12 +1,12 @@
 """What the language-model families share (`glm4_moe_lite`, `nemotron_h`,
-`afmoe`, `ouro`, `mellum`, `lfm2_moe`): token rows, RMSNorm, RoPE, the
-blocked attention core (on a TPU a kernel, `models/attention_kernel.py`,
-where the shapes allow), the sliced head and its loss, the router, the
-expert layer that knows its share, the gated (SwiGLU) expert, the
-counters, the flat key space, the k-step solver with its counts, the
-evaluation, and the task's frame.  A family brings its own configuration,
-its leaves, its blocks and its expert's function; nothing here tests a
-family's name.
+`afmoe`, `ouro`, `mellum`, `lfm2_moe`, `granitemoehybrid`): token rows,
+RMSNorm, RoPE, the blocked attention core (on a TPU a kernel,
+`models/attention_kernel.py`, where the shapes allow), the sliced head and
+its loss, the router, the expert layer that knows its share, the gated
+(SwiGLU) expert, the counters, the flat key space, the k-step solver with
+its counts, the evaluation, and the task's frame.  A family brings its
+own configuration, its leaves, its blocks and its expert's function;
+nothing here tests a family's name.
 
 Token rows are `int32[S + 2]`, whose labels are the row itself,
 shifted.  A family's configuration is one JSON file (`--model_json`,

@@ -37,8 +37,9 @@ written once, in `FlatFace`, from the members a family owns.
 Every entry point (runtime worker, fused BSP step, range-sharded step,
 server eval) dispatches through a task; `logreg` stays the default —
 the reference's model — `mlp` is a second classifier, and
-`glm4_moe_lite`, `nemotron_h`, `afmoe`, `ouro`, `mellum` and `lfm2_moe` are
-language models over token rows (models/lm_common.py has what they share).
+`glm4_moe_lite`, `nemotron_h`, `afmoe`, `ouro`, `mellum`, `lfm2_moe` and
+`granitemoehybrid` are language models over token rows (models/lm_common.py
+has what they share).
 
 What a family says of itself, for whoever has to refuse a lever before
 any program is built (cli/run.py): `model_file` (its widths are a file
@@ -266,7 +267,9 @@ _LATE = {"mlp": ("kafka_ps_tpu.models.mlp", "MLPTask"),
          "afmoe": ("kafka_ps_tpu.models.afmoe", "AfmoeTask"),
          "ouro": ("kafka_ps_tpu.models.ouro", "OuroTask"),
          "mellum": ("kafka_ps_tpu.models.mellum", "MellumTask"),
-         "lfm2_moe": ("kafka_ps_tpu.models.lfm2_moe", "Lfm2MoeTask")}
+         "lfm2_moe": ("kafka_ps_tpu.models.lfm2_moe", "Lfm2MoeTask"),
+         "granitemoehybrid": ("kafka_ps_tpu.models.granite_hybrid",
+                              "GraniteHybridTask")}
 
 
 def default_task(cfg: ModelConfig) -> "MLTask":

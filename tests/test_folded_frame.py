@@ -247,7 +247,7 @@ def test_the_parser_holds_75_option_strings_and_every_task_by_name():
     task_flag = next(a for a in parser._actions if a.dest == "task")
     assert task_flag.choices == task_names() == [
         "logreg", "mlp", "glm4_moe_lite", "nemotron_h", "afmoe", "ouro",
-        "mellum", "lfm2_moe"]
+        "mellum", "lfm2_moe", "granitemoehybrid"]
     said = next(a for a in parser._actions if a.dest == "model_json").help
     for name in task_names():
         assert (name in said) == bool(task_class(name).model_file), name
