@@ -29,6 +29,10 @@ the models the repo supports, on data made from a seed:
     matrix written out, at the fifth language-model cell's shape: to
     the bit where a row has one term, NaN in the dead rows, the ms a
     call of each;
+  * the chunked state-space scan as its kernels
+    (`models/ssd_kernel.py`) against the einsums at the seventh and the
+    second language-model cells' shapes, forward and `jax.grad` of all
+    six inputs: the gaps and the ms a call of each;
   * with more than one chip: `--fused -r` and `--fused --param_shards`
     over all of them.
 
@@ -96,6 +100,10 @@ class Sizes:
     norm_rope_positions: int = 4096
     norm_rope_heads: tuple = (32, 4)
     norm_rope_dim: int = 128
+    # the state-space scan at the seventh and second language-model
+    # cells' shapes: (x `[B, S, heads, P]`, groups, state, chunk)
+    scan_shapes: tuple = (((1, 2048, 64, 64), 1, 128, 256),
+                          ((1, 1024, 64, 64), 8, 128, 128))
 
 
 class SmokeFailure(RuntimeError):
@@ -516,6 +524,83 @@ def phase_norm_rope(sizes: Sizes, platform: str, *,
     return rec
 
 
+def phase_ssd_scan(sizes: Sizes, platform: str, *,
+                   interpret: bool = False) -> dict:
+    """The chunked state-space scan as the kernels (`ssd_kernel.scan`)
+    against today's einsums (`nemotron_h.chunks_scanned`), both through
+    `nemotron_h.ssd_chunked`, at the seventh and the second
+    language-model cells' shapes — one group read by 64 heads in chunks
+    of 256, eight groups of eight heads in chunks of 128: y and the
+    gradients of all six inputs of both, the largest gap of each as a
+    share of the einsums' largest value (the two round the same
+    operands to bfloat16; the sums' order differs), and the ms a call
+    of each, forward and `jax.grad`.  `interpret` runs the kernels in
+    Pallas's interpreter (the CPU test), where the einsums are float32
+    and the gap is the rounding's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from kafka_ps_tpu.models import nemotron_h, ssd_kernel
+
+    name, started = "ssd_scan", time.time()
+    timed = functools.partial(_timed, sizes.core_calls)
+    rec = {}
+    for shape, groups, state, chunk in sizes.scan_shapes:
+        case = f"g{groups}_q{chunk}"
+        require(ssd_kernel.takes(shape, groups, state, chunk), name,
+                f"the kernel does not take {shape} under {groups} groups "
+                f"of {state} in chunks of {chunk}")
+        rng = np.random.default_rng(0)
+        b, s, h, _ = shape
+        x, seen = (jnp.asarray(rng.standard_normal(shape), jnp.float32)
+                   for _ in range(2))
+        dt = jnp.asarray(rng.uniform(0.001, 0.1, (b, s, h)), jnp.float32)
+        a = -jnp.asarray(rng.uniform(1.0, 16.0, (h,)), jnp.float32)
+        bm, cm = (jnp.asarray(rng.standard_normal((b, s, groups, state)),
+                              jnp.float32) for _ in range(2))
+        d = jnp.asarray(rng.standard_normal((h,)), jnp.float32)
+        require(_platform_of(x) == platform, name,
+                f"the rows live on {_platform_of(x)}")
+
+        def kernels(x, dt, cum, bm, cm, d):
+            bb, nc, q, g, r = cum.shape
+            packed = jnp.concatenate(
+                [m.reshape(bb, nc * q, -1) for m in (x, bm, cm)], axis=-1)
+            return ssd_kernel.scan(packed, dt, cum.reshape(bb, nc * q, g * r),
+                                   d, g, bm.shape[-1], q, interpret)
+
+        def einsums(x, dt, cum, bm, cm, d):
+            return nemotron_h.chunks_scanned(
+                (x * dt[..., None]).reshape(*cum.shape, -1), cum, bm,
+                cm).reshape(shape) + d[:, None] * x
+        got = {}
+        for way, inside in (("kernel", kernels), ("einsums", einsums)):
+            def scan(x, dt, a, bm, cm, d, inside=inside):
+                # `ssd_chunked` with the way to run the chunks stated
+                g, n = bm.shape[2:]
+                nc, r = s // chunk, h // g
+                cum = jnp.cumsum((dt * a).reshape(b, nc, chunk, g, r), axis=2)
+                return inside(x, dt, cum, bm.reshape(b, nc, chunk, g, n),
+                              cm.reshape(b, nc, chunk, g, n), d)
+            out, rec[f"{case}_{way}_forward_ms"] = timed(
+                jax.jit(scan), x, dt, a, bm, cm, d)
+            grads, rec[f"{case}_{way}_grad_ms"] = timed(jax.jit(jax.grad(
+                lambda *args: jnp.sum(scan(*args) * seen),
+                argnums=(0, 1, 2, 3, 4, 5))), x, dt, a, bm, cm, d)
+            got[way] = (out, *grads)
+        for what, u, v in zip(("y", "dx", "ddt", "da", "db", "dc", "dd"),
+                              got["kernel"], got["einsums"]):
+            u, v = np.asarray(u), np.asarray(v)
+            gap = float(np.abs(u - v).max() / np.abs(v).max())
+            require(np.isfinite(u).all() and gap <= 0.05, name,
+                    f"{case} {what}: the kernels are off the einsums' by "
+                    f"{gap} of their largest")
+            rec[f"{case}_{what}_gap"] = gap
+    rec["wall_s"] = round(time.time() - started, 2)
+    return rec
+
+
 def phase_placement_products(sizes: Sizes, platform: str, *,
                              interpret: bool = False) -> dict:
     """The expert layer's two products with its 0/1 placement matrix as
@@ -689,6 +774,7 @@ def run_phases(sizes: Sizes, platform: str, device_count: int,
         platform)
     phases["norm_rope"] = phase_norm_rope(sizes, platform)
     phases["placement_products"] = phase_placement_products(sizes, platform)
+    phases["ssd_scan"] = phase_ssd_scan(sizes, platform)
     if device_count > 1:
         phases.update(phase_multichip(workdir, train, test, sizes,
                                       platform, device_count))

@@ -21,9 +21,12 @@ task's frame are `models/lm_common.py`'s, shared with the other six
 families; the Mamba-2 mixer is `models/nemotron_h.py`'s, whole
 (`mamba2`: `[z | xBC | dt] = u W_in`, the causal 4-tap convolution with
 bias and `silu`, `ssd_chunked`'s recurrence in chunks of
-`mamba_chunk_size`, the `D` term, the gate BEFORE the norm, no
-projection bias) — imported, not copied, and read here at ONE group of
-B and C that all the heads share.  This family's own:
+`mamba_chunk_size` — on a TPU, at the published 64 heads of 64 channels
+and chunks of 256, `models/ssd_kernel.py`'s kernels: the `[256, 256]`
+decays and weighted scores of a head and the state between the chunks
+live in VMEM; anywhere else the einsums — the `D` term, the gate BEFORE
+the norm, no projection bias) — imported, not copied, and read here at
+ONE group of B and C that all the heads share.  This family's own:
 
   * a block of two branches, each added back times
     `residual_multiplier`, and the three other scalars: the embedding
@@ -359,7 +362,8 @@ class GraniteHybridTask(lm.TokenRowsTask):
     model_type = "granitemoehybrid"
     config_cls = GraniteHybridConfig
     slots_a_token = 0            # no expert layer: the `moe.*` read 0
-    counter_names = lm.COUNTERS + ("ssm.chunks", "attn.pairs_window",
+    counter_names = lm.COUNTERS + ("ssm.chunks", "ssm.kernel_chunks",
+                                   "attn.pairs_window",
                                    "attn.pairs_full", "attn.block_pairs",
                                    "attn.kernel_block_pairs",
                                    "attn.norm_rope_rows",
@@ -379,7 +383,8 @@ class GraniteHybridTask(lm.TokenRowsTask):
 
     def own_counts(self, rows) -> tuple:
         """`ssm.chunks` as `nemotron_h` counts them (chunks scanned by
-        one pass: every row of the slab through every Mamba-2 layer);
+        one pass: every row of the slab through every Mamba-2 layer)
+        and `ssm.kernel_chunks`, those the kernel scanned;
         `attn.pairs_window` (0: no layer slides), `attn.pairs_full`,
         `attn.block_pairs` and `attn.kernel_block_pairs` of one pass as
         `afmoe` counts them, in units of PAIRS_UNIT pairs;
@@ -391,7 +396,8 @@ class GraniteHybridTask(lm.TokenRowsTask):
         full, blocks = (rows.shape[0] * n // PAIRS_UNIT
                         for n in pair_counts(c))
         heads = c.num_attention_heads // c.num_key_value_heads
-        return (rows.shape[0] * c.chunks_a_row * c.layers(MAMBA),
+        chunks = rows.shape[0] * c.chunks_a_row * c.layers(MAMBA)
+        return (chunks, chunks * nemotron_h.kernel_chunks(rows.shape[0], c),
                 0, full, blocks, blocks * lm.kernel_attends(
                     (rows.shape[0], c.sequence_length, c.num_key_value_heads,
                      heads, c.head_dim), c.attention_block),

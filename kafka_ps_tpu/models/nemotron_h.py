@@ -27,9 +27,11 @@ counters and the task's frame are `models/lm_common.py`'s, shared with
     `out = y W_out`.  The recurrence is computed in chunks of
     `chunk_size` tokens (`ssd_chunked`): inside a chunk as products
     with the lower-triangular matrix of decays, each chunk's own
-    contribution to its end state, and a `lax.scan` over the chunks
-    that hands the state on; `sequence_length` must be a whole number
-    of chunks;
+    contribution to its end state, and the state handed on from chunk
+    to chunk — on a TPU, at chunks and a state of whole lanes, as
+    `models/ssd_kernel.py`'s kernels (the decays and the state in
+    VMEM), anywhere else as einsums and a `lax.scan` over the chunks;
+    `sequence_length` must be a whole number of chunks;
   * attention: `num_attention_heads` query heads, `num_key_value_heads`
     key/value heads of `head_dim`, scores ÷ √head_dim, causal softmax,
     no bias and NO positional encoding (the published modelling code
@@ -38,9 +40,10 @@ counters and the task's frame are `models/lm_common.py`'s, shared with
     expert the same form at its own width.
 
 Every block is recomputed in the backward pass (`jax.checkpoint`), the
-chunked scan with it.  The blocks are written out in their published
-order, each with leaves of its own (`b<i>.<name>`): the pattern is not
-periodic, so there is no stack to scan.
+chunked scan with it (its kernels' forward call once more).  The blocks
+are written out in their published order, each with leaves of its own
+(`b<i>.<name>`): the pattern is not periodic, so there is no stack to
+scan.
 
 Assumed, where the published config says nothing (each also noted in
 the benchmark's reference): the selection bias held fixed at zero;
@@ -56,12 +59,14 @@ depthwise convolution of kernel k left to itself);
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
 from kafka_ps_tpu.models import lm_common as lm
+from kafka_ps_tpu.models import ssd_kernel
 from kafka_ps_tpu.models.lm_common import rms_norm, sub  # noqa: F401
 
 
@@ -232,28 +237,14 @@ def causal_conv(x, w, bias):
     return out
 
 
-def ssd_chunked(x, dt, a, bm, cm, chunk: int):
-    """The state-space recurrence `H_t = exp(Δ_t A) H_t−1 + Δ_t x_t ⊗
-    B_t`, `y_t = H_t C_t` from a zero state, in chunks of `chunk`
-    tokens.  `x` `[B, S, heads, P]`, `dt` `[B, S, heads]` (Δ, positive),
-    `a` `[heads]` (negative), `bm`, `cm` `[B, S, groups, N]` → `[B, S,
-    heads, P]`.
-
-    With `cum` the running sum of Δ·A inside a chunk: a token s reaches
-    a later token l of its chunk with decay `exp(cum_l − cum_s)` (the
-    lower-triangular products), reaches the chunk's end with
-    `exp(cum_end − cum_s)` (the chunk's own state), and the state that
-    enters a chunk reaches its token l with `exp(cum_l)`; the chunks'
-    states are handed on by a scan, `H_end = exp(cum_end) H_enter +
-    own`."""
-    b, s, h, p = x.shape
-    g, n = bm.shape[2:]
-    nc, r = s // chunk, h // g
-    da = (dt * a).reshape(b, nc, chunk, g, r)
-    xd = (x * dt[..., None]).reshape(b, nc, chunk, g, r, p)
-    bm = bm.reshape(b, nc, chunk, g, n)
-    cm = cm.reshape(b, nc, chunk, g, n)
-    cum = jnp.cumsum(da, axis=2)                       # [b, nc, Q, g, r]
+def chunks_scanned(xd, cum, bm, cm):
+    """The recurrence over a row's chunks in plain `jax.numpy`: `xd` =
+    Δ·x `[b, chunks, Q, g, r, P]`, `cum` `[b, chunks, Q, g, r]`, `bm`,
+    `cm` `[b, chunks, Q, g, N]` -> y `[b, chunks, Q, g, r, P]`.  The
+    decay of every head, `[b, chunks, Q, Q, g, r]`, is the largest
+    array it makes."""
+    b, nc, chunk, g, r, p = xd.shape
+    n = bm.shape[-1]
     # inside a chunk: target l, source s <= l
     seg = cum[:, :, :, None] - cum[:, :, None, :]      # [b, nc, l, s, g, r]
     lower = jnp.tril(jnp.ones((chunk, chunk), bool))[:, :, None, None]
@@ -270,11 +261,94 @@ def ssd_chunked(x, dt, a, bm, cm, chunk: int):
         return through_c[..., None, None] * state + own_c, state
 
     _, entering = jax.lax.scan(
-        hand_on, jnp.zeros((b, g, r, p, n), x.dtype),
+        hand_on, jnp.zeros((b, g, r, p, n), xd.dtype),
         (own.swapaxes(0, 1), through.swapaxes(0, 1)))
-    y = y + jnp.einsum("bclgn,cbgrpn->bclgrp", cm, entering) \
+    return y + jnp.einsum("bclgn,cbgrpn->bclgrp", cm, entering) \
         * jnp.exp(cum)[..., None]
-    return y.reshape(b, s, h, p)
+
+
+def _chunks_scanned_kernel(packed, dt, cum, d, groups, state):
+    """`chunks_scanned` over Δ·x, plus `d`·x, as `ssd_kernel.scan` runs
+    it on x, B and C side by side: the product with Δ and the D term
+    inside."""
+    b, nc, chunk, g, r = cum.shape
+    return ssd_kernel.scan(packed, dt, cum.reshape(b, nc * chunk, g * r), d,
+                           groups, state, chunk)
+
+
+def ssd_chunked(x, dt, a, bm, cm, chunk: int, d=None, packed=None):
+    """The state-space recurrence `H_t = exp(Δ_t A) H_t−1 + Δ_t x_t ⊗
+    B_t`, `y_t = H_t C_t` from a zero state, in chunks of `chunk`
+    tokens.  `x` `[B, S, heads, P]`, `dt` `[B, S, heads]` (Δ, positive),
+    `a` `[heads]` (negative), `bm`, `cm` `[B, S, groups, N]` → `[B, S,
+    heads, P]`; with `d` `[heads]`, the mixer's D, `y_t + d · x_t`;
+    `packed`: the array `[B, S, heads x P + 2 x groups x N]` that x,
+    `bm` and `cm` are the three parts of, where the caller has it (the
+    kernels read its lanes where they lie; without it the three are
+    laid side by side for them).
+
+    With `cum` the running sum of Δ·A inside a chunk: a token s reaches
+    a later token l of its chunk with decay `exp(cum_l − cum_s)` (the
+    lower-triangular products), reaches the chunk's end with
+    `exp(cum_end − cum_s)` (the chunk's own state), and the state that
+    enters a chunk reaches its token l with `exp(cum_l)`; the chunks'
+    states are handed on in order, `H_end = exp(cum_end) H_enter +
+    own`.
+
+    One computation, two ways to run it, and the input says which, as
+    `lm_common.blocked_attention` chooses its core.  On a TPU, at
+    shapes the kernel takes (`ssd_kernel.takes`: chunks and state of
+    whole lanes, heads of 64 channels in whole sublanes a group), it is
+    `ssd_kernel.scan`: a head's `[Q, Q]` decay and weighted scores are
+    formed in VMEM and never written, and the state stays there while
+    the chunks go by.  Anywhere else — another platform, a chunk of 16
+    — it is `chunks_scanned`, plain `jax.numpy` (einsums and a
+    `lax.scan` over the chunks), and the program is what it was before
+    there was a kernel.  The discretisation and the running sum are
+    plain either way."""
+    b, s, h, p = x.shape
+    g, n = bm.shape[2:]
+    nc, r = s // chunk, h // g
+    taken = ssd_kernel.takes(x.shape, g, n, chunk)
+
+    def scaled(x, dt):
+        return (x * dt[..., None]).reshape(b, nc, chunk, g, r, p)
+
+    def plain(x, xd, cum, bm, cm, d):
+        y = chunks_scanned(xd, cum, bm, cm).reshape(b, s, h, p)
+        return y if d is None else y + d[:, None] * x
+    da = (dt * a).reshape(b, nc, chunk, g, r)
+    # (the kernels form Δ·x themselves; handed to both ways it would be
+    # differentiated, and its zero cotangent multiplied out, on a TPU too)
+    xd = None if taken else scaled(x, dt)
+    bm = bm.reshape(b, nc, chunk, g, n)
+    cm = cm.reshape(b, nc, chunk, g, n)
+    cum = jnp.cumsum(da, axis=2)                       # [b, nc, Q, g, r]
+    if not taken:
+        return plain(x, xd, cum, bm, cm, d)
+    if packed is None:
+        packed = jnp.concatenate(
+            [m.reshape(b, s, -1) for m in (x, bm, cm)], axis=-1)
+
+    def einsums(packed, dt, cum, d):
+        x, bm, cm = (m.reshape(b, nc, chunk, g, -1) for m in jnp.split(
+            packed, [h * p, h * p + g * n], axis=-1))
+        x = x.reshape(b, s, h, p)
+        return plain(x, scaled(x, dt), cum, bm, cm, d)
+    return jax.lax.platform_dependent(
+        packed, dt, cum, jnp.zeros((h,), x.dtype) if d is None else d,
+        default=einsums, tpu=functools.partial(
+            _chunks_scanned_kernel, groups=g, state=n))
+
+
+def kernel_chunks(rows: int, c):
+    """1 where `mamba2` under `c` scans `rows` rows' chunks as the
+    kernel, 0 where plain: chosen as `ssd_chunked` chooses."""
+    if not ssd_kernel.takes((rows, c.sequence_length, c.mamba_num_heads,
+                             c.mamba_head_dim), c.n_groups,
+                            c.ssm_state_size, c.chunk_size):
+        return 0
+    return jax.lax.platform_dependent(tpu=lambda: 1, default=lambda: 0)
 
 
 def gated_group_norm(y, z, w, groups: int, eps: float):
@@ -305,8 +379,8 @@ def mamba2(u, p: dict, c: NemotronHConfig):
             bm = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
             cm = xbc[..., inner + g * n:].reshape(b, s, g, n)
             y = ssd_chunked(x, jax.nn.softplus(dt + p["dt_bias"]),
-                            -jnp.exp(p["A_log"]), bm, cm, c.chunk_size)
-            y = (y + p["D"][:, None] * x).reshape(b, s, inner)
+                            -jnp.exp(p["A_log"]), bm, cm, c.chunk_size,
+                            p["D"], xbc).reshape(b, s, inner)
         with jax.named_scope("kps.ssm.norm"):
             y = gated_group_norm(y, z, p["gate_norm"], g,
                                  c.layer_norm_epsilon)
@@ -408,7 +482,7 @@ class NemotronHTask(lm.TokenRowsTask):
 
     model_type = "nemotron_h"
     config_cls = NemotronHConfig
-    counter_names = lm.COUNTERS + ("ssm.chunks",)
+    counter_names = lm.COUNTERS + ("ssm.chunks", "ssm.kernel_chunks")
 
     def leaf_specs(self):
         return leaf_specs(self.arch)
@@ -428,6 +502,8 @@ class NemotronHTask(lm.TokenRowsTask):
 
     def own_counts(self, rows) -> tuple:
         """`ssm.chunks`: chunks scanned by one pass, every row of the
-        slab through every Mamba-2 block."""
-        return (rows.shape[0] * self.arch.chunks_a_row
-                * self.arch.kinds("M"),)
+        slab through every Mamba-2 block; `ssm.kernel_chunks`: those
+        the kernel scanned (`kernel_chunks`)."""
+        chunks = (rows.shape[0] * self.arch.chunks_a_row
+                  * self.arch.kinds("M"))
+        return chunks, chunks * kernel_chunks(rows.shape[0], self.arch)

@@ -10,6 +10,7 @@ nothing from this module by itself."""
 
 import collections
 import importlib.util
+import math
 import os
 import re
 import sys
@@ -19,6 +20,7 @@ import pytest
 
 from kafka_ps_tpu.models import lm_common as lm
 from kafka_ps_tpu.models import placement_kernel
+from kafka_ps_tpu.models import ssd_kernel
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -278,6 +280,50 @@ def norm_and_rope_are_one_kernel_pass(chunk, rotating, plain):
         r"kps\.attn\.norm_rope/(slice|neg|concatenate)\"", line)]
     assert not [line for line in copies if re.search(
         r"= f32\[(1,)?4096,32,128\]", line)]
+
+
+def the_scan_is_the_kernels_and_no_decay_is_written(chunk, mixers):
+    """Where `ssd_kernel.takes` the Mamba-2 mixers' shape, the chunked
+    scan is Mosaic calls lowered for the chip, ALL under
+    `kps.ssm.scan` — the scope `ssm_scan_roofline_share`,
+    `ssm_share` and benchmark/self_time.py find the scan's device time
+    by, forward, recomputed and backward — and none under another or no
+    name: a forward one a mixer a pass, recomputed with the block in a
+    gradient pass (2 x 2 + the loss's = 5), and a backward one a
+    gradient pass (2), as the attention core's are counted.
+
+    And what the plain lines wrote is gone with them: NO float32 array
+    of chunks x Q x Q x heads elements — the decay `[b, chunks, l, s, g,
+    r]`, its product with the scores, their cotangents — is made
+    anywhere in the program, in any order of its axes, and nothing
+    under the scope is as large (before PR 49 `f32[1,8,256,256,1,64]`,
+    134 MB a layer a pass, was the largest array the Granite cell's
+    scope made; `f32[1,8,128,128,8,8]`, 33.5 MB, the Nemotron cell's).
+    Counts from the text, never a time."""
+    c = chunk.task.arch
+    chunks, q, heads = c.chunks_a_row, c.chunk_size, c.mamba_num_heads
+    assert ssd_kernel.takes((1, c.sequence_length, heads, c.mamba_head_dim),
+                            c.n_groups, c.ssm_state_size, q)
+    calls = mosaic_calls(chunk.text, "kps_ssd_")
+    assert by_kernel_and_scope(calls, ("kps.ssm.scan",)) == {
+        ("kps_ssd_forward", "kps.ssm.scan"): 5 * mixers,
+        ("kps_ssd_backward", "kps.ssm.scan"): 2 * mixers}
+    # y `[S, heads x P]` forward; backward dx in its lanes of `[x | B | C]`
+    assert {(kernel, made) for kernel, made, *_ in calls} == {
+        ("kps_ssd_forward", f"f32[1,{c.sequence_length},{c.mamba_inner}]"),
+        ("kps_ssd_backward", f"f32[1,{c.sequence_length},{c.conv_dim}]")}
+
+    def axes(shape):
+        return sorted(d for d in shape if d > 1)
+    decays = [axes((chunks, q, q, heads)),
+              axes((chunks, q, q, c.n_groups, heads // c.n_groups))]
+    assert not [sh for sh in shapes_made(chunk.text, "f32")
+                if axes(sh) in decays]
+    under = shapes_made("\n".join(
+        line for line in chunk.text.splitlines()
+        if "kps.ssm.scan" in line), "f32")
+    assert under and max(math.prod(sh) for sh in under) \
+        < chunks * q * q * heads
 
 
 def norm_and_rope_are_the_plain_lines(chunk, lines):

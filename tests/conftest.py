@@ -34,17 +34,17 @@ def pytest_configure(config):
 
 @pytest.fixture
 def the_tpus_branch(monkeypatch):
-    """The attention core, a head's norm and RoPE and the expert
-    layer's placement as the chip runs them, on the CPU: every
-    `jax.lax.platform_dependent` takes its `tpu` branch and the kernels
-    run in Pallas's interpreter.  The test's own steering; the program
+    """The attention core, a head's norm and RoPE, the expert layer's
+    placement and the state-space scan as the chip runs them, on the
+    CPU: every `jax.lax.platform_dependent` takes its `tpu` branch and
+    the kernels run in Pallas's interpreter.  The test's own steering; the program
     has no option that does this."""
     import jax
 
     from kafka_ps_tpu.models import (attention_kernel, norm_rope_kernel,
-                                     placement_kernel)
+                                     placement_kernel, ssd_kernel)
     kernel, multiply = attention_kernel.attend, placement_kernel.multiply
-    norm_rope = norm_rope_kernel.norm_rope
+    norm_rope, scan = norm_rope_kernel.norm_rope, ssd_kernel.scan
     monkeypatch.setattr(jax.lax, "platform_dependent",
                         lambda *args, tpu, default: tpu(*args))
     monkeypatch.setattr(
@@ -54,6 +54,7 @@ def the_tpus_branch(monkeypatch):
         norm_rope_kernel, "norm_rope",
         lambda x, w, cos, sin, eps, scale=1.0: norm_rope(
             x, w, cos, sin, eps, scale, True))
+    monkeypatch.setattr(ssd_kernel, "scan", lambda *args: scan(*args, True))
     monkeypatch.setattr(
         placement_kernel, "multiply",
         lambda x, plan, back, passes, chunk=None: multiply(

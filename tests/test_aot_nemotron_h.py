@@ -12,16 +12,17 @@ def test_nemotron_hs_chunk_fits_the_chip_and_walks_its_widths_in_told_tiles(
         aot, chunk):
     """667.0 M parameters held, 1 row a worker at the cell's own
     sequence length.  The leaves are donated and there is no second
-    copy of the shared leaves: the scratch reads 9.09 GB at the cell's
-    1,024 tokens (7.55 while the fold's running sum went through the
-    barrier, before PR 47: the reading counts the sum's carried buffer
-    twice, what is alive at once is the parent's 8,839,067,136 bytes,
-    tests/aot_described.py; 7.66 before PR 40; 9.01 GB at 2,048, to the
-    byte what the chip's backend reported, PR 31), a copy of the
+    copy of the shared leaves: the scratch reads 9.03 GB at the cell's
+    1,024 tokens (9.09 before PR 49, while the scan wrote its decays;
+    7.55 while the fold's running sum went through the barrier, before
+    PR 47: the reading counts the sum's carried buffer twice; 7.66
+    before PR 40; 9.01 GB at 2,048, to the byte what the chip's backend
+    reported, PR 31), what is alive at once is 8,805,378,048 bytes
+    (8,839,067,136 before PR 49, tests/aot_described.py), a copy of the
     parameters is 2.67 GB, and the limit is the reading and a tenth.
     About 90 s."""
-    described.leaves_are_donated_and_fit(chunk, 666_963_456, 10.0e9,
-                                         8_839_067_136)
+    described.leaves_are_donated_and_fit(chunk, 666_963_456, 9.94e9,
+                                         8_805_378_048)
     # every grouped product — the two of an expert, their dx and dW,
     # under the bound's 768 rows and over it at 6,144 — runs the chip's
     # kernel in the tiles `grouped_tiles` states for the call's OWN
@@ -36,8 +37,12 @@ def test_nemotron_hs_chunk_fits_the_chip_and_walks_its_widths_in_told_tiles(
     assert all(tiles == lm.grouped_tiles(*shape) for shape, tiles in calls), \
         sorted(set(calls))
     assert not any(tiles.endswith(",128,128") for _, tiles in calls)
-    # the chunked scan is in the program under its own scope
+    # the chunked scan is in the program under its own scope, as the
+    # kernels (eight groups of eight heads, chunks of 128), and no decay
+    # `f32[1,8,128,128,8,8]` is written
     assert "kps.ssm.scan" in chunk.text and "kps.attn" in chunk.text
+    described.the_scan_is_the_kernels_and_no_decay_is_written(
+        chunk, mixers=4)
 
 
 def test_nemotron_hs_taken_branch_of_the_bound_writes_no_zeros(aot, chunk):

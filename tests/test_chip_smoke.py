@@ -27,7 +27,8 @@ TINY = chip_smoke.Sizes(
     core_window=200, core_block=128,
     core_calls=1,
     placement_shape=(512, 1024, 128), placement_groups=(90, 0, 37, 60),
-    norm_rope_positions=40, norm_rope_heads=(8, 2))
+    norm_rope_positions=40, norm_rope_heads=(8, 2),
+    scan_shapes=(((1, 256, 8, 64), 1, 128, 128),))
 
 
 def _run(script_dir, env_extra, *args):
@@ -114,6 +115,19 @@ def test_norm_rope_phase():
     with pytest.raises(chip_smoke.SmokeFailure, match="does not take"):
         chip_smoke.phase_norm_rope(
             chip_smoke.Sizes(norm_rope_positions=40, norm_rope_dim=16),
+            "cpu", interpret=True)
+
+
+def test_ssd_scan_phase():
+    """The kernels in Pallas's interpreter against the einsums on a row
+    of two chunks: the gaps are bfloat16's (the CPU's einsums are
+    float32), the times are the CPU's and mean nothing."""
+    rec = chip_smoke.phase_ssd_scan(TINY, "cpu", interpret=True)
+    for what in ("y", "dx", "ddt", "da", "db", "dc", "dd"):
+        assert 0 < rec[f"g1_q128_{what}_gap"] < 0.02
+    with pytest.raises(chip_smoke.SmokeFailure, match="does not take"):
+        chip_smoke.phase_ssd_scan(
+            chip_smoke.Sizes(scan_shapes=(((1, 64, 8, 64), 1, 128, 16),)),
             "cpu", interpret=True)
 
 

@@ -29,6 +29,7 @@ from kafka_ps_tpu.models import attention_kernel
 from kafka_ps_tpu.models import granite_hybrid as gh
 from kafka_ps_tpu.models import lm_common as lm
 from kafka_ps_tpu.models import nemotron_h
+from kafka_ps_tpu.models import ssd_kernel
 from kafka_ps_tpu.models.task import get_task
 from kafka_ps_tpu.parallel import bsp
 from kafka_ps_tpu.utils.config import BufferConfig, ModelConfig, PSConfig
@@ -576,24 +577,28 @@ def test_the_counters_at_the_cells_size_and_the_kernel_takes_heads_of_64(
     32 updates).  Heads of 64 channels ride the attention kernel two to
     a lane vector (8 KV heads, an even number), so with the TPU's
     branch taken `attn.kernel_block_pairs` is `attn.block_pairs`, and 0
-    on the CPU; no head is normed or rotated, so the two norm-and-RoPE
-    counters read 0 on both."""
+    on the CPU; the scan's chunks of 256 under 64 heads of 64 channels
+    are `ssd_kernel`'s the same way, `ssm.kernel_chunks` `ssm.chunks`
+    there and 0 here; no head is normed or rotated, so the two
+    norm-and-RoPE counters read 0 on both."""
     task = get_task(TASK, ModelConfig(model_json=PUBLISHED))
     c = task.arch
     q_shape = (1, c.sequence_length, c.num_key_value_heads,
                c.num_attention_heads // c.num_key_value_heads, c.head_dim)
     assert (q_shape, c.attention_block) == ((1, 2048, 8, 4, 64), 512)
     assert attention_kernel.takes(q_shape, c.attention_block)
+    assert ssd_kernel.takes((1, 2048, 64, 64), c.n_groups, 128, 256)
     slab = jax.ShapeDtypeStruct((1, task.row_width), jnp.int32)
     assert gh.pair_counts(c) == (2_098_176, 2_621_440)
-    want = {"ssm.chunks": 72, "attn.pairs_window": 0,
-            "attn.pairs_full": 2049, "attn.block_pairs": 2560,
+    want = {"ssm.chunks": 72, "ssm.kernel_chunks": 0,
+            "attn.pairs_window": 0, "attn.pairs_full": 2049, "attn.block_pairs": 2560,
             "attn.kernel_block_pairs": 0, "attn.norm_rope_rows": 0,
             "attn.norm_rope_kernel_rows": 0, "mlp.rows": 20}
     names = task.counter_names[len(lm.COUNTERS):]
     assert dict(zip(names, (int(n) for n in task.own_counts(slab)))) == want
     request.getfixturevalue("the_tpus_branch")
-    on_the_chip = dict(want, **{"attn.kernel_block_pairs": 2560})
+    on_the_chip = dict(want, **{"attn.kernel_block_pairs": 2560,
+                                "ssm.kernel_chunks": 72})
     assert dict(zip(names, (int(n) for n in task.own_counts(slab)))) \
         == on_the_chip
     # int32 a dispatch: a chunk of 32 updates x 3 passes
@@ -621,7 +626,8 @@ def test_the_counters_count_through_fit_counted_at_rows_of_a_unit(tmp_path):
     assert counted["attn.pairs_full"] == 2 * (2 * 128 * 129 // 2 // 1024)
     assert counted["attn.block_pairs"] == 2 * (2 * 128 * 128 // 1024)
     for name in ("attn.pairs_window", "attn.kernel_block_pairs",
-                 "attn.norm_rope_rows", "attn.norm_rope_kernel_rows"):
+                 "attn.norm_rope_rows", "attn.norm_rope_kernel_rows",
+                 "ssm.kernel_chunks"):
         assert counted[name] == 0, name
 
 

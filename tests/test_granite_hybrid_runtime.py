@@ -31,9 +31,10 @@ def counted(task, counters):
     assert gh.pair_counts(task.arch) == (528, 1024)
     assert counters["attn.pairs_full"] == 32 * 3 * 1
     assert counters["attn.block_pairs"] == 32 * 3 * 2
-    for name in ("attn.pairs_window", "attn.kernel_block_pairs",
-                 "attn.norm_rope_rows", "attn.norm_rope_kernel_rows",
-                 "mlp.rows"):
+    # (the kernels are the chip's: a chunk of 16 tokens, on the CPU)
+    for name in ("ssm.kernel_chunks", "attn.pairs_window",
+                 "attn.kernel_block_pairs", "attn.norm_rope_rows",
+                 "attn.norm_rope_kernel_rows", "mlp.rows"):
         assert counters[name] == 0, name
     for name in lm.COUNTERS:
         if name.startswith("moe."):
@@ -46,7 +47,7 @@ FAMILY = Family(
     digests="granite_hybrid_tiny_stablehlo.json", reads=reads,
     counted=counted,
     counter_names=lm.COUNTERS + (
-        "ssm.chunks", "attn.pairs_window", "attn.pairs_full",
-        "attn.block_pairs", "attn.kernel_block_pairs", "attn.norm_rope_rows",
-        "attn.norm_rope_kernel_rows", "mlp.rows"),
+        "ssm.chunks", "ssm.kernel_chunks", "attn.pairs_window",
+        "attn.pairs_full", "attn.block_pairs", "attn.kernel_block_pairs",
+        "attn.norm_rope_rows", "attn.norm_rope_kernel_rows", "mlp.rows"),
     slots_a_token=0)            # no expert layer

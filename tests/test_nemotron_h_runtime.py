@@ -17,11 +17,13 @@ def reads(c):
 def counted(task, counters):
     # 32 updates x (k + 1) passes x 2 rows x 4 chunks x 2 Mamba-2 blocks
     assert counters["ssm.chunks"] == 32 * 3 * 2 * task.arch.chunks_a_row * 2
+    # the kernel is the chip's, and takes no chunk of 16 tokens
+    assert counters["ssm.kernel_chunks"] == 0
 
 
 FAMILY = Family(
     name="nemotron_h", module=nh,
     tiny="benchmark/families/nemotron-h/tiny.model.json",
     digests="nemotron_tiny_stablehlo.json", reads=reads, counted=counted,
-    counter_names=lm.COUNTERS + ("ssm.chunks",),
+    counter_names=lm.COUNTERS + ("ssm.chunks", "ssm.kernel_chunks"),
     slots_a_token=2 * 2)        # 2 experts in each of 2 expert blocks
