@@ -529,14 +529,18 @@ def apply_platform_env() -> None:
     socket roles, cli/socket_mode.py), run before first backend use:
     KPS_PLATFORM pins the JAX platform for deployments that set the
     program's own variable (deploy/: =cpu for a broker-less smoke run
-    or a CPU-mesh CI job — plain JAX_PLATFORMS works the same), and the
-    persistent compile cache is placed (utils/device.py)."""
+    or a CPU-mesh CI job — plain JAX_PLATFORMS works the same), the
+    persistent compile cache is placed and the start-up record's
+    listeners for `jax.monitoring`'s build events stand (utils/device.py:
+    the record's `import` phase ends and its `backend` phase begins
+    where this returns)."""
     from kafka_ps_tpu.utils import device
     platform = os.environ.get("KPS_PLATFORM")
     if platform:
         import jax
         jax.config.update("jax_platforms", platform)
     device.configure_compile_cache()
+    device.env_ready()
 
 
 def announce_device(cfg, fused: bool = False) -> None:
@@ -632,7 +636,20 @@ def run_with_args(args) -> int:
         for k, v in sorted(vars(args).items()):
             print(f"    {k}: {v}")
     announce_device(cfg_from_args(args), fused=args.fused)
+    # the profiler's session opens before the app is built: the
+    # start-up phase `kps.setup.app_init` and the first call's builds
+    # then lie on the host plane of the same trace as the device's
+    # operations (docs/OBSERVABILITY.md "One clock"); whatever way the
+    # run ends, the session is stopped and its trace written
+    from kafka_ps_tpu.utils.trace import device_trace
+    with device_trace(args.device_trace):
+        return _run_announced(args, distributed, tier_hot, tier_warm)
 
+
+def _run_announced(args, distributed: bool, tier_hot: int,
+                   tier_warm: int) -> int:
+    """`run_with_args` from the `[device]` line on: the app built, the
+    feed started, the drive call and the teardown."""
     process_index = 0
     if distributed:
         import jax
@@ -821,22 +838,20 @@ def run_with_args(args) -> int:
     app.wait_for_stream_settle(producer)
 
     max_iters = args.max_iterations or sys.maxsize
-    from kafka_ps_tpu.utils.trace import device_trace
     try:
-        with device_trace(args.device_trace):
-            status_every = getattr(args, "status_every", 0.0)
-            if args.fused:
-                app.run_fused_bsp(max_server_iterations=max_iters,
-                                  mesh=mesh, status_every=status_every)
-            elif args.mode == "serial":
-                app.run_serial(max_server_iterations=max_iters,
-                               pump=lambda: None,
-                               status_every=status_every)
-            else:
-                app.run_threaded(max_server_iterations=max_iters,
-                                 failure_policy=args.failure_policy,
-                                 heartbeat_timeout=args.heartbeat_timeout,
-                                 status_every=status_every)
+        status_every = getattr(args, "status_every", 0.0)
+        if args.fused:
+            app.run_fused_bsp(max_server_iterations=max_iters,
+                              mesh=mesh, status_every=status_every)
+        elif args.mode == "serial":
+            app.run_serial(max_server_iterations=max_iters,
+                           pump=lambda: None,
+                           status_every=status_every)
+        else:
+            app.run_threaded(max_server_iterations=max_iters,
+                             failure_policy=args.failure_policy,
+                             heartbeat_timeout=args.heartbeat_timeout,
+                             status_every=status_every)
     except KeyboardInterrupt:
         print("interrupted — shutting down", file=sys.stderr)
         app.stop()

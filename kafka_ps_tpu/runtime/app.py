@@ -32,7 +32,7 @@ from kafka_ps_tpu.runtime import fabric as fabric_mod
 from kafka_ps_tpu.runtime.server import LogSink, ServerNode
 from kafka_ps_tpu.runtime.worker import WorkerNode
 from kafka_ps_tpu.telemetry import NULL_TELEMETRY
-from kafka_ps_tpu.utils import asynclog
+from kafka_ps_tpu.utils import asynclog, device
 from kafka_ps_tpu.utils.asynclog import DeferredSink
 from kafka_ps_tpu.utils.config import PSConfig, SEQUENTIAL
 from kafka_ps_tpu.utils.trace import NULL_TRACER
@@ -63,6 +63,18 @@ class StreamingPSApp:
                  telemetry=None):
         self.tracer = tracer or NULL_TRACER
         self.telemetry = telemetry or NULL_TELEMETRY
+        # the start-up record's `app_init` phase (utils/device.py): the
+        # whole assembly, and from here on the record's builds and
+        # retroactive phases are this tracer's spans too
+        device.attach(self.tracer)
+        with device.setup_phase("app_init", self.tracer):
+            self._assemble(cfg, test_x, test_y, server_log, worker_log,
+                           clock_ms, fabric)
+
+    def _assemble(self, cfg, test_x, test_y, server_log, worker_log,
+                  clock_ms, fabric) -> None:
+        """`__init__`'s work: the task and its flat vector, the test
+        set placed, buffers, nodes, evaluation engine, sinks."""
         self.cfg = cfg
         # callers may supply a durable fabric (log/durable_fabric.py,
         # `--durable-log`); default stays the volatile in-memory one
@@ -440,7 +452,8 @@ class StreamingPSApp:
     def _record_run(self, path: str, t_call: float, backlog0, **more) -> None:
         """`last_run` of the drive call that began at `t_call` with the
         sinks' backlog counts at `backlog0`.  On every path it holds
-        `path` ("serial" / "fused"), `seconds` (the whole call),
+        `path` ("serial" / "threaded" / "fused"), `seconds` (the whole
+        call),
         `slab_refreshes` / `slab_refresh_s` / `slab_refresh_bytes`
         (NO_SLAB_REFRESH where nothing was uploaded), `theta_up_s` /
         `device_wait_s` / `theta_down_s` (a folded task's flat vector
@@ -453,6 +466,9 @@ class StreamingPSApp:
                          "seconds": time.perf_counter() - t_call, **more,
                          "log_backlog_waits": waits - backlog0[0],
                          "log_backlog_wait_s": wait_s - backlog0[1]}
+        # the start-up record keeps every call's start stamp and seconds,
+        # and the process's first call whole (utils/device.py)
+        device.record_call(self.last_run, self.telemetry)
 
     def close_logs(self) -> None:
         """Close the deferred sinks: joins their drain threads (which
@@ -669,6 +685,7 @@ class StreamingPSApp:
                     evict(w, f"no heartbeat for {heartbeat_timeout}s")
 
         reporter = self._start_status(status_every)
+        t_call, backlog0 = time.perf_counter(), self._log_backlog()
         try:
             self.server.start_training_loop()
             while self.server.iterations < max_server_iterations:
@@ -707,6 +724,8 @@ class StreamingPSApp:
             for t in threads.values():
                 t.join(timeout=60.0)
             self.flush_logs()
+        self._record_run("threaded", t_call, backlog0, **NO_SLAB_REFRESH,
+                         **NO_CALL_EDGES)
         if worker_errors:
             raise RuntimeError("worker thread failed") from worker_errors[0]
 
@@ -965,6 +984,7 @@ class StreamingPSApp:
                 self.tracer.count("bsp.steps")
                 clock += r
                 self.server.iterations += r * len(active)
+                device.mark("first_update")
                 with self.tracer.span("fused.publish"):
                     # theta is updated by replacement everywhere
                     # (runtime/server module doc), so the device array

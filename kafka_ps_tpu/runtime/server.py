@@ -46,7 +46,7 @@ from kafka_ps_tpu.telemetry import (CLOCK_BUCKETS, NULL_TELEMETRY,
                                     model_name)
 from kafka_ps_tpu.telemetry.flight import FLIGHT
 from kafka_ps_tpu.telemetry.modelhealth import NULL_MODEL_HEALTH
-from kafka_ps_tpu.utils import asynclog
+from kafka_ps_tpu.utils import asynclog, device
 from kafka_ps_tpu.utils.config import EVENTUAL, PSConfig
 from kafka_ps_tpu.utils.trace import NULL_TRACER
 
@@ -702,6 +702,7 @@ class ServerNode:
                 host[lo:hi] += self.cfg.server_lr * np.asarray(msg.values)
                 self.theta = host
             self.iterations += 1
+            device.mark("first_update")
 
         if fused_eval:
             if m is None:            # partial-range splice path
@@ -1020,6 +1021,7 @@ class ServerNode:
                                               delta.values)
             self.tracer.count("dispatch.device")
             self.iterations += len(live)
+            device.mark("first_update")
         if fused_eval:
             self._emit_eval(clock, m)
         elif defer_eval:
@@ -1151,6 +1153,7 @@ class ServerNode:
                 jnp.asarray(self.theta), self.test_x, self.test_y,
                 *[m.values for m in live])
             self.iterations += k
+            device.mark("first_update")
             for m in live:
                 fid = getattr(m, "trace", None)
                 if fid is not None:

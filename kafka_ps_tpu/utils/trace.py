@@ -83,6 +83,16 @@ class Tracer:
         if self.enabled:
             self._record(name, start, end, args)
 
+    def span_at_wall(self, name: str, start: float, end: float,
+                     **args) -> None:
+        """`span_at` for two `time.time()` stamps (a `jax.monitoring`
+        time span, a phase of the start-up record, utils/device.py):
+        they are brought to this tracer's clock by the anchor `dump()`
+        exports, so they may predate the tracer."""
+        if self.enabled:
+            shift = self._t0 - self._wall0
+            self._record(name, start + shift, end + shift, args)
+
     def _record(self, name: str, start: float, end: float,
                 args: dict) -> None:
         with self._lock:
